@@ -11,7 +11,7 @@ restart cost a cache load instead:
   ratchet, so the AOT store and the budget guard can never drift apart.
 * :mod:`~go_ibft_tpu.boot.aot` — the AOT program store: lowers and
   compiles every pinned family through JAX's persistent compilation
-  cache (``GO_IBFT_CACHE_DIR``), classifies each restore cold vs cached
+  cache (``utils/jaxcache.py``), classifies each restore cold vs cached
   by measured wall, and records cold compiles to the cost ledger.
 * :mod:`~go_ibft_tpu.boot.warmstart` — warm-start: WAL replay +
   verdict-cache seeding + compiled-program restore, all *before* the

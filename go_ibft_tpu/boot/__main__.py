@@ -6,7 +6,7 @@ requested pinned programs (recorded cold compiles on a cold cache, cache
 loads on a warm one), then bring up a small real-crypto cluster and
 finalize its first height — and prints one JSON line with the measured
 milestones.  Bench config #14 runs this as a child process twice against
-the same ``GO_IBFT_CACHE_DIR``: the first boot pays the cold compiles,
+the same ``JAX_COMPILATION_CACHE_DIR``: the first boot pays the cold compiles,
 the second proves the cache (and its compile ledger proves ZERO cold
 events).
 

@@ -1,7 +1,8 @@
 """The AOT program store: restore compiled programs before the first round.
 
 Artifacts live in two layers under the persistent cache directory
-(``GO_IBFT_CACHE_DIR``, resolved by :mod:`go_ibft_tpu.utils.jaxcache`):
+(``JAX_COMPILATION_CACHE_DIR`` where set, else ``<checkout>/.cache/xla`` —
+resolved by :mod:`go_ibft_tpu.utils.jaxcache`):
 
 * **XLA's persistent compilation cache** — jax keys entries on the HLO
   module + compile options + jax/XLA version + device topology, so a
@@ -106,12 +107,12 @@ class AOTStore:
     """Lower + compile pinned program families through the persistent
     cache, with sidecar bookkeeping for skip/report decisions.
 
-    ``cache_dir=None`` resolves through the jaxcache chain (explicit >
-    ``GO_IBFT_CACHE_DIR`` > ``JAX_COMPILATION_CACHE_DIR`` > default).
-    Note jax pins its compilation cache dir for the process on first
-    enable — an explicit ``cache_dir`` differing from an already-enabled
-    one affects only the sidecar store, so boot harnesses set
-    ``GO_IBFT_CACHE_DIR`` before importing jax-heavy modules.
+    ``cache_dir=None`` resolves through the jaxcache rule
+    (``JAX_COMPILATION_CACHE_DIR`` where set, else the in-checkout
+    default).  Note jax pins its compilation cache dir for the process on
+    first enable — an explicit ``cache_dir`` differing from an
+    already-enabled one affects only the sidecar store, so boot harnesses
+    hand their children ``JAX_COMPILATION_CACHE_DIR`` instead.
     """
 
     def __init__(

@@ -1,13 +1,12 @@
 """Spawn one real boot leg (``python -m go_ibft_tpu.boot``) and parse it.
 
 Bench config #14 measures restart-to-first-finalized by restarting the
-node FOR REAL: a fresh interpreter, fresh jax, one shared
-``GO_IBFT_CACHE_DIR``.  That process-spawning lives here — in the boot
-package that owns the child entrypoint — so ``bench.py`` keeps exactly
-one subprocess implementation (the shared backend probe,
-``utils/probe.py``).  This module must stay import-light: the PARENT
-imports it, and pulling jax in here would distort the very spawn cost
-the legs measure.
+node FOR REAL: a fresh interpreter, fresh jax, one shared compile cache
+handed to the child as ``JAX_COMPILATION_CACHE_DIR``.  That
+process-spawning lives here — in the boot package that owns the child
+entrypoint — so ``bench.py`` keeps exactly one subprocess implementation.
+This module must stay import-light: the PARENT imports it, and pulling
+jax in here would distort the very spawn cost the legs measure.
 """
 
 from __future__ import annotations
@@ -41,18 +40,17 @@ def run_boot_leg(
 ) -> dict:
     """Run one restart leg; return ``{spawn_ms, report, events}``.
 
-    The child keys its persistent cache off ``cache_dir`` alone
-    (``JAX_COMPILATION_CACHE_DIR`` is scrubbed — a user-level cache dir
-    would leak pre-warmed artifacts into the "cold" leg and fake the
-    ratio) and writes its compile ledger to ``ledger_path`` so the
-    caller can assert the cached legs recorded ZERO compile events.
+    The child receives ``cache_dir`` as ``JAX_COMPILATION_CACHE_DIR``
+    (the one rule of ``utils/jaxcache.py`` — a cold leg is a leg handed an
+    empty directory, so no other cache can leak pre-warmed artifacts into
+    it) and writes its compile ledger to ``ledger_path`` so the caller can
+    assert the cached legs recorded ZERO compile events.
     Raises :class:`BootLegTimeout` when the wall budget runs out and
     ``RuntimeError`` on a nonzero child exit.
     """
     env = dict(os.environ)
-    env["GO_IBFT_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["GO_IBFT_COMPILE_LEDGER"] = ledger_path
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     t0 = time.perf_counter()
     try:
         proc = subprocess.run(

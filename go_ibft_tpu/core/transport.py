@@ -103,7 +103,24 @@ class BatchingIngress:
     slow to fill the batch inside the ceiling flushes eagerly instead of
     idling.  ``max_delay`` stays the hard ceiling; pass
     ``calibrate=False`` for the fixed legacy window.
+
+    **Held-back arrivals.**  A flush runs on the event loop, so while one
+    blocks — a device dispatch is tens of milliseconds however few lanes
+    it carries — the transport cannot deliver: a socket transport then
+    hands over its backlog one message per loop turn, and an eager flush
+    per message turns a 100-validator flood into 100 one-lane dispatches.
+    (Both detectors above read the wall clock, so from inside that loop a
+    flood held back by slow flushes looks like a trickle.)  After a flush
+    that blocked for more than ``HELD_BACK_FACTOR`` windows the next flush
+    therefore waits the ``max_delay`` ceiling to let the backlog in — a
+    wait that is small against the flush that caused it, and one a
+    sub-cutover host flush (fractions of a millisecond per lane) never
+    triggers.
     """
+
+    # A flush that blocked the loop for more than this many ``max_delay``
+    # windows held arrivals back (see the class docstring).
+    HELD_BACK_FACTOR = 4.0
 
     def __init__(
         self,
@@ -139,6 +156,7 @@ class BatchingIngress:
         # timed window for nothing).
         self._recent: deque = deque()
         self._recent_n = 0
+        self._held_back = False
 
     def _trim_recent(self, now: float) -> None:
         while self._recent and now - self._recent[0][0] > self.max_delay:
@@ -165,7 +183,9 @@ class BatchingIngress:
         elif self._handle is None:
             loop = asyncio.get_running_loop()
             self._trim_recent(time.monotonic())
-            if self._recent_n + len(self._buffer) >= self.eager_cutover:
+            if self._held_back:
+                self._handle = loop.call_later(self.max_delay, self.flush)
+            elif self._recent_n + len(self._buffer) >= self.eager_cutover:
                 window = self._window()
                 if window > 0:
                     self._handle = loop.call_later(window, self.flush)
@@ -186,6 +206,9 @@ class BatchingIngress:
         self._recent_n += len(batch)
         self._trim_recent(now)
         self._add_messages(batch)
+        self._held_back = (
+            time.monotonic() - now > self.HELD_BACK_FACTOR * self.max_delay
+        )
 
     def close(self) -> None:
         """Drop buffered messages and cancel the pending flush timer."""

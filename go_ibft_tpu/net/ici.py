@@ -41,13 +41,13 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..messages.wire import IbftMessage
 from ..obs import ledger as cost_ledger
 from ..obs import trace
 from ..ops import quorum
-from ..parallel.mesh import shard_map
 from ..utils import metrics
 
 _LEN_BYTES = 4

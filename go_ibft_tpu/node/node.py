@@ -43,6 +43,7 @@ from ..core import IBFT, BatchingIngress
 from ..crypto import PrivateKey
 from ..crypto.backend import ECDSABackend
 from ..net import GrpcTransport
+from ..obs import ledger as cost_ledger
 from ..obs import trace
 from ..utils import metrics
 from ..verify import HostBatchVerifier
@@ -120,11 +121,12 @@ class ValidatorNode:
         if config.sched_enabled:
             from ..sched import TenantScheduler
 
-            # Route per config ("host" default).  The auto route's device
-            # cutover (>=16 lanes) would park the flush thread inside a
-            # first-flush XLA compile — wedging live rounds, the proof
-            # API's read tier AND scheduler.stop() during drain — so the
-            # device path is opt-in and pre-compiled at boot (below).
+            # Route per config ("host" default).  A device-route flush at
+            # an unwarmed shape would park the flush thread inside an XLA
+            # compile — wedging live rounds, the proof API's read tier AND
+            # scheduler.stop() during drain — so the device path is opt-in
+            # and every shape this committee can dispatch is compiled at
+            # boot (run(), below).
             self.scheduler = TenantScheduler(route=config.sched_route)
             batch_verifier = self.scheduler.register(
                 f"node{config.node_id}/consensus",
@@ -190,6 +192,7 @@ class ValidatorNode:
             )
 
         self.telemetry = None
+        self._ledger_owned = False
         self._drained = False
         self._started_at = time.monotonic()
 
@@ -204,12 +207,23 @@ class ValidatorNode:
             for sig in (signal.SIGTERM, signal.SIGINT):
                 loop.add_signal_handler(sig, stop_requested.set)
 
+        if cfg.telemetry.listen and not cost_ledger.enabled():
+            # The telemetry mount serves the cost ledger (/statusz totals,
+            # /metrics families): turn it on BEFORE the warm-up so boot
+            # compiles are on the record and "compiles after boot" reads
+            # straight off a scrape.  Process-global, so the drain turns
+            # off what this node turned on.
+            cost_ledger.enable()
+            self._ledger_owned = True
         if self.scheduler is not None:
             self.scheduler.start()
             if cfg.sched_route != "host":
-                # Compile the device kernels NOW, while /readyz is still
-                # 503 — never on the first >=cutover flush mid-round.
-                self.scheduler.warmup()
+                # Compile NOW, while /readyz is still 503, every (lane
+                # bucket, table bucket) this committee can make the
+                # dispatcher launch — derived from the validator count, so
+                # a 100-validator node never meets its first 128-lane
+                # program on the flush thread mid-round.
+                self.scheduler.warmup_committee(len(cfg.validators))
         await self.transport.start()
         bound_consensus = self.transport.bound_port
 
@@ -296,6 +310,9 @@ class ValidatorNode:
         await self.transport.stop()
         if self.telemetry is not None:
             self.runner.stop_telemetry()
+        if self._ledger_owned:
+            cost_ledger.disable()
+            self._ledger_owned = False
         self.ingress.close()
         self.engine.messages.close()
         speculator = getattr(self.engine, "speculator", None)
@@ -322,6 +339,22 @@ class ValidatorNode:
             ),
             "resumed_at_height": resumed_at,
         }
+        if self.scheduler is not None and self.config.sched_route != "host":
+            # The device the boot warm-up compiled for, as JAX reports it,
+            # and where those programs are cached.  Never on the host
+            # route: asking would initialize a backend, and a node that
+            # dispatches nothing must not claim a chip.
+            import jax
+
+            from ..utils.jaxcache import resolve_cache_dir
+
+            devices = jax.devices()
+            line["device"] = {
+                "platform": devices[0].platform,
+                "kind": devices[0].device_kind,
+                "count": len(devices),
+            }
+            line["compile_cache"] = resolve_cache_dir()
         print(json.dumps(line), flush=True)
 
     def _report(self, trace_path, trace_events: int) -> dict:

@@ -1,4 +1,4 @@
-"""Observability subsystem: flight recorder, hang-proof evidence, gates.
+"""Observability subsystem: flight recorder, evidence writer, gates.
 
 Three pillars (ISSUE 4):
 
@@ -11,12 +11,9 @@ Three pillars (ISSUE 4):
   export, so a multi-node height renders as a readable multi-track
   timeline (``bench.py --trace out.json``, ``scripts/chaos_replay.py
   --trace``).
-* :mod:`~go_ibft_tpu.obs.evidence` — hang-proof evidence capture: device
-  probing in a subprocess with a hard wall-clock deadline and a cached
-  backend fingerprint (TTL + ``--reprobe``), plus an append-only,
-  per-record-flushed JSONL evidence writer so every bench config leaves a
-  record even when the run crashes mid-way.  Supersedes
-  ``go_ibft_tpu.bench.evidence``.
+* :mod:`~go_ibft_tpu.obs.evidence` — the append-only,
+  per-record-flushed JSONL evidence writer, so every bench config leaves
+  a record even when the run crashes mid-way.
 * :mod:`~go_ibft_tpu.obs.ledger` / :mod:`~go_ibft_tpu.obs.devprof` —
   the runtime cost ledger (ISSUE 14): per-dispatch device-time
   attribution keyed by compile-budget program names, live-vs-padded
@@ -30,7 +27,7 @@ Three pillars (ISSUE 4):
 """
 
 from . import clock, devprof, ledger, trace
-from .evidence import EvidenceWriter, Fingerprint, probe_fingerprint
+from .evidence import EvidenceWriter
 from .export import to_chrome_trace, write_chrome_trace
 from .gates import GateResult, gate_evidence, gate_slo_records, render_table
 from .httpd import TelemetryServer
@@ -43,8 +40,6 @@ __all__ = [
     "ledger",
     "trace",
     "EvidenceWriter",
-    "Fingerprint",
-    "probe_fingerprint",
     "to_chrome_trace",
     "write_chrome_trace",
     "GateResult",

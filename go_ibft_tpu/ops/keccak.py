@@ -139,9 +139,11 @@ _RC_WORDS = np.asarray(
 def _pallas_mode() -> str:
     """'' (off) | 'compiled' | 'interpret' — the Pallas permutation switch.
 
-    ``GO_IBFT_PALLAS=1`` selects the Pallas kernel on TPU backends (no-op
-    elsewhere: the compiled kernel needs Mosaic); ``GO_IBFT_PALLAS=interpret``
-    forces the interpreter on any backend (tests/debugging).
+    ``GO_IBFT_PALLAS=1`` selects the compiled Pallas kernel, which needs
+    Mosaic: on a backend that cannot compile it the switch RAISES instead
+    of silently running the XLA path under the kernel's name.
+    ``GO_IBFT_PALLAS=interpret`` forces the interpreter on any backend
+    (tests/debugging).
     """
     import os
 
@@ -151,8 +153,13 @@ def _pallas_mode() -> str:
     if flag == "1":
         from .pallas_keccak import pallas_supported  # the single predicate
 
-        if pallas_supported():
-            return "compiled"
+        if not pallas_supported():
+            raise RuntimeError(
+                "GO_IBFT_PALLAS=1 asks for the compiled Pallas keccak, but "
+                f"the JAX backend is {jax.default_backend()!r}, not a TPU "
+                "(use GO_IBFT_PALLAS=interpret to run the interpreter)"
+            )
+        return "compiled"
     return ""
 
 

@@ -50,6 +50,7 @@ from ..verify.batch import (
     ADDRESS_BYTES,
     SIG_BYTES,
     _BATCH_BUCKETS,
+    _TABLE_BUCKETS,
     _bucket,
     pack_seal_lanes,
     pack_validator_table,
@@ -154,6 +155,13 @@ class CoalescedDispatcher:
         if route not in ("auto", "host", "device"):
             raise ValueError(f"unknown route {route!r}")
         self.route = route
+        if route != "host":
+            # Like DeviceBatchVerifier: whoever can launch the ladder
+            # programs keeps them in the persistent cache, so a node's
+            # second boot loads its warm-up instead of compiling it.
+            from ..utils.jaxcache import enable_persistent_cache
+
+            enable_persistent_cache()
         if cutover_lanes is None:
             from ..utils import calibration
 
@@ -177,6 +185,12 @@ class CoalescedDispatcher:
             self._mask_kernel = mesh_verify_mask(mesh)
         # The recover programs compile per lane bucket; serialize warmup.
         self._warm_lock = threading.Lock()
+        # What actually served the flushes: kernel launches per
+        # ``<route>/<padded lanes>`` and host flushes under ``host`` —
+        # counted where the work runs, so /statusz can show a device-route
+        # node really dispatching (and at which bucket), not a setting.
+        self._served_lock = threading.Lock()
+        self._served: Dict[str, int] = {}
 
     def describe(self) -> dict:
         """Shape of this dispatcher (scheduler stats / resize evidence)."""
@@ -186,6 +200,15 @@ class CoalescedDispatcher:
             "sharded": self.mesh is not None,
             "cutover": self.cutover,
         }
+
+    def served(self) -> Dict[str, int]:
+        """Launch counts by ``<route>/<padded lanes>`` (``host``: flushes)."""
+        with self._served_lock:
+            return dict(self._served)
+
+    def _note_served(self, key: str) -> None:
+        with self._served_lock:
+            self._served[key] = self._served.get(key, 0) + 1
 
     def _pad_lanes(self, n: int) -> int:
         """Mesh dispatches pin the lane dim to ``bucket(ceil(n/dp)) x dp``
@@ -246,6 +269,19 @@ class CoalescedDispatcher:
                         )
                     )
 
+    def warmup_committee(self, n_validators: int) -> None:
+        """Pre-compile what a committee of ``n_validators`` can make this
+        dispatcher launch: every lane bucket (the consensus tier flushes
+        up to one phase's worth of lanes, the read tier coalesces proof
+        ranges up to a full dispatch), each with the membership table its
+        flush would pack — one claimed address per lane, capped by the
+        committee size."""
+        for bb in _BATCH_BUCKETS:
+            self.warmup(
+                lanes=(bb,),
+                table_rows=_bucket(min(bb, n_validators), _TABLE_BUCKETS),
+            )
+
     def dispatch(
         self,
         sender_msgs: Sequence[IbftMessage],
@@ -290,6 +326,7 @@ class CoalescedDispatcher:
                     out = self._host(
                         sender_msgs, seal_lanes, pack_caches or {}
                     )
+                self._note_served("host")
         metrics.observe(DISPATCH_MS_KEY, (_time.perf_counter() - t0) * 1e3)
         metrics.observe(DISPATCH_LANES_KEY, float(total))
         return out
@@ -362,7 +399,9 @@ class CoalescedDispatcher:
                 jnp.asarray(table),
                 jnp.asarray(live),
             )
-            return np.asarray(mask)
+            mask = np.asarray(mask)
+        self._note_served(f"{'mesh' if sharded else 'device'}/{mask.shape[0]}")
+        return mask
 
     # -- host route ------------------------------------------------------
 

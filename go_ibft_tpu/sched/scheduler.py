@@ -368,6 +368,11 @@ class TenantScheduler:
         """Pre-compile the shared kernels (node startup; never mid-round)."""
         self._dispatcher.warmup(**kw)
 
+    def warmup_committee(self, n_validators: int) -> None:
+        """Pre-compile every shape a committee of ``n_validators`` can make
+        the dispatcher launch (node startup, while ``/readyz`` is 503)."""
+        self._dispatcher.warmup_committee(n_validators)
+
     def reconfigure(
         self,
         *,
@@ -869,12 +874,17 @@ class TenantScheduler:
                 round(requests / dispatches, 3) if dispatches else None
             ),
             "flush_faults": faults,
-            # Tests wrap the dispatcher in doubles without describe();
-            # degrade to the class name rather than breaking stats().
+            # Tests wrap the dispatcher in doubles without describe() /
+            # served(); degrade rather than breaking stats().
             "dispatcher": (
                 self._dispatcher.describe()
                 if hasattr(self._dispatcher, "describe")
                 else {"route": type(self._dispatcher).__name__}
+            ),
+            "served": (
+                self._dispatcher.served()
+                if hasattr(self._dispatcher, "served")
+                else None
             ),
         }
 

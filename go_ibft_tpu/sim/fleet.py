@@ -516,12 +516,20 @@ def launch_fleet(
 
     stdout/stderr land in ``node-<i>.{out,err}.log`` under ``run_dir``
     (the boot line + drain report are parsed off the .out file — the
-    ``boot/restart.py`` subprocess idiom)."""
-    base_env = dict(os.environ)
-    base_env.setdefault("JAX_PLATFORMS", "cpu")
-    base_env.update(env or {})
+    ``boot/restart.py`` subprocess idiom).
+
+    A child is pinned to the CPU only when its own ``[sched] route`` is
+    ``host`` (it will never dispatch to a device, so it must not claim
+    one — a chip belongs to one process).  A device-route child inherits
+    the environment untouched."""
+    from ..node.config import load_config
+
     procs = []
     for i, path in enumerate(config_paths):
+        child_env = dict(os.environ)
+        if load_config(path).sched_route == "host":
+            child_env.setdefault("JAX_PLATFORMS", "cpu")
+        child_env.update(env or {})
         out = open(os.path.join(run_dir, f"node-{i}.out.log"), "wb")
         err = open(os.path.join(run_dir, f"node-{i}.err.log"), "wb")
         procs.append(
@@ -530,7 +538,7 @@ def launch_fleet(
                 stdout=out,
                 stderr=err,
                 cwd=_REPO_ROOT,
-                env=base_env,
+                env=child_env,
             )
         )
         out.close()

@@ -6,11 +6,14 @@ stall mid-round for a compile (the round timer would expire, SURVEY.md §7
 (d)), so anything constructing device verifiers should enable the cache and
 pre-warm the hot shapes.
 
-The cache directory resolves ``path`` argument > ``GO_IBFT_CACHE_DIR`` >
-``JAX_COMPILATION_CACHE_DIR`` (JAX reads the latter natively) > the default
-``~/.cache/go_ibft_tpu/xla``.  Growth is bounded by the same posture as the
-backend probe cache (obs/evidence.py): entries older than
-``GO_IBFT_CACHE_TTL_S`` are dropped, and when the directory exceeds
+The cache directory follows ONE rule.  Where ``JAX_COMPILATION_CACHE_DIR``
+is set (JAX reads it natively at import), that directory is the cache:
+nothing else is set in code and it is never pruned — it belongs to whoever
+placed it.  Where it is not set, the cache is ``<checkout>/.cache/xla``, a
+fixed path beside the package, so every process of a run finds what an
+earlier one compiled — never ``~``, ``/tmp``, a pid or a time.
+That default directory is ours, so its growth is bounded: entries older
+than ``GO_IBFT_CACHE_TTL_S`` are dropped, and when it exceeds
 ``GO_IBFT_CACHE_MAX_BYTES`` the oldest entries are evicted first.  JAX's
 own cache key covers jax version / backend / XLA flags, so entries written
 by an older jax can never be *loaded* as a wrong program — the TTL merely
@@ -25,7 +28,12 @@ from typing import Optional, Tuple
 
 import jax
 
-_DEFAULT_DIR = os.path.expanduser("~/.cache/go_ibft_tpu/xla")
+# <checkout>/.cache/xla: this file is <checkout>/go_ibft_tpu/utils/jaxcache.py.
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".cache",
+    "xla",
+)
 
 # Bounded-growth defaults: generous enough that a full warm_kernels sweep
 # (every pinned family, multiple shape buckets, ~tens of MB each) never
@@ -36,34 +44,29 @@ DEFAULT_TTL_S = 30 * 24 * 3600.0  # 30 days
 _enabled = False
 
 
-def resolve_cache_dir(path: Optional[str] = None) -> str:
-    """The cache directory ``enable_persistent_cache`` would select."""
-    current = jax.config.jax_compilation_cache_dir
-    if current is not None:
-        return current
-    return (
-        path
-        or os.environ.get("GO_IBFT_CACHE_DIR")
-        or os.environ.get("JAX_COMPILATION_CACHE_DIR")
-        or _DEFAULT_DIR
-    )
+def resolve_cache_dir() -> str:
+    """The cache directory ``enable_persistent_cache`` would select
+    (``jax.config`` already holds ``JAX_COMPILATION_CACHE_DIR``: jax reads
+    it at import)."""
+    return jax.config.jax_compilation_cache_dir or _DEFAULT_DIR
 
 
-def enable_persistent_cache(path: Optional[str] = None) -> str:
+def enable_persistent_cache() -> str:
     """Idempotently enable the JAX persistent compilation cache.
 
-    Respects an existing user-configured cache dir; otherwise resolves via
-    :func:`resolve_cache_dir`.  Prunes the directory once per process (TTL
-    + size bound) before handing it to jax.  Returns the effective dir.
+    A directory placed from outside (``JAX_COMPILATION_CACHE_DIR``, or a
+    ``jax_compilation_cache_dir`` the embedder configured) is used as is
+    and never pruned; otherwise the in-checkout default is created, pruned
+    once per process (TTL + size bound) and handed to jax.  Returns the
+    effective dir.
     """
     global _enabled
-    target = resolve_cache_dir(path)
     if _enabled:
-        return target
-    if jax.config.jax_compilation_cache_dir is None:
-        os.makedirs(target, exist_ok=True)
-        prune_cache(target)
-        jax.config.update("jax_compilation_cache_dir", target)
+        return resolve_cache_dir()
+    if not jax.config.jax_compilation_cache_dir:
+        os.makedirs(_DEFAULT_DIR, exist_ok=True)
+        prune_cache(_DEFAULT_DIR)
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
     # Floor below which compiles are not persisted (they cost less than
     # the disk round-trip).  ``GO_IBFT_CACHE_MIN_COMPILE_S=0`` persists
     # everything — the CI boot check uses it so even the sub-second
@@ -73,7 +76,7 @@ def enable_persistent_cache(path: Optional[str] = None) -> str:
         float(os.environ.get("GO_IBFT_CACHE_MIN_COMPILE_S", 1)),
     )
     _enabled = True
-    return target
+    return resolve_cache_dir()
 
 
 def prune_cache(
@@ -83,14 +86,15 @@ def prune_cache(
     max_age_s: Optional[float] = None,
     now: Optional[float] = None,
 ) -> Tuple[int, int]:
-    """Bound the persistent cache: drop stale entries, evict oldest-first.
+    """Bound the in-checkout cache: drop stale entries, evict oldest-first.
 
-    Runs once per process from :func:`enable_persistent_cache` (explicit
-    calls always run).  Never raises — a concurrently-pruning sibling
-    process or a read-only cache degrades to a no-op, mirroring the probe
-    cache's never-fault posture.  Returns ``(files_removed, bytes_removed)``.
+    Runs once per process from :func:`enable_persistent_cache` for the
+    default directory only (explicit calls always run; ``path`` defaults
+    to the in-checkout directory, never to one placed from outside).
+    Never raises — a concurrently-pruning sibling process or a read-only
+    cache degrades to a no-op.  Returns ``(files_removed, bytes_removed)``.
     """
-    target = path or resolve_cache_dir()
+    target = path or _DEFAULT_DIR
     if max_bytes is None:
         max_bytes = int(
             os.environ.get("GO_IBFT_CACHE_MAX_BYTES", DEFAULT_MAX_BYTES)

@@ -633,10 +633,10 @@ def _mesh_multi_pairing(mesh):
         return hit
 
     import jax
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops import bls12_381 as dev
-    from ..parallel.mesh import shard_map
 
     lane = P("dp")
 

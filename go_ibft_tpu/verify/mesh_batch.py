@@ -53,6 +53,7 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..messages.helpers import CommittedSeal
@@ -61,7 +62,7 @@ from ..obs import ledger as cost_ledger
 from ..obs import trace
 from ..ops import quorum
 from ..ops import secp256k1 as sec
-from ..parallel.mesh import mesh_context, shard_map
+from ..parallel.mesh import mesh_context
 from ..utils import metrics
 from .batch import (
     _BATCH_BUCKETS,

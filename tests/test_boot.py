@@ -122,13 +122,12 @@ def _boot_once(tag: str, cache_dir: str, tmp_path) -> tuple:
     """One full boot in a child process; returns (report, ledger events)."""
     ledger = tmp_path / f"compile_ledger_{tag}.jsonl"
     env = dict(os.environ)
-    env["GO_IBFT_CACHE_DIR"] = cache_dir
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["GO_IBFT_COMPILE_LEDGER"] = str(ledger)
     # Persist even the sub-second digest compile (jax's floor is 1 s) and
     # classify it cold (~0.4 s compile vs ~0.04 s cache load).
     env["GO_IBFT_CACHE_MIN_COMPILE_S"] = "0"
     env["GO_IBFT_BOOT_COLD_S"] = "0.15"
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     proc = subprocess.run(
         [
             sys.executable,
@@ -157,7 +156,7 @@ def _boot_once(tag: str, cache_dir: str, tmp_path) -> tuple:
 def test_second_boot_pays_zero_cold_compiles(tmp_path):
     cache_dir = str(tmp_path / "xla")
     cold_report, cold_events = _boot_once("cold", cache_dir, tmp_path)
-    # Empty GO_IBFT_CACHE_DIR: the first boot MUST pay and record.
+    # Empty cache directory: the first boot MUST pay and record.
     assert cold_report["cold"] >= 1
     assert cold_report["programs"]["digest_words_8l"]["status"] == "cold"
     assert len(cold_events) >= 1

@@ -6,14 +6,10 @@ Pins the ISSUE 4 contracts:
   ``trace_event`` JSON with one track per node;
 * disabled-mode tracing is a single predicate check (a no-op context
   manager — no recorder, no clock reads);
-* the backend fingerprint can never hang past its deadline (subprocess
-  probe; a sleeping stub yields ``probe: timeout``), is cached with a TTL
-  and invalidated by ``reprobe``/env-pin changes;
 * the evidence writer is append-only JSONL, flushed per record, stamped
   with ``backend``/``probe`` provenance;
-* ``bench.py`` with a HANGING probe still exits rc=0 with one evidence
-  line per config (the hang-proof acceptance criterion — no code path
-  blocks on ``jax.devices()`` in the bench process);
+* ``bench.py`` that was not told to use the CPU and finds no TPU exits
+  non-zero (there is no fallback);
 * the regression gates compare fresh evidence against the best prior
   ``BENCH_r*.json`` on the same backend only, direction-aware.
 """
@@ -203,53 +199,8 @@ async def test_cluster_height_emits_per_node_tracks():
 
 
 # ---------------------------------------------------------------------------
-# evidence: fingerprint cache + writer
+# evidence: the append-only writer
 # ---------------------------------------------------------------------------
-
-_SLEEPY_PROBE = "import time; time.sleep(60)"
-
-
-def test_probe_timeout_classified_and_deadline_enforced(tmp_path, monkeypatch):
-    monkeypatch.setenv("GO_IBFT_PROBE_SRC", _SLEEPY_PROBE)
-    cache = tmp_path / "probe.json"
-    t0 = time.monotonic()
-    fp = evidence.probe_fingerprint(1.0, cache_path=str(cache))
-    elapsed = time.monotonic() - t0
-    assert elapsed < 10.0  # hard deadline, not the stub's 60s sleep
-    assert fp.probe == "timeout" and fp.platform is None
-    assert fp.backend_label() == "cpu-fallback"
-    # the verdict (including a timeout) is cached for later probe points
-    fp2 = evidence.probe_fingerprint(1.0, cache_path=str(cache))
-    assert fp2.probe == "cached" and fp2.platform is None
-
-
-def test_probe_cache_ttl_reprobe_and_env_pin(tmp_path, monkeypatch):
-    cache = tmp_path / "probe.json"
-    monkeypatch.setenv(
-        "GO_IBFT_PROBE_SRC", "print('PLATFORM=stubtpu')"
-    )
-    fp = evidence.probe_fingerprint(30.0, cache_path=str(cache))
-    assert fp.probe == "ok" and fp.platform == "stubtpu"
-    # fresh cache serves without a subprocess
-    monkeypatch.setenv("GO_IBFT_PROBE_SRC", _SLEEPY_PROBE)
-    fp2 = evidence.probe_fingerprint(1.0, cache_path=str(cache))
-    assert fp2.probe == "cached" and fp2.platform == "stubtpu"
-    # reprobe bypasses the cache (and here, times out against the stub)
-    fp3 = evidence.probe_fingerprint(
-        1.0, cache_path=str(cache), reprobe=True
-    )
-    assert fp3.probe == "timeout"
-    # an expired TTL re-probes too
-    fp4 = evidence.probe_fingerprint(1.0, cache_path=str(cache), ttl_s=0.0)
-    assert fp4.probe == "timeout"
-    # a different JAX_PLATFORMS pin invalidates the cached verdict
-    monkeypatch.setenv("GO_IBFT_PROBE_SRC", "print('PLATFORM=other')")
-    evidence.probe_fingerprint(30.0, cache_path=str(cache))
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
-    monkeypatch.setenv("GO_IBFT_PROBE_SRC", "print('PLATFORM=pinned')")
-    fp5 = evidence.probe_fingerprint(30.0, cache_path=str(cache))
-    assert fp5.probe == "ok" and fp5.platform == "pinned"
-
 
 def test_evidence_writer_appends_flushes_and_stamps(tmp_path):
     path = tmp_path / "ev.jsonl"
@@ -271,98 +222,41 @@ def test_evidence_writer_appends_flushes_and_stamps(tmp_path):
             assert field in line, (field, line)
         assert line["backend"] == "cpu-fallback"
         assert line["probe"] == "timeout"
-    # append-only across writers (the late TPU re-probe appends)
+    # append-only across writers
     with evidence.EvidenceWriter(str(path), backend="tpu", probe="ok") as w2:
         w2.record("config_c", {"metric": "config_c", "value": 2.0})
     assert len(path.read_text().splitlines()) == 3
 
 
 # ---------------------------------------------------------------------------
-# the hang-proof acceptance criterion (satellite: probe-timeout coverage)
+# no fallback: a run that was not told to use the CPU needs the chip
 # ---------------------------------------------------------------------------
 
 
-def test_bench_survives_hanging_probe_with_full_evidence(tmp_path):
-    """A probe subprocess that sleeps past its deadline must cost bench.py
-    exactly the deadline: the run pins CPU, every config writes a
-    ``probe: timeout`` / ``backend: cpu-fallback`` evidence line (skips
-    included — a skip is evidence too), and rc is 0 because every config
-    produced evidence and none crashed.  No code path may block on
-    ``jax.devices()`` in the bench process itself."""
-    ev_path = tmp_path / "ev.jsonl"
-    env = dict(
-        os.environ,
-        GO_IBFT_PROBE_SRC=_SLEEPY_PROBE,
-        GO_IBFT_PROBE_TIMEOUT="2",
-        GO_IBFT_PROBE_CACHE=str(tmp_path / "probe.json"),
-        GO_IBFT_BENCH_BUDGET_S="45",
-        GO_IBFT_EVIDENCE_PATH=str(ev_path),
-    )
-    env.pop("JAX_PLATFORMS", None)  # the probe decides, not an env pin
+def test_bench_without_tpu_and_without_cpu_pin_exits_nonzero(tmp_path):
+    """``python bench.py`` that was NOT asked for the CPU and finds no TPU
+    must exit non-zero, naming the platform it found, before any config
+    runs — the CPU schedule is for ``JAX_PLATFORMS=cpu`` only."""
+    env = dict(os.environ, GO_IBFT_EVIDENCE_PATH=str(tmp_path / "ev.jsonl"))
+    env.pop("JAX_PLATFORMS", None)
     proc = subprocess.run(
         [sys.executable, "bench.py"],
         cwd=REPO,
         capture_output=True,
         text=True,
-        timeout=240,
+        timeout=120,
         env=env,
     )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert proc.returncode != 0, proc.stdout + proc.stderr
     lines = [
         json.loads(line)
-        for line in ev_path.read_text().splitlines()
-        if line.strip()
+        for line in proc.stdout.splitlines()
+        if line.startswith("{")
     ]
-    by_config = {}
-    for line in lines:
-        by_config.setdefault(line["config"], line)
-        assert line["probe"] == "timeout", line
-        assert line["backend"] == "cpu-fallback", line
-    import bench
-
-    for key in (
-        "happy_path_4v_height_latency",
-        "ecdsa_1000v_10h_pipelined_throughput",
-        "bls_aggregate_verify_p50_100v",
-        "byzantine_300v_30pct_prepare_commit_p50",
-        "chaos_degraded_overhead_100v",
-        bench.headline_metric(True),
-    ):
-        assert key in by_config, (key, sorted(by_config))
-
-
-def test_reprobe_child_gets_its_own_evidence_path(tmp_path, monkeypatch):
-    """The late-reprobe child bench must never inherit the parent's
-    per-config evidence path: the child truncates its evidence file at
-    startup while the parent still holds an open append handle with
-    configs left to record — the child writes to a sibling file."""
-    captured = {}
-
-    def fake_run(cmd, **kw):
-        captured["env"] = kw["env"]
-
-        class _P:
-            returncode = 0
-
-        return _P()
-
-    monkeypatch.setattr(evidence.subprocess, "run", fake_run)
-    monkeypatch.setattr(
-        evidence,
-        "probe_fingerprint",
-        lambda *a, **kw: evidence.Fingerprint(
-            platform="tpu", probe="ok", detail="ok", probed_at=0.0
-        ),
-    )
-    parent_path = str(tmp_path / "bench_evidence.jsonl")
-    monkeypatch.setenv("GO_IBFT_EVIDENCE_PATH", parent_path)
-    platform, detail = evidence.reprobe_and_capture(
-        600.0, str(REPO / "bench.py"), evidence_path=str(tmp_path / "tpu.jsonl")
-    )
-    assert platform == "tpu", detail
-    child_path = captured["env"]["GO_IBFT_EVIDENCE_PATH"]
-    assert child_path != parent_path
-    assert child_path.endswith(".configs.jsonl")
+    # the error, then only the run's own ledger summary: no config ran
+    assert lines[0]["metric"] == "bench_error", lines
+    assert {line["metric"] for line in lines[1:]} <= {"cost_ledger"}, lines
+    assert "'cpu'" in lines[0]["error"] and "no TPU" in lines[0]["error"]
 
 
 # ---------------------------------------------------------------------------

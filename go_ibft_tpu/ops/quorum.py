@@ -59,7 +59,8 @@ def split_power(power: int) -> Tuple[int, int]:
 
 def _recover_address(z_limbs, r, s, v):
     qx, qy, ok = sec.ecdsa_recover(z_limbs, r, s, v)
-    return dk.pubkey_to_address_words(qx, qy), ok
+    with jax.named_scope("recover.address"):
+        return dk.pubkey_to_address_words(qx, qy), ok
 
 
 def digest_words(blocks, nblocks):
@@ -83,10 +84,12 @@ def sig_checks_zw(zw, r, s, v, claimed_w, live):
     address AND the lane is live.  Serves BOTH envelope senders (zw =
     payload digests) and committed seals (zw = the proposal hash) — one
     compiled program per lane bucket."""
-    z = dk.words_le_to_limbs(zw, sec.FIELD.nlimbs)
+    with jax.named_scope("recover.address"):
+        z = dk.words_le_to_limbs(zw, sec.FIELD.nlimbs)
     addr, ok = _recover_address(z, r, s, v)
-    match = jnp.all(addr == claimed_w, axis=-1)
-    return ok & match & live
+    with jax.named_scope("recover.address"):
+        match = jnp.all(addr == claimed_w, axis=-1)
+        return ok & match & live
 
 
 def sender_sig_checks(blocks, nblocks, r, s, v, sender_w, live):
@@ -102,7 +105,8 @@ def seal_sig_checks(hash_zw, r, s, v, signer_w, live):
 
 def membership_eq(sender_w, table_w):
     """``(B, V)`` sender-to-validator-row equality matrix."""
-    return jnp.all(sender_w[:, None, :] == table_w[None, :, :], axis=-1)
+    with jax.named_scope("recover.membership"):
+        return jnp.all(sender_w[:, None, :] == table_w[None, :, :], axis=-1)
 
 
 def sender_validity(blocks, nblocks, r, s, v, sender_w, table_w, live):

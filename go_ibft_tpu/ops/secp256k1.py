@@ -540,42 +540,44 @@ def ecmul2_base(
     field elements.
     """
     batch = jnp.broadcast_shapes(k1.shape[:-1], k2.shape[:-1], qx.shape[:-1])
-    qx = jnp.broadcast_to(qx, batch + (_L,))
-    qy = jnp.broadcast_to(qy, batch + (_L,))
-    qtx, qty, qtz = _q_window_table(batch, qx, qy)  # (16, ..., L)
-    # phi(Q) table: x scaled by BETA across the table axis (one batched mul).
-    qptx = fields.mul(FIELD, qtx, jnp.asarray(FIELD.const(_BETA)))
+    with jax.named_scope("recover.q_table"):
+        qx = jnp.broadcast_to(qx, batch + (_L,))
+        qy = jnp.broadcast_to(qy, batch + (_L,))
+        qtx, qty, qtz = _q_window_table(batch, qx, qy)  # (16, ..., L)
+        # phi(Q) table: x scaled by BETA across the table axis (one batched mul).
+        qptx = fields.mul(FIELD, qtx, jnp.asarray(FIELD.const(_BETA)))
 
-    a1, s1, a2, s2 = glv_split(fields.canon(ORDER, k1))  # G half-scalars
-    b1, t1, b2, t2 = glv_split(fields.canon(ORDER, k2))  # Q half-scalars
-    # Digit streams stacked on a leading term axis: (33, 4) + batch.
-    digits = jnp.stack(
-        [
-            jnp.broadcast_to(_glv_nibbles_msb(a), (_GLV_NWIN,) + batch)
-            for a in (a1, a2, b1, b2)
-        ],
-        axis=1,
-    )
-
-    # Stacked per-term Jacobian tables, (16, 4) + batch + (L,).  G/phi(G)
-    # entries are compile-time constants with z = 1 (z = 0 at digit 0);
-    # Q/phi(Q) come from the per-batch window table.
-    ones = jnp.broadcast_to(jnp.asarray(FIELD.const(1)), batch + (_L,))
-
-    def bc(tab):  # (16, L) constant -> (16,) + batch + (L,)
-        return jnp.broadcast_to(
-            jnp.asarray(tab)[(slice(None),) + (None,) * len(batch)],
-            (16,) + batch + (_L,),
+    with jax.named_scope("recover.glv_split"):
+        a1, s1, a2, s2 = glv_split(fields.canon(ORDER, k1))  # G half-scalars
+        b1, t1, b2, t2 = glv_split(fields.canon(ORDER, k2))  # Q half-scalars
+        # Digit streams stacked on a leading term axis: (33, 4) + batch.
+        digits = jnp.stack(
+            [
+                jnp.broadcast_to(_glv_nibbles_msb(a), (_GLV_NWIN,) + batch)
+                for a in (a1, a2, b1, b2)
+            ],
+            axis=1,
         )
 
-    g_z = jnp.concatenate(
-        [jnp.zeros_like(ones)[None], jnp.broadcast_to(ones, (15,) + batch + (_L,))]
-    )
-    tx = jnp.stack([bc(_G_TAB_X), bc(_GP_TAB_X), qtx, qptx], axis=1)
-    ty = jnp.stack([bc(_G_TAB_Y), bc(_G_TAB_Y), qty, qty], axis=1)
-    tz = jnp.stack([g_z, g_z, qtz, qtz], axis=1)
-    # Per-term negation flags, (4,) + batch: negate y at gather time.
-    neg = jnp.stack([s1, s2, t1, t2], axis=0)
+        # Stacked per-term Jacobian tables, (16, 4) + batch + (L,).  G/phi(G)
+        # entries are compile-time constants with z = 1 (z = 0 at digit 0);
+        # Q/phi(Q) come from the per-batch window table.
+        ones = jnp.broadcast_to(jnp.asarray(FIELD.const(1)), batch + (_L,))
+
+        def bc(tab):  # (16, L) constant -> (16,) + batch + (L,)
+            return jnp.broadcast_to(
+                jnp.asarray(tab)[(slice(None),) + (None,) * len(batch)],
+                (16,) + batch + (_L,),
+            )
+
+        g_z = jnp.concatenate(
+            [jnp.zeros_like(ones)[None], jnp.broadcast_to(ones, (15,) + batch + (_L,))]
+        )
+        tx = jnp.stack([bc(_G_TAB_X), bc(_GP_TAB_X), qtx, qptx], axis=1)
+        ty = jnp.stack([bc(_G_TAB_Y), bc(_G_TAB_Y), qty, qty], axis=1)
+        tz = jnp.stack([g_z, g_z, qtz, qtz], axis=1)
+        # Per-term negation flags, (4,) + batch: negate y at gather time.
+        neg = jnp.stack([s1, s2, t1, t2], axis=0)
 
     def body(acc, d):
         # 4 doublings of all four accumulator lanes (infinity-safe)
@@ -585,7 +587,8 @@ def ecmul2_base(
         addend = JacobianPoint(_one_hot_select(d, tx), y, _one_hot_select(d, tz))
         return point_add(acc, addend), None
 
-    acc, _ = jax.lax.scan(body, point_infinity((4,) + batch), digits)
+    with jax.named_scope("recover.glv_ladder"):
+        acc, _ = jax.lax.scan(body, point_infinity((4,) + batch), digits)
     # Combine the four lanes with two MORE calls to the SAME ``(4,) + batch``
     # complete add the ladder body uses, padding spent lanes with infinity.
     # Nested-jit point ops dedup per input shape: a (2,)+batch pair-add plus
@@ -602,9 +605,10 @@ def ecmul2_base(
             jnp.concatenate([pt.z[lanes], pinf.z]),
         )
 
-    half = point_add(_pad4(acc, slice(0, None, 2)), _pad4(acc, slice(1, None, 2)))
-    out = point_add(_pad4(half, slice(0, 1)), _pad4(half, slice(1, 2)))
-    return JacobianPoint(out.x[0], out.y[0], out.z[0])
+    with jax.named_scope("recover.combine"):
+        half = point_add(_pad4(acc, slice(0, None, 2)), _pad4(acc, slice(1, None, 2)))
+        out = point_add(_pad4(half, slice(0, 1)), _pad4(half, slice(1, 2)))
+        return JacobianPoint(out.x[0], out.y[0], out.z[0])
 
 
 def _in_scalar_range(v: jnp.ndarray) -> jnp.ndarray:
@@ -679,29 +683,32 @@ def ecdsa_recover(
     *recovered* from the signature and compared against the claimed address,
     exactly one ladder per message.
     """
-    ok = _in_scalar_range(r) & _in_scalar_range(s)
-    ok = ok & ((v == 0) | (v == 1))
+    with jax.named_scope("recover.range_check"):
+        ok = _in_scalar_range(r) & _in_scalar_range(s)
+        ok = ok & ((v == 0) | (v == 1))
 
-    f = FIELD
-    x = fields.canon(ORDER, r)  # r < N < P: also a canonical field element
-    # y = sqrt(x^3 + 7); P === 3 (mod 4) so sqrt = pow((P+1)/4).  The
-    # square root (mod P) and r^-1 (mod N) are data-independent, so they
-    # ride ONE merged scan — two sequential ~64-window chains would double
-    # the pre-ladder latency (fields.pow_fixed2).
-    y2 = fields.add(f, fields.mul(f, fields.sqr(f, x), x), jnp.asarray(f.const(7)))
-    y, rinv = fields.pow_fixed2(f, y2, _SQRT_EXP, ORDER, x, N - 2)
-    ok = ok & fields.eq_mod(f, fields.sqr(f, y), y2)  # r was a valid x-coord
-    y_canon = fields.canon(f, y)
-    parity = (y_canon[..., 0] & 1).astype(jnp.int32)
-    y_neg = fields.canon(f, fields.sub(f, jnp.zeros_like(y_canon), y_canon))
-    y_sel = fields.select(parity == v.astype(jnp.int32), y_canon, y_neg)
+    with jax.named_scope("recover.lift_x"):
+        f = FIELD
+        x = fields.canon(ORDER, r)  # r < N < P: also a canonical field element
+        # y = sqrt(x^3 + 7); P === 3 (mod 4) so sqrt = pow((P+1)/4).  The
+        # square root (mod P) and r^-1 (mod N) are data-independent, so they
+        # ride ONE merged scan — two sequential ~64-window chains would double
+        # the pre-ladder latency (fields.pow_fixed2).
+        y2 = fields.add(f, fields.mul(f, fields.sqr(f, x), x), jnp.asarray(f.const(7)))
+        y, rinv = fields.pow_fixed2(f, y2, _SQRT_EXP, ORDER, x, N - 2)
+        ok = ok & fields.eq_mod(f, fields.sqr(f, y), y2)  # r was a valid x-coord
+        y_canon = fields.canon(f, y)
+        parity = (y_canon[..., 0] & 1).astype(jnp.int32)
+        y_neg = fields.canon(f, fields.sub(f, jnp.zeros_like(y_canon), y_canon))
+        y_sel = fields.select(parity == v.astype(jnp.int32), y_canon, y_neg)
 
-    # Q = r^-1 * (s*R - z*G)  ==  (-z * r^-1)*G + (s * r^-1)*R
-    u1 = fields.mul(
-        ORDER, fields.sub(ORDER, jnp.zeros_like(z), z), rinv
-    )
-    u2 = fields.mul(ORDER, s, rinv)
+        # Q = r^-1 * (s*R - z*G)  ==  (-z * r^-1)*G + (s * r^-1)*R
+        u1 = fields.mul(
+            ORDER, fields.sub(ORDER, jnp.zeros_like(z), z), rinv
+        )
+        u2 = fields.mul(ORDER, s, rinv)
     q = ecmul2_base(u1, u2, x, y_sel)
-    ok = ok & ~is_infinity(q)
-    qx, qy = to_affine(q)
+    with jax.named_scope("recover.to_affine"):
+        ok = ok & ~is_infinity(q)
+        qx, qy = to_affine(q)
     return qx, qy, ok

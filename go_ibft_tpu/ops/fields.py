@@ -176,35 +176,57 @@ def _carry(z: jnp.ndarray, passes: int) -> jnp.ndarray:
 def _conv(a: jnp.ndarray, b: jnp.ndarray, out_len: int) -> jnp.ndarray:
     """Schoolbook product columns (no carries): ``out[k] = sum_i a_i*b_(k-i)``.
 
-    Implemented as ONE outer product + a shear-by-reshape + a row
-    reduction (~7 HLO ops), not ``la`` shifted pad-adds (~7*la ops): a
-    field `mul` built from the unrolled form lowered to ~800 stablehlo
-    lines, and with ~50 muls inside every ladder-scan body, trace size
-    WAS the XLA:CPU compile time (265 s for the smallest certify program,
-    VERDICT r04 weak #3).  The shear: row ``i`` of the padded outer
-    product holds ``a_i * b`` at columns 0..lb-1 of width ``W``;
-    re-viewing the flat buffer with rows one element NARROWER shifts row
-    ``i`` right by ``i``, so a plain column sum produces the convolution.
-    The wrapped tail a narrower view reads from the previous row lands in
-    that row's zero padding (W >= out_len + la guarantees it).  Also
-    serves truncated products down to ``out_len >= lb - 1``: columns at
-    or beyond ``out_len`` fall off the slice — exact int32 column sums
-    either way (bounds unchanged: <= la * 2**26 < 2**31).  Truncating
-    below ``lb - 1`` would let a narrower view's wrapped tail land inside
-    retained columns (silently wrong sums), hence the assert.
+    ONE outer product, merged to a flat buffer, and the sum of ``la``
+    windows of it — with the limb axes LEADING and the batch axes
+    trailing from the product to the column sum.  Row ``i`` of the outer
+    product is ``a_i * b`` with ``b`` zero-padded to the product's
+    ``w = la + lb - 1`` columns.  In the flat ``la*w`` buffer the element
+    ``f[k + i*(w-1)]`` is row ``i``, column ``k - i``: the window that
+    starts at ``i*(w-1)`` is row ``i`` shifted right by ``i`` (the shear),
+    its first ``i`` elements the zero tail of row ``i - 1``.  So the
+    windows add up to the convolution, and XLA fuses the ``la`` slices
+    into the adds: the product is written once and read once.  The
+    shorter operand gives the rows, whatever the order of the arguments.
+
+    ~2*la + 10 HLO ops; ``la`` shifted pad-adds of products (no outer
+    product at all) are ~5*la, and with ~50 muls in every ladder-scan
+    body trace size is XLA:CPU compile time.
+
+    Why limbs lead: the TPU keeps the batch lanes in the minor (tiled)
+    dimensions whatever the logical order, so merging the two MAJOR axes
+    is a bitcast.  The form of rounds 5-24 kept the batch leading, merged
+    and re-split the TRAILING axes, and XLA transposed every padded
+    product for it: on the v5e the copies of ``s32[4,lanes,1200]`` /
+    ``[.,945]`` alone were a third of the recover program and data
+    movement two thirds of it.  PERF.md section 6 (PR 25) has the chip
+    numbers of the forms tried, and why the windows are not ONE dilated
+    ``reduce_window`` (this libtpu computes it wrongly).
+
+    ``b`` may be a constant ``(lb,)`` with no batch axes and ``a`` may
+    carry any leading axes; limbs are on the last axis going in and
+    coming out.  Exact for ANY ``out_len``: columns at or beyond it are
+    never summed, columns beyond ``la + lb - 2`` are zero (int32 column
+    sums <= min(la, lb) * 2**26 < 2**31, in any order).
     """
+    if a.shape[-1] > b.shape[-1]:
+        a, b = b, a
     la, lb = a.shape[-1], b.shape[-1]
-    if out_len < lb - 1:
-        raise ValueError(
-            f"shear conv requires out_len >= lb - 1 ({out_len} < {lb - 1})"
-        )
-    w = out_len + la
-    outer = a[..., :, None] * b[..., None, :]  # (..., la, lb)
-    batch = outer.shape[:-2]
-    x = jnp.pad(outer, [(0, 0)] * len(batch) + [(0, 0), (0, w - lb)])
-    flat = x.reshape(batch + (la * w,))
-    sheared = flat[..., : la * (w - 1)].reshape(batch + (la, w - 1))
-    return jnp.sum(sheared, axis=-2)[..., :out_len]
+    batch = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
+    ones = (1,) * len(batch)
+    still = [(0, 0)] * len(batch)
+
+    def limbs_first(x):  # (..., l) -> (l,) + one axis per batch axis
+        x = jnp.moveaxis(x, -1, 0)
+        return x.reshape(x.shape[:1] + ones[: len(batch) + 1 - x.ndim] + x.shape[1:])
+
+    w = la + lb - 1
+    n = min(out_len, w)
+    rows = limbs_first(a)[:, None] * jnp.pad(limbs_first(b), [(0, la - 1)] + still)[None]
+    flat = rows.reshape((la * w,) + batch)
+    cols = flat[:n]
+    for i in range(1, la):
+        cols = cols + flat[i * (w - 1) : i * (w - 1) + n]
+    return _pad_to(jnp.moveaxis(cols, 0, -1), out_len)
 
 
 def _pad_to(z: jnp.ndarray, n: int) -> jnp.ndarray:

@@ -3,10 +3,17 @@
 Trace size IS compile time on XLA:CPU (docs/PERFORMANCE.md): the r04->r05
 rounds cut the 8-lane fused certify cold compile 265s -> 55s almost
 entirely by shrinking the traced program (mul 811 -> 316 lines,
-shear-reshape conv), and this round cut it again (~-31%) by deduplicating
-point-op instantiations.  Those wins regress silently — one refactor that
-unrolls a scan or forks a new shape instantiation quietly re-adds minutes
-of cold compile.  This script LOWERS (never compiles — it stays fast on
+shear-reshape conv), and r06 cut it again (~-31%) by deduplicating
+point-op instantiations.  Since PR 25 the conv keeps its limb axes
+leading and sums the product's la sheared windows as la slices (the
+batch-leading r05 form made the TPU transpose every padded product, and
+a slice of the merged product is a copy unless it fuses into an add:
+PERF.md section 6).  That is 2*la + 10 lines a conv, not 7: +13.6% on
+ecdsa_recover (30,066 -> 34,147 on jax 0.9.0), about 1.5x on the BLS
+programs (30 limbs, three convs a product), XLA:CPU compile of a ladder
+program unchanged within noise.  Those wins regress silently — one refactor
+that unrolls a scan or forks a new shape instantiation quietly re-adds
+minutes of cold compile.  This script LOWERS (never compiles — it stays fast on
 any host) the programs that dominate the cold budget, counts their
 stablehlo lines, and fails when any grows >10% over the checked-in
 snapshot (docs/compile_budget.json).

@@ -7,6 +7,7 @@ Ops are exercised under ``jax.jit`` — the only way they run in production.
 """
 
 import random
+import re
 from functools import partial
 
 import jax
@@ -182,9 +183,81 @@ def test_pow_fixed2_matches_two_pow_fixed():
     ]
 
 
-def test_conv_truncated_columns_exact():
-    """The shear conv's truncating mode (out_len < la+lb-1) keeps exact
-    low columns — the GLV mod-2**143 combinations depend on it."""
+# Every ``(la, lb, out_len)`` the tree hands ``_conv`` (the fold shapes are
+# checked against ``_fold_semi``'s own schedule below), and one below the
+# old shear's ``out_len >= lb - 1`` floor.
+CONV_SHAPES = [
+    pytest.param(20, 20, 41, id="field_product"),
+    pytest.param(21, 3, 25, id="fold0_p"),
+    pytest.param(4, 3, 21, id="fold1_p"),
+    pytest.param(21, 11, 33, id="fold0_n"),
+    pytest.param(11, 11, 23, id="fold1_n"),
+    pytest.param(11, 11, 11, id="glv_conv_lo"),
+    pytest.param(30, 30, 61, id="bls_mul_wide"),
+    pytest.param(30, 30, 59, id="bls_redc_m"),
+    pytest.param(30, 30, 62, id="bls_redc_u"),
+    pytest.param(20, 20, 5, id="truncated_below_lb"),
+    pytest.param(1, 20, 20, id="one_limb"),
+]
+
+
+def _patterns(n):
+    """Limb vectors at their extremes (a lazy limb may sit AT 2**13) and in
+    between: all 2**13, all 0, the two alternations, a ramp, random."""
+    top = F.LIMB_MASK + 1
+    rng = np.random.default_rng(n)
+    alt = np.arange(n) % 2
+    return np.stack(
+        [
+            np.full(n, top),
+            np.zeros(n, dtype=np.int64),
+            alt * top,
+            (1 - alt) * top,
+            np.arange(n) * top // n,
+            rng.integers(0, top + 1, n),
+        ]
+    ).astype(np.int32)
+
+
+def _conv_ints(a, b, out_len):
+    """Schoolbook columns in python ints, broadcasting like ``_conv``."""
+    batch = np.broadcast_shapes(a.shape[:-1], b.shape[:-1])
+    a = np.broadcast_to(a.astype(object), batch + a.shape[-1:])
+    b = np.broadcast_to(b.astype(object), batch + b.shape[-1:])
+    out = np.zeros(batch + (out_len,), dtype=object)
+    for i in range(a.shape[-1]):
+        for j in range(min(b.shape[-1], out_len - i)):
+            out[..., i + j] += a[..., i] * b[..., j]
+    return out
+
+
+CONV_OPERANDS = {
+    # b batched like a: pattern against rotated pattern, lane for lane
+    "b_batched": lambda pa, pb: (pa, np.roll(pb, 1, axis=0)),
+    # b a constant with no batch axes (c_fold, BETA, the GLV and BLS constants)
+    "b_const_top": lambda pa, pb: (pa, pb[0]),
+    "b_const_random": lambda pa, pb: (pa, pb[5]),
+    # a with two leading batch axes (the (16, 4) + batch tables), b with one or none
+    "a_two_leading": lambda pa, pb: (np.stack([pa, pa[::-1]]), pb),
+    "a_two_leading_b_const": lambda pa, pb: (np.stack([pa, pa[::-1]]), pb[3]),
+}
+
+
+@pytest.mark.parametrize("operands", CONV_OPERANDS)
+@pytest.mark.parametrize("la,lb,out_len", CONV_SHAPES)
+def test_conv_columns_exact(la, lb, out_len, operands):
+    """``_conv`` against python integers, limbs on the last axis going in
+    and coming out, whatever the batch axes of either operand."""
+    a, b = CONV_OPERANDS[operands](_patterns(la), _patterns(lb))
+    out = np.asarray(jax.jit(lambda x, y: F._conv(x, y, out_len))(a, b))
+    want = _conv_ints(a, b, out_len)
+    assert out.dtype == np.int32 and out.shape == want.shape
+    assert (out.astype(object) == want).all()
+
+
+def test_conv_truncated_value_mod_2_143():
+    """The truncating mode (out_len < la+lb-1) keeps the VALUE of the low
+    columns — the GLV mod-2**143 combinations depend on it."""
     rng = random.Random(11)
     a_int = [rng.randrange(2**143) for _ in range(5)]
     b_int = [rng.randrange(2**143) for _ in range(5)]
@@ -196,3 +269,52 @@ def test_conv_truncated_columns_exact():
     assert [g % 2**143 for g in got] == [
         (x * y) % 2**143 for x, y in zip(a_int, b_int)
     ]
+
+
+@pytest.mark.parametrize("p", MODULI)
+def test_conv_shapes_of_mul_are_the_tested_ones(p, monkeypatch):
+    """The shapes ``mul`` and ``_fold_semi`` derive for each secp256k1
+    modulus are cases of ``test_conv_columns_exact``."""
+    seen = []
+    real = F._conv
+
+    def spy(a, b, out_len):
+        seen.append((a.shape[-1], b.shape[-1], out_len))
+        return real(a, b, out_len)
+
+    monkeypatch.setattr(F, "_conv", spy)
+    m = F.Modulus(p)
+    x = jax.ShapeDtypeStruct((3, m.nlimbs), jnp.int32)
+    jax.eval_shape(partial(F.mul, m), x, x)
+    tested = {tuple(c.values) for c in CONV_SHAPES}
+    assert len(seen) == 3 and seen[0] == (20, 20, 41) and set(seen) <= tested
+
+
+def test_mul_reshapes_keep_the_batch_behind_the_product_axes():
+    """From the outer product to the column sum no array carries the
+    flattened ``la*w`` axis BEHIND the batch axes: the TPU keeps the batch
+    lanes minor, so such a reshape costs a transposing copy of the padded
+    product (a third of the recover program until PR 25).  In every
+    reshape of the lowered ``mul`` the batch axes trail, but for the limb
+    axis going in or out."""
+    m = _ops(P_SECP)["m"]
+    x = jax.ShapeDtypeStruct((4, 8, m.nlimbs), jnp.int32)
+    text = jax.jit(partial(F.mul, m)).lower(x, x).as_text()
+    reshapes = re.findall(
+        r"stablehlo\.reshape .*?\(tensor<([0-9x]+)xi32>\) -> tensor<([0-9x]+)xi32>", text
+    )
+    assert reshapes, "the shear is a reshape; none found in the lowered text"
+    flattened = 0
+    for types in reshapes:
+        for shape in types:
+            dims = [int(d) for d in shape.split("x")]
+            at = [i for i in range(len(dims) - 1) if dims[i : i + 2] == [4, 8]]
+            if not at:  # a constant operand: no batch axes
+                continue
+            behind = dims[at[-1] + 2 :]
+            # behind the batch: nothing, or the one limb axis going in or out
+            assert len(behind) <= 1 and all(d <= 2 * m.nlimbs + 2 for d in behind), (
+                f"product axis behind the batch axes: tensor<{shape}>"
+            )
+            flattened += any(d >= m.nlimbs**2 for d in dims)
+    assert flattened >= 1  # the field product's 20 x 39 rows, merged

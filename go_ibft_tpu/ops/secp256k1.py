@@ -115,12 +115,19 @@ def point_double(p: JacobianPoint) -> JacobianPoint:
 
 
 @jax.jit
-def point_add(p: JacobianPoint, q: JacobianPoint) -> JacobianPoint:
-    """Complete Jacobian addition via branchless selects.
+def _point_add_core(p: JacobianPoint, q: JacobianPoint) -> JacobianPoint:
+    """INCOMPLETE Jacobian addition: the generic formula (4 squarings + 12
+    products) and the two infinity selects.
 
-    Handles all exceptional cases: either operand at infinity, P == Q
-    (falls back to doubling), and P == -Q (returns infinity, which the
-    generic formula produces naturally since H == 0, R != 0 => Z3 == 0).
+    Right for every pair except ``P == Q`` off infinity: there ``H == R ==
+    0`` and the formula gives ``(0, 0, 0)`` where the answer is ``2P``.
+    ``P == -Q`` needs nothing: ``H == 0, R != 0`` gives ``Z3 == 0``.  Two
+    callers only (``tests/test_ladder_structure.py`` holds the tree to it):
+    :func:`point_add`, which overlays the doubling, and the scan body of
+    :func:`ecmul2_base`, whose operands provably never coincide.  Both call
+    it at ``(4,) + batch`` and use all of what it returns, so a program
+    instantiates it once (an output one caller drops is pruned from that
+    caller's copy, and the copies no longer dedup).
     """
     f = FIELD
     z1s = fields.sqr(f, p.z)
@@ -139,14 +146,27 @@ def point_add(p: JacobianPoint, q: JacobianPoint) -> JacobianPoint:
         f, fields.mul(f, r, fields.sub(f, u1hs, x3)), fields.mul(f, s1, hc)
     )
     z3 = fields.mul(f, fields.mul(f, p.z, q.z), h)
-    generic = JacobianPoint(x3, y3, z3)
+    out = _sel_pt(is_infinity(p), q, JacobianPoint(x3, y3, z3))
+    return _sel_pt(is_infinity(q), p, out)
 
-    same_x = fields.is_zero_fast(f, h)
-    same_y = fields.is_zero_fast(f, r)
-    out = _sel_pt(same_x & same_y, point_double(p), generic)
-    out = _sel_pt(is_infinity(p), q, out)
-    out = _sel_pt(is_infinity(q), p, out)
-    return out
+
+@jax.jit
+def point_add(p: JacobianPoint, q: JacobianPoint) -> JacobianPoint:
+    """Complete Jacobian addition via branchless selects.
+
+    Handles all exceptional cases: either operand at infinity, P == Q
+    (falls back to doubling), and P == -Q (returns infinity, which the
+    generic formula produces naturally since H == 0, R != 0 => Z3 == 0).
+    It is :func:`_point_add_core` with the doubling laid over the lanes
+    where ``P == Q``, read off the core's own result: with neither operand
+    at infinity ``Z3 = Z1*Z2*H`` is zero exactly where ``H`` is, and there
+    ``X3 = R**2`` is zero exactly where ``R`` is.  With ``P`` alone at
+    infinity the core returned ``Q`` (``Z != 0``), with ``Q`` alone ``P``;
+    with both it returned ``P``, which ``~is_infinity(p)`` leaves alone.
+    """
+    out = _point_add_core(p, q)
+    same = fields.is_zero_fast(FIELD, out.z) & fields.is_zero_fast(FIELD, out.x)
+    return _sel_pt(same & ~is_infinity(p), point_double(p), out)
 
 
 def _inv_lanes(m: fields.Modulus, a: jnp.ndarray) -> jnp.ndarray:
@@ -526,18 +546,44 @@ def ecmul2_base(
     ``(4,) + batch`` Jacobian point (``acc_i = sum_j 16**j * T_i[d_ij]``
     — doublings distribute over the final sum), combined by two batched
     adds after the scan.  A scan step is therefore 4 batched doublings +
-    ONE batched complete add over the stacked ``(16, 4, ...)`` tables —
+    ONE batched add over the stacked ``(16, 4, ...)`` tables —
     the per-step sequential chain drops from 8 point ops (4 dbl + 4
     serial adds, the r04 shape) to 5, and the traced body roughly halves,
     which is compile time on XLA:CPU (VERDICT r04 weak #3).  Table entry
-    0 is the point at infinity, so zero digits need no select — complete
-    addition absorbs them.  Net: 132 sequential doublings instead of the
-    Shamir ladder's 256 (this is the hottest loop of the framework — the
-    per-message ``Verifier`` work of reference messages/messages.go:183-198
-    rides entirely on it).
+    0 is the point at infinity, so zero digits need no select — the add's
+    infinity selects absorb them.  Net: 132 sequential doublings instead
+    of the Shamir ladder's 256 (this is the hottest loop of the framework
+    — the per-message ``Verifier`` work of reference
+    messages/messages.go:183-198 rides entirely on it).
+
+    **The add in the scan is the incomplete one** (:func:`_point_add_core`:
+    no ``P == Q`` fallback), 28 + 16 = 44 field products a step where the
+    complete add made it 51, because its operands cannot coincide.  Each
+    accumulator lane carries ONE digit stream of ONE base point ``B`` in
+    {G, phi(G), Q, phi(Q)}, and the half-scalar's sign is a per-lane flag
+    (``neg``) that is constant over the scan.  After ``j`` steps the lane
+    holds ``+-m*B``, ``m`` the top ``j`` nibbles of a half-scalar, ``m <
+    2**132``; after ``_double4`` it holds ``+-16m*B`` and the addend is
+    ``+-d*B``, ``0 <= d < 16``, with the SAME sign.  secp256k1 has prime
+    order ``N > 2**255`` and cofactor 1, so every ``B`` on the curve other
+    than infinity has order ``N``: ``16m*B == d*B`` needs ``16m === d (mod
+    N)``, and ``0 <= 16m, d < 2**136 < N`` leaves ``16m == d``, that is
+    ``m == d == 0``, both operands infinity, which the infinity selects
+    answer.  (``16m*B == -d*B`` is as impossible, and would give ``Z3 ==
+    0`` from the formula anyway.)  The two places where operands CAN
+    coincide are outside the scan and keep the complete add: the Q window
+    table (``2Q = Q + Q``; :func:`_q_window_table`, ``point_add_mixed``)
+    and ``recover.combine`` below (the caller chooses ``k1``, ``k2``, so
+    ``a*G + b*phi(G)`` can equal ``c*Q + d*phi(Q)``).  What would break
+    the invariant: an accumulator shared between streams (as
+    :func:`_ecmul2_base_shamir`'s is, which keeps the complete add),
+    digit streams of ``log2(N) - 4`` bits or more (today 132 of 256), or a
+    curve with a cofactor (a base point of small order).
 
     ``k1``/``k2`` are semi-reduced scalars mod N; ``qx``/``qy`` affine
-    field elements.
+    field elements.  ``(qx, qy)`` is on the curve, or the lane's result is
+    unspecified: off it the group law, and with it the invariant above,
+    does not hold.
     """
     batch = jnp.broadcast_shapes(k1.shape[:-1], k2.shape[:-1], qx.shape[:-1])
     with jax.named_scope("recover.q_table"):
@@ -585,17 +631,19 @@ def ecmul2_base(
         y = _one_hot_select(d, ty)
         y = fields.select(neg, fields.sub(FIELD, jnp.zeros_like(y), y), y)
         addend = JacobianPoint(_one_hot_select(d, tx), y, _one_hot_select(d, tz))
-        return point_add(acc, addend), None
+        # Incomplete on purpose: acc == addend only at infinity (docstring).
+        return _point_add_core(acc, addend), None
 
     with jax.named_scope("recover.glv_ladder"):
         acc, _ = jax.lax.scan(body, point_infinity((4,) + batch), digits)
-    # Combine the four lanes with two MORE calls to the SAME ``(4,) + batch``
-    # complete add the ladder body uses, padding spent lanes with infinity.
-    # Nested-jit point ops dedup per input shape: a (2,)+batch pair-add plus
-    # a batch-shaped final add each instantiate their own point_add AND
-    # embedded point_double functions (~13k stablehlo lines — a third of the
-    # fused 8-lane certify program), while two wasted infinity lanes cost a
-    # few VPU ops.  Trace size is compile time on XLA:CPU.
+    # Combine the four lanes with two COMPLETE adds (these operands can
+    # coincide) at the SAME ``(4,) + batch`` as the ladder body's core,
+    # padding spent lanes with infinity.  Nested-jit point ops dedup per
+    # input shape: a (2,)+batch pair-add plus a batch-shaped final add each
+    # instantiate their own add core AND embedded point_double functions
+    # (~13k stablehlo lines — a third of the fused 8-lane certify program),
+    # while two wasted infinity lanes cost a few VPU ops.  Trace size is
+    # compile time on XLA:CPU.
     def _pad4(pt: JacobianPoint, lanes: slice) -> JacobianPoint:
         x = pt.x[lanes]
         pinf = point_infinity((4 - x.shape[0],) + batch)
@@ -638,9 +686,12 @@ def ecdsa_verify(
     Inputs are limb vectors broadcast over leading batch axes: affine public
     key ``(qx, qy)``, digest-as-scalar ``z`` (already reduced mod N by the
     packing layer), and signature ``(r, s)`` as raw 256-bit values (range
-    checks happen here, on device).
+    checks happen here, on device).  A key off the curve is rejected, as
+    Go's ``ecdsa.Verify`` rejects it: :func:`ecmul2_base` promises nothing
+    for one.
     """
     ok_range = _in_scalar_range(r) & _in_scalar_range(s)
+    ok_key = on_curve(qx, qy)
     # raw 256-bit s is semi-reduced for ORDER (s < 2**256 < 2N), so the
     # tree/Fermat inverse applies directly.
     w = _inv_lanes(ORDER, s)
@@ -658,7 +709,7 @@ def ecdsa_verify(
     eq2 = fields.eq_mod(
         FIELD, x_aff, fields.add(FIELD, r_canon, jnp.asarray(_N_AS_FIELD))
     )
-    return ok_range & not_inf & (eq1 | (r_small & eq2))
+    return ok_range & ok_key & not_inf & (eq1 | (r_small & eq2))
 
 
 # (P + 1) // 4: square-root exponent for P === 3 (mod 4).
@@ -696,7 +747,9 @@ def ecdsa_recover(
         # the pre-ladder latency (fields.pow_fixed2).
         y2 = fields.add(f, fields.mul(f, fields.sqr(f, x), x), jnp.asarray(f.const(7)))
         y, rinv = fields.pow_fixed2(f, y2, _SQRT_EXP, ORDER, x, N - 2)
-        ok = ok & fields.eq_mod(f, fields.sqr(f, y), y2)  # r was a valid x-coord
+        # r was a valid x-coord: (x, y_sel) is on the curve exactly where this
+        # holds, which is what ecmul2_base's incomplete ladder add asks for.
+        ok = ok & fields.eq_mod(f, fields.sqr(f, y), y2)
         y_canon = fields.canon(f, y)
         parity = (y_canon[..., 0] & 1).astype(jnp.int32)
         y_neg = fields.canon(f, fields.sub(f, jnp.zeros_like(y_canon), y_canon))

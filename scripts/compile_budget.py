@@ -11,7 +11,11 @@ a slice of the merged product is a copy unless it fuses into an add:
 PERF.md section 6).  That is 2*la + 10 lines a conv, not 7: +13.6% on
 ecdsa_recover (30,066 -> 34,147 on jax 0.9.0), about 1.5x on the BLS
 programs (30 limbs, three convs a product), XLA:CPU compile of a ladder
-program unchanged within noise.  Those wins regress silently — one refactor
+program unchanged within noise.  PR 28 split ``point_add`` into a shared
+incomplete core and the ``P == Q`` overlay (the ladder's scan body calls
+the core): +83 lines on every ladder program (34,147 -> 34,230), one more
+zero test in the overlay; what the ladder stopped executing was never a
+second instantiation.  Those wins regress silently — one refactor
 that unrolls a scan or forks a new shape instantiation quietly re-adds
 minutes of cold compile.  This script LOWERS (never compiles — it stays fast on
 any host) the programs that dominate the cold budget, counts their

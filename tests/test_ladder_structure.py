@@ -1,0 +1,191 @@
+"""The GLV ladder's scan body carries the add it needs and no more.
+
+``ecmul2_base``'s 33-step scan adds with ``_point_add_core`` (no ``P == Q``
+fallback): 28 + 16 = 44 field products a step where the complete add made
+it 51.  That is sound only while an accumulator lane and its addend cannot
+be the same point off infinity; the integer walk below states that invariant
+over the scalars most likely to break it.  Everything here traces or counts
+— nothing compiles — so it runs in tier-1 (the compiled ladder is held to
+Python integers in ``test_secp256k1.py``, slow tier).
+"""
+
+import ast
+import collections
+import math
+import os
+import random
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from go_ibft_tpu.ops import secp256k1 as sec
+
+import ladder_cases as lc
+
+LANES = 8
+L = sec.FIELD.nlimbs
+OPS_DIR = os.path.dirname(os.path.abspath(sec.__file__))
+
+
+def _sub_jaxprs(eqn):
+    for v in eqn.params.values():
+        inner = getattr(v, "jaxpr", v)
+        if hasattr(inner, "eqns"):
+            yield inner
+
+
+def _count(jaxpr, pred) -> int:
+    """Equations matching ``pred``, an inner scan's counted once per
+    iteration."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += bool(pred(eqn))
+        times = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        total += times * sum(_count(j, pred) for j in _sub_jaxprs(eqn))
+    return total
+
+
+def _calls(jaxpr, under_scan=False) -> collections.Counter:
+    """``(jitted function's name, inside an inner scan)`` per call site."""
+    seen = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "jit":
+            seen[(eqn.params["name"], under_scan)] += 1
+        inner = under_scan or eqn.primitive.name == "scan"
+        for j in _sub_jaxprs(eqn):
+            seen += _calls(j, inner)
+    return seen
+
+
+def _scans(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        else:
+            for j in _sub_jaxprs(eqn):
+                yield from _scans(j)
+
+
+@pytest.fixture(scope="module")
+def ladder_body():
+    """The body of the ``recover.glv_ladder`` scan, traced at 8 lanes."""
+    s = jax.ShapeDtypeStruct((LANES, L), jnp.int32)
+    closed = jax.make_jaxpr(sec.ecmul2_base)(s, s, s, s)
+    ladders = [
+        e
+        for e in _scans(closed.jaxpr)
+        if "recover.glv_ladder" in str(e.source_info.name_stack)
+    ]
+    assert len(ladders) == 1 and ladders[0].params["length"] == sec._GLV_NWIN
+    return ladders[0].params["jaxpr"].jaxpr
+
+
+def _is_field_product(eqn) -> bool:
+    """``fields._conv``'s outer product of two 20-limb elements on the
+    ladder's ``(4, lanes)`` batch (the fold's product has 3 rows)."""
+    return (
+        eqn.primitive.name == "mul"
+        and eqn.outvars[0].aval.shape == (L, 2 * L - 1, 4, LANES)
+    )
+
+
+def test_scan_step_is_44_field_products(ladder_body):
+    # 4 doublings x (5 sqr + 2 mul) + one generic add (4 sqr + 12 mul); the
+    # complete add's embedded doubling made it 51.
+    assert _count(ladder_body, _is_field_product) == 4 * 7 + 16
+
+
+def test_scan_step_calls_the_core_and_only_double4_doubles(ladder_body):
+    calls = _calls(ladder_body)
+    points = {k: n for k, n in calls.items() if k[0].startswith(("point_", "_point_"))}
+    assert points == {("_point_add_core", False): 1, ("point_double", True): 1}
+    # is_zero_fast is two ``jnp.all`` (value 0 or p): the two infinity
+    # selects of the core and nothing else (H == 0, R == 0 are not asked).
+    assert _count(ladder_body, lambda e: e.primitive.name == "reduce_and") == 4
+
+
+def test_core_is_called_from_the_ladder_body_and_the_complete_add_only():
+    repo = os.path.dirname(os.path.dirname(OPS_DIR))
+    callers = collections.Counter()
+    for top in ("go_ibft_tpu", "scripts", "benchmark"):
+        for dirpath, _, names in os.walk(os.path.join(repo, top)):
+            for name in (n for n in names if n.endswith(".py")):
+                with open(os.path.join(dirpath, name)) as fh:
+                    tree = ast.parse(fh.read())
+                for fn in ast.walk(tree):
+                    if not isinstance(fn, ast.FunctionDef):
+                        continue
+                    for node in ast.walk(fn):
+                        if "_point_add_core" in (
+                            getattr(node, "id", None),
+                            getattr(node, "attr", None),
+                        ):
+                            callers[(name, fn.name)] += 1
+    # ``body`` is ecmul2_base's scan body: ast.walk reports it under both.
+    assert callers == {
+        ("secp256k1.py", "point_add"): 1,
+        ("secp256k1.py", "ecmul2_base"): 1,
+        ("secp256k1.py", "body"): 1,
+    }
+    with open(os.path.join(OPS_DIR, "secp256k1.py")) as fh:
+        src = fh.read()
+    shamir = src[src.index("def _ecmul2_base_shamir") : src.index("def ecmul2_base")]
+    assert "_point_add_core" not in shamir and "point_add(acc, addq)" in shamir
+
+
+def _assert_never_coincide(k: int) -> None:
+    steps = list(lc.ladder_steps(k))
+    assert len(steps) == 2 * lc.NWIN
+    for m, d in steps:
+        assert m < 1 << 132 and 16 * m + d < lc.N
+        same = (16 * m - d) % lc.N == 0  # 16m*B == d*B
+        opposite = (16 * m + d) % lc.N == 0  # 16m*B == -d*B
+        assert (same or opposite) == (m == 0 and d == 0), (hex(k), m, d)
+
+
+EDGE_SCALARS = lc.edge_scalars()
+
+
+@pytest.mark.parametrize(
+    "k", [k for _, k in EDGE_SCALARS], ids=[n for n, _ in EDGE_SCALARS]
+)
+def test_accumulator_never_meets_its_addend(k):
+    _assert_never_coincide(k)
+
+
+def test_accumulator_never_meets_its_addend_random():
+    rng = random.Random(2828)
+    for _ in range(2000):
+        _assert_never_coincide(rng.randrange(lc.N))
+
+
+def _is_prime(n: int) -> bool:
+    """Miller-Rabin over fixed bases."""
+    d, r = n - 1, 0
+    while d % 2 == 0:
+        d, r = d // 2, r + 1
+    for a in (2, 3, 5, 7, 11, 13, 17, 19, 23, 29, 31, 37):
+        x = pow(a, d, n)
+        if x in (1, n - 1):
+            continue
+        for _ in range(r - 1):
+            x = x * x % n
+            if x == n - 1:
+                break
+        else:
+            return False
+    return True
+
+
+def test_what_the_invariant_leans_on():
+    """Streams of 132 bits and digits under 16, so ``16m + d < 2**136 < N``;
+    and every point but infinity has order N: G has (N prime, N*G is
+    infinity), and N is the only multiple of N in Hasse's interval around
+    P + 1, so the group has N points and no cofactor."""
+    assert 4 * sec._GLV_NWIN == 132 and sec._WINDOW == 4
+    assert 1 << 136 < sec.N
+    assert _is_prime(sec.N)
+    assert lc.host.scalar_mul(sec.N - 1, lc.G) == lc.neg(lc.G)
+    hasse = 2 * (math.isqrt(sec.P) + 1)
+    assert abs(sec.N - (sec.P + 1)) <= hasse and sec.N > 2 * hasse

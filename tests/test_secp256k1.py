@@ -7,7 +7,10 @@ verification results must agree across CPU/TPU backends.
 Kernels compile once per process; tests share fixtures to amortize.
 """
 
+import random
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -15,6 +18,8 @@ from go_ibft_tpu.crypto import ecdsa as host
 from go_ibft_tpu.crypto import keccak256
 from go_ibft_tpu.ops import fields
 from go_ibft_tpu.ops import secp256k1 as sec
+
+import ladder_cases as lc
 
 # Cold EC-ladder kernel compiles take minutes; slow tier only.
 pytestmark = pytest.mark.slow
@@ -136,14 +141,19 @@ def test_ecdsa_verify_mask(signatures):
     zs[3] = (zs[3] + 1) % host.N
     rs[4] = 0
     ss[5] = host.N
+    # lanes 6, 7: lane 0's good signature under a key off the curve (y + 1;
+    # x + 1 keeping y), which ecmul2_base's ladder is not specified for
+    qxs = [k.pubkey[0] for k in keys] + [keys[0].pubkey[0], keys[0].pubkey[0] + 1]
+    qys = [k.pubkey[1] for k in keys] + [keys[0].pubkey[1] + 1, keys[0].pubkey[1]]
+    assert not host.on_curve(qxs[6], qys[6]) and not host.on_curve(qxs[7], qys[7])
     ok = sec.ecdsa_verify(
-        pack(k.pubkey[0] for k in keys),
-        pack(k.pubkey[1] for k in keys),
-        pack(zs),
-        pack(rs),
-        pack(ss),
+        pack(qxs),
+        pack(qys),
+        pack(zs + [zs[0]] * 2),
+        pack(rs + [rs[0]] * 2),
+        pack(ss + [ss[0]] * 2),
     )
-    assert list(np.asarray(ok)) == [True, True, True, False, False, False]
+    assert list(np.asarray(ok)) == [True, True, True, False, False, False, False, False]
 
 
 def test_ecdsa_recover_roundtrip(signatures):
@@ -232,10 +242,7 @@ def test_glv_split_parity():
     a1v, a2v = fields.from_limbs(a1), fields.from_limbs(a2)
     n1v, n2v = np.asarray(n1), np.asarray(n2)
     for i, k in enumerate(ks):
-        c1 = (k * sec._GLV_G1 + (1 << 383)) >> 384
-        c2 = (k * sec._GLV_G2 + (1 << 383)) >> 384
-        k1 = k - c1 * sec._GLV_A1 - c2 * sec._GLV_A2
-        k2 = -c1 * sec._GLV_B1 - c2 * sec._GLV_B2
+        k1, k2 = lc.glv_split_int(k)
         got1 = -a1v[i] if n1v[i] else a1v[i]
         got2 = -a2v[i] if n2v[i] else a2v[i]
         assert (got1, got2) == (k1, k2), hex(k)
@@ -268,3 +275,212 @@ def test_glv_ladder_negative_half_scalar_edges(points):
     got_q = unpack_affine(sec.ecmul2_base(pack([0] * 4), pack(ks), J.x, J.y))
     expected_q = [host.scalar_mul(k, p) for k, p in zip(ks, pts)]
     assert got_q == expected_q
+
+
+# -- the incomplete ladder add (PR 28) ----------------------------------------
+#
+# ecmul2_base's scan body adds without the P == Q fallback
+# (tests/test_ladder_structure.py has the count and the invariant).  Here the
+# COMPILED ladder meets Python integers and the Shamir ladder (one
+# accumulator, complete adds, no decomposition shared) on the scalars and
+# points of ladder_cases.py, and ecdsa_recover meets the host oracle lane for
+# lane.  With GO_IBFT_TPU_TESTS=1 on a TPU the same lanes also run tiled to
+# the sync path's 2,048: exactness on XLA:CPU is not exactness on the chip
+# (PERF.md section 6, PR 25).
+
+ECMUL2_CASES = lc.ecmul2_cases()
+SYNC_LANES = 2048
+
+
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def _tile(values, lanes):
+    values = list(values)
+    return [values[i % len(values)] for i in range(lanes)]
+
+
+def _ecmul2_affine(fn, lanes):
+    k1, k2, q = zip(*[(c[1], c[2], c[3]) for c in _tile(ECMUL2_CASES, lanes)])
+    j = fn(pack(k1), pack(k2), pack(p[0] for p in q), pack(p[1] for p in q))
+    inf = list(np.asarray(sec.is_infinity(j)))
+    return [None if i else xy for i, xy in zip(inf, unpack_affine(j))]
+
+
+@pytest.fixture(scope="module")
+def ecmul2_lanes():
+    lanes = -(-len(ECMUL2_CASES) // 128) * 128
+    return _ecmul2_affine(sec.ecmul2_base, lanes), _ecmul2_affine(
+        sec._ecmul2_base_shamir, lanes
+    )
+
+
+@pytest.mark.parametrize(
+    "lane", range(len(ECMUL2_CASES)), ids=[c[0] for c in ECMUL2_CASES]
+)
+def test_glv_ladder_edge_case(ecmul2_lanes, lane):
+    glv, shamir = ecmul2_lanes
+    _, k1, k2, q = ECMUL2_CASES[lane]
+    want = lc.expected_point(k1, k2, q)
+    assert glv[lane] == want
+    assert shamir[lane] == want
+
+
+def test_glv_ladder_edge_cases_cover_what_they_name():
+    ids = [c[0] for c in ECMUL2_CASES]
+    assert len(set(ids)) == len(ids)
+    wants = {c[0]: lc.expected_point(*c[1:]) for c in ECMUL2_CASES if ":" in c[0]}
+    assert all(w is None for n, w in wants.items() if n.startswith("inf:"))
+    assert all(w is not None for n, w in wants.items() if n.startswith("dbl:"))
+    for n, (_, k1, k2, q) in zip(ids, ECMUL2_CASES):
+        if n.startswith("dbl:"):  # the G half and the Q half are one point
+            assert host.scalar_mul(k1, lc.G) == host.scalar_mul(k2, q)
+
+
+def test_glv_ladder_edge_cases_at_sync_lanes_on_the_chip():
+    if not _on_tpu():
+        pytest.skip("2,048 lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
+    glv = _ecmul2_affine(sec.ecmul2_base, SYNC_LANES)
+    want = [lc.expected_point(*c[1:]) for c in ECMUL2_CASES]
+    assert glv == _tile(want, SYNC_LANES)
+
+
+def _non_residue_x():
+    x = 2
+    while pow((x**3 + 7) % host.P, (host.P - 1) // 2, host.P) == 1:
+        x += 1
+    return x
+
+
+def _recover_lanes():
+    """``(category, digest, r, s, v)`` for 128 lanes."""
+    rng = random.Random(2828)
+    keys = [host.PrivateKey.from_seed(f"pr28-{i}".encode()) for i in range(16)]
+    lanes = []
+
+    def signed(i):
+        d = keccak256(f"pr28-payload-{i}".encode())
+        return (d,) + tuple(host.sign(keys[i % len(keys)], d))
+
+    for i in range(72):
+        lanes.append(("valid",) + signed(i))
+    for i in range(72, 80):  # recovers, to another key than the signer's
+        d, r, s, v = signed(i)
+        lanes.append(("wrong_digest", keccak256(d), r, s, v))
+    for i in range(80, 84):
+        d, r, s, v = signed(i)
+        lanes.append(("wrong_parity", d, r, s, 1 - v))
+    for i in range(84, 88):  # high-s twin: (r, N - s, 1 - v) recovers the same key
+        d, r, s, v = signed(i)
+        lanes.append(("high_s", d, r, host.N - s, 1 - v))
+    gx = host.GX
+    lanes += [
+        ("handmade", bytes(32), gx, gx, 0),  # z = 0: Q = (s/r) * R = R
+        ("handmade", bytes(32), gx, gx, 1),
+        ("handmade", (1).to_bytes(32, "big"), gx, 1, 0),
+        ("handmade", (1).to_bytes(32, "big"), gx, 1, 1),
+        ("handmade", bytes(32), gx, host.N - 1, 0),
+        ("handmade", (host.N - 1).to_bytes(32, "big"), gx, 2, 1),
+    ]
+    # R = +-cG with z = +-s*c: u1*G + u2*R is infinity for one parity
+    for c in (1, 2, 15, rng.randrange(2, host.N)):
+        rx = host.scalar_mul(c, lc.G)[0]
+        if rx >= host.N:
+            continue
+        s = rng.randrange(1, host.N)
+        for v in (0, 1):
+            lanes.append(("infinity", (s * c % host.N).to_bytes(32, "big"), rx, s, v))
+    d, r, s, v = signed(90)
+    bad_x = _non_residue_x()
+    lanes += [
+        ("bad_r", d, 0, s, v),
+        ("bad_r", d, host.N, s, v),
+        ("bad_r", d, host.P - 1, s, v),
+        ("bad_r", d, (1 << 256) - 1, s, v),
+        ("bad_s", d, r, 0, v),
+        ("bad_s", d, r, host.N, v),
+        ("bad_s", d, r, (1 << 256) - 1, v),
+        ("bad_v", d, r, s, 2),
+        ("bad_v", d, r, s, 3),
+        ("bad_v", d, r, s, 27),
+        ("bad_v", d, r, s, -1),
+        ("off_curve_lift", d, bad_x, s, 0),
+        ("off_curve_lift", d, bad_x, s, 1),
+        ("off_curve_lift", bytes(32), bad_x, bad_x, 0),
+    ]
+    i = 100
+    while len(lanes) < 128:
+        lanes.append(("valid",) + signed(i))
+        i += 1
+    assert len(lanes) == 128
+    return lanes
+
+
+RECOVER_CATEGORIES = (
+    "valid",
+    "wrong_digest",
+    "wrong_parity",
+    "high_s",
+    "handmade",
+    "infinity",
+    "bad_r",
+    "bad_s",
+    "bad_v",
+    "off_curve_lift",
+)
+
+
+@pytest.fixture(scope="module")
+def recover_lanes():
+    # Built on first use, not at import: 128 pure-Python signatures.
+    lanes = _recover_lanes()
+    assert {lane[0] for lane in lanes} == set(RECOVER_CATEGORIES)
+    return lanes
+
+
+def _recover_on_device(recover_lanes, lanes):
+    cases = _tile(recover_lanes, lanes)
+    qx, qy, ok = sec.ecdsa_recover(
+        pack(host.digest_to_scalar(c[1]) for c in cases),
+        pack(c[2] for c in cases),
+        pack(c[3] for c in cases),
+        jnp.asarray([c[4] for c in cases], dtype=jnp.int32),
+    )
+    xy = zip(fields.from_limbs(qx), fields.from_limbs(qy))
+    return [q if o else None for o, q in zip(np.asarray(ok), xy)]
+
+
+@pytest.fixture(scope="module")
+def recover_oracle(recover_lanes):
+    return [host.recover_pure(*lane[1:]) for lane in recover_lanes]
+
+
+@pytest.fixture(scope="module")
+def recover_128(recover_lanes):
+    return _recover_on_device(recover_lanes, 128)
+
+
+@pytest.mark.parametrize("category", RECOVER_CATEGORIES)
+def test_ecdsa_recover_matches_host_oracle(
+    recover_lanes, recover_128, recover_oracle, category
+):
+    """Bit-identical key on every lane the oracle recovers, the identical
+    mask on every lane it refuses."""
+    idx = [i for i, lane in enumerate(recover_lanes) if lane[0] == category]
+    assert idx
+    assert [recover_128[i] for i in idx] == [recover_oracle[i] for i in idx]
+    refused = [recover_oracle[i] is None for i in idx]
+    if category in ("bad_r", "bad_s", "bad_v", "off_curve_lift"):
+        assert all(refused)
+    elif category in ("infinity", "handmade"):  # one parity of R cancels
+        assert any(refused) and not all(refused)
+    else:
+        assert not any(refused)
+
+
+def test_ecdsa_recover_at_sync_lanes_on_the_chip(recover_lanes, recover_oracle):
+    if not _on_tpu():
+        pytest.skip("2,048 lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
+    got = _recover_on_device(recover_lanes, SYNC_LANES)
+    assert got == _tile(recover_oracle, SYNC_LANES)

@@ -13,6 +13,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..crypto import PrivateKey
@@ -96,6 +97,36 @@ class RoundWorkload:
     thr_hi: int
     expected_prepare_mask: np.ndarray
     expected_seal_mask: np.ndarray
+
+
+def _quorum_tail(w: RoundWorkload) -> tuple:
+    """Voting powers and threshold, as every fused certify program ends."""
+    return (
+        jnp.asarray(w.powers_lo),
+        jnp.asarray(w.powers_hi),
+        jnp.int32(w.thr_lo),
+        jnp.int32(w.thr_hi),
+    )
+
+
+def prep_args(w: RoundWorkload) -> tuple:
+    """The PREPARE phase as ``ops.quorum.quorum_certify`` takes it."""
+    blocks, counts, r, s, v, senders, live = w.prepare
+    lanes = (blocks, counts, r, s, v, senders, w.table, live)
+    return tuple(jnp.asarray(a) for a in lanes) + _quorum_tail(w)
+
+
+def seal_args(w: RoundWorkload) -> tuple:
+    """The COMMIT-seal phase as ``ops.quorum.seal_quorum_certify`` takes it."""
+    hz, r, s, v, signers, live = w.seals
+    lanes = (hz, r, s, v, signers, w.table, live)
+    return tuple(jnp.asarray(a) for a in lanes) + _quorum_tail(w)
+
+
+def round_args(w: RoundWorkload) -> tuple:
+    """Both phases packed for the single-dispatch ``ops.quorum.round_certify``."""
+    lanes = (*w.prepare, *w.seals, w.table)
+    return tuple(jnp.asarray(a) for a in lanes) + _quorum_tail(w)
 
 
 def build_signed_round(

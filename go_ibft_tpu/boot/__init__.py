@@ -1,7 +1,7 @@
 """The production boot layer (ROADMAP item 5: kill the cold boot).
 
 A restarted node historically paid minutes of XLA:CPU compile before its
-first round (BENCH_r04: ~3 minutes for ``quorum_certify`` alone) — fatal
+first round (~3 minutes for ``quorum_certify`` alone in round 4) — fatal
 for fleet operations where nodes restart constantly.  This package makes
 restart cost a cache load instead:
 
@@ -12,7 +12,7 @@ restart cost a cache load instead:
 * :mod:`~go_ibft_tpu.boot.aot` — the AOT program store: lowers and
   compiles every pinned family through JAX's persistent compilation
   cache (``utils/jaxcache.py``), classifies each restore cold vs cached
-  by measured wall, and records cold compiles to the cost ledger.
+  by the cache's own miss events, and records cold compiles to the cost ledger.
 * :mod:`~go_ibft_tpu.boot.warmstart` — warm-start: WAL replay +
   verdict-cache seeding + compiled-program restore, all *before* the
   first round opens.

@@ -17,15 +17,17 @@ resolved by :mod:`go_ibft_tpu.utils.jaxcache`):
   marks the program stale, so boot tooling re-compiles it — a recorded
   cold compile, never a trusted stale artifact.
 
-Cold vs cached classification is by measured compile wall against
-``cold_threshold_s`` (``GO_IBFT_BOOT_COLD_S``, default 15 s): on this
-repo's CPU posture every pinned family compiles cold in ≥ ~50 s and
-loads warm in ≤ ~5 s, so the default separates the regimes with margin;
-programs below jax's own 1 s persistence floor (the keccak digest pack)
-are never classified cold — they cost less than the classification
-would.  Cold restores are recorded to the cost ledger
-(``compile_ledger.jsonl`` when enabled), which is how the second-boot
-zero-cold-compile proof in tests/test_boot.py reads its evidence.
+Cold vs cached classification is the persistent cache's own: a restore
+during which jax reports ``/jax/compilation_cache/cache_misses`` (it
+compiled the program and wrote the entry) is ``"cold"``; a cache hit is
+``"cached"``, and so is a program below the persistence floor
+(``GO_IBFT_CACHE_MIN_COMPILE_S``; the keccak digest pack), which jax
+compiles without writing and which costs less than a load would.  No
+duration decides it: a cache load on a busy host can take longer than a
+small compile on an idle one.  Cold restores are recorded to the cost
+ledger (``compile_ledger.jsonl`` when enabled), which is how the
+second-boot zero-cold-compile proof in tests/test_boot.py reads its
+evidence.
 """
 
 from __future__ import annotations
@@ -56,7 +58,26 @@ __all__ = [
 # ``_dp4``); ledger events carry bare family names.
 _SHAPE_SUFFIX = re.compile(r"(_dp\d+|_\d+[lv])$")
 
-DEFAULT_COLD_THRESHOLD_S = 15.0
+_cache_misses = 0
+_listening = False
+
+
+def _count_cache_misses() -> None:
+    """Count the persistent cache's miss events for this process (jax has
+    no call to remove a listener, so one is registered once)."""
+    global _listening
+    if _listening:
+        return
+    import jax
+
+    def on_event(name: str, **_kw) -> None:
+        global _cache_misses
+        if name == "/jax/compilation_cache/cache_misses":
+            _cache_misses += 1
+
+    jax.monitoring.register_event_listener(on_event)
+    _listening = True
+
 
 
 def family_of(program: str) -> str:
@@ -119,16 +140,10 @@ class AOTStore:
         self,
         cache_dir: Optional[str] = None,
         *,
-        cold_threshold_s: Optional[float] = None,
         site: str = "boot/aot.py",
     ) -> None:
         self.cache_dir = cache_dir or resolve_cache_dir()
         self.store_dir = os.path.join(self.cache_dir, "aot")
-        if cold_threshold_s is None:
-            cold_threshold_s = float(
-                os.environ.get("GO_IBFT_BOOT_COLD_S", DEFAULT_COLD_THRESHOLD_S)
-            )
-        self.cold_threshold_s = cold_threshold_s
         self.site = site
 
     # -- sidecars --------------------------------------------------------
@@ -190,14 +205,16 @@ class AOTStore:
 
         Each program is lowered at its registry shape and compiled
         through the persistent cache: a warm cache makes ``.compile()``
-        a load (measured, classified ``"cached"``); a cold or stale one
-        pays the real compile (classified ``"cold"`` past the
-        threshold and recorded to the cost ledger when ``record``).
+        a load (classified ``"cached"``); a cold or stale one pays the
+        real compile and writes the entry (classified ``"cold"`` by the
+        cache's miss event, and recorded to the cost ledger when
+        ``record``).
         ``export=True`` additionally serializes the ``jax.export``
         artifact next to the sidecar (provenance/ops tooling; the
         runtime always dispatches its own jit objects).
         """
         enable_persistent_cache()
+        _count_cache_misses()
         out: Dict[str, ProgramStatus] = {}
         for program, build in program_registry(programs).items():
             family = family_of(program)
@@ -206,6 +223,7 @@ class AOTStore:
                 fn, args = build()
                 lowered = fn.lower(*args)
                 t1 = time.perf_counter()
+                misses = _cache_misses
                 lowered.compile()
                 t2 = time.perf_counter()
             except ProgramUnavailable as exc:
@@ -217,7 +235,7 @@ class AOTStore:
             status = ProgramStatus(
                 program,
                 family,
-                "cold" if compile_s >= self.cold_threshold_s else "cached",
+                "cold" if _cache_misses > misses else "cached",
                 compile_ms=compile_s * 1e3,
                 lower_ms=(t1 - t0) * 1e3,
             )

@@ -1,10 +1,11 @@
 """Spawn one real boot leg (``python -m go_ibft_tpu.boot``) and parse it.
 
-Bench config #14 measures restart-to-first-finalized by restarting the
-node FOR REAL: a fresh interpreter, fresh jax, one shared compile cache
-handed to the child as ``JAX_COMPILATION_CACHE_DIR``.  That
-process-spawning lives here — in the boot package that owns the child
-entrypoint — so ``bench.py`` keeps exactly one subprocess implementation.
+Restart-to-first-finalized is measured by restarting the node FOR REAL: a
+fresh interpreter, fresh jax, one shared compile cache handed to the child
+as ``JAX_COMPILATION_CACHE_DIR``.  That process-spawning lives here, in
+the boot package that owns the child entrypoint, for the restart cell to
+use (``ecdsa-100v.restart``, ROADMAP C5); until that cell exists its only
+caller is ``tests/test_chip_smoke.py``.
 This module must stay import-light: the PARENT imports it, and pulling
 jax in here would distort the very spawn cost the legs measure.
 """

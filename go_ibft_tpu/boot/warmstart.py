@@ -20,7 +20,7 @@ A restarted node has three kinds of warmth to recover, in cost order:
    :class:`~go_ibft_tpu.verify.pipeline.PackCache` entries are keyed on
    live message *objects* and are deliberately NOT persisted — they
    rebuild on first pack; restoring them cross-process would alias dead
-   ids (docs/PERFORMANCE.md "Boot & warm-start").
+   ids.
 
 The second-boot proof rides the cost ledger: enable it with a
 ``compile_log`` and a warm boot records ZERO cold-compile events for the

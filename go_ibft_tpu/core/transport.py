@@ -81,7 +81,7 @@ class BatchingIngress:
     adaptive verifier's cutover the batch is host-verified one message at a
     time anyway, so waiting ``max_delay`` for company is pure added latency
     — it put the 4-validator happy path ~2 ms/phase behind the sequential
-    baseline (BENCH_r05: 0.86x).  Small flows therefore flush with
+    baseline (0.86x, a round-5 CPU run).  Small flows therefore flush with
     ``call_soon``: every message delivered in the same event-loop tick (a
     loopback multicast, a burst drained from one socket read) still lands
     in ONE batch, but the flush costs zero wall-clock.  The timed window

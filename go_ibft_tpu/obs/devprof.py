@@ -7,15 +7,11 @@ a ``jax.profiler`` window whose Chrome-format output
 :func:`go_ibft_tpu.obs.timeline.merge_device_trace`, so ONE file shows
 consensus phases over host spans over device ops.
 
-Two entry points:
+One entry point, :func:`capture`: a fixed-length window (the ``/profilez``
+endpoint: ``GET /profilez?seconds=0.5`` on a live
+:class:`~go_ibft_tpu.obs.httpd.TelemetryServer`).
 
-* :func:`capture` — a fixed-length window (the ``/profilez`` endpoint:
-  ``GET /profilez?seconds=0.5`` on a live
-  :class:`~go_ibft_tpu.obs.httpd.TelemetryServer`);
-* :func:`window` — a context manager wrapping a whole run
-  (``bench.py --device-trace OUT_DIR``).
-
-Both stamp ``host_anchor_us`` — the flight recorder's monotonic
+It stamps ``host_anchor_us`` — the flight recorder's monotonic
 microsecond clock read immediately after ``start_trace`` — so the merge
 can rebase device timestamps (which are relative to the profiler
 session) onto the exported host trace's clock.  Alignment is anchor-
@@ -38,10 +34,9 @@ import shutil
 import tempfile
 import threading
 import time
-from contextlib import contextmanager
 from typing import Optional
 
-__all__ = ["capture", "window", "newest_trace"]
+__all__ = ["capture", "newest_trace"]
 
 _lock = threading.Lock()
 
@@ -139,48 +134,4 @@ def capture(seconds: float = 0.5, out_dir: Optional[str] = None) -> dict:
             meta["error"] = "profiler window produced no .trace.json.gz"
         return meta
     finally:
-        _lock.release()
-
-
-@contextmanager
-def window(out_dir: str):
-    """Profile everything inside the block (``bench.py --device-trace``).
-
-    Yields the capture metadata dict; ``path`` / ``ok`` are filled in
-    when the block exits (read them AFTER the with-statement).  A
-    profiler that fails to start yields ``ok: False`` and the block runs
-    unprofiled — a dead profiler must not kill a bench run.
-    """
-    meta: dict = {"ok": False, "dir": out_dir, "path": None}
-    if not _lock.acquire(blocking=False):
-        meta["error"] = "busy: a profiler window is already open"
-        yield meta
-        return
-    started = False
-    try:
-        try:
-            os.makedirs(out_dir, exist_ok=True)
-        except OSError as mkdir_err:
-            # An unwritable --device-trace target must degrade like a
-            # dead profiler: the wrapped run proceeds unprofiled.
-            meta["error"] = f"{type(mkdir_err).__name__}: {mkdir_err}"
-            yield meta
-            return
-        err = _start(out_dir)
-        if err is None:
-            started = True
-            meta["host_anchor_us"] = time.perf_counter_ns() // 1000
-        else:
-            meta["error"] = err
-        yield meta
-    finally:
-        if started:
-            err = _stop()
-            if err is not None:
-                meta["error"] = err
-            else:
-                meta["path"] = newest_trace(out_dir)
-                meta["ok"] = meta["path"] is not None
-                if meta["path"] is None:
-                    meta["error"] = "profiler window produced no .trace.json.gz"
         _lock.release()

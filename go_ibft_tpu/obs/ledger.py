@@ -1,11 +1,11 @@
 """Process-wide runtime cost ledger: per-dispatch device-time attribution,
 compile-event tracing, and occupancy accounting.
 
-ISSUE 14 tentpole.  Nine bench rounds can say *what* ran but not *where
+ISSUE 14 tentpole.  Nine rounds could say *what* ran but not *where
 device time or padding went*: the verify plane buckets lanes to power-of-
 two shapes (padding waste unmeasured), every subsystem keeps its own
 one-off dispatch counter, and a cold XLA compile — the single worst
-production number (BENCH_r04's ~3-minute quorum_certify build) — leaves
+production number (round 4's ~3-minute quorum_certify build) — leaves
 no record of which program compiled, when, or for how long.  This module
 is the one attributed accounting plane behind all of it:
 
@@ -41,9 +41,8 @@ is the one attributed accounting plane behind all of it:
 Disabled mode is ONE predicate check (the :mod:`~go_ibft_tpu.obs.trace`
 rule): every instrumentation entry point reads one module global and
 returns a shared no-op immediately — no clock reads, no numpy, no lane
-counting.  ``tests/test_bench_contract.py`` pins the resulting overhead
-under 5% of the config #1 happy path alongside the tracing/histogram
-pins.  Thread-safe: accumulators are lock-guarded, the compile log is
+counting (``tests/test_cost_ledger.py`` pins the shared no-op).
+Thread-safe: accumulators are lock-guarded, the compile log is
 flushed per record, and the route tag rides a ``contextvars.ContextVar``
 so transport threads and the engine loop never interleave tags.
 """

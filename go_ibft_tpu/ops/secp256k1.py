@@ -241,8 +241,8 @@ def _double4(p: JacobianPoint) -> JacobianPoint:
 
     Four INLINE ``point_double`` calls instantiate the doubling body four
     times inside the ladder's scan body — ~7k stablehlo lines that are pure
-    compile time (the XLA:CPU certify budget tracks trace size,
-    docs/PERFORMANCE.md).  A fixed-length inner scan traces it ONCE; the
+    compile time (the XLA:CPU budget tracks trace size,
+    ``scripts/compile_budget.py``).  A fixed-length inner scan traces it ONCE; the
     cost is 3 extra While iterations per ladder window (~100 per recover),
     noise against the ~40 field muls each iteration already runs.
     """

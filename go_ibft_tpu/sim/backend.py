@@ -1,7 +1,7 @@
 """Deterministic simulation backend for cluster-scale lock-step runs.
 
 The sim-crypto analogue of the test harness MockBackend, importable from
-production drivers (bench.py, scripts/) without reaching into tests/:
+production drivers (``sim/cluster.py``, scripts/) without reaching into tests/:
 proposals are a pure function of HEIGHT (never round), so two runs that
 finalize every height produce byte-identical chains even when round
 timers jittered differently along the way — the property the cluster

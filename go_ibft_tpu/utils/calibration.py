@@ -3,13 +3,14 @@
 The AdaptiveBatchVerifier's cutover must come from measurement, not
 assertion (VERDICT r03 weak #5): the crossover lane count is
 ``device_dispatch_floor / host_per_verify_cost``, both of which depend on
-the actual chip and host CPU.  ``bench.py`` measures both on the
-target platform and persists them here; verifier construction reads them.
+the actual chip and host CPU.  :func:`save_calibration` persists a
+measurement of both; verifier construction reads it.  There is no writer
+at present (ROADMAP Queue 1 item 8: the sweep that measures the knees
+brings one), so every process runs on the static default below.
 
 The file lives next to the persistent XLA cache — same lifecycle: valid
-until the hardware or the kernels change, cheap to regenerate (one bench
-run), absent on a fresh checkout (the verifier then uses a conservative
-static default).
+until the hardware or the kernels change, absent on a fresh checkout (the
+verifier then uses a conservative static default).
 """
 
 from __future__ import annotations
@@ -22,11 +23,9 @@ from typing import Optional
 
 _DEFAULT_PATH = os.path.expanduser("~/.cache/go_ibft_tpu/calibration.json")
 
-# Conservative static fallback when no measurement exists: past the
-# smallest pad bucket the fused dispatch has historically beaten the
-# native host loop on a live chip (docs/PERFORMANCE.md); a wrong guess
-# here costs latency, never correctness (both routes are differential-
-# tested equal).
+# Conservative static fallback when no measurement exists (never measured
+# on the chip: ROADMAP Queue 1 item 8); a wrong guess here costs latency,
+# never correctness (both routes are differential-tested equal).
 DEFAULT_CUTOVER_LANES = 16
 
 
@@ -200,8 +199,7 @@ def measured_cutover() -> Optional[int]:
     Records measured on a non-TPU platform are ignored: a CPU "device
     floor" is enormous and would derive a cutover that silently disables
     the device path on a later live-TPU run sharing the same home dir.
-    (bench.py only saves on TPU runs; this is the belt to that suspender —
-    checked against the record, not ``jax.default_backend()``, so verifier
+    (Checked against the record, not ``jax.default_backend()``, so verifier
     construction never initializes a backend: a parent process that only
     builds verifiers must not claim the chip.)
     """

@@ -13,8 +13,7 @@ reads a second family — classic Prometheus-style cumulative-bucket
 histograms recorded at the hot seams (accept->finalize, verify drains per
 route, per-tenant scheduler drains, proof serving, WAL appends).  They are
 OFF by default behind one module-global predicate, exactly like the trace
-recorder: a disabled ``observe_fixed`` site costs one attribute read and
-the bench contract pins the tax under 5% of the config #1 happy path.
+recorder: a disabled ``observe_fixed`` site costs one attribute read.
 """
 
 from __future__ import annotations

@@ -2196,9 +2196,9 @@ class AdaptiveBatchVerifier:
 
         self._validators = validators_for_height
         if cutover_lanes is None:
-            # Measurement first (bench.py persists the device-dispatch
-            # floor vs host per-verify crossover for THIS platform), static
-            # conservative default only when no measurement exists.
+            # Measurement first (a persisted device-dispatch floor vs host
+            # per-verify crossover for THIS platform; no writer at present,
+            # utils/calibration.py), static default when none exists.
             cutover_lanes = (
                 calibration.measured_cutover()
                 or calibration.DEFAULT_CUTOVER_LANES

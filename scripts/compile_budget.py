@@ -1,6 +1,6 @@
 """Compile-budget regression guard: stablehlo line counts of the hot programs.
 
-Trace size IS compile time on XLA:CPU (docs/PERFORMANCE.md): the r04->r05
+Trace size IS compile time on XLA:CPU: the r04->r05
 rounds cut the 8-lane fused certify cold compile 265s -> 55s almost
 entirely by shrinking the traced program (mul 811 -> 316 lines,
 shear-reshape conv), and r06 cut it again (~-31%) by deduplicating

@@ -1,19 +1,18 @@
 #!/usr/bin/env python
 """Runtime cost report: top programs by device time, waste, compile cost.
 
-The read side of the ISSUE 14 cost ledger.  Sources, in priority order:
+The read side of the ISSUE 14 cost ledger.  One source is required:
 
 * ``--drain`` — run a small in-process host-route drain with the ledger
   enabled and report the live snapshot (the ``make cost-report`` CI
   smoke: proves the whole plane — seams, accumulators, attribution —
   renders end to end without any device compile);
-* ``--snapshot cost_ledger.json`` — the snapshot ``bench.py`` dumps at
-  exit (the acceptance path: report over a real bench run);
-* ``--evidence bench_evidence.jsonl`` — per-config ledger blocks stamped
-  on evidence lines (dispatches / occupancy / compiles per config).
-* ``--compile-ledger compile_ledger.jsonl`` — the append-only compile
-  event log (cold-compile duration table per program + call site),
-  printed alongside either of the above when the file exists.
+* ``--snapshot FILE`` — an ``obs.ledger.snapshot()`` a process saved as
+  JSON.
+
+``--compile-ledger compile_ledger.jsonl`` — the append-only compile event
+log (cold-compile duration table per program + call site) — is printed
+alongside either when the file exists.
 
 Attribution: dispatch records use the family names of the
 ``scripts/compile_budget.py`` registry (shape suffixes stripped), so the
@@ -30,7 +29,6 @@ import json
 import os
 import re
 import sys
-from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -242,43 +240,6 @@ def render_compile_ledger(path: str, *, top: int = 30) -> str:
     return "\n".join(lines)
 
 
-def render_evidence(path: str) -> str:
-    """Per-config ledger blocks off an evidence JSONL."""
-    from go_ibft_tpu.obs import gates
-
-    try:
-        lines_in = gates.parse_artifact(path)
-    except OSError as err:
-        return f"(cannot read evidence {path!r}: {err})"
-    rows = []
-    for line in lines_in:
-        block = line.get("ledger")
-        if not isinstance(block, dict):
-            continue
-        rows.append(
-            (
-                line.get("metric"),
-                block.get("dispatches"),
-                "-"
-                if block.get("occupancy") is None
-                else f"{block['occupancy']:.3f}",
-                block.get("device_ms"),
-                block.get("compiles"),
-                block.get("compile_ms"),
-            )
-        )
-    if not rows:
-        return f"(no ledger blocks in {path!r})"
-    out = ["== per-config ledger blocks (evidence deltas) =="]
-    out.append(
-        _table(
-            ("config", "dispatches", "occupancy", "device_ms", "compiles", "compile_ms"),
-            rows,
-        )
-    )
-    return "\n".join(out)
-
-
 def run_drain(compile_log=None) -> dict:
     """A small host-route drain with the ledger on (the CI smoke).
 
@@ -325,15 +286,16 @@ def run_drain(compile_log=None) -> dict:
 
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--snapshot", default="cost_ledger.json")
-    parser.add_argument("--compile-ledger", default="compile_ledger.jsonl")
-    parser.add_argument("--evidence", default=None)
-    parser.add_argument(
+    source_arg = parser.add_mutually_exclusive_group(required=True)
+    source_arg.add_argument(
+        "--snapshot", help="a saved cost-ledger snapshot (JSON) to report over"
+    )
+    source_arg.add_argument(
         "--drain",
         action="store_true",
-        help="run a small in-process host drain and report its ledger "
-        "(ignores --snapshot)",
+        help="run a small in-process host drain and report its ledger",
     )
+    parser.add_argument("--compile-ledger", default="compile_ledger.jsonl")
     parser.add_argument("--top", type=int, default=20)
     parser.add_argument(
         "--check",
@@ -354,9 +316,7 @@ def main() -> int:
                 snap = json.load(fh)
         except (OSError, ValueError) as err:
             print(
-                f"cost_report: cannot read snapshot {args.snapshot!r} "
-                f"({err}); run `python bench.py` (writes cost_ledger.json) "
-                "or use --drain",
+                f"cost_report: cannot read snapshot {args.snapshot!r} ({err})",
                 file=sys.stderr,
             )
             return 2
@@ -374,9 +334,6 @@ def main() -> int:
     if os.path.exists(args.compile_ledger):
         print()
         print(render_compile_ledger(args.compile_ledger))
-    if args.evidence:
-        print()
-        print(render_evidence(args.evidence))
 
     if args.check:
         rows = snap.get("dispatches", [])

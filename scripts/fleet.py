@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Multi-process fleet smoke: real validator processes, real sockets, SLO-gated.
 
-The CI entry (`make fleet-smoke` / `make fleet-bench`) for the node
+The CI entry (`make fleet-smoke`) for the node
 layer (ISSUE 19).  Launches N `python -m go_ibft_tpu.node` subprocesses
 gossiping IBFT over TCP/gRPC, floods their proof APIs with a concurrent
 client fleet plus churn/slowloris adversaries, then grades the run

@@ -3,8 +3,8 @@
 The r05 round recorded the 4-validator happy path at 0.86x of the
 sequential host baseline (33.9 ms/height) with no attribution — this
 script is the profiler that turns that one number into a budget.  It runs
-the same cluster shape as ``bench.py`` config #1 (4 validators, real
-ECDSA, BatchingIngress gossip, adaptive verifier) with the hot seams
+a 4-validator cluster (real ECDSA, BatchingIngress gossip, adaptive
+verifier; the shape ``tests/test_span_contract.py`` counts) with the hot seams
 instrumented from OUTSIDE the engine:
 
 * ``sign_ms``      — outbound envelope + seal signing (crypto.ecdsa.sign)

@@ -5,8 +5,7 @@ packing a 1000-message round costs more than the kernel, the end-to-end
 p50 is host-bound.  This script times each packing stage separately so
 optimization effort lands where the time actually goes, and diffs the
 vectorized packers against the kept per-message reference loops
-(``_pack_*_reference``) — the before/after evidence quoted in
-docs/PERFORMANCE.md's "Packing & pipelining" section.
+(``_pack_*_reference``).  XLA:CPU and host timings: never chip evidence.
 """
 
 import sys

@@ -3,9 +3,9 @@
 CI's slow tier runs each test under a per-test timeout; a cold-cache BLS
 pairing or EC-ladder compile can exceed that budget on a weak host.  This
 script runs compiles with NO per-test timeout so the subsequent pytest run
-only pays cache loads.  Shapes warmed here are the ones the slow suites and
-``bench.py`` actually dispatch (verifier buckets + bench workload buckets +
-the pairing program + the Pallas interpret-mode keccak).
+only pays cache loads.  Shapes warmed here are the ones the slow suites
+actually dispatch (verifier buckets + workload buckets + the pairing
+program + the Pallas interpret-mode keccak).
 
 The ``XLA_FLAGS`` device-count flag is part of the persistent-cache key,
 so this script force-matches tests/conftest.py's 8-virtual-device setup
@@ -48,9 +48,9 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# Default sizes cover the slow-tier suites + the bench CPU-fallback path
-# (8-lane engine bucket, 100-validator headline bucket).  The 300/1000
-# configs only run on a live TPU, where compiles happen on-chip against
+# Default sizes cover the slow-tier suites (8-lane engine bucket,
+# 100-validator bucket).  The 300/1000 committees
+# only run on a live TPU, where compiles happen on-chip against
 # the TPU cache key — CPU-warming them costs ~an hour each for nothing;
 # opt in with --sizes 8,100,300,1000 when needed.
 _DEFAULT_SIZES = (8, 100)
@@ -195,6 +195,10 @@ def _finish(cold: int) -> int:
 
 
 def main() -> int:
+    if "--help" in sys.argv or "-h" in sys.argv:
+        print(__doc__)
+        return 0
+
     from go_ibft_tpu.obs import ledger as cost_ledger
     from go_ibft_tpu.utils.jaxcache import enable_persistent_cache
 
@@ -215,11 +219,8 @@ def main() -> int:
 
     import jax.numpy as jnp
 
-    # bench.py owns the canonical argument packing for the fused kernels;
-    # importing it keeps the warmed programs in lockstep with what the
-    # bench and the engine actually dispatch.
-    from bench import _prep_args, _round_args, _seal_args
     from go_ibft_tpu.bench import build_round_workload
+    from go_ibft_tpu.bench.workload import prep_args, round_args, seal_args
     from go_ibft_tpu.ops.quorum import (
         quorum_certify,
         round_certify,
@@ -227,9 +228,8 @@ def main() -> int:
     )
     from go_ibft_tpu.verify import DeviceBatchVerifier
 
-    # Mesh FIRST: MULTICHIP_r{N}.json is the artifact a cold cache kills
-    # (r03 rc=124); everything after this line is cheaper to lose to a
-    # budget cut than the dryrun programs.
+    # Mesh FIRST: the dryrun programs are what a cold cache costs most;
+    # everything after this line is cheaper to lose to a time limit.
     if "--skip-mesh" not in sys.argv:
         t0 = time.perf_counter()
         from __graft_entry__ import dryrun_multichip
@@ -285,7 +285,7 @@ def main() -> int:
     # lanes coalesce into the SAME pinned recover/digest programs at the
     # claimed-signer-table shapes ((8, 8) for the tier-1 suites, (128,
     # 128) for a 100-validator quorum drain).  Cold-compiling either
-    # inside a test or bench timeout is the failure mode warmed here.
+    # inside a test timeout is the failure mode warmed here.
     t0 = time.perf_counter()
     from go_ibft_tpu.sched import CoalescedDispatcher
 
@@ -295,9 +295,9 @@ def main() -> int:
     # Lock-step cluster tick collective (ISSUE 17): the rows variant at
     # the 8-node real-crypto shape is AOT-pinned above (ici_tick_8n);
     # this additionally warms the lite variant at the 100-validator
-    # bench/soak shape (100 nodes -> 5-way shard over the 8 forced host
-    # devices) so `make cluster-bench` and the tier-1 cluster soak never
-    # pay the gather compile inside a timed window or per-test budget.
+    # soak shape (100 nodes -> 5-way shard over the 8 forced host
+    # devices) so the tier-1 cluster soak never pays the gather compile
+    # inside a per-test budget.
     t0 = time.perf_counter()
     import jax
     import numpy as _np_ici
@@ -333,9 +333,9 @@ def main() -> int:
             ),
             site="scripts/warm_kernels.py",
         ):
-            quorum_certify(*_prep_args(w))[0].block_until_ready()
-            seal_quorum_certify(*_seal_args(w))[0].block_until_ready()
-            round_certify(*_round_args(w))[0].block_until_ready()
+            quorum_certify(*prep_args(w))[0].block_until_ready()
+            seal_quorum_certify(*seal_args(w))[0].block_until_ready()
+            round_certify(*round_args(w))[0].block_until_ready()
         _stamp(f"quorum kernels @{n} validators", t0)
 
     t0 = time.perf_counter()

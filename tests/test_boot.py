@@ -14,8 +14,6 @@ Pins the tentpole contracts:
 * ``TenantScheduler`` live reconfiguration: zero-downtime add/remove
   (drained removal, stale handles shed to the host oracle), mid-traffic
   dispatcher swaps, and per-tenant budgets surfaced in ``stats()``;
-* ``obs/gates.py`` synthesizes ``boot_cold_ms`` / ``boot_cached_ms``
-  regression metrics from the config #14 evidence line;
 * ``scripts/boot_check.py`` passes a genuine cold->warm manifest pair
   and fails a no-speedup or fingerprint-mismatched one.
 """
@@ -124,10 +122,9 @@ def _boot_once(tag: str, cache_dir: str, tmp_path) -> tuple:
     env = dict(os.environ)
     env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     env["GO_IBFT_COMPILE_LEDGER"] = str(ledger)
-    # Persist even the sub-second digest compile (jax's floor is 1 s) and
-    # classify it cold (~0.4 s compile vs ~0.04 s cache load).
+    # Persist even the sub-second digest compile (jax's floor is 1 s), so
+    # that the first boot writes a cache entry and the second one hits it.
     env["GO_IBFT_CACHE_MIN_COMPILE_S"] = "0"
-    env["GO_IBFT_BOOT_COLD_S"] = "0.15"
     proc = subprocess.run(
         [
             sys.executable,
@@ -168,9 +165,6 @@ def test_second_boot_pays_zero_cold_compiles(tmp_path):
     assert warm_report["cold"] == 0
     assert warm_report["programs"]["digest_words_8l"]["status"] == "cached"
     assert warm_events == []
-    warm_ms = warm_report["programs"]["digest_words_8l"]["compile_ms"]
-    cold_ms = cold_report["programs"]["digest_words_8l"]["compile_ms"]
-    assert warm_ms < cold_ms
 
 
 # ---------------------------------------------------------------------------
@@ -369,30 +363,8 @@ def test_per_tenant_budgets_surface_in_stats():
 
 
 # ---------------------------------------------------------------------------
-# gates + boot_check wiring
+# boot_check wiring
 # ---------------------------------------------------------------------------
-
-
-def test_gates_synthesize_boot_metric_lines():
-    from go_ibft_tpu.obs.gates import higher_is_better, ledger_metric_lines
-
-    lines = [
-        {
-            "metric": "boot_warm_start",
-            "value": 10.0,
-            "unit": "x",
-            "backend": "cpu-fallback",
-            "boot_cold_ms": 58268.8,
-            "boot_cached_ms": 5804.7,
-        },
-        {"metric": "bench_platform", "value": "cpu"},
-    ]
-    synth = {s["metric"]: s for s in ledger_metric_lines(lines)}
-    assert synth["boot_warm_start.boot_cold_ms"]["value"] == 58268.8
-    assert synth["boot_warm_start.boot_cached_ms"]["value"] == 5804.7
-    for s in synth.values():
-        assert s["unit"] == "ms"
-        assert not higher_is_better(s["metric"], s["unit"])
 
 
 def test_boot_check_passes_speedup_and_fails_regression():

@@ -248,33 +248,6 @@ def test_fleet_pins_only_host_route_children_to_the_cpu(tmp_path, monkeypatch):
     assert "JAX_PLATFORMS" not in envs[1]  # inherits the environment untouched
 
 
-def test_bench_device_schedule_skips_configs_that_spawn_children():
-    import bench
-
-    assert bench._CHILD_PROCESS_CONFIGS == (
-        bench.config14_boot_warm_start,
-        bench.config17_fleet,
-    )
-    for fn in bench._CHILD_PROCESS_CONFIGS:  # still scheduled: skip is a line
-        assert fn in [f for f, _ in bench._DEVICE_SCHEDULE]
-
-
-def test_bench_route_stamp_comes_from_the_ledger():
-    import bench
-    from go_ibft_tpu.obs import ledger
-
-    ledger.enable()
-    try:
-        before = ledger.snapshot()
-        ledger.record_dispatch("ecdsa_recover", "warmup", live=0, padded=128)
-        ledger.record_dispatch("ecdsa_recover", "host", live=3, padded=3)
-        ledger.record_dispatch("ecdsa_recover", "device", live=90, padded=128)
-        assert bench._served_route(before) == "device"
-        assert bench._served_route(ledger.snapshot()) == "none"
-    finally:
-        ledger.disable()
-
-
 # ---------------------------------------------------------------------------
 # the node warms what its committee dispatches; flushes are counted where
 # they run

@@ -12,8 +12,7 @@ Pins the tentpole contracts:
 * the ledger's counts agree with the legacy ad-hoc counters
   (``multipair_dispatches``, ``merge_dispatches``, sched dispatch
   observations) on a fixed workload — the counter-unification satellite;
-* /statusz, /metrics, /profilez, the evidence-line ledger block, the
-  ledger regression gates, and the cost-report renderer all read it.
+* /statusz, /metrics, /profilez and the cost-report renderer all read it.
 """
 
 import gzip
@@ -91,8 +90,8 @@ def test_dispatch_records_accumulate_with_occupancy():
 
 
 def test_totals_exclude_warmup_routes_from_occupancy():
-    """Warmup lanes are all-dead by design; totals()/status()/evidence
-    occupancy must not be dragged toward 0 by a warmup having run."""
+    """Warmup lanes are all-dead by design; totals()/status() occupancy
+    must not be dragged toward 0 by a warmup having run."""
     ledger.enable()
     ledger.record_dispatch("quorum_certify", "device", live=6, padded=8, ms=1.0)
     ledger.record_dispatch("ecdsa_recover", "warmup", live=0, padded=2048, ms=900.0)
@@ -129,7 +128,7 @@ def test_shared_compile_span_wall_splits_not_multiplies(tmp_path):
 
 def test_compile_ledger_record_schema_is_pinned(tmp_path):
     """The compile_ledger.jsonl record schema is a cross-process contract:
-    boot/aot.py writes it, bench config #14's second-boot proof and
+    boot/aot.py writes it, tests/test_boot.py's second-boot proof and
     scripts/cost_report.py's event table read it.  Exactly ``{program,
     ms, site, ts}`` per record, plus ``shared_span`` only when several
     programs split one timed span."""
@@ -368,7 +367,7 @@ def test_pipeline_readback_attributes_device_ms():
 
 
 # ---------------------------------------------------------------------------
-# export surfaces: /metrics, /statusz, evidence, gates, report
+# export surfaces: /metrics, /statusz, report
 # ---------------------------------------------------------------------------
 
 
@@ -392,89 +391,6 @@ def test_metrics_exposition_renders_ledger_families():
         series['go_ibft_ledger_compile_ms_total{program="quorum_certify"}']
         == 120.0
     )
-
-
-def test_evidence_lines_carry_ledger_delta_blocks(tmp_path):
-    from go_ibft_tpu.obs.evidence import EvidenceWriter
-
-    ledger.enable()
-    writer = EvidenceWriter(str(tmp_path / "ev.jsonl"), truncate=True)
-    ledger.record_dispatch("quorum_certify", "device", live=4, padded=8, ms=3.0)
-    ledger.record_compile("quorum_certify", 50.0)
-    rec1 = writer.record("config_a", value=1.0)
-    ledger.record_dispatch("quorum_certify", "device", live=8, padded=8)
-    rec2 = writer.record("config_b", value=2.0)
-    rec3 = writer.record("config_c", value=3.0)
-    writer.close()
-    assert rec1["ledger"]["dispatches"] == 1
-    assert rec1["ledger"]["occupancy"] == pytest.approx(0.5)
-    assert rec1["ledger"]["compiles"] == 1
-    # Deltas, not cumulative: config_b only sees its own dispatch.
-    assert rec2["ledger"]["dispatches"] == 1
-    assert rec2["ledger"]["occupancy"] == pytest.approx(1.0)
-    assert rec2["ledger"]["compiles"] == 0
-    assert rec3["ledger"]["dispatches"] == 0
-    # And the lines on disk match what record() returned.
-    lines = [
-        json.loads(line)
-        for line in (tmp_path / "ev.jsonl").read_text().splitlines()
-    ]
-    assert [line["ledger"]["dispatches"] for line in lines] == [1, 1, 0]
-
-
-def test_evidence_without_ledger_has_no_block(tmp_path):
-    from go_ibft_tpu.obs.evidence import EvidenceWriter
-
-    writer = EvidenceWriter(str(tmp_path / "ev.jsonl"), truncate=True)
-    rec = writer.record("config_a", value=1.0)
-    writer.close()
-    assert "ledger" not in rec
-
-
-def test_gate_ledger_evidence_flags_dispatch_growth(tmp_path):
-    from go_ibft_tpu.obs import gates
-
-    prior = [
-        {"metric": "bench_platform", "value": "cpu"},
-        {
-            "metric": "config_a",
-            "value": 1.0,
-            "backend": "cpu-fallback",
-            "ledger": {"dispatches": 10, "occupancy": 0.9},
-        },
-    ]
-    (tmp_path / "BENCH_r01.json").write_text(
-        json.dumps({"rc": 0, "tail": "\n".join(json.dumps(p) for p in prior)})
-    )
-    fresh = [
-        {
-            "metric": "config_a",
-            "value": 1.0,
-            "backend": "cpu-fallback",
-            "ledger": {"dispatches": 15, "occupancy": 0.5},
-        },
-    ]
-    results = gates.gate_ledger_evidence(
-        fresh, str(tmp_path), backend="cpu-fallback"
-    )
-    by_config = {r.config: r for r in results}
-    # +50% dispatches fails; occupancy halving fails too (higher=better).
-    assert by_config["config_a.ledger_dispatches"].status == "fail"
-    assert by_config["config_a.ledger_occupancy"].status == "fail"
-    # Same counts pass.
-    ok = gates.gate_ledger_evidence(
-        [
-            {
-                "metric": "config_a",
-                "value": 1.0,
-                "backend": "cpu-fallback",
-                "ledger": {"dispatches": 10, "occupancy": 0.9},
-            }
-        ],
-        str(tmp_path),
-        backend="cpu-fallback",
-    )
-    assert {r.status for r in ok} == {"pass"}
 
 
 def test_cost_report_renderer_and_attribution():

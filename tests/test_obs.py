@@ -1,23 +1,15 @@
-"""Observability subsystem: flight recorder, evidence capture, gates.
+"""Observability subsystem: the flight recorder and its export.
 
 Pins the ISSUE 4 contracts:
 
 * the span API is thread-safe, ring-bounded, and exports valid Chrome
   ``trace_event`` JSON with one track per node;
 * disabled-mode tracing is a single predicate check (a no-op context
-  manager — no recorder, no clock reads);
-* the evidence writer is append-only JSONL, flushed per record, stamped
-  with ``backend``/``probe`` provenance;
-* ``bench.py`` that was not told to use the CPU and finds no TPU exits
-  non-zero (there is no fallback);
-* the regression gates compare fresh evidence against the best prior
-  ``BENCH_r*.json`` on the same backend only, direction-aware.
+  manager — no recorder, no clock reads).
 """
 
 import json
-import os
 import pathlib
-import subprocess
 import sys
 import threading
 import time
@@ -26,10 +18,8 @@ import pytest
 
 sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
-from go_ibft_tpu.obs import evidence, export, gates, trace
+from go_ibft_tpu.obs import export, trace
 from go_ibft_tpu.obs.recorder import RingRecorder
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 @pytest.fixture(autouse=True)
@@ -196,191 +186,3 @@ async def test_cluster_height_emits_per_node_tracks():
     assert "prepare.drain" in names and "commit.drain" in names
     node_tracks = {r[2] for r in records if r[1] == "round.start"}
     assert len(node_tracks) == 4  # one timeline row per validator
-
-
-# ---------------------------------------------------------------------------
-# evidence: the append-only writer
-# ---------------------------------------------------------------------------
-
-def test_evidence_writer_appends_flushes_and_stamps(tmp_path):
-    path = tmp_path / "ev.jsonl"
-    with evidence.EvidenceWriter(
-        str(path), backend="cpu-fallback", probe="timeout"
-    ) as writer:
-        writer.record("config_a", {"metric": "config_a", "value": 1.5})
-        # flushed per record: the line is on disk BEFORE the writer closes
-        lines = path.read_text().splitlines()
-        assert len(lines) == 1
-        writer.record("config_b", {"metric": "config_b", "value": None})
-        assert writer.missing(["config_a", "config_b", "config_c"]) == [
-            "config_c"
-        ]
-    lines = [json.loads(line) for line in path.read_text().splitlines()]
-    assert [line["config"] for line in lines] == ["config_a", "config_b"]
-    for line in lines:
-        for field in evidence.REQUIRED_EVIDENCE_FIELDS:
-            assert field in line, (field, line)
-        assert line["backend"] == "cpu-fallback"
-        assert line["probe"] == "timeout"
-    # append-only across writers
-    with evidence.EvidenceWriter(str(path), backend="tpu", probe="ok") as w2:
-        w2.record("config_c", {"metric": "config_c", "value": 2.0})
-    assert len(path.read_text().splitlines()) == 3
-
-
-# ---------------------------------------------------------------------------
-# no fallback: a run that was not told to use the CPU needs the chip
-# ---------------------------------------------------------------------------
-
-
-def test_bench_without_tpu_and_without_cpu_pin_exits_nonzero(tmp_path):
-    """``python bench.py`` that was NOT asked for the CPU and finds no TPU
-    must exit non-zero, naming the platform it found, before any config
-    runs — the CPU schedule is for ``JAX_PLATFORMS=cpu`` only."""
-    env = dict(os.environ, GO_IBFT_EVIDENCE_PATH=str(tmp_path / "ev.jsonl"))
-    env.pop("JAX_PLATFORMS", None)
-    proc = subprocess.run(
-        [sys.executable, "bench.py"],
-        cwd=REPO,
-        capture_output=True,
-        text=True,
-        timeout=120,
-        env=env,
-    )
-    assert proc.returncode != 0, proc.stdout + proc.stderr
-    lines = [
-        json.loads(line)
-        for line in proc.stdout.splitlines()
-        if line.startswith("{")
-    ]
-    # the error, then only the run's own ledger summary: no config ran
-    assert lines[0]["metric"] == "bench_error", lines
-    assert {line["metric"] for line in lines[1:]} <= {"cost_ledger"}, lines
-    assert "'cpu'" in lines[0]["error"] and "no TPU" in lines[0]["error"]
-
-
-# ---------------------------------------------------------------------------
-# regression gates
-# ---------------------------------------------------------------------------
-
-
-def _write_prior(tmp_path, name, platform, lines):
-    tail = "\n".join(json.dumps(line) for line in lines)
-    tail = json.dumps({"metric": "bench_platform", "value": platform}) + "\n" + tail
-    (tmp_path / name).write_text(
-        json.dumps({"n": 1, "rc": 0, "tail": tail})
-    )
-
-
-def test_gates_direction_aware_pass_warn_fail(tmp_path):
-    _write_prior(
-        tmp_path,
-        "BENCH_r01.json",
-        "cpu (fallback: default backend unavailable)",
-        [
-            {"metric": "lat_ms", "value": 10.0, "unit": "ms"},
-            {"metric": "tput", "value": 1000.0, "unit": "sig-verifies/sec"},
-            {"metric": "steady", "value": 5.0, "unit": "ms"},
-        ],
-    )
-    # A prior TPU round must NOT gate a CPU-fallback run.
-    _write_prior(
-        tmp_path,
-        "BENCH_r02.json",
-        "tpu",
-        [{"metric": "lat_ms", "value": 0.001, "unit": "ms"}],
-    )
-    fresh = [
-        {"metric": "bench_platform", "value": "cpu (fallback: x)"},
-        {"metric": "lat_ms", "value": 14.0, "unit": "ms"},  # +40% -> fail
-        {"metric": "tput", "value": 880.0, "unit": "sig-verifies/sec"},  # -12% -> warn
-        {"metric": "steady", "value": 5.2, "unit": "ms"},  # +4% -> pass
-        {"metric": "brand_new", "value": 1.0, "unit": "ms"},  # no prior -> info
-    ]
-    results = {r.config: r for r in gates.gate_evidence(fresh, str(tmp_path))}
-    assert results["lat_ms"].status == "fail"
-    assert results["lat_ms"].prior == 10.0  # the CPU prior, not the TPU one
-    assert results["tput"].status == "warn"
-    assert results["steady"].status == "pass"
-    assert results["brand_new"].status == "info"
-    table = gates.render_table(list(results.values()))
-    assert "FAIL" in table and "BENCH_r01.json" in table
-
-
-def test_gates_best_prior_picks_best_not_latest(tmp_path):
-    _write_prior(
-        tmp_path,
-        "BENCH_r01.json",
-        "cpu",
-        [{"metric": "lat_ms", "value": 8.0, "unit": "ms"}],
-    )
-    _write_prior(
-        tmp_path,
-        "BENCH_r03.json",
-        "cpu",
-        [{"metric": "lat_ms", "value": 12.0, "unit": "ms"}],
-    )
-    best = gates.best_prior(str(tmp_path), "cpu-fallback")
-    assert best["lat_ms"][0] == 8.0 and best["lat_ms"][1] == "BENCH_r01.json"
-
-
-def test_gates_missing_fresh_measurement_warns(tmp_path):
-    _write_prior(
-        tmp_path,
-        "BENCH_r01.json",
-        "cpu",
-        [{"metric": "lat_ms", "value": 8.0, "unit": "ms"}],
-    )
-    fresh = [
-        {"metric": "bench_platform", "value": "cpu"},
-        {"metric": "lat_ms", "value": None, "note": "skipped: no budget"},
-    ]
-    (result,) = gates.gate_evidence(fresh, str(tmp_path))
-    assert result.status == "warn" and "skipped" in result.note
-
-
-def test_gates_parse_real_driver_artifact():
-    """The repo's own BENCH_r05.json (driver wrapper schema) parses and
-    classifies as cpu-fallback."""
-    lines = gates.parse_artifact(str(REPO / "BENCH_r05.json"))
-    assert gates.artifact_backend(lines) == "cpu-fallback"
-    assert "happy_path_4v_height_latency" in gates.config_lines(lines)
-
-
-def test_obs_report_cli_runs_against_repo(tmp_path):
-    """scripts/obs_report.py end to end over a synthetic fresh artifact."""
-    fresh = tmp_path / "bench_evidence.jsonl"
-    fresh.write_text(
-        "\n".join(
-            json.dumps(line)
-            for line in [
-                {
-                    "metric": "happy_path_4v_height_latency",
-                    "config": "happy_path_4v_height_latency",
-                    "value": 20.0,
-                    "unit": "ms",
-                    "backend": "cpu-fallback",
-                    "probe": "ok",
-                    "ts": 0,
-                }
-            ]
-        )
-    )
-    proc = subprocess.run(
-        [
-            sys.executable,
-            str(REPO / "scripts" / "obs_report.py"),
-            "--evidence",
-            str(fresh),
-            "--repo",
-            str(REPO),
-            "--fail-on",
-            "never",
-        ],
-        capture_output=True,
-        text=True,
-        timeout=60,
-    )
-    assert proc.returncode == 0, proc.stdout + proc.stderr
-    assert "happy_path_4v_height_latency" in proc.stdout
-    assert "backend: cpu-fallback" in proc.stdout

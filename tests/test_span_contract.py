@@ -1,0 +1,366 @@
+"""The host half of the span contract, and the counts a height implies.
+
+``benchmark/layer_metrics/engine_self_ms.py`` subtracts the union of the
+verifier's OUTER spans (``verify.drain`` / ``verify.early_exit``) from an
+operation's wall, and the ``verify.pack`` / ``verify.device_wait`` readers
+take those children per drain.  Both stand on one rule: every call of a
+verifier entry point the engine or ``SyncClient`` makes emits exactly one
+outer span, with the children its route owes inside it and on its track.
+``tests/test_kernel_stages.py`` holds the device half (the ``recover.*``
+scopes); this file holds the host half, on both routes, with the device
+programs replaced by stubs so that no ladder compiles.
+
+The second half pins what one finalized round-0 height costs in COUNTS on
+a loopback cluster (verifier calls, lanes, ingress flushes): integers the
+protocol implies, never wall times.
+"""
+
+import asyncio
+import collections
+
+import numpy as np
+import pytest
+
+from go_ibft_tpu.bench import build_seal_lane_workload, build_signed_round
+from go_ibft_tpu.chain import FinalizedBlock, LoopbackSyncNetwork, SyncClient
+from go_ibft_tpu.core import IBFT, BatchingIngress
+from go_ibft_tpu.core.validator_manager import calculate_quorum
+from go_ibft_tpu.crypto import PrivateKey
+from go_ibft_tpu.crypto.backend import ECDSABackend, proposal_hash_of
+from go_ibft_tpu.messages.helpers import extract_committed_seal
+from go_ibft_tpu.messages.wire import Proposal, View
+from go_ibft_tpu.obs import ledger as cost_ledger
+from go_ibft_tpu.obs import trace
+from go_ibft_tpu.utils import metrics
+from go_ibft_tpu.verify import (
+    AdaptiveBatchVerifier,
+    DeviceBatchVerifier,
+    HostBatchVerifier,
+    batch,
+)
+from go_ibft_tpu.verify.batch import (
+    EARLY_EXIT_DRAINS_KEY,
+    EARLY_EXIT_SKIPPED_KEY,
+)
+from go_ibft_tpu.verify.mesh_batch import MeshBatchVerifier
+
+# 24 validators: above the 16-lane default cutover, so the default
+# ``AdaptiveBatchVerifier`` (what an engine gets) takes the device route
+# with the whole round and the host route with a handful of it.
+N_VALIDATORS = 24
+HOST_LANES = 4
+
+OUTER = ("verify.drain", "verify.early_exit")
+CHILDREN = (
+    "verify.pack",
+    "verify.dispatch",
+    "verify.device_wait",
+    "verify.quorum",
+)
+DRAIN_CHILDREN = {name: 1 for name in CHILDREN}
+
+
+@pytest.fixture
+def recorder():
+    assert not trace.enabled()
+    rec = trace.enable()
+    try:
+        yield rec
+    finally:
+        trace.disable()
+
+
+@pytest.fixture
+def stub_kernels(monkeypatch):
+    """The two served programs, replaced by shape-true stubs: every live
+    lane is valid, every digest is zero.  The seams look the kernels up in
+    the module at call time, so nothing of the ladder is traced."""
+
+    def recover(zw, r, s, v, claimed, table, live):
+        return np.asarray(live, dtype=bool)
+
+    def digest(blocks, counts):
+        return np.zeros((np.shape(blocks)[0], 8), dtype=np.uint32)
+
+    monkeypatch.setattr(batch, "_recover_kernel", recover)
+    monkeypatch.setattr(batch, "_digest_kernel", digest)
+
+
+@pytest.fixture(scope="module")
+def signed_round():
+    return build_signed_round(N_VALIDATORS, seed=29)
+
+
+def _validators(signed):
+    return ECDSABackend.static_validators(
+        {m.sender: 1 for m in signed.prepares}
+    )
+
+
+def _outer_and_children(rec, outer_name):
+    """The one outer span of ``rec`` and the count of each ``verify.*``
+    child that lies inside it on its track."""
+    assert rec.dropped == 0
+    spans = [r for r in rec.snapshot() if r[0] == "X"]
+    outers = [r for r in spans if r[1] in OUTER]
+    assert [r[1] for r in outers] == [outer_name], outers
+    (outer,) = outers
+    _, _, track, t0, dur, _ = outer
+    inside = collections.Counter()
+    for _, name, child_track, ts, child_dur, _ in spans:
+        if name not in CHILDREN:
+            continue
+        # Every verify.* child belongs to the drain: same track, and
+        # within it (timestamps are whole microseconds, hence the 1).
+        assert child_track == track, (name, child_track, track)
+        assert t0 <= ts and ts + child_dur <= t0 + dur + 1, (name, outer)
+        inside[name] += 1
+    return outer, dict(inside)
+
+
+def _call(verifier, entry, signed, lanes):
+    if entry == "verify_senders":
+        return verifier.verify_senders(signed.prepares[:lanes])
+    if entry == "verify_committed_seals":
+        return verifier.verify_committed_seals(
+            signed.proposal_hash, signed.seals[:lanes], signed.height
+        )
+    if entry == "verify_seal_lanes":
+        return verifier.verify_seal_lanes(
+            [(signed.proposal_hash, s) for s in signed.seals[:lanes]],
+            signed.height,
+        )
+    report = verifier.verify_seals_early_exit(
+        signed.proposal_hash, signed.seals[:lanes], signed.height
+    )
+    return report.mask[report.verified]
+
+
+# entry point -> (outer span, children owed on the host route, on the
+# device route).  The host early exit is one sequential loop: it owes no
+# phases.  The device early exit stops dispatching at quorum and leaves
+# the tally to exact host ints outside any ``verify.quorum`` span; 24
+# equal-power seals fit its first 32-lane chunk, so one of each.
+ENTRY_POINTS = {
+    "verify_senders": ("verify.drain", DRAIN_CHILDREN, DRAIN_CHILDREN),
+    "verify_committed_seals": ("verify.drain", DRAIN_CHILDREN, DRAIN_CHILDREN),
+    "verify_seal_lanes": ("verify.drain", DRAIN_CHILDREN, DRAIN_CHILDREN),
+    "verify_seals_early_exit": (
+        "verify.early_exit",
+        {},
+        {"verify.pack": 1, "verify.dispatch": 1, "verify.device_wait": 1},
+    ),
+}
+
+
+@pytest.mark.parametrize("route", ["host", "device"])
+@pytest.mark.parametrize("entry", list(ENTRY_POINTS))
+def test_one_outer_span_with_the_children_its_route_owes(
+    entry, route, signed_round, stub_kernels, recorder
+):
+    outer_name, host_children, device_children = ENTRY_POINTS[entry]
+    verifier = AdaptiveBatchVerifier(_validators(signed_round))
+    assert HOST_LANES < verifier.cutover <= N_VALIDATORS
+    lanes = HOST_LANES if route == "host" else N_VALIDATORS
+    mask = _call(verifier, entry, signed_round, lanes)
+    outer, inside = _outer_and_children(recorder, outer_name)
+    assert outer[5]["route"] == route
+    assert inside == (host_children if route == "host" else device_children)
+    # The host route really verified; the stub accepts every live lane.
+    assert mask.all() and len(mask) > 0
+
+
+def test_chunked_device_drain_is_one_span_with_a_set_of_phases_a_chunk(
+    stub_kernels, recorder
+):
+    """A drain above the largest lane bucket is still ONE ``verify.drain``
+    (what ``engine_self_ms`` subtracts once): a pack, a dispatch and a wait
+    per chunk, one ``verify.quorum``; and the pipeline has packed the
+    second chunk before it waits for the first."""
+    cap = batch._BATCH_BUCKETS[-1]
+    w = build_seal_lane_workload(2 * cap + 4, n_validators=N_VALIDATORS)
+    verifier = DeviceBatchVerifier(w.validators)
+    mask = verifier.verify_seal_lanes(w.lanes, w.height)
+    assert mask.all() and len(mask) == 2 * cap + 4
+    _, inside = _outer_and_children(recorder, "verify.drain")
+    assert inside == {
+        "verify.pack": 3,
+        "verify.dispatch": 3,
+        "verify.device_wait": 3,
+        "verify.quorum": 1,
+    }
+    starts = collections.defaultdict(list)
+    for _, name, _, ts, _, _ in recorder.snapshot():
+        starts[name].append(ts)
+    assert sorted(starts["verify.pack"])[1] <= min(starts["verify.device_wait"])
+
+
+def test_mesh_route_owes_the_same_phases_inside_a_shard_span(
+    stub_kernels, recorder
+):
+    w = build_seal_lane_workload(64, n_validators=N_VALIDATORS)
+    verifier = MeshBatchVerifier(w.validators, dp=4)
+    assert verifier.sharded
+
+    def mask_kernel(zw, r, s, v, claimed, table, live):
+        return np.asarray(live, dtype=bool)
+
+    verifier._mask_kernel = mask_kernel
+    mask = verifier.verify_seal_lanes(w.lanes, w.height)
+    assert mask.all() and len(mask) == 64
+    outer, inside = _outer_and_children(recorder, "verify.drain")
+    assert outer[5]["route"] == "mesh"
+    assert inside == DRAIN_CHILDREN
+    shards = [r for r in recorder.snapshot() if r[1] == "verify.shard"]
+    assert len(shards) == 1 and shards[0][5]["devices"] == 4
+
+
+def test_catch_up_on_the_host_route_is_one_drain_a_validator_set(recorder):
+    """``SyncClient.catch_up`` with a static validator set hands the whole
+    range to ONE ``verify_seal_lanes`` call: one ``chain.sync.verify``
+    span holding one ``verify.drain`` (``sync_host_ms_per_call`` is the
+    call's wall minus exactly that)."""
+    heights = 3
+    keys = [PrivateKey.from_seed(b"span-sync-%d" % i) for i in range(4)]
+    src = ECDSABackend.static_validators({k.address: 1 for k in keys})
+    backends = [ECDSABackend(k, src) for k in keys]
+    blocks = []
+    for h in range(1, heights + 1):
+        proposal = Proposal(raw_proposal=b"span block %d" % h, round=0)
+        commits = [
+            b.build_commit_message(proposal_hash_of(proposal), View(h, 0))
+            for b in backends
+        ]
+        blocks.append(
+            FinalizedBlock(h, proposal, [extract_committed_seal(c) for c in commits])
+        )
+
+    class _Source:
+        def latest_height(self):
+            return heights
+
+        def get_blocks(self, start, end):
+            return blocks[start - 1 : end]
+
+    network = LoopbackSyncNetwork()
+    network.register(b"peer", _Source())
+    client = SyncClient(b"me", network, HostBatchVerifier(src), src)
+    assert len(client.catch_up(1, heights)) == heights
+    outer, inside = _outer_and_children(recorder, "verify.drain")
+    assert outer[5]["lanes"] == heights * len(keys)
+    assert inside == DRAIN_CHILDREN
+    (sync,) = [r for r in recorder.snapshot() if r[1] == "chain.sync.verify"]
+    assert sync[5]["drains"] == 1
+    assert sync[3] <= outer[3] and outer[3] + outer[4] <= sync[3] + sync[4] + 1
+
+
+# ---------------------------------------------------------------------------
+# Counts per finalized height (host route, round 0, loopback, message delay 0)
+# ---------------------------------------------------------------------------
+
+
+class _NullLogger:
+    def info(self, *a):
+        pass
+
+    debug = error = info
+
+
+HEIGHTS = 2
+
+
+def _run_cluster(n: int) -> dict:
+    """``n`` engines on a loopback multicast, each behind its own
+    ``BatchingIngress`` and a ``HostBatchVerifier``; returns what
+    ``HEIGHTS`` finalized heights cost in counts, summed over the nodes."""
+    keys = [PrivateKey.from_seed(b"span-%d-%d" % (n, i)) for i in range(n)]
+    src = ECDSABackend.static_validators({k.address: 1 for k in keys})
+    nodes = []
+    flush_sizes = collections.defaultdict(list)
+
+    class _Transport:
+        def multicast(self, message):
+            for _, ingress in nodes:
+                ingress.submit(message)
+
+    for i, key in enumerate(keys):
+        core = IBFT(
+            _NullLogger(),
+            ECDSABackend(key, src),
+            _Transport(),
+            batch_verifier=HostBatchVerifier(src),
+        )
+        core.set_base_round_timeout(30.0)
+
+        def add_messages(batch_, core=core, i=i):
+            flush_sizes[i].append(len(batch_))
+            core.add_messages(batch_)
+
+        nodes.append((core, BatchingIngress(add_messages)))
+
+    async def heights():
+        try:
+            for h in range(1, HEIGHTS + 1):
+                await asyncio.wait_for(
+                    asyncio.gather(*(c.run_sequence(h) for c, _ in nodes)), 60
+                )
+        finally:
+            for core, ingress in nodes:
+                ingress.close()
+                core.messages.close()
+
+    metrics.reset()
+    cost_ledger.enable()
+    try:
+        asyncio.run(heights())
+        rows = cost_ledger.snapshot()["dispatches"]
+    finally:
+        cost_ledger.disable()
+    out = {
+        "rows": rows,
+        "flush_sizes": dict(flush_sizes),
+        "early_exit_drains": metrics.get_counter(EARLY_EXIT_DRAINS_KEY),
+        "early_exit_skipped": metrics.get_counter(EARLY_EXIT_SKIPPED_KEY),
+        "rounds": {
+            proposal.round
+            for core, _ in nodes
+            for proposal, _seals in core.backend.inserted
+        },
+        "inserted": [len(core.backend.inserted) for core, _ in nodes],
+    }
+    metrics.reset()
+    return out
+
+
+@pytest.fixture(scope="module", params=[4, 16])
+def cluster_counts(request):
+    return request.param, _run_cluster(request.param)
+
+
+def test_verifier_calls_and_lanes_per_height(cluster_counts):
+    """A node's round-0 height is FOUR verifier calls: the PREPREPARE's
+    sender (1 lane), the other nodes' PREPAREs (n - 1: the proposer sends
+    none), every node's COMMIT envelope (n), and the committed seals,
+    which the host early exit stops at quorum (q lanes)."""
+    n, counts = cluster_counts
+    assert counts["inserted"] == [HEIGHTS] * n
+    assert counts["rounds"] == {0}
+    (row,) = counts["rows"]
+    assert (row["program"], row["route"]) == ("ecdsa_recover", "host")
+    node_heights = n * HEIGHTS
+    assert row["dispatches"] == 4 * node_heights
+    lanes = 1 + (n - 1) + n + calculate_quorum(n)
+    assert row["live_lanes"] == row["padded_lanes"] == lanes * node_heights
+    # One early-exit drain a node a height; it skips the seals past quorum.
+    assert counts["early_exit_drains"] == node_heights
+    assert counts["early_exit_skipped"] == (n - calculate_quorum(n)) * node_heights
+
+
+def test_ingress_flushes_per_height(cluster_counts):
+    """Message delay 0 on a loopback: each phase reaches a node within one
+    event-loop turn, so its ingress flushes THREE times a height, with the
+    PREPREPARE, the n - 1 PREPAREs and the n COMMITs."""
+    n, counts = cluster_counts
+    assert len(counts["flush_sizes"]) == n
+    for sizes in counts["flush_sizes"].values():
+        assert sizes == [1, n - 1, n] * HEIGHTS

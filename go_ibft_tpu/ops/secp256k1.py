@@ -26,6 +26,7 @@ Curve: y**2 = x**3 + 7 over GF(P), group order N (both primes close under
 
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Tuple
 
 import jax
@@ -125,7 +126,8 @@ def _point_add_core(p: JacobianPoint, q: JacobianPoint) -> JacobianPoint:
     callers only (``tests/test_ladder_structure.py`` holds the tree to it):
     :func:`point_add`, which overlays the doubling, and the scan body of
     :func:`ecmul2_base`, whose operands provably never coincide.  Both call
-    it at ``(4,) + batch`` and use all of what it returns, so a program
+    it at the ladder's batch (``(4,) + batch``, folded where
+    :func:`ecmul2_base` folds it) and use all of what it returns, so a program
     instantiates it once (an output one caller drops is pruned from that
     caller's copy, and the copies no longer dedup).
     """
@@ -580,6 +582,28 @@ def ecmul2_base(
     digit streams of ``log2(N) - 4`` bits or more (today 132 of 256), or a
     curve with a cofactor (a base point of small order).
 
+    **The batch is folded** where that fills the TPU's vector registers.
+    A v5e register is one ``T(8,128)`` tile, 8 sublanes x 128 lanes, and
+    XLA lays the tile over the two minor batch axes (the limb axes go
+    major).  On ``(4, lanes)`` that is ``T(4,128)``: every load, multiply
+    and store of the scan runs with 4 of its 8 sublanes masked off
+    (``sm:$0xf`` in the LLO dump; PERF.md section 6, PR 30).  Every op of
+    the scan body and of ``recover.combine`` is elementwise over the batch
+    axes, so ``(4,) + batch`` is reshaped, before the scan, to
+    ``(lanes // 32, 128)`` with ``lanes = prod(batch)``: the same values in
+    the same row-major order, hence the same arithmetic and bit-identical
+    results, on full tiles.  The rule reads the shape alone: fold when
+    ``lanes % 256 == 0`` (whole 128-lane rows, and ``4 * lanes // 128`` rows
+    a multiple of 8), else keep ``(4,) + batch``; of the verifier's lane
+    buckets 512, 1,024 and 2,048 fold, and 8, 32 and 128 lower to the
+    program they always did (``(4, 128)`` is half a register whatever its
+    shape).  **The stream index is the major part of the folded axis**:
+    stream ``i`` is rows ``i * lanes // 128 ...`` of it, i.e. index ``i`` of
+    the ``(4, lanes // 128, 128)`` view.  The stacking of ``digits``,
+    ``neg`` and the tables, and ``_pad4``'s stream slices, rely on that;
+    the combine stays in the folded shape so that its two adds share the
+    ladder body's one instance of :func:`_point_add_core`.
+
     ``k1``/``k2`` are semi-reduced scalars mod N; ``qx``/``qy`` affine
     field elements.  ``(qx, qy)`` is on the curve, or the lane's result is
     unspecified: off it the group law, and with it the invariant above,
@@ -625,6 +649,23 @@ def ecmul2_base(
         # Per-term negation flags, (4,) + batch: negate y at gather time.
         neg = jnp.stack([s1, s2, t1, t2], axis=0)
 
+        # The ladder's batch (docstring, "The batch is folded"): ``view``
+        # keeps the stream axis apart, ``ladder`` is what the scan carries.
+        # Where the two are ``(4,) + batch`` every reshape is the identity.
+        lanes = math.prod(batch)
+        folded = lanes % 256 == 0
+        view = (4, lanes // 128, 128) if folded else (4,) + batch
+        ladder = (lanes // 32, 128) if folded else view
+
+        def fold(a, lead):  # lead + (4,) + batch [+ (L,)] -> lead + ladder [+ (L,)]
+            return a.reshape(a.shape[:lead] + ladder + a.shape[lead + 1 + len(batch) :])
+
+        def unfold(c):  # a coordinate on ``ladder`` -> on ``view``
+            return c.reshape(view + (_L,))
+
+        digits, neg = fold(digits, 1), fold(neg, 0)
+        tx, ty, tz = fold(tx, 1), fold(ty, 1), fold(tz, 1)
+
     def body(acc, d):
         # 4 doublings of all four accumulator lanes (infinity-safe)
         acc = _double4(acc)
@@ -635,28 +676,31 @@ def ecmul2_base(
         return _point_add_core(acc, addend), None
 
     with jax.named_scope("recover.glv_ladder"):
-        acc, _ = jax.lax.scan(body, point_infinity((4,) + batch), digits)
+        acc, _ = jax.lax.scan(body, point_infinity(ladder), digits)
     # Combine the four lanes with two COMPLETE adds (these operands can
-    # coincide) at the SAME ``(4,) + batch`` as the ladder body's core,
+    # coincide) at the SAME batch as the ladder body's core (``ladder``),
     # padding spent lanes with infinity.  Nested-jit point ops dedup per
     # input shape: a (2,)+batch pair-add plus a batch-shaped final add each
     # instantiate their own add core AND embedded point_double functions
     # (~13k stablehlo lines — a third of the fused 8-lane certify program),
     # while two wasted infinity lanes cost a few VPU ops.  Trace size is
     # compile time on XLA:CPU.
-    def _pad4(pt: JacobianPoint, lanes: slice) -> JacobianPoint:
-        x = pt.x[lanes]
-        pinf = point_infinity((4 - x.shape[0],) + batch)
+    def _pad4(pt: JacobianPoint, streams: slice) -> JacobianPoint:
+        def pad(c, inf):  # (op order kept: the unfolded program is PR 28's HLO)
+            return jnp.concatenate([c, inf]).reshape(ladder + (_L,))
+
+        x = unfold(pt.x)[streams]
+        pinf = point_infinity((4 - x.shape[0],) + view[1:])
         return JacobianPoint(
-            jnp.concatenate([x, pinf.x]),
-            jnp.concatenate([pt.y[lanes], pinf.y]),
-            jnp.concatenate([pt.z[lanes], pinf.z]),
+            pad(x, pinf.x),
+            pad(unfold(pt.y)[streams], pinf.y),
+            pad(unfold(pt.z)[streams], pinf.z),
         )
 
     with jax.named_scope("recover.combine"):
         half = point_add(_pad4(acc, slice(0, None, 2)), _pad4(acc, slice(1, None, 2)))
         out = point_add(_pad4(half, slice(0, 1)), _pad4(half, slice(1, 2)))
-        return JacobianPoint(out.x[0], out.y[0], out.z[0])
+        return JacobianPoint(*(unfold(c)[0].reshape(batch + (_L,)) for c in out))
 
 
 def _in_scalar_range(v: jnp.ndarray) -> jnp.ndarray:

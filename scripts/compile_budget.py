@@ -15,7 +15,9 @@ program unchanged within noise.  PR 28 split ``point_add`` into a shared
 incomplete core and the ``P == Q`` overlay (the ladder's scan body calls
 the core): +83 lines on every ladder program (34,147 -> 34,230), one more
 zero test in the overlay; what the ladder stopped executing was never a
-second instantiation.  Those wins regress silently — one refactor
+second instantiation.  PR 30 folds the ladder's batch to full TPU tiles
+only where lanes % 256 == 0 (+35 lines at 2,048 lanes); every program
+pinned here is 8 lanes and did not move by a line.  Those wins regress silently — one refactor
 that unrolls a scan or forks a new shape instantiation quietly re-adds
 minutes of cold compile.  This script LOWERS (never compiles — it stays fast on
 any host) the programs that dominate the cold budget, counts their

@@ -67,26 +67,34 @@ def _scans(jaxpr):
                 yield from _scans(j)
 
 
-@pytest.fixture(scope="module")
-def ladder_body():
-    """The body of the ``recover.glv_ladder`` scan, traced at 8 lanes."""
-    s = jax.ShapeDtypeStruct((LANES, L), jnp.int32)
-    closed = jax.make_jaxpr(sec.ecmul2_base)(s, s, s, s)
+def _trace(lanes):
+    s = jax.ShapeDtypeStruct((lanes, L), jnp.int32)
+    return jax.make_jaxpr(sec.ecmul2_base)(s, s, s, s).jaxpr
+
+
+def _ladder_scan(jaxpr):
+    """The one ``recover.glv_ladder`` scan of a traced ``ecmul2_base``."""
     ladders = [
         e
-        for e in _scans(closed.jaxpr)
+        for e in _scans(jaxpr)
         if "recover.glv_ladder" in str(e.source_info.name_stack)
     ]
     assert len(ladders) == 1 and ladders[0].params["length"] == sec._GLV_NWIN
-    return ladders[0].params["jaxpr"].jaxpr
+    return ladders[0]
 
 
-def _is_field_product(eqn) -> bool:
+@pytest.fixture(scope="module")
+def ladder_body():
+    """The body of the ``recover.glv_ladder`` scan, traced at 8 lanes."""
+    return _ladder_scan(_trace(LANES)).params["jaxpr"].jaxpr
+
+
+def _is_field_product(eqn, batch=(4, LANES)) -> bool:
     """``fields._conv``'s outer product of two 20-limb elements on the
-    ladder's ``(4, lanes)`` batch (the fold's product has 3 rows)."""
+    ladder's batch (the fold's product has 3 rows)."""
     return (
         eqn.primitive.name == "mul"
-        and eqn.outvars[0].aval.shape == (L, 2 * L - 1, 4, LANES)
+        and eqn.outvars[0].aval.shape == (L, 2 * L - 1) + batch
     )
 
 
@@ -94,6 +102,50 @@ def test_scan_step_is_44_field_products(ladder_body):
     # 4 doublings x (5 sqr + 2 mul) + one generic add (4 sqr + 12 mul); the
     # complete add's embedded doubling made it 51.
     assert _count(ladder_body, _is_field_product) == 4 * 7 + 16
+
+
+def _is_reshape(eqn) -> bool:
+    return eqn.primitive.name == "reshape"
+
+
+def _core_shapes(jaxpr):
+    """Operand shapes of every ``_point_add_core`` call site, any depth."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "jit" and eqn.params["name"] == "_point_add_core":
+            yield tuple(v.aval.shape for v in eqn.invars)
+        for j in _sub_jaxprs(eqn):
+            yield from _core_shapes(j)
+
+
+# The ladder's batch is folded so that the TPU tiles it T(8,128), where that
+# fills whole tiles (PR 30): lanes % 256 == 0.  8 is every budget program's
+# shape, 128 the flood's; 512, 1,024 and 2,048 are the lane buckets that fold.
+@pytest.mark.parametrize(
+    "lanes,batch",
+    [
+        (8, (4, 8)),
+        (128, (4, 128)),
+        (512, (16, 128)),
+        (1024, (32, 128)),
+        (2048, (64, 128)),
+    ],
+)
+def test_ladder_batch_fills_whole_tiles_where_it_can(ladder_body, lanes, batch):
+    jaxpr = _trace(lanes)
+    scan = _ladder_scan(jaxpr)
+    carry = scan.invars[scan.params["num_consts"] :][: scan.params["num_carry"]]
+    assert [v.aval.shape for v in carry] == [batch + (L,)] * 3
+    # Tables, digits and neg enter folded as well: nothing in the body is
+    # left on (4, lanes) for XLA to re-tile each step.
+    body = scan.params["jaxpr"].jaxpr
+    shapes = {v.aval.shape for v in body.invars if v.aval.ndim > 1}  # not constants
+    assert shapes == {batch + (L,), (16,) + batch + (L,), batch}
+    assert _count(body, lambda e: _is_field_product(e, batch)) == 4 * 7 + 16
+    assert _count(body, _is_reshape) == _count(ladder_body, _is_reshape)
+    # One instance of the core for the body and recover.combine's two
+    # complete adds: same operand shapes at all three call sites.
+    assert list(_core_shapes(jaxpr)) == [(batch + (L,),) * 6] * 3
+    assert [v.aval.shape for v in jaxpr.outvars] == [(lanes, L)] * 3
 
 
 def test_scan_step_calls_the_core_and_only_double4_doubles(ladder_body):

@@ -290,6 +290,10 @@ def test_glv_ladder_negative_half_scalar_edges(points):
 
 ECMUL2_CASES = lc.ecmul2_cases()
 SYNC_LANES = 2048
+# ecmul2_base folds its (4, lanes) ladder batch into (lanes // 32, 128) where
+# lanes % 256 == 0 (PR 30): 128 is the flood's shape and is not folded, 512
+# is the smallest lane bucket that is.
+LADDER_LANES = (128, 512)
 
 
 def _on_tpu() -> bool:
@@ -302,29 +306,38 @@ def _tile(values, lanes):
 
 
 def _ecmul2_affine(fn, lanes):
-    k1, k2, q = zip(*[(c[1], c[2], c[3]) for c in _tile(ECMUL2_CASES, lanes)])
-    j = fn(pack(k1), pack(k2), pack(p[0] for p in q), pack(p[1] for p in q))
-    inf = list(np.asarray(sec.is_infinity(j)))
-    return [None if i else xy for i, xy in zip(inf, unpack_affine(j))]
+    """ECMUL2_CASES, tiled up to whole dispatches of ``lanes``, through
+    ``fn``: lane ``i`` holds case ``i % len(ECMUL2_CASES)`` as an affine
+    point or None."""
+    cases = _tile(ECMUL2_CASES, -(-len(ECMUL2_CASES) // lanes) * lanes)
+    out = []
+    for at in range(0, len(cases), lanes):
+        k1, k2, q = zip(*[(c[1], c[2], c[3]) for c in cases[at : at + lanes]])
+        j = fn(pack(k1), pack(k2), pack(p[0] for p in q), pack(p[1] for p in q))
+        inf = list(np.asarray(sec.is_infinity(j)))
+        out += [None if i else xy for i, xy in zip(inf, unpack_affine(j))]
+    return out
+
+
+@pytest.fixture(scope="module", params=LADDER_LANES, ids=lambda n: f"{n}l")
+def glv_lanes(request):
+    return _ecmul2_affine(sec.ecmul2_base, request.param)
 
 
 @pytest.fixture(scope="module")
-def ecmul2_lanes():
-    lanes = -(-len(ECMUL2_CASES) // 128) * 128
-    return _ecmul2_affine(sec.ecmul2_base, lanes), _ecmul2_affine(
-        sec._ecmul2_base_shamir, lanes
-    )
+def shamir_lanes():
+    return _ecmul2_affine(sec._ecmul2_base_shamir, 256)
 
 
 @pytest.mark.parametrize(
     "lane", range(len(ECMUL2_CASES)), ids=[c[0] for c in ECMUL2_CASES]
 )
-def test_glv_ladder_edge_case(ecmul2_lanes, lane):
-    glv, shamir = ecmul2_lanes
+def test_glv_ladder_edge_case(glv_lanes, shamir_lanes, lane):
     _, k1, k2, q = ECMUL2_CASES[lane]
     want = lc.expected_point(k1, k2, q)
-    assert glv[lane] == want
-    assert shamir[lane] == want
+    for got in (glv_lanes, shamir_lanes):  # every tiled copy of the case
+        copies = got[lane :: len(ECMUL2_CASES)]
+        assert copies == [want] * len(copies)
 
 
 def test_glv_ladder_edge_cases_cover_what_they_name():
@@ -456,21 +469,23 @@ def recover_oracle(recover_lanes):
     return [host.recover_pure(*lane[1:]) for lane in recover_lanes]
 
 
-@pytest.fixture(scope="module")
-def recover_128(recover_lanes):
-    return _recover_on_device(recover_lanes, 128)
+@pytest.fixture(scope="module", params=LADDER_LANES, ids=lambda n: f"{n}l")
+def recover_device(request, recover_lanes):
+    return _recover_on_device(recover_lanes, request.param)
 
 
 @pytest.mark.parametrize("category", RECOVER_CATEGORIES)
 def test_ecdsa_recover_matches_host_oracle(
-    recover_lanes, recover_128, recover_oracle, category
+    recover_lanes, recover_device, recover_oracle, category
 ):
     """Bit-identical key on every lane the oracle recovers, the identical
-    mask on every lane it refuses."""
+    mask on every lane it refuses, in every tile of the dispatch."""
     idx = [i for i, lane in enumerate(recover_lanes) if lane[0] == category]
     assert idx
-    assert [recover_128[i] for i in idx] == [recover_oracle[i] for i in idx]
-    refused = [recover_oracle[i] is None for i in idx]
+    want = [recover_oracle[i] for i in idx]
+    for tile in range(0, len(recover_device), len(recover_lanes)):
+        assert [recover_device[tile + i] for i in idx] == want
+    refused = [w is None for w in want]
     if category in ("bad_r", "bad_s", "bad_v", "off_curve_lift"):
         assert all(refused)
     elif category in ("infinity", "handmade"):  # one parity of R cancels

@@ -70,11 +70,20 @@ class BatchingIngress:
     burst and flushes it through :meth:`IBFT.add_messages`, so sender
     signatures for the whole burst are verified in ONE device batch.
 
-    Flushes when ``max_batch`` messages accumulate or at the end of the
-    current event-loop tick / after ``max_delay`` seconds, whichever the
-    adaptive window picks (below).  Event-loop affine (call :meth:`submit`
-    from the loop thread); ``flush`` may be called directly for
-    deterministic tests.
+    Flushes at the end of the current event-loop tick / after ``max_delay``
+    seconds, whichever the adaptive window picks (below), or on the spot
+    when an explicit ``max_batch`` messages accumulate.  Event-loop affine
+    (call :meth:`submit` from the loop thread); ``flush`` may be called
+    directly for deterministic tests.
+
+    **No ceiling by default.**  A phase of an n-validator committee arrives
+    as n - 1 messages in one loop turn, and every cut of it costs a device
+    dispatch (a 300-validator phase cut at 256 was a 512-lane dispatch and
+    then a 128-lane one, in series), while a batch over the verifier's
+    largest dispatch is split there, where pack and device overlap.  So a
+    default-constructed ingress never cuts a burst; ``max_batch=`` asks for
+    the cut, and is then also the batch the calibrated window projects to
+    fill (``FILL_TARGET`` without it).
 
     **Adaptive window.**  The wall-clock window only earns its latency when
     the resulting batch is big enough to take the device route; below the
@@ -121,12 +130,15 @@ class BatchingIngress:
     # A flush that blocked the loop for more than this many ``max_delay``
     # windows held arrivals back (see the class docstring).
     HELD_BACK_FACTOR = 4.0
+    # The batch the calibrated window projects to fill where ``max_batch``
+    # is left out.
+    FILL_TARGET = 256
 
     def __init__(
         self,
         add_messages: Callable[[Sequence[IbftMessage]], None],
         *,
-        max_batch: int = 256,
+        max_batch: Optional[int] = None,
         max_delay: float = 0.002,
         eager_cutover: Optional[int] = None,
         calibrate: bool = True,
@@ -166,7 +178,10 @@ class BatchingIngress:
         """The timed-window wait: calibrated projection, ceiling-clamped."""
         if self.calibrator is None:
             return self.max_delay
-        window = self.calibrator.window(len(self._buffer), self.max_batch)
+        window = self.calibrator.window(
+            len(self._buffer),
+            self.FILL_TARGET if self.max_batch is None else self.max_batch,
+        )
         trace.instant(
             "ingress.calibrate",
             window_us=round(window * 1e6, 1),
@@ -178,8 +193,8 @@ class BatchingIngress:
         self._buffer.append(message)
         if self.calibrator is not None:
             self.calibrator.observe()
-        if len(self._buffer) >= self.max_batch:
-            self.flush()
+        if self.max_batch is not None and len(self._buffer) >= self.max_batch:
+            self._flush(cut=True)
         elif self._handle is None:
             loop = asyncio.get_running_loop()
             self._trim_recent(time.monotonic())
@@ -195,6 +210,11 @@ class BatchingIngress:
                 self._handle = loop.call_soon(self.flush)
 
     def flush(self) -> None:
+        """Hand over what is buffered (the timer's callback; also called
+        directly)."""
+        self._flush(cut=False)
+
+    def _flush(self, cut: bool) -> None:
         if self._handle is not None:
             self._handle.cancel()
             self._handle = None
@@ -205,7 +225,10 @@ class BatchingIngress:
         self._recent.append((now, len(batch)))
         self._recent_n += len(batch)
         self._trim_recent(now)
-        self._add_messages(batch)
+        # ``cut``: ``max_batch`` fired this flush in the middle of a burst
+        # (the engine's ``ingress.batch`` span cannot know why it was fed).
+        with trace.span("ingress.flush", lanes=len(batch), cut=cut):
+            self._add_messages(batch)
         self._held_back = (
             time.monotonic() - now > self.HELD_BACK_FACTOR * self.max_delay
         )

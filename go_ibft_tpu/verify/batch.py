@@ -140,6 +140,24 @@ EARLY_EXIT_DRAINS_KEY = ("go-ibft", "early_exit", "drains")
 VERIFY_DRAIN_MS_KEY = ("go-ibft", "latency", "verify_drain_ms")
 
 
+def _note_verdicts(kind: str, route: str, mask: np.ndarray, judged=None) -> None:
+    """``verify.verdicts``: what one drain decided, as an instant inside its
+    outer span (``verify.drain`` / ``verify.early_exit``).  ``live`` counts
+    the lanes that got a verdict (``judged`` marks them where a drain may
+    stop early; all of ``mask`` otherwise), ``rejected`` those of them it
+    refused.  One predicate when tracing is off."""
+    if not trace.enabled():
+        return
+    live = len(mask) if judged is None else int(np.count_nonzero(judged))
+    trace.instant(
+        "verify.verdicts",
+        kind=kind,
+        route=route,
+        live=live,
+        rejected=live - int(np.count_nonzero(mask)),
+    )
+
+
 @dataclass
 class EarlyExitReport:
     """One early-exit seal drain's outcome.
@@ -265,6 +283,7 @@ class HostBatchVerifier:
                         host_ecdsa.pubkey_to_address(*pub) == msg.sender
                         and self._is_member(msg.view.height, msg.sender)
                     )
+            _note_verdicts("senders", "host", out)
         if t0 is not None:
             metrics.observe_fixed(
                 VERIFY_DRAIN_MS_KEY + ("host",),
@@ -318,6 +337,7 @@ class HostBatchVerifier:
                         host_ecdsa.pubkey_to_address(*pub) == seal.signer
                         and self._is_member(height, seal.signer)
                     )
+            _note_verdicts("seals", "host", out)
         if t0 is not None:
             metrics.observe_fixed(
                 VERIFY_DRAIN_MS_KEY + ("host",),
@@ -378,6 +398,7 @@ class HostBatchVerifier:
                         host_ecdsa.pubkey_to_address(*pub) == seal.signer
                         and self._is_member(height, seal.signer)
                     )
+            _note_verdicts("seal_lanes", "host", out)
         if t0 is not None:
             metrics.observe_fixed(
                 VERIFY_DRAIN_MS_KEY + ("host",),
@@ -442,6 +463,7 @@ class HostBatchVerifier:
                 mask[i] = ok
                 if ok:
                     tally.add(seal.signer)
+            _note_verdicts("seals", "host", mask, verified)
         skipped = n - done
         metrics.inc_counter(EARLY_EXIT_DRAINS_KEY)
         if skipped:
@@ -1549,6 +1571,7 @@ class DeviceBatchVerifier:
             with trace.span("verify.quorum", route="mask"):
                 for (_, chunk), mask in results:
                     out[np.asarray(chunk)] = mask[: len(chunk)]
+            _note_verdicts("senders", self._route, out)
         return out
 
     def verify_sender_rows(
@@ -1618,6 +1641,7 @@ class DeviceBatchVerifier:
             with trace.span("verify.quorum", route="mask"):
                 for chunk, mask in results:
                     out[np.asarray(chunk)] = mask[: len(chunk)]
+            _note_verdicts("seals", self._route, out)
         return out
 
     def verify_seal_lanes(
@@ -1667,6 +1691,7 @@ class DeviceBatchVerifier:
             with trace.span("verify.quorum", route="mask"):
                 for chunk, mask in results:
                     out[np.asarray(chunk)] = mask[: len(chunk)]
+            _note_verdicts("seal_lanes", self._route, out)
         return out
 
     def verify_seals_early_exit(
@@ -1750,6 +1775,7 @@ class DeviceBatchVerifier:
                         tally.add(seals[i].signer)
                 pos += len(take)
                 chunk = min(chunk * 2, self._dispatch_cap)
+            _note_verdicts("seals", self._route, mask, verified)
         skipped = len(order) - pos
         metrics.inc_counter(EARLY_EXIT_DRAINS_KEY)
         if skipped:

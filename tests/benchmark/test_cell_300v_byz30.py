@@ -1,0 +1,198 @@
+"""The cell ``ecdsa-300v.flood-byz30`` (PR 31): the real files through
+``run.load_cell``, the metric list ISSUE 31 gives it (and the three its
+review added), the traffic mix's arithmetic, its two reader modules, and a
+CPU rehearsal of the cell's control
+flow at ten validators (no number of which is a device number)."""
+
+import json
+import math
+import os
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+HERE = os.path.join(ROOT, "benchmark")
+for p in (ROOT, HERE):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import run as bench_run  # noqa: E402  (benchmark/run.py)
+from benchmark.drivers import round0  # noqa: E402
+from benchmark.lib.readers import read_metric  # noqa: E402
+from test_benchmark_rehearsal import _run as rehearse  # noqa: E402  (beside this file)
+
+CELL = "ecdsa-300v.flood-byz30"
+END_TO_END = ["finalize_p50_ms", "finalize_p90_ms", "setup_s"]
+SHARED_WITH_THE_100V_FLOOD = [
+    "compiles_in_window",
+    "device_lane_share",
+    "device_wait_ms_per_drain",
+    "dispatches_per_height",
+    "engine_self_ms",
+    "lane_occupancy",
+    "msgs_per_flush",
+    "pack_ms_per_drain",
+    "warm_s",
+]
+NEW_IN_PR_31 = [
+    "flood300_recover_address_us_per_lane",
+    "flood300_recover_affine_us_per_lane",
+    "flood300_recover_ladder_us_per_lane",
+    "flood300_recover_layout_share",
+    "flood300_recover_pre_ladder_us_per_lane",
+    "flood300_recover_unscoped_share",
+    "flood300_recover_us_per_lane",
+    "flushes_per_height",
+    "rejected_lane_share",
+]
+
+
+@pytest.fixture(scope="module")
+def cell():
+    return bench_run.load_cell(CELL)
+
+
+def test_the_cell_loads_with_the_metrics_the_issue_lists(cell):
+    assert cell["cell"] == {
+        "name": CELL,
+        "config": "ecdsa-300v",
+        "traffic": "flood-byz30",
+        # One validator's verifier is one chip and nothing here exists only
+        # across chips: the cell holds the whole four-chip host for a steady
+        # host clock alone (on one chip the driver's check refused the spread
+        # of finalize_p90_ms), and its `why` has to say so.
+        "chips": 4,
+        "why": cell["cell"]["why"],
+    }
+    assert "512 lanes" in cell["cell"]["why"] and len(cell["cell"]["why"]) <= 200
+    assert "steadiness alone" in cell["cell"]["why"]
+    assert [m["name"] for _p, m in cell["end_to_end"]] == END_TO_END
+    got = [m["name"] for _p, m in cell["per_layer"]]
+    assert got == sorted(SHARED_WITH_THE_100V_FLOOD + NEW_IN_PR_31)
+    for _path, m in cell["per_layer"]:
+        if m["name"] in NEW_IN_PR_31:
+            assert m["drivers"] == ["round0"] and m["workloads"] == [CELL]
+            assert m["moves"] == "finalize_p50_ms"
+    # The old flood keeps its own kernel metrics and gets none of the new.
+    old = [m["name"] for _p, m in bench_run.load_cell("ecdsa-100v.flood")["per_layer"]]
+    assert not set(old) & set(NEW_IN_PR_31)
+    assert "recover_us_per_lane" in old and "recover_us_per_lane" not in got
+
+
+def test_the_kernel_metrics_are_the_100v_readers_on_another_cell(cell):
+    files = {m["name"]: m for _p, m in cell["per_layer"]}
+    for name in NEW_IN_PR_31:
+        if not name.startswith("flood300_"):
+            continue
+        with open(os.path.join(HERE, "layer_metrics", name[len("flood300_"):] + ".json")) as fh:
+            old = json.load(fh)
+        new = files[name]
+        assert new["read"] == old["read"]  # same module, same stages or share
+        for key in ("layer", "unit", "better", "source", "moves", "drivers"):
+            assert new[key] == old[key], (name, key)
+
+
+def test_the_deployment_and_its_byzantine_arithmetic(cell):
+    cfg, mix = cell["config"], cell["traffic"]
+    n = cfg["validators"]
+    assert (n, cfg["quorum"], cfg["max_faulty"]) == (300, 201, 99)
+    assert cfg["quorum"] == 2 * n // 3 + 1 and cfg["max_faulty"] == (n - 1) // 3
+    assert cfg["architecture"] is None and cfg["reduced"] == ["peers"]
+    assert cfg["scheme"] == "ecdsa-secp256k1" and cfg["base_round_timeout_s"] == 10
+    with open(os.path.join(HERE, "configs", "ecdsa-100v.json")) as fh:
+        assert cfg["guarantees"] == json.load(fh)["guarantees"]  # word for word
+    assert cfg["quorum"] <= cfg["assumed"]["seals_per_block"] <= 254
+    assert len(cfg["source"]) <= 200 and "byzantine_test.go" in cfg["source"]
+
+    assert (mix["driver"], mix["delay_ms"], mix["warm_heights"]) == ("round0", 0, 3)
+    corrupt = round0.corrupted_peers(n, mix["corrupt_share"])
+    assert corrupt == cfg["byzantine_peers_per_height"] == 90 <= cfg["max_faulty"]
+    followers = n - 2  # the peers without the height's proposer
+    bad_envelopes = corrupt // 2
+    honest_prepares = followers - corrupt
+    valid_envelope_commits = (n - 1) - bad_envelopes
+    valid_seals = (n - 1) - corrupt + 1  # with the node's own
+    assert (honest_prepares, valid_envelope_commits, valid_seals) == (208, 254, 210)
+    # A PREPARE quorum is quorum - 1 beside the proposal; the node's own counts.
+    assert honest_prepares >= cfg["quorum"] - 1 and valid_seals >= cfg["quorum"]
+    assert valid_seals - cfg["quorum"] == 9
+    # ISSUE 31's 8000 messages a second: 270 heights, of which PR 31's chip
+    # runs used 245-253 (warm heights and a traced run's extra ones counted).
+    assert mix["presign_msgs_per_s"] == 8000
+    assert round0.presigned_heights(mix, n, 20.0) == 3 + math.ceil(20 * 8000 / 600) == 270
+    # Every flood of a height fits one 512-lane dispatch and no smaller bucket.
+    from go_ibft_tpu.verify.batch import _BATCH_BUCKETS
+
+    for lanes in (followers + 1, n, valid_envelope_commits + 1):
+        assert next(b for b in _BATCH_BUCKETS if b >= lanes) == 512
+
+
+def test_flushes_per_height_reads_the_span_and_returns_nothing_without_it(cell):
+    path, metric = next(
+        (p, m) for p, m in cell["per_layer"] if m["name"] == "flushes_per_height"
+    )
+    flush = ("X", "ingress.flush", "MainThread", 0, 10, {"lanes": 299, "cut": False})
+    spans = [flush] * 29 + [
+        ("X", "ingress.batch", "node-0", 0, 9, {"lanes": 299}),
+        ("i", "ingress.flush", "MainThread", 0, 0, None),
+    ]
+    ctx = {"spans": spans, "counts": {"operations": 10}}
+    assert read_metric(ctx, metric, path) == pytest.approx(2.9)
+    # The parent's program has no such span: nothing to read, and no raise.
+    assert read_metric({"spans": spans[29:], "counts": {"operations": 10}}, metric, path) is None
+    assert read_metric({"spans": None, "counts": {}}, metric, path) is None
+    assert read_metric({"spans": [flush], "counts": {"operations": 0}}, metric, path) is None
+
+
+def test_rejected_lane_share_reads_the_verdicts_and_returns_nothing_without_them(cell):
+    path, metric = next(
+        (p, m) for p, m in cell["per_layer"] if m["name"] == "rejected_lane_share"
+    )
+
+    def verdicts(kind, live, rejected):
+        args = {"kind": kind, "route": "device", "live": live, "rejected": rejected}
+        return ("i", "verify.verdicts", "node-0", 0, 0, args)
+
+    # One height by the mix's labels: 90 + 45 bad envelopes, 45 bad seals.
+    height = [
+        verdicts("senders", 1, 0),
+        verdicts("senders", 299, 90),
+        verdicts("senders", 300, 45),
+        verdicts("seals", 255, 45),
+    ]
+    other = [("X", "verify.drain", "node-0", 0, 9, {"kind": "senders", "route": "device"})]
+    got = read_metric({"spans": height * 3 + other, "counts": {}}, metric, path)
+    assert got == pytest.approx(100 * 180 / 855)
+    assert "21.05%" in metric["what"] and round(got, 2) == 21.05
+    # The parent's program has no such instant: nothing to read, and no raise.
+    assert read_metric({"spans": other, "counts": {}}, metric, path) is None
+    assert read_metric({"spans": None, "counts": {}}, metric, path) is None
+
+
+def test_rehearsal_at_ten_validators_reports_the_cells_host_side_metrics(monkeypatch):
+    """``run.run`` on the CPU with the committee cut to ten (three corrupted
+    a height): the traced line carries every metric of the cell that needs
+    neither the device route nor a device trace, ``flushes_per_height``
+    among them."""
+    rc, lines = rehearse(monkeypatch, CELL, 1, 1.0, validators=10)
+    result, info = lines[-1], lines[-2]
+    assert rc == 0 and result["correct"] is True and result["failed"] == 0
+    assert info["corrupted_peers_per_height"] == 3 and info["ran_out_of_presigned"] is False
+    got = result["metrics"]
+    # Ten validators are under the cutover, so the host route serves and the
+    # device route's spans and ledger rows are not there to read.
+    device_route = {"device_wait_ms_per_drain", "lane_occupancy", "pack_ms_per_drain"}
+    host_side = set(SHARED_WITH_THE_100V_FLOOD) - device_route | {
+        "flushes_per_height",
+        "rejected_lane_share",
+    }
+    assert host_side <= set(got), host_side - set(got)
+    assert got["device_lane_share"]["value"] == 0.0
+    # One height in ten is the node's own proposal: two flushes, else three.
+    assert 2.5 <= got["flushes_per_height"]["value"] <= 3.0
+    assert got["msgs_per_flush"]["value"] == 9  # median of 1, 9, 10
+    # 3 + 1 bad envelopes of 1 + 9 + 10 and at most 2 bad seals of <= 9: the
+    # host early exit stops at quorum, in arrival order.
+    assert 100 * 4 / 29 <= got["rejected_lane_share"]["value"] <= 100 * 6 / 27
+    assert got["compiles_in_window"]["value"] == 0

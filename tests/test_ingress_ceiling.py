@@ -1,0 +1,136 @@
+"""The ingress's flush ceiling (ISSUE 31).
+
+A phase of an n-validator committee reaches a node as n - 1 messages in
+one event-loop turn.  A default-constructed ``BatchingIngress`` used to cut
+such a burst at 256 messages; it now has no ceiling at all and flushes by
+the timer or at the end of the turn only (above the verifier's largest
+lane bucket the verifier splits the batch itself, overlapping pack and
+device).  What an explicit ``max_batch=`` does, and what any burst under
+256 does, is as it was (``tests/test_ingress.py`` and
+``tests/test_calibration.py`` hold that, unedited): the cases below add the
+sizes between and the span.
+"""
+
+import asyncio
+
+import pytest
+
+from go_ibft_tpu.core.transport import BatchingIngress
+from go_ibft_tpu.obs import trace
+from go_ibft_tpu.verify import batch
+
+CAP = batch._BATCH_BUCKETS[-1]  # the verifier's largest single dispatch
+
+
+def test_the_default_has_no_ceiling_and_keeps_the_fill_target():
+    assert CAP == 2048
+    ing = BatchingIngress(lambda b: None)
+    assert ing.max_batch is None and BatchingIngress.FILL_TARGET == 256
+    assert BatchingIngress(lambda b: None, max_batch=256).max_batch == 256
+    # The calibrated window projects to the same batch either way.
+    asked = []
+
+    class Spy:
+        def window(self, pending, target):
+            asked.append(target)
+            return 0.0
+
+    for kwargs in ({}, {"max_batch": 256}, {"max_batch": 64}):
+        ing = BatchingIngress(lambda b: None, **kwargs)
+        ing.calibrator = Spy()
+        ing._window()
+    assert asked == [256, 256, 64]
+
+
+def _burst(n: int, **kwargs):
+    """``n`` submits in one loop turn, then the loop runs until the timers
+    are through.  Returns the flush sizes, the ``ingress.flush`` spans'
+    args, and whether the FIRST submit armed a timer (``call_later``)
+    rather than the end of the turn (``call_soon``)."""
+    sizes = []
+
+    async def main():
+        ing = BatchingIngress(lambda b: sizes.append(len(b)), **kwargs)
+        timed = None
+        for i in range(n):
+            ing.submit(object())
+            if i == 0:
+                timed = isinstance(ing._handle, asyncio.TimerHandle)
+        await asyncio.sleep(0.02)
+        ing.close()
+        return timed
+
+    rec = trace.enable()
+    try:
+        timed = asyncio.run(main())
+        spans = [r[5] for r in rec.snapshot() if r[:2] == ("X", "ingress.flush")]
+    finally:
+        trace.disable()
+    return sizes, spans, timed
+
+
+@pytest.mark.parametrize(
+    "n, kwargs, want",
+    [
+        # Under the old ceiling: one flush at the end of the turn, as before.
+        (1, {}, [(1, False)]),
+        (99, {}, [(99, False)]),
+        (255, {}, [(255, False)]),
+        # A 300-validator phase: ONE batch (it was 256 + 44).
+        (299, {}, [(299, False)]),
+        (300, {}, [(300, False)]),
+        # Over the verifier's largest dispatch: still one batch (the
+        # verifier splits it, the ingress cuts nowhere).
+        (CAP - 1, {}, [(CAP - 1, False)]),
+        (CAP + 5, {}, [(CAP + 5, False)]),
+        (2 * CAP + 1, {}, [(2 * CAP + 1, False)]),
+        # An explicit max_batch is the ceiling, as it always was.
+        (7, {"max_batch": 3}, [(3, True), (3, True), (1, False)]),
+        (300, {"max_batch": 256}, [(256, True), (44, False)]),
+        (255, {"max_batch": 256}, [(255, False)]),
+    ],
+)
+def test_a_burst_in_one_turn(n, kwargs, want):
+    sizes, spans, timed = _burst(n, **kwargs)
+    assert sizes == [lanes for lanes, _cut in want]
+    # The span says how many lanes it handed over and whether ``max_batch``
+    # fired it (the timer and a direct flush() say False).
+    assert [(a["lanes"], a["cut"]) for a in spans] == want
+    assert all(set(a) == {"lanes", "cut"} for a in spans)
+    # A fresh ingress flushes a first burst at the end of its turn.
+    assert timed is False
+
+
+def test_a_held_back_ingress_still_waits_the_window_and_takes_the_phase_whole():
+    """After a flush that blocked the loop (a device dispatch), the next
+    flush waits ``max_delay``: the timer path of a live 300-validator
+    height.  The phase is one batch on that path too."""
+    sizes = []
+
+    async def main():
+        ing = BatchingIngress(lambda b: sizes.append(len(b)))
+        ing._held_back = True
+        for _ in range(299):
+            ing.submit(object())
+        assert isinstance(ing._handle, asyncio.TimerHandle)
+        assert sizes == []
+        await asyncio.sleep(0.02)
+        ing.close()
+
+    asyncio.run(main())
+    assert sizes == [299]
+
+
+def test_no_flush_span_when_tracing_is_off():
+    assert not trace.enabled()
+    sizes = []
+
+    async def main():
+        ing = BatchingIngress(sizes.append, max_batch=2)
+        ing.submit(object())
+        ing.submit(object())
+        ing.close()
+
+    assert trace.span("ingress.flush", lanes=1, cut=False) is trace._NULL
+    asyncio.run(main())
+    assert [len(b) for b in sizes] == [2]

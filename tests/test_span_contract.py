@@ -5,7 +5,9 @@ verifier's OUTER spans (``verify.drain`` / ``verify.early_exit``) from an
 operation's wall, and the ``verify.pack`` / ``verify.device_wait`` readers
 take those children per drain.  Both stand on one rule: every call of a
 verifier entry point the engine or ``SyncClient`` makes emits exactly one
-outer span, with the children its route owes inside it and on its track.
+outer span, with the children its route owes inside it and on its track,
+and one ``verify.verdicts`` instant inside it that counts what the call
+decided (lanes with a verdict, lanes refused).
 ``tests/test_kernel_stages.py`` holds the device half (the ``recover.*``
 scopes); this file holds the host half, on both routes, with the device
 programs replaced by stubs so that no ladder compiles.
@@ -17,6 +19,7 @@ protocol implies, never wall times.
 
 import asyncio
 import collections
+import math
 
 import numpy as np
 import pytest
@@ -99,9 +102,11 @@ def _validators(signed):
 
 def _outer_and_children(rec, outer_name):
     """The one outer span of ``rec`` and the count of each ``verify.*``
-    child that lies inside it on its track."""
+    child that lies inside it on its track.  The drain's one
+    ``verify.verdicts`` instant is held to the same place."""
     assert rec.dropped == 0
     spans = [r for r in rec.snapshot() if r[0] == "X"]
+    _verdicts_of(rec)
     outers = [r for r in spans if r[1] in OUTER]
     assert [r[1] for r in outers] == [outer_name], outers
     (outer,) = outers
@@ -116,6 +121,20 @@ def _outer_and_children(rec, outer_name):
         assert t0 <= ts and ts + child_dur <= t0 + dur + 1, (name, outer)
         inside[name] += 1
     return outer, dict(inside)
+
+
+def _verdicts_of(rec) -> dict:
+    """The args of the one ``verify.verdicts`` instant of ``rec``: inside
+    the one outer span, on its track, named after its kind and route."""
+    (outer,) = [r for r in rec.snapshot() if r[0] == "X" and r[1] in OUTER]
+    (verdicts,) = [r for r in rec.snapshot() if r[1] == "verify.verdicts"]
+    ph, _, track, ts, _, args = verdicts
+    assert ph == "i" and track == outer[2]
+    assert outer[3] <= ts <= outer[3] + outer[4] + 1
+    assert set(args) == {"kind", "route", "live", "rejected"}
+    assert (args["kind"], args["route"]) == (outer[5]["kind"], outer[5]["route"])
+    assert 0 <= args["rejected"] <= args["live"]
+    return args
 
 
 def _call(verifier, entry, signed, lanes):
@@ -168,6 +187,43 @@ def test_one_outer_span_with_the_children_its_route_owes(
     assert inside == (host_children if route == "host" else device_children)
     # The host route really verified; the stub accepts every live lane.
     assert mask.all() and len(mask) > 0
+    verdicts = _verdicts_of(recorder)
+    assert (verdicts["live"], verdicts["rejected"]) == (lanes, 0)
+
+
+@pytest.mark.parametrize("entry", list(ENTRY_POINTS))
+def test_verdicts_count_the_lanes_a_drain_refused(entry, recorder):
+    """Six corrupted validators of 24, host route (real recovers): the
+    instant's ``rejected`` is the generator's count of bad lanes among the
+    lanes that got a verdict.  The early exit cannot reach quorum on six
+    lanes, so it judges all of them."""
+    signed = build_signed_round(N_VALIDATORS, corrupt_frac=0.25, seed=31)
+    lanes = 6
+    bad = signed.expected_prepare_mask[:lanes]
+    assert (bad == signed.expected_seal_mask[:lanes]).all()
+    want = lanes - int(bad.sum())
+    assert 0 < want < lanes  # the seed puts both kinds among the first six
+    mask = _call(HostBatchVerifier(_validators(signed)), entry, signed, lanes)
+    assert int(mask.sum()) == lanes - want
+    verdicts = _verdicts_of(recorder)
+    assert (verdicts["live"], verdicts["rejected"]) == (lanes, want)
+
+
+def test_no_verdict_instant_when_tracing_is_off(signed_round, monkeypatch):
+    """``--trace 0`` pays one predicate: the counting is behind it."""
+    assert not trace.enabled()
+
+    def instant(*a, **k):
+        raise AssertionError("verify.verdicts built with tracing off")
+
+    monkeypatch.setattr(trace, "instant", instant)
+    for entry in ENTRY_POINTS:
+        _call(
+            HostBatchVerifier(_validators(signed_round)),
+            entry,
+            signed_round,
+            HOST_LANES,
+        )
 
 
 def test_chunked_device_drain_is_one_span_with_a_set_of_phases_a_chunk(
@@ -296,7 +352,13 @@ def _run_cluster(n: int) -> dict:
             flush_sizes[i].append(len(batch_))
             core.add_messages(batch_)
 
-        nodes.append((core, BatchingIngress(add_messages)))
+        ingress = BatchingIngress(add_messages)
+        # The counts below are those of a host that keeps up: a 16-lane host
+        # flush that a loaded test box stretches past the held-back threshold
+        # would make the next flush wait ``max_delay`` and take the
+        # PREPREPARE together with the first PREPAREs.
+        ingress.HELD_BACK_FACTOR = math.inf
+        nodes.append((core, ingress))
 
     async def heights():
         try:

@@ -483,11 +483,15 @@ def engine_phase(
         )
 
         # Warm what this committee makes the verifier dispatch: a phase's
-        # flood at the committee's lane bucket, and the 8-lane bucket an
+        # flood (at twice the committee's lane bucket where that is free,
+        # each COMMIT's seal beside its envelope), a seal drain that missed
+        # the verdicts at the bucket itself, and the 8-lane bucket an
         # early-exit seal drain takes when only a few lanes are still owed.
         t0 = time.perf_counter()
         verifier.warmup(
-            lanes=(8, lane_bucket), blocks=(2,), table_rows=table_rows
+            lanes=(8, *vbatch.committee_lanes(validators)),
+            blocks=(2,),
+            table_rows=table_rows,
         )
         warmup_s = round(time.perf_counter() - t0, 3)
         warm = cost_ledger.snapshot()
@@ -596,11 +600,12 @@ def engine_phase(
         small = sum(b for b in batches if b < verifier.cutover)
         big = sum(b for b in batches if b >= verifier.cutover)
         _check(big > 0, "ingress never delivered a cutover-sized batch")
+        flood_lanes = vbatch.committee_lanes(validators)[-1]
         _check(
             device_dispatches > 0
-            and device_padded == device_dispatches * lane_bucket,
+            and device_padded == device_dispatches * flood_lanes,
             f"device route: {device_dispatches} dispatches over {device_padded} "
-            f"padded lanes, expected every one at {lane_bucket} lanes",
+            f"padded lanes, expected every one at {flood_lanes} lanes",
         )
         # Every lane of a >= cutover batch (and every seal lane of a quorum
         # drain) was the device's; the host rows hold exactly the lanes of

@@ -877,12 +877,17 @@ class IBFT:
     def _handle_commit(self, view: View) -> bool:
         """Drain COMMITs; move to fin on quorum (reference core/ibft.go:931-967).
 
-        With a batch verifier this is the seal hot path: committed seals
-        are NEW cryptographic material (not covered by the ingress envelope
-        check), verified in batches at first sight and cached by identity
-        (``_seal_verdicts``), so each seal costs exactly one recover no
-        matter how many wakeups the phase takes.  The quorum reduction is
-        exact host ints over the cached-valid set.
+        With a batch verifier this is the seal hot path: a committed seal
+        is its own cryptographic material (the envelope check does not
+        cover it) but signs the hash carried IN its COMMIT, so nothing
+        makes it wait for this phase.  The device route verifies it in the
+        SAME dispatch as its envelope at ingress (``verify_senders``,
+        ISSUE 32) and the drain below is served from the verdicts that
+        left; on the other routes, and for a seal that did not ride, the
+        drain verifies in batches at first sight.  Either way the verdict
+        is cached by identity (``_seal_verdicts``), so each seal costs
+        exactly one recover no matter how many wakeups the phase takes.
+        The quorum reduction is exact host ints over the cached-valid set.
 
         Aggregate short-circuit: a pending quorum certificate for this
         height that hash-matches the accepted proposal and verifies (ONE
@@ -1461,7 +1466,10 @@ class IBFT:
         Sender signatures for the whole batch are verified in one device call
         (when a batch verifier is present), then each message passes the same
         height/round acceptance gate as ``add_message``.  Observable semantics
-        match N calls to ``add_message``; cost is one kernel launch.
+        match N calls to ``add_message``; cost is one kernel launch, which on
+        the device route also carries the committed seal of every COMMIT in
+        the batch (the verifier keeps those verdicts for ``_handle_commit``'s
+        drain; the mask returned here is the envelopes' alone).
         """
         if not batch:
             return

@@ -20,6 +20,7 @@ for committed seals.
 
 from __future__ import annotations
 
+import threading
 import time
 from contextlib import ExitStack
 from dataclasses import dataclass
@@ -34,8 +35,8 @@ from ..crypto import ecdsa as host_ecdsa
 from ..obs import ledger as cost_ledger
 from ..obs import trace
 from ..crypto.keccak import keccak256, keccak256_many
-from ..messages.helpers import CommittedSeal
-from ..messages.wire import IbftMessage
+from ..messages.helpers import CommittedSeal, extract_committed_seal
+from ..messages.wire import IbftMessage, MessageType
 from ..ops import fields
 from ..ops import keccak as dk
 from ..ops import quorum
@@ -80,6 +81,14 @@ class MalformedLaneError(ValueError):
 # compare.  Lane buckets stay fine-grained: lane count scales the ladder
 # itself, where padding waste is real work.
 _BATCH_BUCKETS = (8, 32, 128, 512, 1024, 2048)
+# From this many lanes up a rung is a multiple of what ``ops/secp256k1.py``
+# folds to full (8, 128) tiles.  A rung below it is a partial vector register
+# on the v5e, by size: a dispatch at TWICE such a rung costs what the rung
+# itself costs (PERF.md section 6, PR 32: 256 lanes against 128), so the
+# sender floods run there, with the second half free for their COMMITs' seals
+# (``DeviceBatchVerifier.verify_senders``), and a committee loads one ladder
+# executable (15 s of a process's set-up each) where two widths would be two.
+_FOLD_LANES = 256
 _BLOCK_BUCKETS = (2, 8, 32)
 _TABLE_BUCKETS = (8, 128, 512, 2048)
 
@@ -105,6 +114,16 @@ def _lane_count(n: int, pad_lanes: int = 0) -> int:
     if pad_lanes >= max(n, 1):
         return pad_lanes
     return max(_bucket(n, _BATCH_BUCKETS), pad_lanes)
+
+
+def committee_lanes(n_validators: int) -> Tuple[int, ...]:
+    """Widths of the recover program that one phase of a committee of
+    ``n_validators`` makes the device route dispatch, for whoever warms
+    shapes at start-up (``warmup(lanes=...)``): the phase's own rung (a
+    seal drain that missed the verdict cache) and, under the fold width,
+    twice it (the sender floods, each COMMIT's seal beside its envelope)."""
+    lanes = _bucket(min(n_validators, _BATCH_BUCKETS[-1]), _BATCH_BUCKETS)
+    return (lanes, 2 * lanes) if lanes < _FOLD_LANES else (lanes,)
 
 
 def host_quorum_reached(
@@ -139,22 +158,91 @@ EARLY_EXIT_DRAINS_KEY = ("go-ibft", "early_exit", "drains")
 # Recorded only while metrics.enable_fixed_histograms() is on.
 VERIFY_DRAIN_MS_KEY = ("go-ibft", "latency", "verify_drain_ms")
 
+# The joint COMMIT dispatch: seal lanes that rode their envelope's dispatch,
+# and what the seal drains then found (hit) or still had to verify (miss).
+SEAL_LANES_CARRIED_KEY = ("go-ibft", "seal_verdicts", "carried")
+SEAL_VERDICT_HITS_KEY = ("go-ibft", "seal_verdicts", "hits")
+SEAL_VERDICT_MISSES_KEY = ("go-ibft", "seal_verdicts", "misses")
+JOINT_FAULTS_KEY = ("go-ibft", "seal_verdicts", "joint_faults")
+
+# Sized like the engine's own seal-verdict cache (core/ibft.py
+# ``_seal_verdict_cap``): a constant, not an option.
+_SEAL_VERDICT_CAP = 16384
+
+SealKey = Tuple[bytes, bytes, bytes]  # (claimed signer, carried hash, seal bytes)
+
+
+class SealVerdictCache:
+    """``(height, claimed signer, carried proposal hash, seal signature
+    bytes) -> bool``: what a joint COMMIT dispatch decided about a seal.
+
+    A committed seal's verdict is a pure function of exactly that binding
+    and the height's validator table, so a hit is the verdict the seal
+    drain would compute and nothing else can reach it: another height,
+    hash, signer or signature is another key.  Bounded: a height the
+    verifier's table cache has dropped goes whole (:meth:`drop_height`),
+    on cap pressure heights below the newest go whole, oldest first, and
+    the newest sheds FIFO (a seal-rewrite flood mints keys there).
+    Thread-safe, like :class:`~go_ibft_tpu.verify.pipeline.PackCache`.
+    """
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        self._by_height: Dict[int, Dict[SealKey, bool]] = {}
+        self._count = 0
+
+    def __len__(self) -> int:
+        with self._lock:
+            return self._count
+
+    def lookup(self, height: int, keys: Sequence[SealKey]) -> List[Optional[bool]]:
+        with self._lock:
+            bucket = self._by_height.get(height)
+            if not bucket:
+                return [None] * len(keys)
+            return [bucket.get(k) for k in keys]
+
+    def store(self, height: int, keys: Sequence[SealKey], verdicts) -> None:
+        with self._lock:
+            bucket = self._by_height.setdefault(height, {})
+            for key, ok in zip(keys, verdicts):
+                if key not in bucket:
+                    self._count += 1
+                bucket[key] = bool(ok)
+            while self._count > _SEAL_VERDICT_CAP:
+                oldest = min(self._by_height)
+                bucket = self._by_height[oldest]
+                if len(self._by_height) > 1:
+                    self._count -= len(bucket)
+                    del self._by_height[oldest]
+                else:
+                    del bucket[next(iter(bucket))]
+                    self._count -= 1
+
+    def drop_height(self, height: int) -> None:
+        with self._lock:
+            self._count -= len(self._by_height.pop(height, ()))
+
 
 def _note_verdicts(kind: str, route: str, mask: np.ndarray, judged=None) -> None:
     """``verify.verdicts``: what one drain decided, as an instant inside its
     outer span (``verify.drain`` / ``verify.early_exit``).  ``live`` counts
-    the lanes that got a verdict (``judged`` marks them where a drain may
-    stop early; all of ``mask`` otherwise), ``rejected`` those of them it
-    refused.  One predicate when tracing is off."""
+    the lanes that got a verdict HERE (``judged`` marks them where a drain
+    may stop early or serves lanes from the seal-verdict cache, which were
+    noted when they were judged; all of ``mask`` otherwise), ``rejected``
+    those of them it refused.  One predicate when tracing is off."""
     if not trace.enabled():
         return
-    live = len(mask) if judged is None else int(np.count_nonzero(judged))
+    if judged is not None:
+        mask = mask[judged]
+        if not len(mask):
+            return
     trace.instant(
         "verify.verdicts",
         kind=kind,
         route=route,
-        live=live,
-        rejected=live - int(np.count_nonzero(mask)),
+        live=len(mask),
+        rejected=len(mask) - int(np.count_nonzero(mask)),
     )
 
 
@@ -508,6 +596,16 @@ def _recover_fn(zw, r, s, v, claimed_w, table_w, live):
 
 
 _recover_kernel = jax.jit(_recover_fn)
+
+
+def _join_rows_fn(zw, hz):
+    return jnp.concatenate([zw, hz], axis=0)
+
+
+# A sender chunk's digest rows and, behind them, the hash rows of the seals
+# that ride its dispatch: one batch's ``zw`` (a program of its own so that
+# ``warmup`` can load it: nothing compiles at a node's first COMMIT flood).
+_join_rows = jax.jit(_join_rows_fn)
 
 
 def _certify_fn(zw, r, s, v, claimed_w, table_w, live, plo, phi, thr_lo, thr_hi):
@@ -1006,6 +1104,9 @@ class DeviceBatchVerifier:
         # messages (certificate validation re-runs per wakeup) skip the
         # re-encode + re-limb entirely.
         self._pack_cache = PackCache()
+        # What the joint COMMIT dispatches of ``verify_senders`` decided
+        # about the seals that rode them; the seal drains look here first.
+        self._seal_verdicts = SealVerdictCache()
 
     def note_round(self, round_: int) -> None:
         """Engine hook: tag pack-cache entries with the live round (round
@@ -1102,6 +1203,11 @@ class DeviceBatchVerifier:
                         jnp.zeros((bb, nb, 17, 2), jnp.uint32),
                         jnp.ones((bb,), jnp.int32),
                     ).block_until_ready()
+            if self._joint_lanes(bb // 2) == bb // 2:
+                # A sender flood's width (twice a rung under the fold
+                # width): its halves meet in ``_join_rows``.
+                half = jnp.zeros((bb // 2, 8), jnp.uint32)
+                _join_rows(half, half).block_until_ready()
 
     # -- validator table management ------------------------------------
 
@@ -1120,6 +1226,7 @@ class DeviceBatchVerifier:
             evicted = min(self._tables)
             self._tables.pop(evicted)
             self._tables_dev.pop(evicted, None)
+            self._seal_verdicts.drop_height(evicted)
         return table, addrs
 
     def _table(self, height: int) -> np.ndarray:
@@ -1532,7 +1639,133 @@ class DeviceBatchVerifier:
         )
         return report.results
 
+    # -- the joint COMMIT dispatch ---------------------------------------
+    # A committed seal signs the proposal hash carried IN its COMMIT, so
+    # its verdict is known the moment the message arrives: a pure function
+    # of (carried hash, seal bytes, claimed signer, the height's table).
+    # ``verify_senders`` therefore verifies every well-formed COMMIT's seal
+    # in its envelope's drain — in the same dispatch where the rung is under
+    # the fold width (``_FOLD_LANES``: twice the lanes are free there), else
+    # as the drain's next chunk, in the same program, packed while the
+    # envelopes run — and keeps the verdicts for the seal drains, which
+    # look there before they pack.
+
+    def _joint_lanes(self, n: int) -> int:
+        """Lanes of each half of a sender chunk of ``n`` envelopes that runs
+        at twice its rung, the second half for its COMMITs' seals: the rung
+        itself where that is under the fold width (99 envelopes of a
+        100-validator committee: 128 + 128 = 256 lanes, the first width
+        that fills whole tiles, at the cost of 128).  0 from the fold
+        width up, where twice the lanes cost twice (299 envelopes in 512
+        lanes): there the seals are the drain's next chunk, at the
+        envelopes' lane count."""
+        if n > _BATCH_BUCKETS[-1]:
+            return 0
+        lanes = _bucket(n, _BATCH_BUCKETS)
+        return lanes if lanes < _FOLD_LANES else 0
+
+    @staticmethod
+    def _seal_key(m: IbftMessage) -> SealKey:
+        return (m.sender, m.commit_data.proposal_hash, m.commit_data.committed_seal)
+
+    def _seal_riders(self, sub: List[IbftMessage], height: int) -> List[int]:
+        """Positions in ``sub`` of the COMMITs whose seal rides along: a
+        32-byte carried hash, a well-formed seal (the sender's length is
+        the envelope's, checked already), no verdict yet."""
+        riders = [
+            j
+            for j, m in enumerate(sub)
+            if m.type == MessageType.COMMIT
+            and m.commit_data is not None
+            and len(m.commit_data.proposal_hash) == 32
+            and len(m.commit_data.committed_seal) == SIG_BYTES
+        ]
+        if not riders:
+            return riders
+        known = self._seal_verdicts.lookup(
+            height, [self._seal_key(sub[j]) for j in riders]
+        )
+        return [j for j, hit in zip(riders, known) if hit is None]
+
+    def _rider_inputs(self, commits: List[IbftMessage], lanes: int):
+        """The seal rows of ``commits`` on ``lanes`` lanes: per-lane hash
+        words (each COMMIT's own carried hash), claimed signer = sender."""
+        with trace.span("verify.pack", kind="seals", lanes=len(commits)):
+            return pack_seal_lanes(
+                [
+                    (m.commit_data.proposal_hash, extract_committed_seal(m))
+                    for m in commits
+                ],
+                pad_lanes=lanes,
+            )
+
+    def _joint_inputs(self, sub: List[IbftMessage], riders: List[int], lanes: int):
+        """Envelope rows in the first ``lanes`` lanes, the riders' seal rows
+        in the second: the :meth:`certify_round` layout, for the mask-only
+        program."""
+        zw, r, s, v, senders, live = self._sender_inputs(sub, pad_lanes=lanes)
+        hz, r2, s2, v2, signers, live2 = (
+            self._rider_inputs([sub[j] for j in riders], lanes)
+            if riders
+            else pack_seal_lanes([], pad_lanes=lanes)  # a dead half: no pack span
+        )
+        return (
+            _join_rows(jnp.asarray(zw), jnp.asarray(hz)),
+            np.concatenate([r, r2]),
+            np.concatenate([s, s2]),
+            np.concatenate([v, v2]),
+            np.concatenate([senders, signers]),
+            np.concatenate([live, live2]),
+        )
+
+    def cached_seal_verdicts(
+        self, proposal_hash: bytes, seals: Sequence[CommittedSeal], height: int
+    ) -> List[Optional[bool]]:
+        """Per lane, the verdict a joint dispatch left for exactly this
+        (height, signer, hash, signature); ``None`` where none did."""
+        return self._seal_verdicts.lookup(
+            height, [(s.signer, proposal_hash, s.signature) for s in seals]
+        )
+
+    def _serve_cached_seals(self, proposal_hash, seals, idxs, height, out):
+        """Write the cached verdicts of lanes ``idxs`` into ``out``; returns
+        ``(served, missed)`` lane lists.  Counted, never re-noted: a
+        ``verify.verdicts`` instant counts a lane where it was judged."""
+        known = self.cached_seal_verdicts(
+            proposal_hash, [seals[i] for i in idxs], height
+        )
+        served, missed = [], []
+        for i, hit in zip(idxs, known):
+            if hit is None:
+                missed.append(i)
+            else:
+                out[i] = hit
+                served.append(i)
+        if served:
+            metrics.inc_counter(SEAL_VERDICT_HITS_KEY, len(served))
+        if missed:
+            metrics.inc_counter(SEAL_VERDICT_MISSES_KEY, len(missed))
+        return served, missed
+
     def verify_senders(self, msgs: Sequence[IbftMessage]) -> np.ndarray:
+        """Envelope validity mask, one recover dispatch per chunk.
+
+        A COMMIT's committed seal is verified in the SAME drain as its
+        envelope.  A chunk whose rung is under the fold width runs at twice
+        the rung, at the rung's cost, with the seals in the second half
+        (:meth:`_joint_lanes`, :meth:`_joint_inputs`: 99 envelopes + 99
+        seals are one 256-lane run instead of two 128-lane runs in series;
+        a PREPARE flood leaves that half dead, so that a committee's floods
+        are ONE program); from the fold width up the seals are the next
+        chunk of the double-buffered pipeline, in the envelopes' program
+        (299 + 299 at 300 validators: the seals pack while the envelopes
+        run, and no engine wake-up lies between).  The seal
+        drains that follow are served from the verdicts this leaves
+        (:class:`SealVerdictCache`).  The mask returned is bit for bit the
+        envelope-only one.  Messages of other types, malformed seals and
+        seals that already have a verdict do not ride; if a dispatch that
+        carries seals faults, nothing of it is kept, the envelopes run
+        alone as before and the seals wait for their own drain."""
         if not msgs:
             return np.zeros(0, dtype=bool)
         out = np.zeros(len(msgs), dtype=bool)
@@ -1545,33 +1778,80 @@ class DeviceBatchVerifier:
         # 2049 sequential host recovers (VERDICT r04 weak #6) — and the
         # chunks ride the double-buffered pipeline: chunk N+1 packs on host
         # while chunk N executes.
-        items = [
-            (height, idxs[start : start + self._dispatch_cap])
-            for height, idxs in by_height.items()
-            for start in range(0, len(idxs), self._dispatch_cap)
-        ]
+        # An item is ``(height, message idxs, riders, lanes)``: an envelope
+        # chunk whose ``riders`` (positions in it) carry their seal in its
+        # second ``lanes`` lanes, or — ``riders`` None — a chunk of seals
+        # alone, on the ``lanes`` their envelopes' chunk ran at.
+        items, seal_lanes = [], 0
+        for height, idxs in by_height.items():
+            for start in range(0, len(idxs), self._dispatch_cap):
+                chunk = idxs[start : start + self._dispatch_cap]
+                riders = self._seal_riders([msgs[i] for i in chunk], height)
+                seal_lanes += len(riders)
+                half = self._joint_lanes(len(chunk))
+                items.append((height, chunk, riders if half else [], half))
+                if riders and not half:
+                    lanes = _lane_count(len(chunk), self._pad_lanes(len(chunk)))
+                    items.append((height, [chunk[j] for j in riders], None, lanes))
         if not items:
             return out
+        envelopes = [item for item in items if item[2] is not None]
 
         def pack(item):
-            height, chunk = item
-            return (
-                item,
-                self._sender_inputs([msgs[i] for i in chunk]),
-                self._table_dev(height),
-            )
+            height, chunk, riders, lanes = item
+            sub = [msgs[i] for i in chunk]
+            if riders is None:
+                inputs = self._rider_inputs(sub, lanes)
+            elif lanes:  # with or without riders: one program a committee
+                inputs = self._joint_inputs(sub, riders, lanes)
+            else:
+                inputs = self._sender_inputs(sub)
+            return item, inputs, self._table_dev(height)
 
         with trace.span(
-            "verify.drain", route=self._route, kind="senders", chunks=len(items)
+            "verify.drain",
+            route=self._route,
+            kind="senders",
+            chunks=len(items),
+            lanes=sum(len(chunk) for _, chunk, _, _ in envelopes),
+            seal_lanes=seal_lanes,
         ):
-            results = self._run_chunk_pipeline(items, pack, "verify_senders_ms")
+            try:
+                results = self._run_chunk_pipeline(items, pack, "verify_senders_ms")
+            except Exception:
+                if not seal_lanes:
+                    raise
+                # The fault may be the seal rows' and names no message: the
+                # envelopes run as they did before the seals rode with them
+                # (a fault that is theirs raises again, for the ladder to
+                # bisect), the seals wait for their drain.
+                metrics.inc_counter(JOINT_FAULTS_KEY)
+                seal_lanes = 0
+                items = [(height, chunk, [], 0) for height, chunk, _, _ in envelopes]
+                results = self._run_chunk_pipeline(items, pack, "verify_senders_ms")
             # Mask-only drain: the voting-power reduction proper runs in
             # the caller (engine exact ints); this phase is the per-lane
             # verdict assembly.
             with trace.span("verify.quorum", route="mask"):
-                for (_, chunk), mask in results:
-                    out[np.asarray(chunk)] = mask[: len(chunk)]
+                carried = []
+                for (height, chunk, riders, lanes), mask in results:
+                    if riders is None:
+                        commits, sealed = chunk, mask[: len(chunk)]
+                    else:
+                        out[np.asarray(chunk)] = mask[: len(chunk)]
+                        commits = [chunk[j] for j in riders]
+                        sealed = mask[lanes : lanes + len(riders)]
+                    if commits:
+                        carried.append(np.asarray(sealed, dtype=bool))
+                        self._seal_verdicts.store(
+                            height,
+                            [self._seal_key(msgs[i]) for i in commits],
+                            carried[-1],
+                        )
             _note_verdicts("senders", self._route, out)
+            if carried:
+                metrics.inc_counter(SEAL_LANES_CARRIED_KEY, seal_lanes)
+                _note_verdicts("seals", self._route, np.concatenate(carried))
         return out
 
     def verify_sender_rows(
@@ -1618,10 +1898,17 @@ class DeviceBatchVerifier:
     def verify_committed_seals(
         self, proposal_hash: bytes, seals: Sequence[CommittedSeal], height: int
     ) -> np.ndarray:
+        """Seal validity mask.  Lanes whose COMMIT rode a joint
+        :meth:`verify_senders` dispatch are served from the verdicts it
+        left; only the others are packed and dispatched, and a drain whose
+        lanes all hit dispatches nothing."""
         out = np.zeros(len(seals), dtype=bool)
         idxs = [i for i, s in enumerate(seals) if self._well_formed_seal(s)]
         if not idxs or len(proposal_hash) != 32:
             return out
+        served, idxs = self._serve_cached_seals(
+            proposal_hash, seals, idxs, height, out
+        )
         items = [
             idxs[start : start + self._dispatch_cap]
             for start in range(0, len(idxs), self._dispatch_cap)
@@ -1635,13 +1922,20 @@ class DeviceBatchVerifier:
             )
 
         with trace.span(
-            "verify.drain", route=self._route, kind="seals", chunks=len(items)
+            "verify.drain",
+            route=self._route,
+            kind="seals",
+            chunks=len(items),
+            cached=len(served),
         ):
-            results = self._run_chunk_pipeline(items, pack, "verify_seals_ms")
-            with trace.span("verify.quorum", route="mask"):
-                for chunk, mask in results:
-                    out[np.asarray(chunk)] = mask[: len(chunk)]
-            _note_verdicts("seals", self._route, out)
+            if items:
+                results = self._run_chunk_pipeline(items, pack, "verify_seals_ms")
+                with trace.span("verify.quorum", route="mask"):
+                    for chunk, mask in results:
+                        out[np.asarray(chunk)] = mask[: len(chunk)]
+            judged = np.ones(len(seals), dtype=bool)
+            judged[served] = False
+            _note_verdicts("seals", self._route, out, judged)
         return out
 
     def verify_seal_lanes(
@@ -1714,6 +2008,11 @@ class DeviceBatchVerifier:
         dispatched lanes are the kernel's usual mask, bit-identical to
         the sequential oracle; the mesh subclass shards each chunk like
         any other drain.
+
+        Lanes whose COMMIT rode a joint :meth:`verify_senders` dispatch
+        come first, from the verdicts it left (``verified`` true, their
+        power in the tally): only the others are ordered, packed and
+        dispatched, and a drain whose lanes all hit dispatches nothing.
         """
         n = len(seals)
         mask = np.zeros(n, dtype=bool)
@@ -1732,14 +2031,21 @@ class DeviceBatchVerifier:
         malformed = set(range(n)) - set(well_formed)
         if malformed:
             verified[np.asarray(sorted(malformed))] = True
+        served, missed = self._serve_cached_seals(
+            proposal_hash, seals, well_formed, height, mask
+        )
+        verified[served] = True
+        tally = _PowerTally(powers, thr)
+        claimed = _PowerTally(powers, thr)
+        for i in served:
+            if mask[i]:
+                tally.add(seals[i].signer)
+                claimed.add(seals[i].signer)
         # Power-ordered, stable: arrival order breaks ties so equal-power
         # sets (the common 1-power-each committee) drain in arrival order.
-        order = sorted(
-            well_formed, key=lambda i: -powers.get(seals[i].signer, 0)
-        )
+        order = sorted(missed, key=lambda i: -powers.get(seals[i].signer, 0))
         # First chunk: the claimed-power quorum prefix, bucket-padded —
         # the extra bucket lanes are verified for free (they pad anyway).
-        claimed = _PowerTally(powers, thr)
         prefix = 0
         for i in order:
             prefix += 1
@@ -1750,13 +2056,13 @@ class DeviceBatchVerifier:
             if order
             else 0
         )
-        tally = _PowerTally(powers, thr)
         pos = 0
         with trace.span(
             "verify.early_exit",
             route=self._route,
             kind="seals",
             lanes=n,
+            cached=len(served),
         ):
             while pos < len(order) and not tally.reached:
                 take = order[pos : pos + chunk]
@@ -1775,7 +2081,9 @@ class DeviceBatchVerifier:
                         tally.add(seals[i].signer)
                 pos += len(take)
                 chunk = min(chunk * 2, self._dispatch_cap)
-            _note_verdicts("seals", self._route, mask, verified)
+            judged = verified.copy()
+            judged[served] = False
+            _note_verdicts("seals", self._route, mask, judged)
         skipped = len(order) - pos
         metrics.inc_counter(EARLY_EXIT_DRAINS_KEY)
         if skipped:
@@ -2299,10 +2607,34 @@ class AdaptiveBatchVerifier:
         # quarantine instead of raising, device faults demote to host.
         return self._resilient.verify_senders(msgs)
 
+    def _cached_seal_split(
+        self, proposal_hash: bytes, seals: Sequence[CommittedSeal], height: int
+    ) -> Tuple[List[int], List[int]]:
+        """``(hit, miss)`` lanes of a seal drain against the verdicts the
+        device rung's joint COMMIT dispatches left.  The cutover counts
+        lanes that need a recover, so a drain is routed by its misses: the
+        hits go to the device rung, which dispatches nothing for them."""
+        lookup = getattr(self.device, "cached_seal_verdicts", None)
+        if lookup is None or len(proposal_hash) != 32:  # an injected stub
+            return [], list(range(len(seals)))
+        known = lookup(proposal_hash, seals, height)
+        return (
+            [i for i, hit in enumerate(known) if hit is not None],
+            [i for i, hit in enumerate(known) if hit is None],
+        )
+
     def verify_committed_seals(
         self, proposal_hash: bytes, seals: Sequence[CommittedSeal], height: int
     ) -> np.ndarray:
-        if self._host_sized(len(seals)):
+        hit, miss = self._cached_seal_split(proposal_hash, seals, height)
+        if hit and miss:
+            out = np.zeros(len(seals), dtype=bool)
+            for idxs in (hit, miss):
+                out[idxs] = self.verify_committed_seals(
+                    proposal_hash, [seals[i] for i in idxs], height
+                )
+            return out
+        if not hit and self._host_sized(len(seals)):
             return self.host.verify_committed_seals(proposal_hash, seals, height)
         return self._resilient.verify_committed_seals(proposal_hash, seals, height)
 
@@ -2326,8 +2658,33 @@ class AdaptiveBatchVerifier:
         """Early-exit seal drain, routed like every other seal drain:
         tiny batches take the sequential host early-exit (arrival-order
         stop-at-quorum), larger ones the ladder's power-ordered chunked
-        route (mesh/device with full breaker accounting)."""
-        if self._host_sized(len(seals)):
+        route (mesh/device with full breaker accounting).  "Tiny" counts
+        the lanes no joint COMMIT dispatch has judged yet: those that one
+        has come first, from its verdicts, and the rest is routed by its
+        own size against what is left of the threshold."""
+        hit, miss = self._cached_seal_split(proposal_hash, seals, height)
+        if hit and miss:
+            first = self.verify_seals_early_exit(
+                proposal_hash, [seals[i] for i in hit], height, threshold
+            )
+            mask = np.zeros(len(seals), dtype=bool)
+            verified = np.zeros(len(seals), dtype=bool)
+            mask[hit], verified[hit] = first.mask, first.verified
+            if first.reached:
+                return EarlyExitReport(mask, verified, True, len(miss))
+            powers = self._validators(height)
+            if threshold is None:
+                threshold = calculate_quorum(sum(powers.values()))
+            have = {seals[i].signer for i in hit if mask[i]}
+            rest = self.verify_seals_early_exit(
+                proposal_hash,
+                [seals[i] for i in miss],
+                height,
+                threshold - sum(powers.get(a, 0) for a in have),
+            )
+            mask[miss], verified[miss] = rest.mask, rest.verified
+            return EarlyExitReport(mask, verified, rest.reached, rest.skipped)
+        if not hit and self._host_sized(len(seals)):
             return self.host.verify_seals_early_exit(
                 proposal_hash, seals, height, threshold=threshold
             )

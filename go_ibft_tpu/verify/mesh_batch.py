@@ -180,6 +180,18 @@ class MeshBatchVerifier(DeviceBatchVerifier):
             return 0
         return _bucket((n + self.dp - 1) // self.dp, _BATCH_BUCKETS) * self.dp
 
+    def _joint_lanes(self, n: int) -> int:
+        """Sharded sender chunks keep their ``bucket x dp`` shape."""
+        return 0 if self.mesh is not None else super()._joint_lanes(n)
+
+    def _seal_riders(self, sub, height: int):
+        """A sharded drain carries no seals: envelopes and seals keep their
+        separate drains, as before ISSUE 32 (the sharded path has not been
+        timed on a chip; what the joint dispatch buys is a single device's
+        tile fill and one ladder executable).  The one-device degradation
+        is the parent's."""
+        return [] if self.mesh is not None else super()._seal_riders(sub, height)
+
     def _table_dev(self, height: int) -> jnp.ndarray:
         """Validator table replicated across the mesh (uploaded once per
         height, like the parent's single-device pin)."""

@@ -227,6 +227,7 @@ def main() -> int:
         seal_quorum_certify,
     )
     from go_ibft_tpu.verify import DeviceBatchVerifier
+    from go_ibft_tpu.verify.batch import committee_lanes
 
     # Mesh FIRST: the dryrun programs are what a cold cache costs most;
     # everything after this line is cheaper to lose to a time limit.
@@ -276,9 +277,13 @@ def main() -> int:
     # and the (8, 128) shape the weighted-committee suites hit.  Cold-
     # compiling either inside a test timeout is the failure mode this
     # script exists to prevent.
+    # Since ISSUE 32 that committee's sender floods run at twice the rung
+    # (256 lanes, each COMMIT's seal beside its envelope).
     t0 = time.perf_counter()
-    DeviceBatchVerifier(lambda h: {}).warmup(lanes=(8, 128), table_rows=128)
-    _stamp("early-exit drain shapes (8/128 lanes x 128-row table)", t0)
+    DeviceBatchVerifier(lambda h: {}).warmup(
+        lanes=(8, *committee_lanes(100)), table_rows=128
+    )
+    _stamp("early-exit and flood shapes (8/128/256 lanes x 128-row table)", t0)
 
     # Serve-path drain shapes (ISSUE 10): the proof-serving read plane's
     # device route is the multi-tenant CoalescedDispatcher — fresh proof

@@ -241,6 +241,13 @@ def test_device_verifier_chunks_oversize_floods(monkeypatch):
     monkeypatch.setattr(
         dev, "_sender_inputs", lambda ms: (None,) * 5 + (np.ones(len(ms), bool),)
     )
+    # The one-message remainder is under the fold width and packs through
+    # the joint layout (twice its rung, second half dead: ISSUE 32).
+    monkeypatch.setattr(
+        dev,
+        "_joint_inputs",
+        lambda ms, riders, lanes: (None,) * 5 + (np.ones(len(ms), bool),),
+    )
     big = (msgs * 513)[:2049]
     out = dev.verify_senders(big)
     assert sizes == [_BATCH_BUCKETS[-1], 1]

@@ -111,7 +111,9 @@ def test_batched_device_route_equals_the_sequential_reference(n, seed):
     corrupt = driver.corrupted_peers(n, CORRUPT_SHARE)
     assert corrupt == {10: 3, 20: 6}[n]
     # cutover 2: the one-lane PREPREPARE stays on the host, every flood
-    # takes the device route at the 32-lane bucket, the seal drain at 8 or 32.
+    # takes the device route at twice the 32-lane bucket (ISSUE 32: the
+    # second half is free there, and the COMMIT flood's seals ride in it),
+    # and the engine's seal drain dispatches nothing.
     batched = _run(n, seed, lambda src: AdaptiveBatchVerifier(src, cutover_lanes=2))
     reference = _run(n, seed, HostBatchVerifier, batched=False)
     for st in (batched, reference):
@@ -135,28 +137,40 @@ def test_batched_device_route_equals_the_sequential_reference(n, seed):
     device = [r for r in batched.rows if r["route"] == "device"]
     host = [r for r in batched.rows if r["route"] == "host"]
     assert sum(r["dispatches"] for r in host) == HEIGHTS
-    recovers = [r for r in device if r["program"] == "ecdsa_recover"]
-    assert sum(r["dispatches"] for r in recovers) >= 3 * HEIGHTS
+    (recovers,) = [r for r in device if r["program"] == "ecdsa_recover"]
+    # After it a height is TWO recover dispatches: the PREPARE flood, and
+    # the COMMIT flood with every COMMIT's seal in it.
+    assert recovers["dispatches"] == 2 * HEIGHTS
+    assert recovers["padded_lanes"] == 2 * 64 * HEIGHTS
+    assert recovers["live_lanes"] == (n - 1 + 2 * n) * HEIGHTS
 
     # What the drains report is what the generator labelled: per height the
     # PREPREPARE, the PREPARE flood (the node's own among it; every
     # corrupted peer's envelope is mangled), the COMMIT flood (half of them
-    # mangle that envelope too), and the seals of the COMMITs that passed
-    # (the other half carry a mangled seal).
+    # mangle that envelope too), and in the same drain the seal of EVERY
+    # COMMIT of the flood (the other half carry a mangled seal; a seal
+    # behind a mangled envelope is judged too, and never asked for).
     bad_envelopes = corrupt // 2
     assert _verdicts(batched, "senders") == [
         (1, 0),
         (n - 1, corrupt),
         (n, bad_envelopes),
     ] * HEIGHTS
-    seals = _verdicts(batched, "seals")
-    assert len(seals) == HEIGHTS
-    for live, rejected in seals:
-        # The early exit may stop before the last lanes; every valid seal
-        # is needed for quorum here, so it judged all of those.
-        assert live - rejected == n - corrupt
-        assert rejected <= corrupt - bad_envelopes
-        assert live <= n - bad_envelopes
+    assert _verdicts(batched, "seals") == [(n, corrupt - bad_envelopes)] * HEIGHTS
+    # The engine's seal drain asked for the seals of the COMMITs that
+    # passed, and every one was a verdict the COMMIT flood had left.
+    drains = [r[5] for r in batched.records if r[:2] == ("X", "verify.early_exit")]
+    assert [(a["route"], a["lanes"], a["cached"]) for a in drains] == [
+        ("device", n - bad_envelopes, n - bad_envelopes)
+    ] * HEIGHTS
+    floods = [
+        (a["chunks"], a["lanes"], a["seal_lanes"])
+        for r in batched.records
+        if r[:2] == ("X", "verify.drain")
+        for a in [r[5]]
+        if a["route"] == "device"
+    ]
+    assert floods == [(1, n - 1, 0), (1, n, n)] * HEIGHTS
     assert not _verdicts(reference, "senders")  # no batch verifier, no drain
 
 

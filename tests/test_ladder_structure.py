@@ -119,12 +119,14 @@ def _core_shapes(jaxpr):
 
 # The ladder's batch is folded so that the TPU tiles it T(8,128), where that
 # fills whole tiles (PR 30): lanes % 256 == 0.  8 is every budget program's
-# shape, 128 the flood's; 512, 1,024 and 2,048 are the lane buckets that fold.
+# shape, 128 the PREPARE flood's; 256 (the joint COMMIT dispatch at 100
+# validators, PR 32: exactly one tile a row), 512, 1,024 and 2,048 fold.
 @pytest.mark.parametrize(
     "lanes,batch",
     [
         (8, (4, 8)),
         (128, (4, 128)),
+        (256, (8, 128)),
         (512, (16, 128)),
         (1024, (32, 128)),
         (2048, (64, 128)),

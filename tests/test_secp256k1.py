@@ -289,11 +289,14 @@ def test_glv_ladder_negative_half_scalar_edges(points):
 # (PERF.md section 6, PR 25).
 
 ECMUL2_CASES = lc.ecmul2_cases()
-SYNC_LANES = 2048
 # ecmul2_base folds its (4, lanes) ladder batch into (lanes // 32, 128) where
-# lanes % 256 == 0 (PR 30): 128 is the flood's shape and is not folded, 512
-# is the smallest lane bucket that is.
+# lanes % 256 == 0 (PR 30): 128 is the PREPARE flood's shape and is not
+# folded, 512 is the smallest lane bucket that is.
 LADDER_LANES = (128, 512)
+# On the chip only, tile by tile: the joint COMMIT dispatch's widths at 100
+# and 300 validators (256: one full (8, 128) tile; 1,024: PR 32) and the
+# sync path's 2,048.
+CHIP_LANES = (256, 1024, 2048)
 
 
 def _on_tpu() -> bool:
@@ -351,12 +354,13 @@ def test_glv_ladder_edge_cases_cover_what_they_name():
             assert host.scalar_mul(k1, lc.G) == host.scalar_mul(k2, q)
 
 
-def test_glv_ladder_edge_cases_at_sync_lanes_on_the_chip():
+@pytest.mark.parametrize("lanes", CHIP_LANES, ids=lambda n: f"{n}l")
+def test_glv_ladder_edge_cases_at_wide_lanes_on_the_chip(lanes):
     if not _on_tpu():
-        pytest.skip("2,048 lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
-    glv = _ecmul2_affine(sec.ecmul2_base, SYNC_LANES)
+        pytest.skip(f"{lanes} lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
+    glv = _ecmul2_affine(sec.ecmul2_base, lanes)
     want = [lc.expected_point(*c[1:]) for c in ECMUL2_CASES]
-    assert glv == _tile(want, SYNC_LANES)
+    assert glv == _tile(want, len(glv))
 
 
 def _non_residue_x():
@@ -494,8 +498,11 @@ def test_ecdsa_recover_matches_host_oracle(
         assert not any(refused)
 
 
-def test_ecdsa_recover_at_sync_lanes_on_the_chip(recover_lanes, recover_oracle):
+@pytest.mark.parametrize("lanes", CHIP_LANES, ids=lambda n: f"{n}l")
+def test_ecdsa_recover_at_wide_lanes_on_the_chip(
+    recover_lanes, recover_oracle, lanes
+):
     if not _on_tpu():
-        pytest.skip("2,048 lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
-    got = _recover_on_device(recover_lanes, SYNC_LANES)
-    assert got == _tile(recover_oracle, SYNC_LANES)
+        pytest.skip(f"{lanes} lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
+    got = _recover_on_device(recover_lanes, lanes)
+    assert got == _tile(recover_oracle, lanes)

@@ -124,6 +124,16 @@ def _call(st, k: int) -> float:
     return t1 - t0
 
 
+def _one_dispatch(st) -> None:
+    """One dispatch of the window's shape through the verifier's public
+    method, on the first ``check_lanes`` signed lanes (a full chunk): what
+    loads the shape in ``warm``, and a traced run's lead-in (a chunk, not a
+    whole call)."""
+    first, full = st.starts[0], st.traffic["check_lanes"]
+    blocks = [st.source.blocks[h] for h in range(first, first - (-full // st.spb))]
+    st.verifier.verify_seal_lanes(seal_lanes(blocks)[:full], first)
+
+
 def warm(st) -> None:
     """Load the cell's shape through the verifier's public method on the
     first signed lanes; then an unmeasured ``catch_up``."""
@@ -138,11 +148,7 @@ def warm(st) -> None:
         else:
             st.check_range.extend(blocks)
             st.check_want.extend(want)
-    first = st.source.blocks[st.starts[0]]
-    lanes = seal_lanes(
-        [st.source.blocks[h] for h in range(first.height, first.height + st.check_blocks)]
-    )
-    st.verifier.verify_seal_lanes(lanes, first.height)
+    _one_dispatch(st)
     st.spans = []
     st.info["warm_calls_s"] = [
         round(_call(st, 0), 4) for _ in range(st.traffic["warm_calls"])
@@ -162,6 +168,9 @@ def measure(st, seconds: float):
         st.probe.boundary(elapsed)
         if elapsed >= seconds and not st.probe.wants_more():
             break
+        if st.probe.leading():
+            _one_dispatch(st)
+            continue
         attempted += 1
         try:
             samples.append(_call(st, (attempted - 1) % len(st.starts)))
@@ -202,10 +211,15 @@ def check(st) -> bool:
     )
     st.info["check_lanes"] = len(lanes)
     st.info["check_invalid_lanes"] = int((~want).sum())
+    compared = st.info["compared"] = {
+        "mask_lanes_off_the_labels": {"value": int((got != want).sum()), "limit": 0},
+        "oracle_lanes_off_the_labels": {"value": int((oracle != want).sum()), "limit": 0},
+        "sub_quorum_ranges_accepted": {"value": 1, "limit": 0},
+    }
     if not (got == want).all() or not (oracle == want).all():
         st.info["guarantee_broken"] = (
-            f"mask mismatch: verifier {int((got != want).sum())} lanes, "
-            f"host oracle {int((oracle != want).sum())} lanes"
+            f"mask mismatch: verifier {compared['mask_lanes_off_the_labels']['value']} lanes, "
+            f"host oracle {compared['oracle_lanes_off_the_labels']['value']} lanes"
         )
         return False
 
@@ -229,6 +243,7 @@ def check(st) -> bool:
         st.client.catch_up(st.check_start, st.check_start + len(clean) - 1)
     except SyncError as err:
         st.info["sub_quorum_refused"] = str(err)[:120]
+        compared["sub_quorum_ranges_accepted"]["value"] = 0
         return True
     st.info["guarantee_broken"] = "a block with quorum - 1 valid seals was accepted"
     return False

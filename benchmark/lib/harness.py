@@ -3,7 +3,9 @@ compile-event counter, and small helpers over the cost ledger's rows."""
 
 from __future__ import annotations
 
+import math
 import sys
+import time
 from typing import Dict, List, Optional, Tuple
 
 
@@ -56,14 +58,16 @@ class Probe:
     ``phase(name)`` closes the phase that was open and opens ``name``; at
     every instant of the window exactly one phase is open.  ``boundary()``
     is called by the driver between two operations (heights, calls), when
-    nothing is in flight.  The plain probe does nothing with either: it is
-    what a ``--trace 0`` run and the tests get.
+    nothing is in flight; ``left`` is how many more operations the driver
+    has inputs for, where that is bounded (pre-signed heights).  The plain
+    probe does nothing with either: it is what a ``--trace 0`` run and the
+    tests get.
     """
 
     def phase(self, name: Optional[str]) -> None:
         pass
 
-    def boundary(self, elapsed_s: float) -> None:
+    def boundary(self, elapsed_s: float, left: Optional[int] = None) -> None:
         pass
 
     def wants_more(self) -> bool:
@@ -71,16 +75,34 @@ class Probe:
         window is over."""
         return False
 
+    def leading(self) -> bool:
+        """Whether the next operation is a traced run's lead-in (below): a
+        driver that has a shorter piece of the same device work (one
+        dispatch, not a whole call) drives that instead and counts it as no
+        operation."""
+        return False
+
 
 class TraceProbe(Probe):
     """Marks phases with ``jax.profiler.TraceAnnotation`` (so they are on
     the profiler's clock) and captures one profiler window of at least
-    ``trace_seconds`` at the end of the measured window, started and
-    stopped at operation boundaries (it keeps the driver going until it has
-    its seconds: a window of whole operations, and ``stop_trace``, which
-    takes most of a minute, delays nothing that is measured).  ``on_edge``
-    is called at both edges, with nothing in flight: the harness snapshots
-    the cost ledger there."""
+    ``trace_seconds`` and ``MIN_OPERATIONS`` whole operations at the end of
+    the measured window, started and stopped at operation boundaries (it
+    keeps the driver going until it has them: a window of whole operations,
+    and ``stop_trace``, which takes most of a minute, delays nothing that is
+    measured).  The profiler starts ONE operation before the window: the
+    device's op tracing goes live some milliseconds after ``start_trace``
+    returns (2 to 13 ms seen, PR 33), and a dispatch running by then loses
+    its first ops; so the first operation under the profiler is a lead-in,
+    outside the marks, the ledger's edges and every reduction.  The profiler
+    starts an operation and ``trace_seconds`` before the measured window's
+    end, or earlier where the driver's inputs would run out before that:
+    when the operations ``left`` are only the lead-in, those the window
+    needs at the pace so far, and two more.  ``on_edge`` is called at both
+    edges of the window, with nothing in flight: the harness snapshots the
+    cost ledger there."""
+
+    MIN_OPERATIONS = 2
 
     def __init__(
         self, trace_dir: str, window_s: float, trace_seconds: float, on_edge
@@ -89,8 +111,13 @@ class TraceProbe(Probe):
         self.start_at = max(0.0, window_s - trace_seconds)
         self.trace_seconds = trace_seconds
         self.on_edge = on_edge
-        self.state = "before"  # before -> tracing -> done
+        self.state = "before"  # before -> leading -> tracing -> done
         self.started_s = 0.0
+        self.boundaries = 0  # seen so far: operations done, but for the first
+        self.started_at_boundary = 0
+        self.opened_by = None  # "clock" or "operations_left"
+        self.operations = None  # whole operations inside the window, once closed
+        self.stop_trace_s = None
         self._open = None
         self._name: Optional[str] = None
 
@@ -105,39 +132,76 @@ class TraceProbe(Probe):
             self._open = jax.profiler.TraceAnnotation("bench:" + name)
             self._open.__enter__()
 
-    def boundary(self, elapsed_s: float) -> None:
-        import jax
+    def _pace(self, elapsed_s: float) -> float:
+        """Seconds an operation so far (this call's boundary closes
+        operation ``self.boundaries``); 0 before the first has ended."""
+        return elapsed_s / self.boundaries if self.boundaries > 0 else 0.0
 
-        if self.state == "before" and elapsed_s >= self.start_at:
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0
-            options.enable_hlo_proto = False
-            jax.profiler.start_trace(self.trace_dir, profiler_options=options)
-            self.state = "tracing"
-            self.started_s = elapsed_s
-            self.on_edge("start")
-            self.phase(self._name)  # re-open the phase inside the trace
+    def operations_needed(self, elapsed_s: float) -> Optional[int]:
+        """The lead-in, the operations the traced window needs at the pace
+        so far, and two more; ``None`` before the first operation has shown
+        a pace."""
+        pace = self._pace(elapsed_s)
+        if pace <= 0:
+            return None
+        window = max(self.MIN_OPERATIONS, math.ceil(self.trace_seconds / pace))
+        return 1 + window + 2
+
+    def boundary(self, elapsed_s: float, left: Optional[int] = None) -> None:
+        if self.state == "before":
+            by_clock = elapsed_s + self._pace(elapsed_s) >= self.start_at
+            needed = None if left is None else self.operations_needed(elapsed_s)
+            if by_clock or (needed is not None and left <= needed):
+                self.opened_by = "clock" if by_clock else "operations_left"
+                self._start_profiler()
+        elif self.state == "leading":
+            self._open_window(elapsed_s)
         elif (
             self.state == "tracing"
             and elapsed_s - self.started_s >= self.trace_seconds
+            and self.boundaries - self.started_at_boundary >= self.MIN_OPERATIONS
         ):
             self.stop()
+        self.boundaries += 1
+
+    def _start_profiler(self) -> None:
+        import jax
+
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.enable_hlo_proto = False
+        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        self.state = "leading"
+
+    def _open_window(self, elapsed_s: float) -> None:
+        self.state = "tracing"
+        self.started_s = elapsed_s
+        self.started_at_boundary = self.boundaries
+        self.on_edge("start")
+        self.phase(self._name)  # re-open the phase inside the trace
 
     def wants_more(self) -> bool:
-        return self.state == "tracing"
+        return self.state in ("leading", "tracing")
+
+    def leading(self) -> bool:
+        return self.state == "leading"
 
     def stop(self) -> None:
         """Close the profiler window (also called after the window, so a
         driver that ran out of work still yields a trace)."""
-        if self.state != "tracing":
+        if self.state not in ("leading", "tracing"):
             return
         import jax
 
-        name = self._name
-        self.phase(None)
-        self._name = name
-        self.on_edge("stop")
+        if self.state == "tracing":
+            name = self._name
+            self.phase(None)
+            self._name = name
+            self.on_edge("stop")
+            self.operations = self.boundaries - self.started_at_boundary
+        t0 = time.perf_counter()
         jax.profiler.stop_trace()
+        self.stop_trace_s = time.perf_counter() - t0
         self.state = "done"
 
 

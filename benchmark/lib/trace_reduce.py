@@ -162,6 +162,19 @@ def _leaf_seconds(ops: Sequence[Event], lo: float, hi: float) -> Dict[str, float
     return total
 
 
+def marked_window(trace: Trace) -> Optional[Tuple[float, float]]:
+    """From the first harness phase's start to the last one's end: the
+    window the reductions keep to (the profiler runs a lead-in operation
+    before it, whose first device ops may be missing); ``None`` where the
+    trace has no phase mark."""
+    if not trace.phases:
+        return None
+    return (
+        min(s for _n, s, _d in trace.phases),
+        max(s + d for _n, s, d in trace.phases),
+    )
+
+
 def reduce(trace: Trace, window: Optional[Tuple[float, float]] = None) -> dict:
     """The numbers of one traced window.
 
@@ -181,21 +194,15 @@ def reduce(trace: Trace, window: Optional[Tuple[float, float]] = None) -> dict:
     for the single longest gaps), and ``dropped``.
     """
     if window is None:
-        if trace.phases:
-            lo = min(s for _n, s, _d in trace.phases)
-            hi = max(s + d for _n, s, d in trace.phases)
-        else:
-            spans = [
-                iv
-                for dev in trace.devices
-                for iv in _intervals(dev.ops or dev.modules)
-            ]
-            if not spans:
-                return _empty()
-            lo = min(s for s, _e in spans)
-            hi = max(e for _s, e in spans)
-    else:
-        lo, hi = window
+        window = marked_window(trace)
+    if window is None:
+        spans = [
+            iv for dev in trace.devices for iv in _intervals(dev.ops or dev.modules)
+        ]
+        if not spans:
+            return _empty()
+        window = (min(s for s, _e in spans), max(e for _s, e in spans))
+    lo, hi = window
     window_s = hi - lo
     if window_s <= 0:
         return _empty()

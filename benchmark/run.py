@@ -14,6 +14,31 @@ file per metric.  There is no CPU mode: without a TPU, or with fewer chips
 than the cell asks for, the process exits non-zero in seconds and prints
 no result.
 
+Which cell reports which metric is ``BENCHMARK.json``'s alone to say: a
+metric's entry there lists the cells under ``workloads`` (no list: every
+cell).  A metric file says how the number is read, never where.
+
+A driver is any module ``benchmark/drivers/<name>.py`` with four functions;
+a new one needs no edit to a file that is there:
+
+``setup(config, traffic, seed, seconds) -> st``  an object with ``st.probe``
+    (the harness replaces it in a traced run) and ``st.info`` (a dict, printed
+    on an earlier line); makes every input from ``seed``;
+``warm(st)``  loads every shape the window will use; nothing may compile later;
+``measure(st, seconds) -> (samples, counts)``  ``samples`` feed ``read`` kinds
+    ``percentile`` and ``rate`` (``sum_samples``); ``counts`` has ``attempted``,
+    ``failed``, ``operations``, ``elapsed_s`` and whatever the cell's metrics
+    ``read`` by ``key`` (``live_lanes``, ``flush_sizes``, ``op_spans``); between
+    two operations it calls ``st.probe.boundary(elapsed_s, left)`` and it goes
+    on while ``st.probe.wants_more()``; where ``st.probe.leading()`` it may
+    drive one dispatch in place of a whole operation, counted as none;
+``check(st) -> bool``  holds what the window produced to the configuration's
+    guarantees, against labels and a reference that are the driver's own: a
+    driver that brings its own committee (BLS, stake-weighted power) brings
+    the generator's labels and a host oracle for them beside it.  What it
+    compared it leaves in ``st.info["compared"]`` as ``{name: {"value": n,
+    "limit": m}}``; the harness prints it with its own three beside ``correct``.
+
 ``--trace 0`` runs with ``obs/trace``, the cost ledger and the profiler off
 and reports the cell's end-to-end metrics.  ``--trace 1`` turns the span
 ring and the cost ledger on for the whole window, captures a
@@ -63,7 +88,7 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     traffic = load_json(os.path.join(here, "traffic", cell["traffic"] + ".json"))
 
     def metrics_of(group: str, directory: str) -> list:
-        return metric_files(os.path.join(here, directory), bench[group], cell, traffic)
+        return metric_files(os.path.join(here, directory), bench[group], cell)
 
     return {
         "cell": cell,
@@ -74,21 +99,18 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     }
 
 
-def metric_files(directory: str, declared: list, cell: dict, traffic: dict) -> list:
-    """``(path, metric)`` for every metric file of ``directory`` that
-    ``BENCHMARK.json`` declares and that applies to this cell: its driver
-    is the mix's (or ``*``) and, where it lists ``workloads``, the cell is
-    among them."""
-    names = {m["name"] for m in declared}
+def metric_files(directory: str, declared: list, cell: dict) -> list:
+    """``(path, metric)`` for every metric file of ``directory`` whose entry
+    in ``BENCHMARK.json`` (``declared``) applies to this cell: the entry lists
+    the cell under ``workloads``, or has no such list."""
+    entries = {m["name"]: m for m in declared}
     out = []
     for path in sorted(glob.glob(os.path.join(directory, "*.json"))):
         metric = load_json(path)
-        if metric["name"] not in names:
+        entry = entries.get(metric["name"])
+        if entry is None:
             continue
-        drivers = metric.get("drivers", ["*"])
-        if "*" not in drivers and traffic["driver"] not in drivers:
-            continue
-        if "workloads" in metric and cell["name"] not in metric["workloads"]:
+        if "workloads" in entry and cell["name"] not in entry["workloads"]:
             continue
         out.append((path, metric))
     return out
@@ -213,6 +235,13 @@ def run(args) -> int:
     )
     failed = counts["failed"]
     correct = bool(checked) and failed == 0 and compiles_in_window == 0
+    # Every number ``correct`` rests on beside its limit (all exact: 0).
+    compared = {
+        "operations_failed": {"value": failed, "limit": 0},
+        "compiles_in_window": {"value": compiles_in_window, "limit": 0},
+        "guarantees_broken": {"value": 0 if checked else 1, "limit": 0},
+        **st.info.pop("compared", {}),
+    }
 
     ctx = {
         "samples": samples,
@@ -247,6 +276,10 @@ def run(args) -> int:
                     "programs": reduced["programs"],
                     "single_ops": reduced["single_ops"],
                     "longest_gaps": reduced["longest_gaps"],
+                    "xplane_bytes": os.path.getsize(path),
+                    "opened_by": probe.opened_by,
+                    "operations": probe.operations,
+                    "stop_trace_s": probe.stop_trace_s,
                 }
             )
         say(ledger=ctx["ledger"], ledger_traced=ctx["ledger_traced"])
@@ -283,7 +316,11 @@ def run(args) -> int:
     }
     if breakdown is not None:
         result["breakdown"] = breakdown
+    result["compared"] = compared  # last in the line, and the last lines of stderr
     print(json.dumps(result), flush=True)
+    for name, pair in compared.items():
+        print(f"compared {name}: {pair['value']} (limit {pair['limit']})", file=sys.stderr)
+    sys.stderr.flush()
     return 0
 
 

@@ -221,3 +221,167 @@ def test_names_are_shortened_as_the_breakdown_gives_them():
     assert trace_reduce.op_kind("copy.5878") == "copy"
     assert trace_reduce.op_kind("get-tuple-element.12") == "get-tuple-element"
     assert trace_reduce.op_kind("wide.region_1.2") == "wide.region_"
+
+
+# ------------------------------------- the probe's window, the signed heights
+
+
+class _Profiler:
+    """``jax.profiler``'s start and stop, counted and not done."""
+
+    def __init__(self, monkeypatch):
+        import jax
+
+        self.calls = []
+        monkeypatch.setattr(jax.profiler, "start_trace", lambda *a, **k: self.calls.append("start"))
+        monkeypatch.setattr(jax.profiler, "stop_trace", lambda: self.calls.append("stop"))
+
+
+def _drive_probe(probe, pace, operations, signed=None):
+    """Boundaries as ``round0._drive`` calls them: before each operation,
+    with the heights left; goes on while the probe wants more.  Returns how
+    many operations ran and whether the signed heights ran out."""
+    done = 0
+    while True:
+        probe.boundary(done * pace, None if signed is None else signed - done)
+        if done >= operations and not probe.wants_more():
+            return done, False
+        if signed is not None and done >= signed:
+            return done, True
+        done += 1
+
+
+def test_probe_opens_by_the_clock_where_the_signed_heights_last(monkeypatch):
+    from benchmark.lib.harness import TraceProbe
+
+    profiler, edges = _Profiler(monkeypatch), []
+    probe = TraceProbe("unused", 20.0, 0.25, edges.append)
+    # 1/32 s a height (numbers exact in binary): 640 run in 20 s of 800 signed.
+    done, ran_out = _drive_probe(probe, 0.03125, 640, signed=800)
+    assert probe.opened_by == "clock" and not ran_out
+    # The profiler starts one height before window_s - trace_seconds: that
+    # height is the lead-in, and the window's edges are where they were.
+    assert probe.started_s == 19.75 and probe.started_at_boundary == 632
+    assert probe.operations == 8 and done == 640  # 0.25 s of heights, inside the window
+    assert profiler.calls == ["start", "stop"] and edges == ["start", "stop"]
+
+
+def test_the_first_operation_under_the_profiler_is_a_lead_in_outside_the_window(monkeypatch):
+    from benchmark.lib.harness import TraceProbe
+
+    profiler, edges, phases = _Profiler(monkeypatch), [], []
+    probe = TraceProbe("unused", 1.0, 0.25, lambda edge: edges.append((edge, list(profiler.calls))))
+    monkeypatch.setattr(probe, "phase", lambda name: phases.append((probe.state, name)))
+    probe.boundary(0.0)
+    probe.boundary(0.5)  # 0.5 + a pace of 0.5 reaches start_at 0.75: the profiler starts
+    assert probe.state == "leading" and probe.wants_more() and profiler.calls == ["start"]
+    assert edges == [] and phases == []  # no edge, no mark: the lead-in is not the window
+    probe.boundary(1.0)  # the lead-in is over: the window opens, with nothing in flight
+    assert probe.state == "tracing" and edges == [("start", ["start"])]
+    assert probe.started_s == 1.0 and phases == [("tracing", None)]
+    probe.boundary(1.5)
+    probe.boundary(2.0)
+    assert probe.state == "done" and probe.operations == 2
+    assert [e for e, _calls in edges] == ["start", "stop"] and profiler.calls == ["start", "stop"]
+    # A driver that runs dry inside the lead-in still stops the profiler.
+    dry = TraceProbe("unused", 1.0, 0.25, edges.append)
+    dry.boundary(0.0)
+    dry.boundary(0.75)
+    dry.stop()
+    assert dry.state == "done" and profiler.calls[-2:] == ["start", "stop"] and len(edges) == 2
+
+
+def test_probe_opens_by_the_heights_left_before_a_faster_flood_runs_dry(monkeypatch):
+    from benchmark.lib.harness import TraceProbe
+
+    profiler, edges = _Profiler(monkeypatch), []
+    probe = TraceProbe("unused", 20.0, 0.25, edges.append)
+    # 1/64 s a height would use 1280 heights in 20 s; 800 are signed: dry at 12.5 s.
+    done, ran_out = _drive_probe(probe, 0.015625, 1280, signed=800)
+    assert ran_out and done == 800
+    # The lead-in, 0.25 s at 1/64 s (16 heights) and two more: the profiler
+    # starts with 19 left and the window opens with 18.
+    assert probe.opened_by == "operations_left"
+    assert probe.started_at_boundary == 800 - 18 and probe.started_s == 782 * 0.015625
+    assert probe.operations == 16 >= TraceProbe.MIN_OPERATIONS and probe.state == "done"
+    assert profiler.calls == ["start", "stop"]
+
+
+def test_probe_with_twelve_signed_heights_still_gets_two_whole_ones(monkeypatch):
+    from benchmark.lib.harness import TraceProbe
+
+    profiler = _Profiler(monkeypatch)
+    probe = TraceProbe("unused", 20.0, 0.0625, lambda edge: None)  # worth 2 heights of 1/32 s
+    done, ran_out = _drive_probe(probe, 0.03125, 640, signed=12)
+    assert ran_out and done == 12 and probe.opened_by == "operations_left"
+    # With five left: the lead-in, two for the window, two more.
+    assert probe.started_at_boundary == 8 and probe.operations == 2 and probe.state == "done"
+    # ... and a window never closes on fewer than two operations, however
+    # long the first one took.
+    slow = TraceProbe("unused", 1.0, 0.25, lambda edge: None)
+    slow.boundary(0.0, 100)
+    slow.boundary(0.875, 99)  # the profiler starts by the clock: lead-in
+    slow.boundary(1.0, 98)  # the window opens
+    slow.boundary(1.625, 97)  # 0.625 s later, but one operation
+    assert slow.state == "tracing" and slow.wants_more()
+    slow.boundary(1.75, 96)
+    assert slow.state == "done" and slow.operations == 2
+    assert profiler.calls == ["start", "stop", "start", "stop"]
+
+
+def test_probe_without_a_bound_on_its_inputs_keeps_to_the_clock(monkeypatch):
+    from benchmark.lib.harness import Probe, TraceProbe
+
+    _Profiler(monkeypatch)
+    probe = TraceProbe("unused", 20.0, 1.0, lambda edge: None)  # driver sync: no ``left``
+    done, _ = _drive_probe(probe, 0.25, 80)
+    assert probe.opened_by == "clock" and probe.started_s == 19.0
+    assert probe.operations == 4 and done == 80
+    assert Probe().boundary(1.0, 3) is None and Probe().wants_more() is False
+
+
+def test_a_driver_may_drive_a_shorter_lead_in_than_a_whole_operation(monkeypatch):
+    """Driver ``sync``: under the profiler's first operation it drives one
+    chunk, not a call, and counts it as none (``leading()``)."""
+    from benchmark.lib.harness import Probe, TraceProbe
+
+    _Profiler(monkeypatch)
+    edges = []
+    probe = TraceProbe("unused", 2.0, 0.5, edges.append)
+    calls, chunks, t = 0, 0, 0.0
+    while True:  # ``sync.measure``'s loop: a call is 0.25 s, a chunk 0.0625 s
+        probe.boundary(t)
+        if t >= 2.0 and not probe.wants_more():
+            break
+        if probe.leading():
+            chunks += 1
+            t += 0.0625
+            continue
+        calls += 1
+        t += 0.25
+    assert chunks == 1 and probe.operations == 2 and edges == ["start", "stop"]
+    assert probe.started_s == 1.3125  # the window opens behind the chunk, not a call later
+    assert calls == 8 and Probe().leading() is False
+
+
+@pytest.mark.parametrize(
+    "mix, validators, signed, a_second, loop_ms_now",
+    [
+        ("flood", 100, 804, 40.0, 40.0),  # finalizes 24.1-24.8 a second (PR 32)
+        ("flood-byz30", 300, 444, 22.0, 73.0),  # 13.6 a second (PR 32)
+    ],
+)
+def test_presigned_heights_leave_a_third_of_the_window_unused(mix, validators, signed, a_second, loop_ms_now):
+    import json
+
+    from benchmark.drivers import round0
+
+    with open(os.path.join(ROOT, "benchmark", "traffic", mix + ".json")) as fh:
+        traffic = json.load(fh)
+    assert round0.presigned_heights(traffic, validators, 20.0) == signed
+    assert round0.throw_away_heights(traffic) == 1 and round0.throw_away_heights({}) == 0
+    window = signed - 1 - traffic["warm_heights"]
+    assert window / 20.0 == pytest.approx(a_second)
+    used = 20.0 / (loop_ms_now / 1e3)
+    assert 1 - used / window >= 0.35  # ISSUE 33: at least 35% unused at today's pace
+    assert f"= {signed}" in traffic["presign_rule"]  # the text says today's count

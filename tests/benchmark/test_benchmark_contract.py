@@ -13,6 +13,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 HERE = os.path.join(ROOT, "benchmark")
+if HERE not in sys.path:
+    sys.path.insert(0, HERE)  # for ``import run``: benchmark/run.py
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -144,6 +146,9 @@ def test_metric_files_and_benchmark_json_agree(bench):
             for key in ("unit", "better", "source") + (("layer", "moves") if group == "per_layer" else ()):
                 assert f[key] == declared[name][key], (name, key)
             assert f["read"]["kind"] in ("span", "ledger", "count", "percentile", "rate", "reader")
+            # Which cell reports it is BENCHMARK.json's alone to say (PR 33): a
+            # list in the file would decide nothing and go stale.
+            assert "drivers" not in f and "workloads" not in f, name
             if f["read"]["kind"] == "reader":
                 module = f["read"].get("module", name)
                 assert os.path.isfile(os.path.join(HERE, sub, module + ".py")), name
@@ -154,11 +159,10 @@ def _cells_of(metric, bench):
 
     out = []
     for w in bench["workloads"]:
-        mix = _load(os.path.join(HERE, "traffic", w["traffic"] + ".json"))
         directory = os.path.join(
             HERE, "layer_metrics" if "layer" in metric else "end_to_end"
         )
-        got = bench_run.metric_files(directory, [metric], w, mix)
+        got = bench_run.metric_files(directory, [metric], w)
         if got:
             out.append(w["name"])
     return out
@@ -186,6 +190,120 @@ def test_every_layer_metric_names_an_end_to_end_metric_of_a_cell_it_applies_to(b
     for cell, got in reported.items():
         assert "setup_s" in got["e2e"] and len(got["e2e"]) >= 2, cell
         assert got["layer"], cell
+
+
+# What each cell reported on PR 32's tree, where a metric file's ``drivers``
+# and ``workloads`` keys decided it: PR 33 made ``BENCHMARK.json`` the one
+# authority, and the four cells select what they did, in the same order.
+SELECTION_OF_PR_32 = {
+    "ecdsa-100v.flood": {
+        "end_to_end": [
+            "finalize_p50_ms",
+            "finalize_p90_ms",
+            "setup_s"
+        ],
+        "per_layer": [
+            "compiles_in_window",
+            "device_lane_share",
+            "device_wait_ms_per_drain",
+            "dispatches_per_height",
+            "engine_self_ms",
+            "lane_occupancy",
+            "msgs_per_flush",
+            "pack_ms_per_drain",
+            "recover_address_us_per_lane",
+            "recover_affine_us_per_lane",
+            "recover_ladder_us_per_lane",
+            "recover_layout_share",
+            "recover_pre_ladder_us_per_lane",
+            "recover_unscoped_share",
+            "recover_us_per_lane",
+            "warm_s"
+        ]
+    },
+    "ecdsa-100v.sync": {
+        "end_to_end": [
+            "setup_s",
+            "sync_sigs_per_s"
+        ],
+        "per_layer": [
+            "compiles_in_window",
+            "ecdsa_recover_roofline",
+            "sync_device_lane_share",
+            "sync_device_wait_ms_per_chunk",
+            "sync_host_ms_per_call",
+            "sync_lane_occupancy",
+            "sync_pack_ms_per_chunk",
+            "sync_recover_address_us_per_lane",
+            "sync_recover_affine_us_per_lane",
+            "sync_recover_ladder_us_per_lane",
+            "sync_recover_layout_share",
+            "sync_recover_pre_ladder_us_per_lane",
+            "sync_recover_unscoped_share",
+            "sync_recover_us_per_lane",
+            "warm_s"
+        ]
+    },
+    "ecdsa-4v.sync": {
+        "end_to_end": [
+            "setup_s",
+            "sync_sigs_per_s"
+        ],
+        "per_layer": [
+            "compiles_in_window",
+            "ecdsa_recover_roofline",
+            "sync_device_lane_share",
+            "sync_device_wait_ms_per_chunk",
+            "sync_host_ms_per_call",
+            "sync_lane_occupancy",
+            "sync_pack_ms_per_chunk",
+            "sync_recover_address_us_per_lane",
+            "sync_recover_affine_us_per_lane",
+            "sync_recover_ladder_us_per_lane",
+            "sync_recover_layout_share",
+            "sync_recover_pre_ladder_us_per_lane",
+            "sync_recover_unscoped_share",
+            "sync_recover_us_per_lane",
+            "warm_s"
+        ]
+    },
+    "ecdsa-300v.flood-byz30": {
+        "end_to_end": [
+            "finalize_p50_ms",
+            "finalize_p90_ms",
+            "setup_s"
+        ],
+        "per_layer": [
+            "compiles_in_window",
+            "device_lane_share",
+            "device_wait_ms_per_drain",
+            "dispatches_per_height",
+            "engine_self_ms",
+            "flood300_recover_address_us_per_lane",
+            "flood300_recover_affine_us_per_lane",
+            "flood300_recover_ladder_us_per_lane",
+            "flood300_recover_layout_share",
+            "flood300_recover_pre_ladder_us_per_lane",
+            "flood300_recover_unscoped_share",
+            "flood300_recover_us_per_lane",
+            "flushes_per_height",
+            "lane_occupancy",
+            "msgs_per_flush",
+            "pack_ms_per_drain",
+            "rejected_lane_share",
+            "warm_s"
+        ]
+    }
+}
+
+
+@pytest.mark.parametrize("cell", sorted(SELECTION_OF_PR_32))
+def test_the_four_cells_select_the_metrics_they_selected_before_pr_33(cell):
+    import run as bench_run  # benchmark/run.py
+
+    got = bench_run.load_cell(cell)
+    for group, names in SELECTION_OF_PR_32[cell].items():
+        assert [m["name"] for _p, m in got[group]] == names, (cell, group)
 
 
 def test_peaks_table_is_keyed_by_device_kind_and_refuses_the_unknown():

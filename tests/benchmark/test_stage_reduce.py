@@ -65,15 +65,17 @@ def test_ops_of_another_program_are_not_counted():
     with_digest = sr.reduce_stages(OPS, RUNS + [(5.0, 6.0)], STAGE_BY_NAME)
     assert with_digest["busy_s"] - got["busy_s"] == pytest.approx(1.0)
     assert with_digest["seconds"][sr.UNSCOPED] - got["seconds"][sr.UNSCOPED] == pytest.approx(1.0)
-    spans, names = sr.program_intervals(
-        [
-            ("jit__recover_fn(111)", 1.0, 3.0),
-            ("jit_digest_words(222)", 5.0, 1.0),
-            ("jit__recover_fn(111)", 8.0, 1.0),
-        ],
-        ["jit__recover_fn"],
+    from benchmark.lib.trace_reduce import DevicePlane, Trace
+
+    modules = [
+        ("jit__recover_fn(111)", 1.0, 3.0),
+        ("jit_digest_words(222)", 5.0, 1.0),
+        ("jit__recover_fn(111)", 8.0, 1.0),
+    ]
+    per_chip, names, outside = sr.program_runs(
+        Trace(devices=[DevicePlane("/device:TPU:0", modules=modules)]), ["jit__recover_fn"]
     )
-    assert spans == RUNS and names == {"jit__recover_fn(111)"}
+    assert per_chip == [RUNS] and names == {"jit__recover_fn(111)"} and outside == 0
 
 
 def test_stage_sums_and_unscoped_equal_the_programs_busy_seconds():
@@ -297,7 +299,10 @@ def test_xplane_to_stages_through_the_compiled_text():
         # a stale cache: the executable that ran was compiled without scopes
         (COMPILED.replace("recover.", "recovered_"), "no op carries a recover.* stage"),
         # another module than the one that ran: names not found
-        (COMPILED.replace("fusion.", "fusion.9"), "the compiled text is of another module than the one that ran"),
+        (
+            COMPILED.replace("fusion.", "fusion.9"),
+            "the compiled text is of another module than the one that ran",
+        ),
     ],
 )
 def test_fail_safe_reads_none_four_times_and_unscoped_100(compiled, why):
@@ -321,11 +326,27 @@ def test_a_text_that_cannot_be_had_fails_safe_too():
     assert "RuntimeError: no such program" in got["failed"]
 
 
-def test_runs_of_two_shapes_of_the_program_fail_safe():
+def test_runs_of_two_shapes_of_the_program_fail_safe_and_compile_nothing():
+    """A window of 128- and 256-lane runs is two modules: no text is asked
+    for (the parent asked for a 192-lane program there)."""
     data = _xplane([OP_A], second_module="jit__recover_fn(333)")
-    got = sr.stages_from_xplane(data, 16, 2, 8, text_of=lambda lanes, rows: COMPILED)
-    assert got["us_per_lane"] is None and "not one shape" in got["failed"]
-    assert got["busy_s"] == pytest.approx(4e-3)  # both runs are the program's
+
+    def never(lanes, rows):
+        raise AssertionError("a compile for a shape that did not run")
+
+    got = sr.stages_from_xplane(data, 384, 2, 8, text_of=never)
+    assert got["us_per_lane"] is None and got["unscoped_share"] == 100.0
+    assert got["failed"] == "not one shape of the program: modules ['jit__recover_fn(111)', 'jit__recover_fn(333)']"
+    assert got["busy_s"] == pytest.approx(4e-3) and got["module"] is None  # both runs are the program's
+
+
+def test_a_trace_that_lost_a_run_the_ledger_counted_fails_safe():
+    """Seen on the chip in PR 33 (before the lead-in): 7 runs in the trace
+    where the ledger counted 8 dispatches; a per-lane figure over the ledger's
+    lanes would read an eighth low."""
+    got = sr.stages_from_xplane(_xplane([OP_A, OP_B, OP_C]), 16, 2, 8, text_of=lambda lanes, rows: COMPILED)
+    assert got["failed"] == "the trace has 1 runs of the program, the ledger 2 dispatches"
+    assert got["us_per_lane"] is None and got["unscoped_share"] == 100.0 and "compile_text_s" not in got
 
 
 def test_a_run_without_a_device_trace_of_the_program_reads_nothing():
@@ -336,3 +357,150 @@ def test_a_run_without_a_device_trace_of_the_program_reads_nothing():
     assert sr.stages_of_run({"trace": rehearsal, "ledger_traced": rows, "config": cfg}) is None
     host_only = {"programs": {"jit__recover_fn": {"seconds": 1.0, "runs": 1}}}
     assert sr.stages_of_run({"trace": host_only, "ledger_traced": [], "config": cfg}) is None
+
+
+# ------------------------------------------- the marks, and what a trace loses
+
+
+def _traced(groups, marks=()):
+    """An xplane: per group ``(module event name, [(op, start ms, ms)],
+    [(run start ms, ms)])`` on one chip, and the harness's phase ``marks``
+    ``(name, start ms, ms)`` on a host thread."""
+    from jax.profiler import ProfileData
+
+    ms = 1_000_000_000
+    names, ids = [], {}
+
+    def mid(name):
+        if name not in ids:
+            names.append(name)
+            ids[name] = len(names)
+        return ids[name]
+
+    def event(name, start, dur):
+        return f"events {{ metadata_id: {mid(name)} offset_ps: {int(start * ms)} duration_ps: {int(dur * ms)} }}"
+
+    runs, ops = [], []
+    for module, group_ops, group_runs in groups:
+        runs += [event(module, s, d) for s, d in group_runs]
+        ops += [event(op, s, d) for op, s, d in group_ops]
+    meta = "\n".join(
+        f'event_metadata {{ key: {i + 1} value {{ id: {i + 1} name: "{n}" }} }}' for i, n in enumerate(names)
+    )
+    text = f"""
+planes {{ id: 1 name: "/device:TPU:0"
+  lines {{ id: 1 name: "XLA Modules" timestamp_ns: 1000 {" ".join(runs)} }}
+  lines {{ id: 2 name: "XLA Ops" timestamp_ns: 1000 {" ".join(ops)} }}
+  {meta}
+}}
+planes {{ id: 3 name: "/host:CPU"
+  lines {{ id: 7 name: "python" timestamp_ns: 1000
+    {" ".join(f"events {{ metadata_id: {i + 1} offset_ps: {int(s0 * ms)} duration_ps: {int(d0 * ms)} }}" for i, (_n, s0, d0) in enumerate(marks))} }}
+  {" ".join(f'event_metadata {{ key: {i + 1} value {{ id: {i + 1} name: "bench:{n}" }} }}' for i, (n, _s, _d) in enumerate(marks))}
+}}
+"""
+    return ProfileData.from_text_proto(text)
+
+
+RUN = "jit__recover_fn(111)"
+OP_D = "%fusion.2 = u32[8,5]{1,0} fusion(%p)"
+TEXT = "\n".join(
+    [
+        "ENTRY %main (p: s32[8,20]) -> u32[8,5] {",
+        '  %while.7 = (s32[]) while(%t), condition=%c, body=%b, metadata={op_name="jit(_recover_fn)/recover.glv_ladder/while"}',
+        '  %fusion.1 = s32[8,20]{1,0} fusion(%p), kind=kLoop, calls=%f1, metadata={op_name="jit(_recover_fn)/recover.lift_x/mul"}',
+        '  %fusion.2 = u32[8,5]{1,0} fusion(%p), kind=kLoop, calls=%f2, metadata={op_name="jit(_recover_fn)/recover.address/xor"}',
+        "}",
+    ]
+)
+
+
+def _text(lanes, rows):
+    return TEXT
+
+
+def test_a_lead_in_run_before_the_marks_is_left_out_cut_ops_and_all():
+    """PR 33's diagnosis: the device's op tracing goes live some milliseconds
+    after ``start_trace`` returns, and a dispatch running by then has its run
+    on ``XLA Modules`` but not its first ops, the cut ones under names no
+    module has (``region.N``).  The probe gives the profiler a lead-in; the
+    reduction keeps to the marked window."""
+    groups = [
+        (
+            RUN,
+            # the lead-in run 0..2 ms: its first op missing, a cut one named region.6238
+            [("%region.6238 = u32[8,5]{1,0} fusion(%p)", 1, 1)]
+            # two whole runs inside the marks
+            + [(OP_A, 10, 1), (OP_D, 11, 1), (OP_A, 20, 1), (OP_D, 21, 1)],
+            [(0, 2), (10, 2), (20, 2)],
+        )
+    ]
+    marks = [("between_heights", 9, 1), ("prepare_flood", 10, 10), ("commit_flood", 20, 3)]
+    got = sr.stages_from_xplane(_traced(groups, marks), 256, 2, 128, text_of=_text)
+    assert "failed" not in got and got["names_found"] == 1.0 and got["module"] == RUN
+    assert got["runs_outside_the_marks"] == 1 and got["runs"] == 2 == got["dispatches"]
+    assert got["busy_s"] == pytest.approx(4e-3) and got["runs_whole"] == 2 and "runs_cut" not in got
+    assert got["us_per_lane"][LIFT] == pytest.approx(2e3 / 256)
+    # Without the marks (the harness before PR 33 opened its window with the
+    # profiler) the cut run is in: a millisecond of its ops is missing, which
+    # fails the whole reading, and its one named event is of no module.
+    cut = sr.stages_from_xplane(_traced(groups), 384, 3, 128, text_of=_text)
+    assert cut["failed"] == "the compiled text is of another module than the one that ran"
+    assert cut["names_found"] == pytest.approx(4 / 5) and cut["not_found"] == ["region.6238"]
+    assert cut["runs_cut"] == [pytest.approx(0.5)] and cut["runs_whole"] == 2
+    assert cut["us_per_lane"] is None and cut["unscoped_share"] == 100.0
+
+
+def test_a_run_that_lost_ops_is_left_out_and_the_whole_runs_are_the_reading():
+    """Seen on the chip in PR 33, WITH the lead-in: one traced flood in seven
+    had 9,400 of its 1.76 million op events missing, 1.2 ms of one run's 8.3
+    (every name known, all ten runs on ``XLA Modules``).  Every run is the same
+    program on the same shape: the whole ones read what all would."""
+    marks = [("prepare_flood", 9, 20)]
+    lost = [(RUN, [(OP_A, 10.1, 0.9), (OP_D, 11, 1), (OP_A, 20, 1), (OP_D, 21, 1)], [(10, 2), (20, 2)])]
+    got = sr.stages_from_xplane(_traced(lost, marks), 256, 2, 128, text_of=_text)
+    assert "failed" not in got and got["names_found"] == 1.0
+    assert got["runs"] == 2 and got["runs_whole"] == 1 and got["runs_cut"] == [pytest.approx(0.95)]
+    per_lane = got["us_per_lane"]  # the whole run's 2 ms over ITS 128 lanes
+    assert per_lane[LIFT] == pytest.approx(1e3 / 128) and per_lane[ADDRESS] == pytest.approx(1e3 / 128)
+    assert got["busy_s"] == pytest.approx(2e-3) and got["unscoped_share"] == 0.0
+    # Fewer than half of the runs whole: fails safe, never a guess.
+    worse = [(RUN, [(OP_A, 10.1, 0.9), (OP_D, 11, 1), (OP_A, 20, 1), (OP_D, 21.5, 0.5), (OP_A, 30, 1), (OP_D, 31, 1)], [(10, 2), (20, 2), (30, 2)])]
+    got = sr.stages_from_xplane(_traced(worse, [("prepare_flood", 9, 30)]), 384, 3, 128, text_of=_text)
+    assert got["failed"] == "the trace lost ops in 2 of the program's 3 runs"
+    assert got["us_per_lane"] is None and got["unscoped_share"] == 100.0 and got["runs_whole"] == 1
+
+
+def test_a_few_events_under_names_of_no_module_are_charged_to_what_encloses_them():
+    """Seen on the chip in PR 33: in one traced flood in four, a few hundred
+    of 1.76 million op events carry names the module has not (``region.7170``,
+    ``region.7171``, ..., another one each time: 2.5% of the DISTINCT names,
+    which the reading before PR 33 counted, and 0.02% of the events).  They
+    are charged like any op without a stage of its own; the share of EVENTS
+    found says whether the text is of the module that ran."""
+    ops = [("%while.7 = (s32[]) while(%t), body=%b", 0, 200)]
+    ops += [(OP_A if i % 2 else OP_D, i, 1) for i in range(198)]  # 99 of each, inside the while
+    ops += [("%region.7170 = u32[8,5]{1,0} fusion(%p)", 198, 1)]  # inside the while: the ladder's
+    ops += [("%region.7171 = u32[8,5]{1,0} fusion(%p)", 201, 1)]  # after it: nothing encloses it
+    got = sr.stages_from_xplane(_traced([(RUN, ops, [(0, 202)])]), 128, 1, 128, text_of=_text)
+    assert "failed" not in got and got["names_found"] == pytest.approx(1 - 2 / 201)
+    assert got["names_not_found"] == 2 and got["not_found"] == ["region.7170", "region.7171"]
+    assert got["runs_whole"] == 1  # its ops cover 201 of its 202 ms
+    per_lane = got["us_per_lane"]
+    assert per_lane[LADDER] * 128 / 1e3 == pytest.approx(2.0)  # the while's own 199..200, and region.7170
+    assert per_lane[sr.UNSCOPED] * 128 / 1e3 == pytest.approx(1.0)  # region.7171
+    assert per_lane[LIFT] * 128 / 1e3 == pytest.approx(99.0)
+    # Many events under unknown names are another module: fails safe.
+    ops += [(f"%region.{8000 + i} = u32[8,5]{{1,0}} fusion(%p)", 201.0 + i / 100, 0.01) for i in range(1, 9)]
+    got = sr.stages_from_xplane(_traced([(RUN, ops, [(0, 202)])]), 128, 1, 128, text_of=_text)
+    assert got["names_found"] == pytest.approx(1 - 10 / 209) and "another module" in got["failed"]
+
+
+def test_the_stages_divide_by_the_lanes_recover_us_per_lane_divides_by():
+    """One lane count for the whole and its parts: the ledger's, of the marked
+    window; where the trace does not have the ledger's dispatches the parts
+    are left out (above), so they never sum to another whole."""
+    ops = [(OP_A, 0, 1), (OP_D, 1, 1), (OP_A, 10, 1), (OP_D, 11, 1)]
+    got = sr.stages_from_xplane(_traced([(RUN, ops, [(0, 2), (10, 2)])]), 512, 2, 128, text_of=_text)
+    assert got["lanes"] == 512 and got["runs"] == 2
+    assert sum(got["us_per_lane"].values()) == pytest.approx(4e3 / 512)  # the module events' 4 ms over 512

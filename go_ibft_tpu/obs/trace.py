@@ -117,6 +117,9 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args) -> None:
+        pass
+
 
 _NULL = _NullSpan()
 
@@ -135,6 +138,10 @@ class _Span:
     def __enter__(self):
         self._t0 = time.perf_counter_ns()
         return self
+
+    def note(self, **args) -> None:
+        """Attributes known only once the span's work is under way."""
+        self.args.update(args)
 
     def __exit__(self, exc_type, exc, tb):
         now = time.perf_counter_ns()

@@ -55,7 +55,7 @@ from ..verify.batch import (
     pack_seal_lanes,
     pack_validator_table,
 )
-from ..verify.pipeline import PackCache, SenderPack
+from ..verify.pipeline import PackCache, SenderRows
 
 __all__ = [
     "CoalescedDispatcher",
@@ -83,17 +83,24 @@ class _RoutingPackCache:
     A coalesced sender pack mixes messages from many tenants, but
     ``pack_sender_batch`` takes one ``cache`` to store fresh packs into.
     Lookups are supplied pre-routed (``cache_hits``); this shim routes the
-    stores by message identity so one tenant's packs can never land in —
-    or later be served from — another tenant's cache (the namespacing
-    contract of docs/TENANCY.md)."""
+    pack's one by-reference store by message identity, one call per tenant
+    in it, so one tenant's packs can never land in — or later be served
+    from — another tenant's cache (the namespacing contract of
+    docs/TENANCY.md)."""
 
     def __init__(self, owners: Dict[int, PackCache]):
         self._owners = owners
 
-    def store(self, msg, pack: SenderPack) -> None:
-        owner = self._owners.get(id(msg))
-        if owner is not None:
-            owner.store(msg, pack)
+    def store_rows(self, msgs, rows: SenderRows, at) -> None:
+        by_owner: Dict[int, tuple] = {}
+        for msg, row in zip(msgs, at):
+            owner = self._owners.get(id(msg))
+            if owner is not None:
+                mine = by_owner.setdefault(id(owner), (owner, [], []))
+                mine[1].append(msg)
+                mine[2].append(row)
+        for owner, mine, rows_at in by_owner.values():
+            owner.store_rows(mine, rows, rows_at)
 
 
 def well_formed_sender(msg: IbftMessage) -> bool:

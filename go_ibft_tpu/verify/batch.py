@@ -35,15 +35,21 @@ from ..crypto import ecdsa as host_ecdsa
 from ..obs import ledger as cost_ledger
 from ..obs import trace
 from ..crypto.keccak import keccak256, keccak256_many
-from ..messages.helpers import CommittedSeal, extract_committed_seal
-from ..messages.wire import IbftMessage, MessageType
+from ..messages.helpers import CommittedSeal
+from ..messages.wire import IbftMessage, MessageType, payloads_no_sig
 from ..ops import fields
 from ..ops import keccak as dk
 from ..ops import quorum
 from ..ops import secp256k1 as sec
 from ..ops.fields import LIMB_BITS, LIMB_MASK
 from ..utils import metrics
-from .pipeline import CircuitBreaker, PackCache, SenderPack, VerifyPipeline
+from .pipeline import (
+    CircuitBreaker,
+    PackCache,
+    SenderPack,
+    SenderRows,
+    VerifyPipeline,
+)
 
 SIG_BYTES = 65  # r(32) || s(32) || v(1)
 
@@ -164,6 +170,11 @@ SEAL_LANES_CARRIED_KEY = ("go-ibft", "seal_verdicts", "carried")
 SEAL_VERDICT_HITS_KEY = ("go-ibft", "seal_verdicts", "hits")
 SEAL_VERDICT_MISSES_KEY = ("go-ibft", "seal_verdicts", "misses")
 JOINT_FAULTS_KEY = ("go-ibft", "seal_verdicts", "joint_faults")
+
+# Messages of a sender pack whose signed payload the bulk encoder handed to
+# ``IbftMessage.encode`` (any shape but a plain PREPARE / COMMIT); the ones
+# it built from its template are ``templated`` on the ``verify.pack`` span.
+PACK_GENERIC_KEY = ("go-ibft", "pack", "generic")
 
 # Sized like the engine's own seal-verdict cache (core/ibft.py
 # ``_seal_verdict_cap``): a constant, not an option.
@@ -654,6 +665,42 @@ def _pack_scalars(values: List[int], pad_to: int) -> jnp.ndarray:
     return jnp.asarray(fields.to_limbs(values, sec.FIELD.nlimbs))
 
 
+def _signature_words(sigs: Sequence[bytes]) -> Tuple[np.ndarray, np.ndarray]:
+    """N 65-byte signatures (lengths checked by the caller) -> ``(N, 2, 8)``
+    uint32 little-endian value words, ``[:, 0]`` of s and ``[:, 1]`` of r
+    (bytes 63..0 reversed in one copy), and ``v`` as ``(N,)`` int32."""
+    flat = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(-1, SIG_BYTES)
+    words = np.ascontiguousarray(flat[:, 63::-1]).view("<u4")
+    return words.reshape(-1, 2, 8), flat[:, 64].astype(np.int32)
+
+
+def _signature_rows(sigs: Sequence[bytes]):
+    """``(r_limbs, s_limbs, v)`` of N length-checked signatures: r and s
+    limb-split in ONE :func:`_words_to_limbs` pass over the stacked words."""
+    words, v = _signature_words(sigs)
+    limbs = _words_to_limbs(words, sec.FIELD.nlimbs)
+    return limbs[:, 1], limbs[:, 0], v
+
+
+def _address_rows(addresses: Sequence[bytes]) -> np.ndarray:
+    """N length-checked 20-byte addresses -> ``(N, 5)`` uint32 words (the
+    layout of ``ops/keccak.py::addresses_to_words``, without its loop)."""
+    return np.frombuffer(b"".join(addresses), dtype="<u4").reshape(-1, 5)
+
+
+def _dead_rows(lanes: int):
+    """``(r, s, v, claimed, live)`` of ``lanes`` dead lanes: what a packer
+    starts from, and what a dispatch's padding stays."""
+    nl = sec.FIELD.nlimbs
+    return (
+        np.zeros((lanes, nl), dtype=np.int32),
+        np.zeros((lanes, nl), dtype=np.int32),
+        np.zeros((lanes,), dtype=np.int32),
+        np.zeros((lanes, 5), dtype=np.uint32),
+        np.zeros((lanes,), dtype=bool),
+    )
+
+
 def _split_signatures(
     sigs: Sequence[bytes],
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
@@ -668,14 +715,8 @@ def _split_signatures(
     for i, sig in enumerate(sigs):
         if len(sig) != SIG_BYTES:
             raise MalformedLaneError(i, "signature", SIG_BYTES, len(sig))
-    n = len(sigs)
-    if n == 0:
-        z = np.zeros((0, 8), dtype=np.uint32)
-        return z, z.copy(), np.zeros((0,), dtype=np.int32)
-    flat = np.frombuffer(b"".join(sigs), dtype=np.uint8).reshape(n, SIG_BYTES)
-    r_words = np.ascontiguousarray(flat[:, 31::-1]).view("<u4")
-    s_words = np.ascontiguousarray(flat[:, 63:31:-1]).view("<u4")
-    return r_words, s_words, flat[:, 64].astype(np.int32)
+    words, v = _signature_words(sigs)
+    return words[:, 1], words[:, 0], v
 
 
 def _words_to_limbs(words: np.ndarray, nlimbs: int) -> np.ndarray:
@@ -711,6 +752,22 @@ def pack_validator_table(addresses: Sequence[bytes], bucket: bool = True) -> np.
     return table
 
 
+def _signed_payloads(
+    msgs: Sequence[IbftMessage],
+    hits: Optional[Sequence[Optional[SenderPack]]],
+    generic: Optional[list] = None,
+) -> List[bytes]:
+    """Each message's signed bytes: a cache hit's, else encoded in bulk
+    (``messages/wire.py::payloads_no_sig``).  ``hits`` None: all fresh."""
+    if hits is None:
+        return payloads_no_sig(msgs, generic)
+    payloads = [None if h is None else h.payload for h in hits]
+    miss = [i for i, h in enumerate(hits) if h is None]
+    for i, p in zip(miss, payloads_no_sig([msgs[i] for i in miss], generic)):
+        payloads[i] = p
+    return payloads
+
+
 def pack_sender_batch(
     msgs: Sequence[IbftMessage],
     pad_lanes: int = 0,
@@ -718,71 +775,68 @@ def pack_sender_batch(
     cache: Optional[PackCache] = None,
     cache_payloads: Optional[List[bytes]] = None,
     cache_hits: Optional[List[Optional[SenderPack]]] = None,
+    rows=None,
 ):
     """Messages -> device-ready arrays for the sender-validity kernel.
 
     Returns ``(blocks, counts, r, s, v, senders, live)`` as numpy/jax
     arrays padded to bucketed static shapes.  A lane with a wrong-length
     sender or signature raises :class:`MalformedLaneError` naming the lane
-    (validated up front — never a numpy reshape crash).  ``payloads``
+    (validated up front, once — never a numpy reshape crash).  ``payloads``
     overrides the per-message signed bytes (the oversize-payload path
     substitutes empty payloads for lanes whose digest is computed on host).
 
-    Vectorized end to end: signatures split + limbed straight from bytes in
-    one shot (:func:`_split_signatures` -> :func:`_words_to_limbs`), sender
-    addresses bulk-converted, and the keccak block packing done once for
-    the whole batch (``ops/keccak.py::pack_messages``).  Bit-identical to
+    Built in bulk: nothing here runs once per message that can run once per
+    drain.  The signed payloads come from the bulk encoder, signatures are
+    split and limbed straight from bytes in one pass over r and s stacked
+    (:func:`_signature_rows`), sender addresses are bulk-converted, and the
+    keccak block packing is done once for the whole batch
+    (``ops/keccak.py::pack_messages``).  Bit-identical to
     :func:`_pack_sender_batch_reference` (tests/test_pack_vectorized.py).
+
+    ``rows`` hands in the ``(r, s, v, senders, live)`` arrays to write, dead
+    (:func:`_dead_rows`) and of the pack's lane count: a caller that lays
+    several packs out in one dispatch allocates once at the dispatch's lane
+    count and passes each pack its slice, so that no row is copied again.
 
     ``cache`` (a :class:`~go_ibft_tpu.verify.pipeline.PackCache`) reuses a
     message's encoded payload + limb rows from an earlier pack and stores
-    fresh ones; ``cache_payloads`` supplies the TRUE payloads for cache
-    stores when ``payloads`` carries substituted (oversize-lane) bytes —
-    without it, an explicit ``payloads`` disables stores so a substituted
-    payload can never poison the cache.  ``cache_hits`` passes lookups a
-    caller already performed (``_sender_inputs`` needs them for payload
-    sizing) so the hot path pays one lock-guarded lookup per message, not
-    two.
+    the fresh lanes, by reference and in one call; ``cache_payloads``
+    supplies the TRUE payloads for cache stores when ``payloads`` carries
+    substituted (oversize-lane) bytes — without it, an explicit
+    ``payloads`` disables stores so a substituted payload can never poison
+    the cache.  ``cache_hits`` passes lookups a caller already performed.
 
     Empty input returns a fully-dead padded batch (all ``live`` False,
     smallest block bucket) instead of raising — an empty drain is a no-op,
     not a crash.
     """
     n = len(msgs)
+    sigs = [m.signature for m in msgs]
+    addrs = [m.sender for m in msgs]
     # Length validation up front (the whole-batch frombuffer views below
     # would otherwise die in an opaque numpy reshape): the error names the
     # TRUE lane index so degraded-mode drains can quarantine exactly it.
-    for i, m in enumerate(msgs):
-        if len(m.signature) != SIG_BYTES:
-            raise MalformedLaneError(i, "signature", SIG_BYTES, len(m.signature))
-        if len(m.sender) != ADDRESS_BYTES:
-            raise MalformedLaneError(i, "sender", ADDRESS_BYTES, len(m.sender))
+    for i, (sig, addr) in enumerate(zip(sigs, addrs)):
+        if len(sig) != SIG_BYTES:
+            raise MalformedLaneError(i, "signature", SIG_BYTES, len(sig))
+        if len(addr) != ADDRESS_BYTES:
+            raise MalformedLaneError(i, "sender", ADDRESS_BYTES, len(addr))
     bb = _lane_count(n, pad_lanes)
-    nl = sec.FIELD.nlimbs
-    r_limbs = np.zeros((bb, nl), dtype=np.int32)
-    s_limbs = np.zeros((bb, nl), dtype=np.int32)
-    v = np.zeros((bb,), dtype=np.int32)
-    senders = np.zeros((bb, 5), dtype=np.uint32)
-    live = np.zeros((bb,), dtype=bool)
+    r_limbs, s_limbs, v, senders, live = _dead_rows(bb) if rows is None else rows
     if n == 0:
         blocks = np.zeros((bb, _BLOCK_BUCKETS[0], 17, 2), dtype=np.uint32)
         return blocks, np.ones((bb,), np.int32), r_limbs, s_limbs, v, senders, live
 
-    if cache_hits is not None:
-        hits: List[Optional[SenderPack]] = cache_hits
-    elif cache is not None:
-        hits = [cache.lookup(m) for m in msgs]
-    else:
-        hits = [None] * n
-    own_payloads = payloads is None
-    if own_payloads:
-        payloads = [
-            h.payload if h is not None else m.encode(include_signature=False)
-            for h, m in zip(hits, msgs)
-        ]
-        cache_payloads = payloads
+    hits = cache_hits
+    if hits is None and cache is not None:
+        hits = cache.lookup_many(msgs)
+    if hits is not None and hits.count(None) == n:
+        hits = None  # a flood's first pack: every lane is fresh
+    if payloads is None:
+        payloads = cache_payloads = _signed_payloads(msgs, hits)
 
-    max_len = max(len(p) for p in payloads)
+    max_len = max(map(len, payloads))
     nb = _bucket((max_len + 1 + dk.RATE_BYTES - 1) // dk.RATE_BYTES, _BLOCK_BUCKETS)
     blocks = np.zeros((bb, nb, 17, 2), dtype=np.uint32)
     counts = np.ones((bb,), dtype=np.int32)
@@ -790,35 +844,30 @@ def pack_sender_batch(
     blocks[:n] = pb
     counts[:n] = pc
 
-    miss = [i for i, h in enumerate(hits) if h is None]
-    if miss:
-        rw, sw, vv = _split_signatures([msgs[i].signature for i in miss])
-        rl = _words_to_limbs(rw, nl)
-        sl = _words_to_limbs(sw, nl)
-        aw = dk.addresses_to_words([msgs[i].sender for i in miss])
-        idx = np.asarray(miss)
-        r_limbs[idx] = rl
-        s_limbs[idx] = sl
-        v[idx] = vv
-        senders[idx] = aw
-        if cache is not None and cache_payloads is not None:
-            for j, i in enumerate(miss):
-                cache.store(
-                    msgs[i],
-                    SenderPack(
-                        payload=cache_payloads[i],
-                        r_limbs=rl[j].copy(),
-                        s_limbs=sl[j].copy(),
-                        v=int(vv[j]),
-                        sender_words=aw[j].copy(),
-                    ),
-                )
-    for i, h in enumerate(hits):
-        if h is not None:
-            r_limbs[i] = h.r_limbs
-            s_limbs[i] = h.s_limbs
-            v[i] = h.v
-            senders[i] = h.sender_words
+    if hits is None:
+        fresh: Sequence[int] = range(n)
+        r_limbs[:n], s_limbs[:n], v[:n] = _signature_rows(sigs)
+        senders[:n] = _address_rows(addrs)
+    else:
+        fresh = [i for i, h in enumerate(hits) if h is None]
+        if fresh:
+            idx = np.asarray(fresh)
+            r_limbs[idx], s_limbs[idx], v[idx] = _signature_rows(
+                [sigs[i] for i in fresh]
+            )
+            senders[idx] = _address_rows([addrs[i] for i in fresh])
+        for i, h in enumerate(hits):
+            if h is not None:
+                r_limbs[i] = h.r_limbs
+                s_limbs[i] = h.s_limbs
+                v[i] = h.v
+                senders[i] = h.sender_words
+    if cache is not None and cache_payloads is not None and fresh:
+        cache.store_rows(
+            msgs if hits is None else [msgs[i] for i in fresh],
+            SenderRows(cache_payloads, r_limbs, s_limbs, v, senders),
+            fresh,
+        )
     live[:n] = True
     return blocks, counts, r_limbs, s_limbs, v, senders, live
 
@@ -846,18 +895,12 @@ def pack_seal_batch(proposal_hash: bytes, seals: Sequence[CommittedSeal], pad_la
     bb = _lane_count(n, pad_lanes)
     hw = np.frombuffer(proposal_hash, ">u4")[::-1].astype(np.uint32)  # LE words
     hash_zw = np.broadcast_to(hw, (bb, 8)).copy()
-    nl = sec.FIELD.nlimbs
-    r_limbs = np.zeros((bb, nl), dtype=np.int32)
-    s_limbs = np.zeros((bb, nl), dtype=np.int32)
-    v = np.zeros((bb,), dtype=np.int32)
-    signers = np.zeros((bb, 5), dtype=np.uint32)
-    live = np.zeros((bb,), dtype=bool)
+    r_limbs, s_limbs, v, signers, live = _dead_rows(bb)
     if n:
-        rw, sw, vv = _split_signatures([s.signature for s in seals])
-        r_limbs[:n] = _words_to_limbs(rw, nl)
-        s_limbs[:n] = _words_to_limbs(sw, nl)
-        v[:n] = vv
-        signers[:n] = dk.addresses_to_words([s.signer for s in seals])
+        r_limbs[:n], s_limbs[:n], v[:n] = _signature_rows(
+            [s.signature for s in seals]
+        )
+        signers[:n] = _address_rows([s.signer for s in seals])
         live[:n] = True
     return hash_zw, r_limbs, s_limbs, v, signers, live
 
@@ -888,35 +931,40 @@ def pack_seal_lanes(
     here carries its own 32-byte hash.  The device kernel already takes
     per-lane hash words (``hash_zw`` rows); only the packers assumed one
     hash per drain.  Returns the same ``(hash_words, r, s, v, signers,
-    live)`` tuple; lengths are validated up front with
+    live)`` tuple; lengths are validated up front, once, with
     :class:`MalformedLaneError` naming the lane (a bad per-lane hash IS a
     lane fault here, not a batch-wide error).
     """
     validate_seal_lanes(lanes)
-    n = len(lanes)
-    bb = _lane_count(n, pad_lanes)
-    nl = sec.FIELD.nlimbs
-    hash_zw = np.zeros((bb, 8), dtype=np.uint32)
-    r_limbs = np.zeros((bb, nl), dtype=np.int32)
-    s_limbs = np.zeros((bb, nl), dtype=np.int32)
-    v = np.zeros((bb,), dtype=np.int32)
-    signers = np.zeros((bb, 5), dtype=np.uint32)
-    live = np.zeros((bb,), dtype=bool)
+    return _seal_lane_rows(
+        [h for h, _ in lanes],
+        [s.signature for _, s in lanes],
+        [s.signer for _, s in lanes],
+        _lane_count(len(lanes), pad_lanes),
+    )
+
+
+def _seal_lane_rows(
+    hashes: Sequence[bytes],
+    sigs: Sequence[bytes],
+    signers: Sequence[bytes],
+    lanes: int,
+    rows=None,
+):
+    """:func:`pack_seal_lanes` on length-checked fields, a list each, into
+    ``rows`` where given (as :func:`pack_sender_batch` takes them)."""
+    n = len(sigs)
+    r_limbs, s_limbs, v, claimed, live = _dead_rows(lanes) if rows is None else rows
+    hash_zw = np.zeros((lanes, 8), dtype=np.uint32)
     if n:
         # Same word layout as pack_seal_batch: 8 big-endian u32 words per
         # hash, reversed to little-endian value order — vectorized over
         # all lanes in one frombuffer.
-        hw = np.frombuffer(
-            b"".join(h for h, _ in lanes), ">u4"
-        ).reshape(n, 8)[:, ::-1]
-        hash_zw[:n] = hw.astype(np.uint32)
-        rw, sw, vv = _split_signatures([s.signature for _, s in lanes])
-        r_limbs[:n] = _words_to_limbs(rw, nl)
-        s_limbs[:n] = _words_to_limbs(sw, nl)
-        v[:n] = vv
-        signers[:n] = dk.addresses_to_words([s.signer for _, s in lanes])
+        hash_zw[:n] = np.frombuffer(b"".join(hashes), ">u4").reshape(n, 8)[:, ::-1]
+        r_limbs[:n], s_limbs[:n], v[:n] = _signature_rows(sigs)
+        claimed[:n] = _address_rows(signers)
         live[:n] = True
-    return hash_zw, r_limbs, s_limbs, v, signers, live
+    return hash_zw, r_limbs, s_limbs, v, claimed, live
 
 
 # -- reference loop packers (parity oracles) ---------------------------------
@@ -1002,6 +1050,8 @@ def pack_sender_digest_rows(
     cache=None,
     hits: Optional[list] = None,
     pad_lanes: int = 0,
+    rows=None,
+    span=None,
 ):
     """The device sender-route pack sequence: cache-hit reuse, oversize
     payloads digested on host, everything else on the device digest
@@ -1016,25 +1066,33 @@ def pack_sender_digest_rows(
     every lane.
 
     ONE implementation serves both the single-tenant plane
-    (:meth:`DeviceBatchVerifier._sender_inputs_impl`) and the
-    multi-tenant coalesced dispatcher (``sched/dispatch.py``), so a fix
-    to the oversize/cache path can never apply to one and silently miss
-    the other.  ``cache`` is the store target for fresh packs (a
+    (:meth:`DeviceBatchVerifier._sender_inputs`) and the multi-tenant
+    coalesced dispatcher (``sched/dispatch.py``), so a fix to the
+    oversize/cache path can never apply to one and silently miss the
+    other.  ``cache`` is the store target for fresh packs (a
     :class:`PackCache`, or the scheduler's per-tenant routing shim);
-    ``hits`` supplies pre-routed lookups (computed from ``cache`` when
-    omitted).  Returns ``(zw, r, s, v, senders, live)``.
+    ``hits`` supplies pre-routed lookups (one ``lookup_many`` of ``cache``
+    when omitted); ``rows`` is :func:`pack_sender_batch`'s.  ``span``, the
+    caller's open ``verify.pack`` span, is told how many payloads the bulk
+    encoder built from its template (``templated``); those it handed to
+    ``IbftMessage.encode`` are counted under :data:`PACK_GENERIC_KEY`.
+    Returns ``(zw, r, s, v, senders, live)``.
     """
-    if hits is None:
-        hits = (
-            [cache.lookup(m) for m in msgs]
-            if cache is not None
-            else [None] * len(msgs)
-        )
-    payloads = [
-        h.payload if h is not None else m.encode(include_signature=False)
-        for h, m in zip(hits, msgs)
-    ]
-    big = [i for i, p in enumerate(payloads) if len(p) > MAX_DEVICE_PAYLOAD]
+    n = len(msgs)
+    if hits is None and cache is not None:
+        hits = cache.lookup_many(msgs)
+    fresh = n if hits is None else hits.count(None)
+    generic: List[int] = []
+    payloads = _signed_payloads(msgs, None if fresh == n else hits, generic)
+    if generic:
+        metrics.inc_counter(PACK_GENERIC_KEY, len(generic))
+    if span is not None:
+        span.note(templated=fresh - len(generic))
+    big = (
+        [i for i, p in enumerate(payloads) if len(p) > MAX_DEVICE_PAYLOAD]
+        if n and max(map(len, payloads)) > MAX_DEVICE_PAYLOAD
+        else []
+    )
     if big:
         device_payloads = list(payloads)
         for i in big:
@@ -1048,6 +1106,7 @@ def pack_sender_digest_rows(
         cache=cache,
         cache_payloads=payloads,
         cache_hits=hits,
+        rows=rows,
     )
     with cost_ledger.dispatch_span(
         "digest_words",
@@ -1403,30 +1462,28 @@ class DeviceBatchVerifier:
     # reserved for keccak padding in the last block.
     _MAX_DEVICE_PAYLOAD = MAX_DEVICE_PAYLOAD
 
-    def _sender_inputs(self, msgs: List[IbftMessage], pad_lanes: int = 0):
-        pad_lanes = max(pad_lanes, self._pad_lanes(len(msgs)))
-        with trace.span("verify.pack", kind="senders", lanes=len(msgs)):
-            return self._sender_inputs_impl(msgs, pad_lanes)
-
-    def _sender_inputs_impl(self, msgs: List[IbftMessage], pad_lanes: int = 0):
+    def _sender_inputs(self, msgs: List[IbftMessage], pad_lanes: int = 0, rows=None):
         """Pack envelopes; digest on device, oversize payloads on host.
 
         Payload encodings and limb rows come from the pack cache when this
         engine already packed the message (certificate re-validation runs
         per round-change wakeup over the same envelopes); fresh lanes pack
-        vectorized and store back.  Serves both the per-phase dispatches
-        and (via ``pad_lanes``) the single-dispatch ``certify_round``
-        packing; the sequence itself lives in
+        in bulk and are stored back by reference.  Serves the per-phase
+        dispatches and (via ``pad_lanes``, and ``rows`` for the slice of a
+        dispatch's arrays to write) the layouts that put envelopes and
+        seals in one dispatch; the sequence itself lives in
         :func:`pack_sender_digest_rows` (shared with the multi-tenant
         coalesced dispatcher).
         """
-        cache = self._pack_cache
-        return pack_sender_digest_rows(
-            msgs,
-            cache=cache,
-            hits=[cache.lookup(m) for m in msgs],
-            pad_lanes=pad_lanes,
-        )
+        pad_lanes = max(pad_lanes, self._pad_lanes(len(msgs)))
+        with trace.span("verify.pack", kind="senders", lanes=len(msgs)) as span:
+            return pack_sender_digest_rows(
+                msgs,
+                cache=self._pack_cache,
+                pad_lanes=pad_lanes,
+                rows=rows,
+                span=span,
+            )
 
     def _seal_inputs(
         self, proposal_hash: bytes, seals: List[CommittedSeal], pad_lanes: int = 0
@@ -1687,36 +1744,33 @@ class DeviceBatchVerifier:
         )
         return [j for j, hit in zip(riders, known) if hit is None]
 
-    def _rider_inputs(self, commits: List[IbftMessage], lanes: int):
+    def _rider_inputs(self, commits: List[IbftMessage], lanes: int, rows=None):
         """The seal rows of ``commits`` on ``lanes`` lanes: per-lane hash
-        words (each COMMIT's own carried hash), claimed signer = sender."""
+        words (each COMMIT's own carried hash), claimed signer = sender.
+        :meth:`_seal_riders` chose them by their lengths: not checked again."""
         with trace.span("verify.pack", kind="seals", lanes=len(commits)):
-            return pack_seal_lanes(
-                [
-                    (m.commit_data.proposal_hash, extract_committed_seal(m))
-                    for m in commits
-                ],
-                pad_lanes=lanes,
+            return _seal_lane_rows(
+                [m.commit_data.proposal_hash for m in commits],
+                [m.commit_data.committed_seal for m in commits],
+                [m.sender for m in commits],
+                lanes,
+                rows,
             )
 
     def _joint_inputs(self, sub: List[IbftMessage], riders: List[int], lanes: int):
         """Envelope rows in the first ``lanes`` lanes, the riders' seal rows
         in the second: the :meth:`certify_round` layout, for the mask-only
-        program."""
-        zw, r, s, v, senders, live = self._sender_inputs(sub, pad_lanes=lanes)
-        hz, r2, s2, v2, signers, live2 = (
-            self._rider_inputs([sub[j] for j in riders], lanes)
-            if riders
-            else pack_seal_lanes([], pad_lanes=lanes)  # a dead half: no pack span
-        )
-        return (
-            _join_rows(jnp.asarray(zw), jnp.asarray(hz)),
-            np.concatenate([r, r2]),
-            np.concatenate([s, s2]),
-            np.concatenate([v, v2]),
-            np.concatenate([senders, signers]),
-            np.concatenate([live, live2]),
-        )
+        program.  The rows are allocated once, at the dispatch's lane count,
+        and each half is packed into its slice."""
+        rows = _dead_rows(2 * lanes)
+        zw = self._sender_inputs(sub, lanes, tuple(a[:lanes] for a in rows))[0]
+        if riders:
+            hz = self._rider_inputs(
+                [sub[j] for j in riders], lanes, tuple(a[lanes:] for a in rows)
+            )[0]
+        else:  # a dead half: no pack span
+            hz = np.zeros((lanes, 8), dtype=np.uint32)
+        return (_join_rows(jnp.asarray(zw), jnp.asarray(hz)),) + rows
 
     def cached_seal_verdicts(
         self, proposal_hash: bytes, seals: Sequence[CommittedSeal], height: int

@@ -334,6 +334,28 @@ class SenderPack:
     sender_words: np.ndarray  # (5,) uint32
 
 
+@dataclass(frozen=True)
+class SenderRows:
+    """One drain's sender lanes as its packer left them: message ``i`` of the
+    drain is row ``i`` of each array (padding and cache-hit rows included,
+    so a row index is a message's position)."""
+
+    payloads: Sequence[bytes]
+    r_limbs: np.ndarray  # (lanes, nlimbs) int32
+    s_limbs: np.ndarray  # (lanes, nlimbs) int32
+    v: np.ndarray  # (lanes,) int32
+    sender_words: np.ndarray  # (lanes, 5) uint32
+
+    def lane(self, i: int) -> SenderPack:
+        return SenderPack(
+            payload=self.payloads[i],
+            r_limbs=self.r_limbs[i],
+            s_limbs=self.s_limbs[i],
+            v=int(self.v[i]),
+            sender_words=self.sender_words[i],
+        )
+
+
 class PackCache:
     """Message identity -> :class:`SenderPack`, round-scoped eviction.
 
@@ -341,6 +363,14 @@ class PackCache:
     object's recycled id can never alias a stale entry) and guarded by a
     ``(sender, signature)`` token so in-place mutation of either field
     (tests and Byzantine harnesses do this) turns the entry into a miss.
+    A drain stores its fresh lanes BY REFERENCE, in one call under one lock
+    (:meth:`store_rows`): an entry is the drain's :class:`SenderRows` and a
+    row index, and the :class:`SenderPack` is made on a hit.  The packers
+    never write a row array again once it is packed
+    (``tests/test_pack_vectorized.py`` holds them to it).  A dead message's
+    entry is a miss from the moment it dies (its weak reference is gone) and
+    is dropped when the cache is next counted, by eviction in its turn, or
+    by ``clear()``: no callback runs per message.
     The payload itself is NOT re-checked on hit — the cache contract is the
     message-store contract: stored messages are replaced, never mutated
     (``messages/store.py`` dedup is last-write-wins on whole objects), and
@@ -370,10 +400,11 @@ class PackCache:
 
     def __init__(self, cap: int = 8192):
         self._lock = threading.RLock()
-        # (owner, round) -> {id(msg) -> (weakref, token, pack)}
+        # (owner, round) -> {id(msg) -> (weakref, token, source, row)}: the
+        # source is a SenderPack (row None) or a drain's SenderRows.
         self._by_round: Dict[
             Tuple[str, int],
-            Dict[int, Tuple[Any, Tuple[bytes, bytes], SenderPack]],
+            Dict[int, Tuple[Any, Tuple[bytes, bytes], Any, Optional[int]]],
         ] = {}
         self._index: Dict[int, Tuple[str, int]] = {}  # id(msg) -> tag
         self._count = 0
@@ -426,6 +457,7 @@ class PackCache:
 
     def __len__(self) -> int:
         with self._lock:
+            self._prune_dead()
             return self._count
 
     @property
@@ -433,39 +465,54 @@ class PackCache:
         return self._cap
 
     def lookup(self, msg) -> Optional[SenderPack]:
-        mid = id(msg)
+        return self.lookup_many((msg,))[0]
+
+    def lookup_many(self, msgs: Sequence[Any]) -> List[Optional[SenderPack]]:
+        """A drain's lookups in one call, under one lock: per message its
+        :class:`SenderPack`, or ``None`` on a miss."""
+        out: List[Optional[SenderPack]] = [None] * len(msgs)
         with self._lock:
-            tag = self._index.get(mid)
-            if tag is None:
-                self.misses += 1
-                return None
-            wref, token, pack = self._by_round[tag][mid]
-        if wref() is not msg or token != (msg.sender, msg.signature):
-            with self._lock:
-                self.misses += 1
-            return None
-        with self._lock:
-            self.hits += 1
-        return pack
+            index, found = self._index, 0
+            if index:
+                for i, msg in enumerate(msgs):
+                    mid = id(msg)
+                    tag = index.get(mid)
+                    if tag is None:
+                        continue
+                    wref, token, source, row = self._by_round[tag][mid]
+                    if wref() is msg and token == (msg.sender, msg.signature):
+                        out[i] = source if row is None else source.lane(row)
+                        found += 1
+            self.hits += found
+            self.misses += len(out) - found
+        return out
 
     def store(self, msg, pack: SenderPack) -> None:
-        mid = id(msg)
-        try:
-            wref = weakref.ref(msg, lambda _r, mid=mid: self._drop(mid))
-        except TypeError:  # not weak-referenceable; skip caching
-            return
+        self.store_rows((msg,), pack, (None,))
+
+    def store_rows(self, msgs: Sequence[Any], rows, at: Sequence[Optional[int]]) -> None:
+        """A drain's fresh lanes in one call, by reference: ``msgs[k]``'s
+        lane is row ``at[k]`` of ``rows`` (a :class:`SenderRows`, which the
+        caller never writes again; or, row ``None``, a whole
+        :class:`SenderPack`)."""
         with self._lock:
-            self._remove(mid)
             owner = getattr(self._tl, "owner", "")
-            self._rounds.setdefault(owner, 0)
-            tag = (owner, self._rounds[owner])
-            self._by_round.setdefault(tag, {})[mid] = (
-                wref,
-                (msg.sender, msg.signature),
-                pack,
-            )
-            self._index[mid] = tag
-            self._count += 1
+            tag = (owner, self._rounds.setdefault(owner, 0))
+            index, bucket = self._index, None
+            for msg, row in zip(msgs, at):
+                try:
+                    wref = weakref.ref(msg)
+                except TypeError:  # not weak-referenceable; skip caching
+                    continue
+                mid = id(msg)
+                if mid in index:
+                    self._remove(mid)
+                    bucket = None  # it may have gone with its last entry
+                if bucket is None:
+                    bucket = self._by_round.setdefault(tag, {})
+                bucket[mid] = (wref, (msg.sender, msg.signature), rows, row)
+                index[mid] = tag
+                self._count += 1
             self._evict()
 
     def evict(self, msg) -> None:
@@ -480,10 +527,15 @@ class PackCache:
 
     # -- internals ------------------------------------------------------
 
-    def _drop(self, mid: int) -> None:
-        """Weakref death callback: the object is gone, so its id may be
-        recycled — the entry must go with it."""
-        with self._lock:
+    def _prune_dead(self) -> None:
+        """Drop the entries whose message is gone (its id may be recycled)."""
+        dead = [
+            mid
+            for bucket in self._by_round.values()
+            for mid, entry in bucket.items()
+            if entry[0]() is None
+        ]
+        for mid in dead:
             self._remove(mid)
 
     def _remove(self, mid: int) -> None:

@@ -383,10 +383,10 @@ def test_the_ladder_bisects_a_poisoned_joint_dispatch_to_its_message(
     poison = next(m for m in t.commits if m.sender not in t.bad)
 
     class Poisoned(DeviceBatchVerifier):
-        def _sender_inputs(self, msgs, pad_lanes=0):
+        def _sender_inputs(self, msgs, pad_lanes=0, rows=None):
             if any(m is poison for m in msgs):
                 raise RuntimeError("this message crashes the device rung")
-            return super()._sender_inputs(msgs, pad_lanes)
+            return super()._sender_inputs(msgs, pad_lanes, rows)
 
     dev, host = Poisoned(c.src), HostBatchVerifier(c.src)
     quarantined = metrics.get_counter(vbatch.QUARANTINED_LANES_KEY)

@@ -38,7 +38,13 @@ HEIGHTS = 2
 CORRUPT_SHARE = 0.3  # benchmark/traffic/flood-byz30.json
 
 
-def _run(n: int, seed: int, make_verifier, batched: bool = True):
+def _run(
+    n: int,
+    seed: int,
+    make_verifier,
+    batched: bool = True,
+    corrupt_share: float = CORRUPT_SHARE,
+):
     """``HEIGHTS`` heights of the deployment at ``n`` validators, as the
     driver runs a cell: its ``setup`` on a configuration and a traffic mix
     sized to sign exactly ``HEIGHTS`` heights, its ``warm`` (no warm
@@ -48,12 +54,13 @@ def _run(n: int, seed: int, make_verifier, batched: bool = True):
     under test.  Returns the driver's state and what the window recorded."""
     config = {"validators": n, "base_round_timeout_s": 60}
     mix = {
-        "corrupt_share": CORRUPT_SHARE,
+        "corrupt_share": corrupt_share,
         "warm_heights": 0,
         "presign_msgs_per_s": 2 * n * HEIGHTS,
     }
     st = driver.setup(config, mix, seed, 1.0)
-    assert st.heights == HEIGHTS and st.corrupt == driver.corrupted_peers(n, 0.3)
+    assert st.heights == HEIGHTS
+    assert st.corrupt == driver.corrupted_peers(n, corrupt_share)
     st.verifier = make_verifier(st.committee.src)
     if batched:
         st.engine.batch_verifier = st.verifier
@@ -204,3 +211,117 @@ def test_a_committee_just_over_the_old_ceiling_flushes_each_phase_once():
     for live, rejected in _verdicts(st, "seals"):
         assert live - rejected == st.committee.quorum == 173
         assert live < n - corrupt // 2
+
+
+def test_what_pack_does_once_a_drain_at_100_validators(monkeypatch):
+    """The headline deployment's round-0 heights on the device route, the
+    recover and digest programs replaced by stand-ins (every live lane
+    valid, so no peer is corrupted): COUNTS of what the pack layer does per
+    message and per drain (ISSUE 36; ``PERF.md`` section 5).  The generic
+    encoder runs for the node's own messages and the PREPREPARE (one
+    message, host route) and for no peer's PREPARE or COMMIT; the pack
+    cache takes one lookup and one store call a flood; the joint layout is
+    written in place."""
+    import numpy as np
+
+    from go_ibft_tpu.messages.wire import IbftMessage, MessageType
+    from go_ibft_tpu.utils import metrics
+    from go_ibft_tpu.verify import DeviceBatchVerifier, batch
+    from go_ibft_tpu.verify.pipeline import PackCache
+
+    n = 100
+    monkeypatch.setattr(
+        batch, "_recover_kernel", lambda zw, r, s, v, c, t, live: np.asarray(live)
+    )
+    monkeypatch.setattr(
+        batch,
+        "_digest_kernel",
+        lambda blocks, counts: np.zeros((len(counts), 8), np.uint32),
+    )
+    encoded, window = [], []
+    encode, measure = IbftMessage.encode, driver.measure
+
+    def counting_encode(self, **kw):
+        if window:  # the labels and the oracle encode too, afterwards
+            encoded.append((self.sender, self.type))
+        return encode(self, **kw)
+
+    def measure_in_window(st, seconds):
+        window.append(True)
+        try:
+            return measure(st, seconds)
+        finally:
+            window.clear()
+
+    monkeypatch.setattr(IbftMessage, "encode", counting_encode)
+    monkeypatch.setattr(driver, "measure", measure_in_window)
+    calls = {"lookup_many": [], "store_rows": [], "store": [], "lookup": []}
+    for name, sink in calls.items():
+        orig = getattr(PackCache, name)
+
+        def counted(
+            self, first, *rest, _orig=orig, _sink=sink, _whole=name in ("lookup_many", "store_rows")
+        ):
+            if window:  # a drain's call is noted with its message count
+                _sink.append(len(first) if _whole else 1)
+            return _orig(self, first, *rest)
+
+        monkeypatch.setattr(PackCache, name, counted)
+    joint = {"packs": 0, "inside": False, "concatenates": 0}
+    joint_inputs = DeviceBatchVerifier._joint_inputs
+    concatenate = np.concatenate
+
+    def counted_joint(self, sub, riders, lanes):
+        joint["packs"] += bool(window)
+        joint["inside"] = True
+        try:
+            return joint_inputs(self, sub, riders, lanes)
+        finally:
+            joint["inside"] = False
+
+    def counted_concatenate(*a, **kw):
+        joint["concatenates"] += joint["inside"]
+        return concatenate(*a, **kw)
+
+    monkeypatch.setattr(DeviceBatchVerifier, "_joint_inputs", counted_joint)
+    monkeypatch.setattr(np, "concatenate", counted_concatenate)
+    generic = metrics.get_counter(batch.PACK_GENERIC_KEY)
+
+    st = _run(n, 36, AdaptiveBatchVerifier, corrupt_share=0.0)
+    assert st.counts["failed"] == 0 and len(st.samples) == HEIGHTS
+    assert st.counts["flush_sizes"] == [1, n - 1, n] * HEIGHTS
+    node = st.committee.node
+    # The generic encoder: the node signing (and sending) its own PREPARE
+    # and COMMIT, and the peers' PREPREPARE on the host route.  No peer's
+    # PREPARE or COMMIT reaches it: 98 + 99 of them a height were packed
+    # (the node's own two come back through the ingress, and pack too).
+    assert {sender for sender, _ in encoded if sender != node} == {
+        t.proposer for t in st.labels
+    }
+    assert all(
+        kind == MessageType.PREPREPARE for sender, kind in encoded if sender != node
+    )
+    assert metrics.get_counter(batch.PACK_GENERIC_KEY) == generic
+    # Three calls a height, where the parent made 3 + 99 + 100.
+    assert [(sender == node, int(kind)) for sender, kind in encoded] == [
+        (False, MessageType.PREPREPARE),
+        (True, MessageType.PREPARE),
+        (True, MessageType.COMMIT),
+    ] * HEIGHTS
+    # The pack cache: per flood ONE lookup call and ONE store call, for the
+    # whole drain (99 PREPAREs, 100 COMMITs); never a call per message.
+    assert calls["lookup_many"] == calls["store_rows"] == [n - 1, n] * HEIGHTS
+    assert calls["store"] == calls["lookup"] == []
+    # Both floods of a height pack through the joint layout (256 lanes), and
+    # it joins nothing on the host: rows are written where they are sent.
+    assert joint == {"packs": 2 * HEIGHTS, "inside": False, "concatenates": 0}
+    packs = [
+        (r[5]["kind"], r[5]["lanes"], r[5].get("templated"))
+        for r in st.records
+        if r[:2] == ("X", "verify.pack") and "kind" in r[5]
+    ]
+    assert packs == [
+        ("senders", n - 1, n - 1),
+        ("senders", n, n),
+        ("seals", n, None),
+    ] * HEIGHTS

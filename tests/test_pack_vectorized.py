@@ -425,3 +425,323 @@ def test_pack_cache_evict_on_quarantine():
     # evicting an uncached message is a no-op, not an error
     cache.evict(prepares[1])
     assert len(cache) == 2
+
+
+# -- rows built in bulk (ISSUE 36) --------------------------------------------
+# The joint layouts of ``DeviceBatchVerifier.verify_senders`` are held, bit
+# for bit, to the reference loop packers: what a dispatch receives is the
+# envelope rows of ``_pack_sender_batch_reference`` and the seal rows of
+# ``_pack_seal_batch_reference``.  No signature here is valid, and none has
+# to be: a packer moves bytes.
+
+
+def _flood(n, kind, seed=5):
+    """``n`` COMMITs (or PREPAREs) of one height over one hash, random bytes
+    in every signed field; returns ``(msgs, phash)``."""
+    from go_ibft_tpu.messages.wire import (
+        CommitMessage,
+        IbftMessage,
+        MessageType,
+        PrepareMessage,
+    )
+
+    rng = random.Random(seed * 1000 + n)
+    phash = rng.randbytes(32)
+    msgs = []
+    for _ in range(n):
+        msg = IbftMessage(
+            view=View(height=3, round=0),
+            sender=rng.randbytes(20),
+            signature=rng.randbytes(SIG_BYTES),
+        )
+        if kind == "commit":
+            msg.type = MessageType.COMMIT
+            msg.commit_data = CommitMessage(
+                proposal_hash=phash, committed_seal=rng.randbytes(SIG_BYTES)
+            )
+        else:
+            msg.type = MessageType.PREPARE
+            msg.prepare_data = PrepareMessage(proposal_hash=phash)
+        msgs.append(msg)
+    return msgs, phash
+
+
+@pytest.fixture
+def captured(monkeypatch):
+    """A device verifier whose programs are stand-ins that keep what they
+    were handed: ``digests`` (blocks, counts) and ``dispatches`` (the six
+    arrays of a recover dispatch), in order.  The digest rows are the
+    blocks' first eight words, so that a row of ``zw`` names its message."""
+    import jax.numpy as jnp
+
+    from go_ibft_tpu.verify import DeviceBatchVerifier, batch
+
+    got = {"digests": [], "dispatches": []}
+
+    def digest(blocks, counts):
+        got["digests"].append((np.asarray(blocks), np.asarray(counts)))
+        return jnp.asarray(np.asarray(blocks).reshape(len(counts), -1)[:, :8])
+
+    def recover(zw, r, s, v, claimed, table, live):
+        got["dispatches"].append(
+            tuple(np.asarray(a) for a in (zw, r, s, v, claimed, live))
+        )
+        return np.asarray(live, dtype=bool)
+
+    monkeypatch.setattr(batch, "_digest_kernel", digest)
+    monkeypatch.setattr(batch, "_recover_kernel", recover)
+    powers = {bytes([i]) * 20: 1 for i in range(1, 5)}
+    got["verifier"] = DeviceBatchVerifier(lambda height: powers)
+    return got
+
+
+def _seals_of(commits):
+    return [
+        CommittedSeal(signer=m.sender, signature=m.commit_data.committed_seal)
+        for m in commits
+    ]
+
+
+@pytest.mark.parametrize("kind", ["commit", "prepare"])
+def test_joint_layout_of_a_100_validator_flood_is_the_reference_packers(
+    kind, captured
+):
+    """99 envelopes and (COMMITs) their 99 seals in ONE 256-lane dispatch:
+    lanes [0, 128) the reference sender pack, lanes [128, 256) the
+    reference seal pack (a PREPARE flood leaves that half dead)."""
+    msgs, phash = _flood(99, kind)
+    dev = captured["verifier"]
+    assert dev.verify_senders(msgs).all()
+    ((blocks, counts),) = captured["digests"]
+    (got,) = captured["dispatches"]
+    want = _pack_sender_batch_reference(msgs, pad_lanes=128)
+    _assert_tuples_identical((blocks, counts), want[:2])
+    seals = _seals_of(msgs) if kind == "commit" else []
+    hz, r2, s2, v2, signers, live2 = _pack_seal_batch_reference(
+        phash, seals, pad_lanes=128
+    )
+    hz[len(seals) :] = 0  # a dead lane carries no hash (pack_seal_lanes)
+    zw = np.concatenate([blocks.reshape(128, -1)[:, :8], hz])
+    joint = (zw,) + tuple(
+        np.concatenate([a, b]) for a, b in zip(want[2:], (r2, s2, v2, signers, live2))
+    )
+    assert joint[0].shape == (256, 8) and int(joint[5].sum()) == 99 + len(seals)
+    _assert_tuples_identical(got, joint)
+
+
+def test_joint_layout_of_a_300_validator_flood_is_the_reference_packers(captured):
+    """299 COMMITs fold already (512 lanes): their 299 seals are the drain's
+    next chunk, at 512 lanes too."""
+    msgs, phash = _flood(299, "commit")
+    dev = captured["verifier"]
+    assert dev.verify_senders(msgs).all()
+    ((blocks, counts),) = captured["digests"]
+    envelopes, seals = captured["dispatches"]
+    want = _pack_sender_batch_reference(msgs)
+    assert want[0].shape[0] == 512
+    _assert_tuples_identical((blocks, counts), want[:2])
+    _assert_tuples_identical(
+        envelopes, (blocks.reshape(512, -1)[:, :8],) + want[2:]
+    )
+    ref = list(_pack_seal_batch_reference(phash, _seals_of(msgs), pad_lanes=512))
+    ref[0][299:] = 0
+    _assert_tuples_identical(seals, tuple(ref))
+
+
+def test_joint_layout_with_cache_hits_riders_apart_and_odd_messages(captured):
+    """The same layout where some lanes are cache hits, only some COMMITs
+    ride, and a message of another shape goes through the generic encoder."""
+    from go_ibft_tpu.messages.wire import Proposal, PrePrepareMessage, MessageType
+    from go_ibft_tpu.utils import metrics
+    from go_ibft_tpu.verify import batch
+
+    commits, phash = _flood(20, "commit")
+    prepares, _ = _flood(5, "prepare", seed=6)
+    dev = captured["verifier"]
+    dev.verify_senders(commits[:7])  # lanes that will hit; their seals are judged
+    odd = commits[9]
+    odd.type = MessageType.PREPREPARE
+    odd.commit_data = None
+    odd.preprepare_data = PrePrepareMessage(
+        proposal=Proposal(raw_proposal=b"a block", round=0), proposal_hash=phash
+    )
+    commits[12].commit_data.committed_seal = b"\x01" * 64  # does not ride
+    msgs = prepares[:2] + commits + prepares[2:]
+    generic = metrics.get_counter(batch.PACK_GENERIC_KEY)
+    del captured["digests"][:], captured["dispatches"][:]
+    assert dev.verify_senders(msgs).all()
+    # The PREPREPARE and the COMMIT with a 64-byte seal: no plain shape.
+    assert metrics.get_counter(batch.PACK_GENERIC_KEY) == generic + 2
+    ((blocks, counts),) = captured["digests"]
+    (got,) = captured["dispatches"]
+    want = _pack_sender_batch_reference(msgs, pad_lanes=32)
+    _assert_tuples_identical((blocks, counts), want[:2])
+    riders = [m for m in commits[7:] if m is not odd and m is not commits[12]]
+    ref = list(_pack_seal_batch_reference(phash, _seals_of(riders), pad_lanes=32))
+    ref[0][len(riders) :] = 0
+    joint = (np.concatenate([blocks.reshape(32, -1)[:, :8], ref[0]]),) + tuple(
+        np.concatenate([a, b]) for a, b in zip(want[2:], ref[1:])
+    )
+    _assert_tuples_identical(got, joint)
+
+
+# -- the pack cache takes a drain's rows by reference -------------------------
+
+
+def _lane_of(pack):
+    return (
+        pack.payload,
+        pack.r_limbs.tolist(),
+        pack.s_limbs.tolist(),
+        pack.v,
+        pack.sender_words.tolist(),
+    )
+
+
+def _fresh_lanes(msgs):
+    _, _, r, s, v, senders, _ = _pack_sender_batch_reference(msgs)
+    payloads = [m.encode(include_signature=False) for m in msgs]
+    return [
+        (payloads[i], r[i].tolist(), s[i].tolist(), int(v[i]), senders[i].tolist())
+        for i in range(len(msgs))
+    ]
+
+
+def test_a_hit_after_a_by_reference_store_is_a_fresh_pack():
+    msgs, _ = _flood(9, "commit")
+    cache = PackCache()
+    calls = []
+    for name in ("store_rows", "lookup_many"):
+        orig = getattr(cache, name)
+        setattr(cache, name, lambda *a, _o=orig, _n=name: (calls.append(_n), _o(*a))[1])
+    pack_sender_batch(msgs, cache=cache)
+    # One lookup and one store for the drain, each under one lock; no lane
+    # is copied at store: an entry is the drain's arrays and a row index.
+    assert calls == ["lookup_many", "store_rows"] and len(cache) == 9
+    hits = cache.lookup_many(msgs)
+    assert [_lane_of(h) for h in hits] == _fresh_lanes(msgs)
+    assert all(h.r_limbs.base is not None for h in hits)  # views, not copies
+    assert (cache.hits, cache.misses) == (9, 9)
+    assert _lane_of(cache.lookup(msgs[4])) == _fresh_lanes(msgs)[4]
+
+
+def test_a_later_drain_leaves_an_earlier_drains_cached_rows_as_they_were(captured):
+    """The drain's arrays are never written after they are packed: here
+    they are made read-only the moment the dispatch has them, and every
+    later pack (hits among fresh lanes, another drain, the same lanes in
+    another order) still runs and still reads the first drain's lanes."""
+    dev = captured["verifier"]
+    cache = dev._pack_cache
+    first, _ = _flood(11, "commit")
+    second, _ = _flood(11, "prepare", seed=8)
+    stored, frozen = [], []
+    store_rows = cache.store_rows
+
+    def noting_store(msgs, rows, at):
+        for a in (rows.r_limbs, rows.s_limbs, rows.v, rows.sender_words):
+            stored.append(a if a.base is None else a.base)
+        store_rows(msgs, rows, at)
+
+    def freeze():  # every array a finished drain left in the cache
+        while stored:
+            base = stored.pop()
+            base.setflags(write=False)
+            frozen.append((base, base.copy()))
+
+    cache.store_rows = noting_store
+    dev.verify_senders(first)
+    freeze()
+    assert len(frozen) == 4 and all(base.shape[0] == 64 for base, _ in frozen)
+    want = _fresh_lanes(first)
+    assert [_lane_of(h) for h in cache.lookup_many(first)] == want
+    dev.verify_senders(second)
+    freeze()
+    dev.verify_senders(first[5:] + second[:3] + _flood(4, "commit", seed=9)[0])
+    freeze()
+    dev.verify_senders(list(reversed(first)))
+    pack_sender_batch(first[2:6], cache=cache, pad_lanes=64)
+    freeze()
+    assert len(frozen) == 12  # the two all-hit packs stored nothing
+    for base, was in frozen:
+        assert np.array_equal(base, was)
+    assert [_lane_of(h) for h in cache.lookup_many(first)] == want
+    # And what a dispatch received for the reversed drain is a fresh pack's.
+    got = captured["dispatches"][-1]
+    ref = _pack_sender_batch_reference(list(reversed(first)), pad_lanes=32)
+    _assert_tuples_identical(tuple(a[:32] for a in got[1:]), ref[2:])
+
+
+def test_by_reference_entries_guard_a_recycled_id():
+    cache = PackCache()
+    (victim, other), _ = _flood(2, "prepare", seed=11)
+    pack_sender_batch([victim, other], cache=cache)
+    mid = id(victim)
+    del victim
+    gc.collect()
+    # No death callback ran: the entry is there until the cache is counted.
+    assert mid in cache._index
+    # A new message at the dead one's address (here: the dead entry re-keyed
+    # under a live message's id, which is what a recycled id looks like) ...
+    (fresh,), _ = _flood(1, "prepare", seed=12)
+    tag = cache._index.pop(mid)
+    cache._index[id(fresh)] = tag
+    cache._by_round[tag][id(fresh)] = cache._by_round[tag].pop(mid)
+    # ... never reads the dead one's rows, and takes the entry over.
+    assert cache.lookup(fresh) is None
+    pack_sender_batch([fresh], cache=cache)
+    assert _lane_of(cache.lookup(fresh)) == _fresh_lanes([fresh])[0]
+    assert _lane_of(cache.lookup(other)) == _fresh_lanes([other])[0]
+    assert len(cache) == 2
+    # Counting drops what died.
+    del fresh
+    gc.collect()
+    assert len(cache._index) == 2 and len(cache) == 1 == len(cache._index)
+    assert cache.lookup(other) is not None
+
+
+def test_by_reference_stores_keep_evict_cap_and_owner_order():
+    """``evict``, the cap and the per-owner eviction order, with entries
+    stored a drain at a time (the single-lane cases above and in
+    ``tests/test_sched.py`` stay as they are)."""
+    cache = PackCache(cap=6)
+    drains = {}
+
+    def drain(name, n, owner, seed):
+        msgs, _ = _flood(n, "prepare", seed=seed)
+        drains[name] = msgs
+        with cache.owned(owner):
+            pack_sender_batch(msgs, cache=cache)
+
+    cache.note_round(3, owner="a")
+    cache.note_round(8, owner="b")
+    drain("a3", 3, "a", 21)
+    drain("b8", 3, "b", 22)
+    assert len(cache) == 6
+    cache.evict(drains["a3"][1])  # quarantine: one lane of a drain goes
+    assert len(cache) == 5 and cache.lookup(drains["a3"][1]) is None
+    assert cache.lookup(drains["a3"][0]) is not None
+    cache.evict(drains["a3"][1])  # twice is a no-op
+    assert len(cache) == 5
+    # A's rotation kills its round-3 entries; cap pressure takes them whole,
+    # before either owner's live round gives anything up.
+    cache.note_round(4, owner="a")
+    drain("b8+", 2, "b", 23)
+    assert all(cache.lookup(m) is None for m in drains["a3"])
+    assert all(cache.lookup(m) is not None for m in drains["b8"] + drains["b8+"])
+    assert len(cache) == 5
+    # Only live rounds left: the oldest live round sheds, first in first out.
+    drain("a4", 3, "a", 24)
+    assert len(cache) == 6
+    assert [cache.lookup(m) is None for m in drains["a4"]] == [True, True, False]
+    assert all(cache.lookup(m) is not None for m in drains["b8"] + drains["b8+"])
+    # One owner's reset drops its entries only.
+    cache.clear(owner="b")
+    assert len(cache) == 1 and cache.lookup(drains["a4"][2]) is not None
+    # A message stored again moves to its new drain's rows.
+    again = drains["a4"][2]
+    again.signature = bytes(SIG_BYTES)
+    assert cache.lookup(again) is None  # the token guards the old rows
+    with cache.owned("a"):
+        pack_sender_batch([again], cache=cache)
+    assert len(cache) == 1
+    assert _lane_of(cache.lookup(again)) == _fresh_lanes([again])[0]

@@ -78,7 +78,7 @@ ab-keccak:
 
 # Runtime cost-ledger smoke (ISSUE 14, fast-tier CI): a small host-route
 # drain with the ledger on must render the per-program report (top
-# programs by device time, live-vs-padded occupancy, compile table) with
+# programs by wall time, live-vs-padded occupancy, compile table) with
 # every pinned compile-budget family that ran appearing in it.
 cost-report:
 	JAX_PLATFORMS=cpu python scripts/cost_report.py --drain --check

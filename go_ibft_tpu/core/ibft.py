@@ -56,6 +56,13 @@ DEFAULT_BASE_ROUND_TIMEOUT = 10.0
 # is on — the same one-predicate disabled posture as the tracer.
 ACCEPT_FINALIZE_MS_KEY = ("go-ibft", "latency", "accept_finalize_ms")
 
+# The message type whose quorum signal wakes each state's coroutine.
+_AWAITED = {
+    StateName.NEW_ROUND: MessageType.PREPREPARE,
+    StateName.PREPARE: MessageType.PREPARE,
+    StateName.COMMIT: MessageType.COMMIT,
+}
+
 _ROUND_FACTOR_BASE = 2.0
 
 # Exponent cap for the round-timeout formula: 2.0**round_ raises
@@ -192,6 +199,11 @@ class IBFT:
         # accept -> finalize latency anchor (set by _accept_proposal,
         # consumed by _insert_block into ACCEPT_FINALIZE_MS_KEY).
         self._accept_ts: Optional[float] = None
+        # The open ``engine.wake`` / ``engine.teardown`` span (``trace.begin``:
+        # opened where a quorum is signalled / the round is done, closed by
+        # the coroutine that wakes); the shared no-op while none is open.
+        self._wake = trace.NULL_SPAN
+        self._teardown = trace.NULL_SPAN
         # Memoized is_valid_proposal_hash verdicts for the ACCEPTED proposal
         # (cleared whenever it changes): a prepare/commit drain checks the
         # carried hash once per message per wakeup, and the backend call
@@ -617,6 +629,11 @@ class IBFT:
             elif name == StateName.COMMIT:
                 done = await self._run_commit()
             else:  # FIN
+                self._teardown = trace.begin(
+                    "engine.teardown",
+                    track=self._obs_track,
+                    height=self.state.height,
+                )
                 signals.fire(signals.round_done)
                 return
             if done:
@@ -639,8 +656,12 @@ class IBFT:
                 wake = await sub.wait()
                 if wake is None:
                     return True
+                self._woken()
                 with trace.span(
-                    "proposal.drain", track=self._obs_track, round=view.round
+                    "proposal.drain",
+                    track=self._obs_track,
+                    height=view.height,
+                    round=view.round,
                 ):
                     proposal_message = self._handle_preprepare(view)
                 if proposal_message is None:
@@ -675,8 +696,12 @@ class IBFT:
                 # are covered by the store re-read below — coalesce them
                 # instead of re-draining the phase once per signal.
                 sub.drain_pending()
+                self._woken()
                 with trace.span(
-                    "prepare.drain", track=self._obs_track, round=view.round
+                    "prepare.drain",
+                    track=self._obs_track,
+                    height=view.height,
+                    round=view.round,
                 ):
                     quorum = self._handle_prepare(view)
                 if not quorum:
@@ -703,8 +728,12 @@ class IBFT:
                 # repeat it (each repeat is crypto-free thanks to the seal
                 # verdict cache, but still walks the store).
                 sub.drain_pending()
+                self._woken()
                 with trace.span(
-                    "commit.drain", track=self._obs_track, round=view.round
+                    "commit.drain",
+                    track=self._obs_track,
+                    height=view.height,
+                    round=view.round,
                 ):
                     quorum = self._handle_commit(view)
                 if not quorum:
@@ -1476,8 +1505,9 @@ class IBFT:
         if trace.enabled():
             for m in batch:
                 self._record_recv(m)
+        height = self.state.height
         with trace.span(
-            "ingress.batch", track=self._obs_track, lanes=len(batch)
+            "ingress.batch", track=self._obs_track, lanes=len(batch), height=height
         ):
             gated = []
             for m in batch:
@@ -1495,15 +1525,22 @@ class IBFT:
 
         # Store everything first, then signal once per (view, type) key —
         # signaling mid-batch could find quorum incomplete and never re-check.
-        to_signal: dict[tuple[int, int, int], tuple[View, object]] = {}
-        for message in accepted:
-            self.messages.add_message(message)
-            if message.view is not None:
-                key = (message.view.height, message.view.round, int(message.type))
-                to_signal.setdefault(key, (message.view, message.type))
-        self._speculate(accepted)
-        for view, message_type in to_signal.values():
-            self._signal_if_quorum(view, message_type)
+        with trace.span(
+            "engine.store", track=self._obs_track, lanes=len(accepted), height=height
+        ):
+            to_signal: dict[tuple[int, int, int], tuple[View, object]] = {}
+            for message in accepted:
+                self.messages.add_message(message)
+                if message.view is not None:
+                    key = (
+                        message.view.height,
+                        message.view.round,
+                        int(message.type),
+                    )
+                    to_signal.setdefault(key, (message.view, message.type))
+            self._speculate(accepted)
+            for view, message_type in to_signal.values():
+                self._signal_if_quorum(view, message_type)
 
     def add_verified_messages(self, batch: Sequence[IbftMessage]) -> None:
         """Store messages whose envelope signatures the caller has ALREADY
@@ -1706,6 +1743,26 @@ class IBFT:
         msgs = self.messages.get_valid_messages(view, message_type, lambda _m: True)
         if self._has_quorum_by_msg_type(msgs, message_type):
             self.messages.signal_event(message_type, view)
+            # ``engine.wake``: from here to the drain of the state coroutine
+            # this wakes (a loop turn, and whatever runs in it).  Opened only
+            # for the type the current state waits on (a late PREPARE flush
+            # in COMMIT wakes nobody), once per wake-up.
+            if (
+                self._wake is trace.NULL_SPAN
+                and _AWAITED.get(self.state.name) == message_type
+            ):
+                self._wake = trace.begin(
+                    "engine.wake",
+                    track=self._obs_track,
+                    type=int(message_type),
+                    height=view.height,
+                )
+
+    def _woken(self) -> None:
+        """A state coroutine is about to drain: close the ``engine.wake``
+        span the quorum signal opened."""
+        self._wake.end()
+        self._wake = trace.NULL_SPAN
 
     def _is_acceptable_message(self, message: IbftMessage) -> bool:
         """Inbound acceptance gate (reference core/ibft.go:1126-1149).
@@ -1785,6 +1842,7 @@ class IBFT:
     def _move_to_new_round(self, round_: int) -> None:
         """(reference core/ibft.go:994-1003)"""
         trace.instant("round.change", track=self._obs_track, round=round_)
+        self._woken()  # a wake-up the expired round's coroutine never took
         self._hash_memo.clear()
         # Round advance drives the pack cache's oldest-round-first eviction
         # (entries packed for dead rounds yield before the live round's).
@@ -1821,21 +1879,29 @@ class IBFT:
         WAL that cannot append must stop the height from pruning the only
         other copy of its evidence (chaos kill-point test pins this)."""
         height = self.state.height
-        proposal = Proposal(
-            raw_proposal=self.state.raw_proposal or b"",
+        self._teardown.end()
+        self._teardown = trace.NULL_SPAN
+        with trace.span(
+            "engine.finalize",
+            track=self._obs_track,
+            height=height,
             round=self.state.round,
-        )
-        seals = self.state.committed_seals
-        if self._accept_ts is not None:
-            metrics.observe_fixed(
-                ACCEPT_FINALIZE_MS_KEY,
-                (time.perf_counter() - self._accept_ts) * 1e3,
+        ):
+            proposal = Proposal(
+                raw_proposal=self.state.raw_proposal or b"",
+                round=self.state.round,
             )
-            self._accept_ts = None
-        self.backend.insert_proposal(proposal, seals)
-        if self.on_finalize is not None:
-            self.on_finalize(height, proposal, seals)
-        self.messages.prune_by_height(height)
+            seals = self.state.committed_seals
+            if self._accept_ts is not None:
+                metrics.observe_fixed(
+                    ACCEPT_FINALIZE_MS_KEY,
+                    (time.perf_counter() - self._accept_ts) * 1e3,
+                )
+                self._accept_ts = None
+            self.backend.insert_proposal(proposal, seals)
+            if self.on_finalize is not None:
+                self.on_finalize(height, proposal, seals)
+            self.messages.prune_by_height(height)
 
     # -- outbound (reference core/ibft.go:1234-1270) ------------------------
 
@@ -1885,12 +1951,20 @@ class IBFT:
             )
         )
 
+    def _send_own(self, message_type: MessageType, build, view: View) -> None:
+        """Build, sign and multicast the node's own PREPARE or COMMIT."""
+        with trace.span(
+            "engine.send",
+            track=self._obs_track,
+            type=int(message_type),
+            height=view.height,
+        ):
+            self._multicast(build(self.state.proposal_hash or b"", view))
+
     def _send_prepare_message(self, view: View) -> None:
-        self._multicast(
-            self.backend.build_prepare_message(self.state.proposal_hash or b"", view)
+        self._send_own(
+            MessageType.PREPARE, self.backend.build_prepare_message, view
         )
 
     def _send_commit_message(self, view: View) -> None:
-        self._multicast(
-            self.backend.build_commit_message(self.state.proposal_hash or b"", view)
-        )
+        self._send_own(MessageType.COMMIT, self.backend.build_commit_message, view)

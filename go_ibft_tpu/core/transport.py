@@ -169,6 +169,11 @@ class BatchingIngress:
         self._recent: deque = deque()
         self._recent_n = 0
         self._held_back = False
+        # ``ingress.wait``: from the submit that found the buffer empty to
+        # the flush that hands the burst over, with why the flush came when
+        # it did (``soon`` / ``window`` / ``held_back``; ``cut`` at flush).
+        self._wait = trace.NULL_SPAN
+        self._why = "soon"
 
     def _trim_recent(self, now: float) -> None:
         while self._recent and now - self._recent[0][0] > self.max_delay:
@@ -190,6 +195,9 @@ class BatchingIngress:
         return window
 
     def submit(self, message: IbftMessage) -> None:
+        if not self._buffer:
+            # Once a burst, not once a message: a flood submits hundreds.
+            self._wait = trace.begin("ingress.wait")
         self._buffer.append(message)
         if self.calibrator is not None:
             self.calibrator.observe()
@@ -198,11 +206,14 @@ class BatchingIngress:
         elif self._handle is None:
             loop = asyncio.get_running_loop()
             self._trim_recent(time.monotonic())
+            self._why = "soon"
             if self._held_back:
+                self._why = "held_back"
                 self._handle = loop.call_later(self.max_delay, self.flush)
             elif self._recent_n + len(self._buffer) >= self.eager_cutover:
                 window = self._window()
                 if window > 0:
+                    self._why = "window"
                     self._handle = loop.call_later(window, self.flush)
                 else:
                     self._handle = loop.call_soon(self.flush)
@@ -221,13 +232,19 @@ class BatchingIngress:
         if not self._buffer:
             return
         batch, self._buffer = self._buffer, []
+        # The burst's height, for the spans: that of its first message.
+        view = getattr(batch[0], "view", None)
+        height = view.height if view is not None else None
+        self._wait.end(
+            lanes=len(batch), why="cut" if cut else self._why, height=height
+        )
         now = time.monotonic()
         self._recent.append((now, len(batch)))
         self._recent_n += len(batch)
         self._trim_recent(now)
         # ``cut``: ``max_batch`` fired this flush in the middle of a burst
         # (the engine's ``ingress.batch`` span cannot know why it was fed).
-        with trace.span("ingress.flush", lanes=len(batch), cut=cut):
+        with trace.span("ingress.flush", lanes=len(batch), cut=cut, height=height):
             self._add_messages(batch)
         self._held_back = (
             time.monotonic() - now > self.HELD_BACK_FACTOR * self.max_delay
@@ -238,4 +255,6 @@ class BatchingIngress:
         if self._handle is not None:
             self._handle.cancel()
             self._handle = None
+        if self._buffer:
+            self._wait.end(lanes=len(self._buffer), why="closed")
         self._buffer.clear()

@@ -10,11 +10,15 @@
   timeline (``scripts/chaos_replay.py --trace``; ``benchmark/run.py`` reads
   the ring itself).
 * :mod:`~go_ibft_tpu.obs.ledger` / :mod:`~go_ibft_tpu.obs.devprof` —
-  the runtime cost ledger (ISSUE 14): per-dispatch device-time
+  the runtime cost ledger (ISSUE 14): per-dispatch wall-time
   attribution keyed by compile-budget program names, live-vs-padded
   lane occupancy, compile-event tracing into ``compile_ledger.jsonl``,
   and on-demand ``jax.profiler`` windows (``/profilez``) merged into the
   Perfetto timeline.
+* :mod:`~go_ibft_tpu.obs.idle` — the device's idle time charged to the
+  span the host had open, and each span's self time, from one profiler
+  window: while tracing is enabled every span is also a profiler event on
+  the device events' clock (``scripts/idle_report.py``).
 * :mod:`~go_ibft_tpu.obs.gates` — SLO gates: the soaks' records graded
   against absolute limits (``scripts/slo_gates.py`` / ``make slo-gates``).
 """

@@ -2,22 +2,26 @@
 
 The cost ledger (:mod:`go_ibft_tpu.obs.ledger`) attributes *wall* time
 per program; this module captures what the device itself was doing —
-a ``jax.profiler`` window whose Chrome-format output
-(``*.trace.json.gz``) merges into the PR-11 Perfetto document via
-:func:`go_ibft_tpu.obs.timeline.merge_device_trace`, so ONE file shows
-consensus phases over host spans over device ops.
+a ``jax.profiler`` window.  The program's spans are IN that window's
+file: :func:`capture` turns the tracer's bridge on for its duration
+(:func:`go_ibft_tpu.obs.trace.bridged`), flight recorder or no flight
+recorder, so every ``trace.span`` is an ``ibft:<name>`` event in the
+xplane's host plane, on the device events' clock.
+``scripts/idle_report.py <dir>`` (:mod:`go_ibft_tpu.obs.idle`) charges the
+device's idle time to them; the Chrome-format output (``*.trace.json.gz``)
+also merges into the PR-11 Perfetto document via
+:func:`go_ibft_tpu.obs.timeline.merge_device_trace`.
 
 One entry point, :func:`capture`: a fixed-length window (the ``/profilez``
 endpoint: ``GET /profilez?seconds=0.5`` on a live
 :class:`~go_ibft_tpu.obs.httpd.TelemetryServer`).
 
-It stamps ``host_anchor_us`` — the flight recorder's monotonic
-microsecond clock read immediately after ``start_trace`` — so the merge
-can rebase device timestamps (which are relative to the profiler
-session) onto the exported host trace's clock.  Alignment is anchor-
-based and therefore approximate to within the ``start_trace`` call
-overhead (sub-millisecond); the per-track orderings inside either source
-stay exact.
+It also stamps ``host_anchor_us`` — the flight recorder's monotonic
+microsecond clock read after ``start_trace`` returned — which the merge
+falls back on where the window and the host document share no span.  It
+is a guess: the device's op tracing goes live 2 to 13 ms after
+``start_trace`` returns (PERF.md §6, PR 33), and nothing ties the
+profiler's zero to that read.
 
 The profiler is a process-global singleton in jax: captures serialize on
 a module lock, and a second concurrent request reports ``busy`` instead
@@ -35,6 +39,8 @@ import tempfile
 import threading
 import time
 from typing import Optional
+
+from . import trace
 
 __all__ = ["capture", "newest_trace"]
 
@@ -102,7 +108,9 @@ def capture(seconds: float = 0.5, out_dir: Optional[str] = None) -> dict:
     Returns ``{"ok", "dir", "path", "host_anchor_us", "seconds"}`` —
     ``path`` is the Chrome-format trace the window produced (None plus an
     ``error`` when the profiler is unavailable, already busy, or wrote
-    nothing).
+    nothing); the ``.xplane.pb`` that ``scripts/idle_report.py`` reads lies
+    beside it under ``dir``.  Program spans opened and closed inside the
+    window are in both.
 
     Without ``out_dir`` the capture lands in one per-process directory
     that is PRUNED before each new window — a scraper polling /profilez
@@ -114,12 +122,13 @@ def capture(seconds: float = 0.5, out_dir: Optional[str] = None) -> dict:
         return {"ok": False, "error": "busy: a profiler window is already open"}
     try:
         out_dir = out_dir or _default_capture_dir()
-        err = _start(out_dir)
-        if err is not None:
-            return {"ok": False, "error": err, "dir": out_dir}
-        anchor_us = time.perf_counter_ns() // 1000
-        time.sleep(seconds)
-        err = _stop()
+        with trace.bridged():
+            err = _start(out_dir)
+            if err is not None:
+                return {"ok": False, "error": err, "dir": out_dir}
+            anchor_us = time.perf_counter_ns() // 1000
+            time.sleep(seconds)
+            err = _stop()
         if err is not None:
             return {"ok": False, "error": err, "dir": out_dir}
         path = newest_trace(out_dir)

@@ -25,8 +25,10 @@ a given host) and serves:
   ``cost_ledger`` block (dispatch/occupancy/compile totals) whenever the
   runtime cost ledger is on;
 * ``GET /profilez?seconds=0.5`` — an on-demand ``jax.profiler`` window
-  (:mod:`go_ibft_tpu.obs.devprof`): captures device activity for the
-  given window and returns the trace path + host-clock anchor, ready for
+  (:mod:`go_ibft_tpu.obs.devprof`): captures device activity AND the
+  program's spans (``ibft:<span>`` events on the device's clock, flight
+  recorder or no flight recorder) for the given window and returns its
+  directory and trace path, ready for ``scripts/idle_report.py`` and
   ``obs/timeline.py::merge_device_trace``.  409 when a window is already
   open, 503 when the profiler is unavailable.  The ONLY non-read-only
   endpoint — it writes a trace file to a temp dir, never touches

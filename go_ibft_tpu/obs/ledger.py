@@ -1,5 +1,10 @@
-"""Process-wide runtime cost ledger: per-dispatch device-time attribution,
+"""Process-wide runtime cost ledger: per-dispatch wall-time attribution,
 compile-event tracing, and occupancy accounting.
+
+``wall_ms`` is HOST wall time around a dispatch's blocking part, recorded on
+every route (host and python included): what the device itself spent comes
+from a profiler trace alone (``benchmark/lib/trace_reduce.py``,
+``obs/idle.py``).
 
 ISSUE 14 tentpole.  Nine rounds could say *what* ran but not *where
 device time or padding went*: the verify plane buckets lanes to power-of-
@@ -62,7 +67,7 @@ __all__ = [
     "enabled",
     "get",
     "record_dispatch",
-    "add_device_ms",
+    "add_wall_ms",
     "record_compile",
     "dispatch_span",
     "compile_watch",
@@ -114,7 +119,7 @@ class CostLedger:
         max_programs: int = DEFAULT_MAX_PROGRAMS,
     ) -> None:
         self._lock = threading.Lock()
-        # (program, route) -> [dispatches, live_lanes, padded_lanes, device_ms]
+        # (program, route) -> [dispatches, live_lanes, padded_lanes, wall_ms]
         self._stats: Dict[Tuple[str, str], list] = {}
         # program -> [compiles, compile_ms]
         self._compiles: Dict[str, list] = {}
@@ -161,7 +166,7 @@ class CostLedger:
             slot[2] += int(padded)
             slot[3] += float(ms)
 
-    def add_device_ms(self, program: str, route: str, ms: float) -> None:
+    def add_wall_ms(self, program: str, route: str, ms: float) -> None:
         """Attribute block-until-ready time to an already-recorded
         dispatch (the async-pipeline path: queue time and wait time are
         observed at different seams)."""
@@ -230,7 +235,7 @@ class CostLedger:
 
     def snapshot(self) -> dict:
         """Full ledger state: per-(program, route) dispatch rows (sorted
-        by device time, descending) + per-program compile accumulators."""
+        by wall time, descending) + per-program compile accumulators."""
         with self._lock:
             rows = [
                 {
@@ -239,7 +244,7 @@ class CostLedger:
                     "dispatches": d,
                     "live_lanes": live,
                     "padded_lanes": padded,
-                    "device_ms": round(ms, 3),
+                    "wall_ms": round(ms, 3),
                     "occupancy": round(live / padded, 4) if padded else None,
                 }
                 for (program, route), (d, live, padded, ms) in self._stats.items()
@@ -249,7 +254,7 @@ class CostLedger:
                 for program, (c, ms) in self._compiles.items()
             }
             overflowed = self.overflowed
-        rows.sort(key=lambda r: (-r["device_ms"], -r["dispatches"]))
+        rows.sort(key=lambda r: (-r["wall_ms"], -r["dispatches"]))
         return {
             "dispatches": rows,
             "compiles": compiles,
@@ -286,14 +291,14 @@ class CostLedger:
             "dispatches": d,
             "live_lanes": live,
             "padded_lanes": padded,
-            "device_ms": round(ms, 3),
+            "wall_ms": round(ms, 3),
             "compiles": compiles,
             "compile_ms": round(compile_ms, 3),
         }
 
     def status(self) -> dict:
         """Compact /statusz block: totals + occupancy + the top program
-        by attributed device time."""
+        by attributed wall time."""
         t = self.totals()
         t["occupancy"] = (
             round(t["live_lanes"] / t["padded_lanes"], 4)
@@ -309,7 +314,7 @@ class CostLedger:
             ]
             top = max(production, key=lambda kv: kv[1][3], default=None)
         t["top_program"] = (
-            {"program": top[0][0], "route": top[0][1], "device_ms": round(top[1][3], 3)}
+            {"program": top[0][0], "route": top[0][1], "wall_ms": round(top[1][3], 3)}
             if top is not None and top[1][3] > 0
             else None
         )
@@ -365,11 +370,11 @@ def record_dispatch(
     led.record_dispatch(program, route, live, padded, ms)
 
 
-def add_device_ms(program: str, route: str, ms: float) -> None:
+def add_wall_ms(program: str, route: str, ms: float) -> None:
     led = _ledger
     if led is None:
         return
-    led.add_device_ms(program, route, ms)
+    led.add_wall_ms(program, route, ms)
 
 
 def record_compile(
@@ -559,10 +564,10 @@ def dispatch_span(
     (jit tracing + XLA compilation run synchronously inside the call, so
     a cache that grew inside the span means this span paid the compile
     and its wall time measures it).  ``block=True`` adds the span's wall
-    time to the program's device_ms (use when the span covers the
+    time to the program's wall_ms (use when the span covers the
     blocking readback); ``block=False`` records the dispatch without
     timing (async queue seams — the readback seam adds the wait via
-    :func:`add_device_ms`).
+    :func:`add_wall_ms`).
     """
     led = _ledger
     if led is None:

@@ -157,7 +157,7 @@ def _render_ledger(lines: List[str], emit_type) -> None:
         ("go_ibft_ledger_dispatches_total", "dispatches", "counter"),
         ("go_ibft_ledger_lanes_live_total", "live_lanes", "counter"),
         ("go_ibft_ledger_lanes_padded_total", "padded_lanes", "counter"),
-        ("go_ibft_ledger_device_ms_total", "device_ms", "counter"),
+        ("go_ibft_ledger_wall_ms_total", "wall_ms", "counter"),
         ("go_ibft_ledger_occupancy", "occupancy", "gauge"),
     ):
         for row, label in zip(rows, labels):

@@ -534,19 +534,29 @@ def merge_device_trace(
     over host spans over device ops — the cost-ledger drill-down view.
 
     Clock alignment: device timestamps are relative to the profiler
-    session start; ``host_anchor_us`` (the devprof capture's anchor — the
-    flight recorder's monotonic µs clock read at ``start_trace``) minus
-    the document's ``otherData.clockBaseUs`` rebases them onto the host
-    document's clock.  Without either anchor the device group merges
-    unshifted, ordered internally but not aligned (flagged in
-    ``otherData.deviceTraceAligned``).
+    session start.  While tracing is enabled every span is also a profiler
+    event ``ibft:<name>`` that carries ``ts_us``, the ring's start of the
+    same span (:mod:`go_ibft_tpu.obs.trace`); each such event whose span
+    ``doc`` holds too (same name, same start against the document's
+    ``otherData.clockBaseUs``) gives the shift exactly, and the median over
+    them is taken (``otherData.deviceTraceShiftSource`` ``"spans"``,
+    ``deviceTraceMatchedSpans`` how many, ``deviceTraceShiftSpreadUs`` how
+    far they disagree).  Where no span matched (a window taken with the
+    flight recorder off, an export that wrapped past it) the shift falls
+    back on ``host_anchor_us`` (the devprof capture's clock read after
+    ``start_trace``, ``"anchor"``): a guess, milliseconds off where the
+    profiler came up slowly.  Without either the device group merges
+    unshifted, ordered internally but not aligned (``"none"``;
+    ``otherData.deviceTraceAligned`` false).
 
-    The profiler's Python-frame events (names starting ``$``) duplicate
-    what the flight recorder's spans already show and dominate the file
-    size; they are dropped unless ``keep_python_frames``.  Mutates and
-    returns ``doc``.
+    The profiler's Python-frame events (names starting ``$``) and its
+    copies of spans ``doc`` already holds are dropped (the former unless
+    ``keep_python_frames``).  Mutates and returns ``doc``.
     """
     import gzip
+    import statistics
+
+    from .trace import ANNOTATION_PREFIX
 
     opener = gzip.open if device_trace_path.endswith(".gz") else open
     with opener(device_trace_path, "rt") as fh:
@@ -554,12 +564,37 @@ def merge_device_trace(
 
     other = doc.setdefault("otherData", {})
     base = other.get("clockBaseUs")
-    shift = 0
-    aligned = host_anchor_us is not None and base is not None
-    if aligned:
-        shift = int(host_anchor_us) - int(base)
-
     events = doc.setdefault("traceEvents", [])
+
+    # The spans both documents hold: (name, start on doc's clock).
+    held = {
+        (e.get("name"), e.get("ts")) for e in events if e.get("ph") == "X"
+    }
+    shifts = []
+    matched = set()  # ids of the device document's copies
+    if base is not None:
+        for e in device_doc.get("traceEvents", []):
+            name = e.get("name", "")
+            if e.get("ph") != "X" or not name.startswith(ANNOTATION_PREFIX):
+                continue
+            ts_us = (e.get("args") or {}).get("ts_us")
+            if ts_us is None:
+                continue
+            on_doc = int(ts_us) - int(base)
+            if (name[len(ANNOTATION_PREFIX) :], on_doc) in held:
+                shifts.append(on_doc - e.get("ts", 0))
+                matched.add(id(e))
+    if shifts:
+        shift = statistics.median(shifts)
+        source = "spans"
+        other["deviceTraceShiftSpreadUs"] = max(shifts) - min(shifts)
+    elif host_anchor_us is not None and base is not None:
+        shift = int(host_anchor_us) - int(base)
+        source = "anchor"
+    else:
+        shift = 0
+        source = "none"
+
     pid_base = max((e.get("pid", 0) for e in events), default=0) + 1
     pid_map: Dict[int, int] = {}
     merged = 0
@@ -577,7 +612,7 @@ def merge_device_trace(
                 out["args"] = args
             events.append(out)
             continue
-        if ph != "X":
+        if ph != "X" or id(e) in matched:
             continue
         name = e.get("name", "")
         if name.startswith("$") and not keep_python_frames:
@@ -587,6 +622,9 @@ def merge_device_trace(
         events.append(out)
         merged += 1
     other["deviceTrace"] = device_trace_path
-    other["deviceTraceAligned"] = aligned
+    other["deviceTraceAligned"] = source != "none"
+    other["deviceTraceShiftUs"] = shift
+    other["deviceTraceShiftSource"] = source
+    other["deviceTraceMatchedSpans"] = len(shifts)
     other["deviceTraceEvents"] = merged
     return doc

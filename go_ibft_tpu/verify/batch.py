@@ -1453,7 +1453,7 @@ class DeviceBatchVerifier:
         # The dispatch record itself landed in _dispatch_async (block=False
         # — queue time only); the synchronous path knows the full
         # block-until-ready wall, so attribute it here.
-        cost_ledger.add_device_ms(
+        cost_ledger.add_wall_ms(
             self._program_of(quorum_args), self._route, dt_ms
         )
         return mask, reached

@@ -148,7 +148,7 @@ def _aggregate_check_device(proposal_hash, points, pubkeys) -> bool:
     # The dispatch record landed inside aggregate_verify_commit
     # (block=False — it returns a device future); THIS is the seam that
     # blocks on the verdict, so it attributes the pairing's wall time.
-    cost_ledger.add_device_ms("bls_aggregate_verify", "device", dt_ms)
+    cost_ledger.add_wall_ms("bls_aggregate_verify", "device", dt_ms)
     return out
 
 

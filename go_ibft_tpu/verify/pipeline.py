@@ -287,7 +287,7 @@ class VerifyPipeline:
             wait_s += dt
             metrics.observe(READBACK_WAIT_MS_KEY, dt * 1e3)
             if self.ledger_key is not None:
-                cost_ledger.add_device_ms(
+                cost_ledger.add_wall_ms(
                     self.ledger_key[0], self.ledger_key[1], dt * 1e3
                 )
 

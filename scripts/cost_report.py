@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Runtime cost report: top programs by device time, waste, compile cost.
+"""Runtime cost report: top programs by wall time, waste, compile cost.
 
 The read side of the ISSUE 14 cost ledger.  One source is required:
 
@@ -113,7 +113,7 @@ def render_snapshot(snap: dict, *, top: int = 20, families=None) -> str:
     rows = snap.get("dispatches", [])
     lines = []
 
-    lines.append(f"== top {min(top, len(rows))} programs by device time ==")
+    lines.append(f"== top {min(top, len(rows))} programs by wall time ==")
     table_rows = []
     for row in rows[:top]:
         waste = row["padded_lanes"] - row["live_lanes"]
@@ -126,7 +126,7 @@ def render_snapshot(snap: dict, *, top: int = 20, families=None) -> str:
                 row["padded_lanes"],
                 "-" if row["occupancy"] is None else f"{row['occupancy']:.3f}",
                 waste,
-                f"{row['device_ms']:.1f}",
+                f"{row['wall_ms']:.1f}",
                 "yes" if row["program"] in families else "NO",
             )
         )
@@ -140,7 +140,7 @@ def render_snapshot(snap: dict, *, top: int = 20, families=None) -> str:
                 "padded",
                 "occupancy",
                 "waste",
-                "device_ms",
+                "wall_ms",
                 "pinned",
             ),
             table_rows,

@@ -57,7 +57,7 @@ class FakeJit:
 def test_disabled_mode_records_nothing_and_shares_one_null_span():
     assert not ledger.enabled()
     ledger.record_dispatch("quorum_certify", "device", live=4, padded=8)
-    ledger.add_device_ms("quorum_certify", "device", 5.0)
+    ledger.add_wall_ms("quorum_certify", "device", 5.0)
     ledger.record_compile("quorum_certify", 100.0)
     assert ledger.snapshot() is None
     assert ledger.totals() is None
@@ -79,7 +79,7 @@ def test_dispatch_records_accumulate_with_occupancy():
     assert qc["dispatches"] == 2
     assert qc["live_lanes"] == 12 and qc["padded_lanes"] == 16
     assert qc["occupancy"] == pytest.approx(0.75)
-    assert qc["device_ms"] == pytest.approx(3.0)
+    assert qc["wall_ms"] == pytest.approx(3.0)
     assert by_key[("ecdsa_recover", "host")]["occupancy"] == 1.0
     totals = ledger.totals()
     assert totals["dispatches"] == 3
@@ -177,7 +177,7 @@ def test_dispatch_span_counts_mask_and_times_block():
         pass
     row = ledger.snapshot()["dispatches"][0]
     assert row["live_lanes"] == 2 and row["padded_lanes"] == 4
-    assert row["device_ms"] > 0  # block=True adds the span wall
+    assert row["wall_ms"] > 0  # block=True adds the span wall
 
 
 def test_dispatch_span_detects_compiles_and_logs_jsonl(tmp_path):
@@ -345,7 +345,7 @@ def test_sched_host_flush_parity_with_dispatch_observations():
     assert rows[0]["live_lanes"] == rows[0]["padded_lanes"] == 2
 
 
-def test_pipeline_readback_attributes_device_ms():
+def test_pipeline_readback_attributes_wall_ms():
     import time
 
     from go_ibft_tpu.verify.pipeline import VerifyPipeline
@@ -363,7 +363,7 @@ def test_pipeline_readback_attributes_device_ms():
         for r in ledger.snapshot()["dispatches"]
         if (r["program"], r["route"]) == ("ecdsa_recover", "device")
     ]
-    assert rows and rows[0]["device_ms"] >= 2.0
+    assert rows and rows[0]["wall_ms"] >= 2.0
 
 
 # ---------------------------------------------------------------------------
@@ -385,7 +385,7 @@ def test_metrics_exposition_renders_ledger_families():
     assert series[f"go_ibft_ledger_lanes_live_total{labels}"] == 6
     assert series[f"go_ibft_ledger_lanes_padded_total{labels}"] == 8
     assert series[f"go_ibft_ledger_occupancy{labels}"] == 0.75
-    assert series[f"go_ibft_ledger_device_ms_total{labels}"] == 2.5
+    assert series[f"go_ibft_ledger_wall_ms_total{labels}"] == 2.5
     assert series['go_ibft_ledger_compiles_total{program="quorum_certify"}'] == 1
     assert (
         series['go_ibft_ledger_compile_ms_total{program="quorum_certify"}']
@@ -416,7 +416,7 @@ def test_cost_report_renderer_and_attribution():
                 "dispatches": 19,
                 "live_lanes": 100,
                 "padded_lanes": 128,
-                "device_ms": 50.0,
+                "wall_ms": 50.0,
                 "occupancy": 0.781,
             },
             {
@@ -425,7 +425,7 @@ def test_cost_report_renderer_and_attribution():
                 "dispatches": 1,
                 "live_lanes": 1,
                 "padded_lanes": 1,
-                "device_ms": 1.0,
+                "wall_ms": 1.0,
                 "occupancy": 1.0,
             },
         ],
@@ -489,7 +489,7 @@ def test_statusz_carries_cost_ledger_block():
         "dispatches",
         "live_lanes",
         "padded_lanes",
-        "device_ms",
+        "wall_ms",
         "compiles",
         "compile_ms",
         "occupancy",

@@ -96,7 +96,9 @@ def test_a_burst_in_one_turn(n, kwargs, want):
     # The span says how many lanes it handed over and whether ``max_batch``
     # fired it (the timer and a direct flush() say False).
     assert [(a["lanes"], a["cut"]) for a in spans] == want
-    assert all(set(a) == {"lanes", "cut"} for a in spans)
+    # ``height`` is the burst's first message's (PR 37): these have none.
+    assert all(set(a) == {"lanes", "cut", "height"} for a in spans)
+    assert all(a["height"] is None for a in spans)
     # A fresh ingress flushes a first burst at the end of its turn.
     assert timed is False
 

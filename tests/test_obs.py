@@ -10,6 +10,7 @@ Pins the ISSUE 4 contracts:
 
 import json
 import pathlib
+import re
 import sys
 import threading
 import time
@@ -186,3 +187,66 @@ async def test_cluster_height_emits_per_node_tracks():
     assert "prepare.drain" in names and "commit.drain" in names
     node_tracks = {r[2] for r in records if r[1] == "round.start"}
     assert len(node_tracks) == 4  # one timeline row per validator
+
+
+# ---------------------------------------------------------------------------
+# span names: the code and docs/OBSERVABILITY.md's taxonomy, both ways
+# ---------------------------------------------------------------------------
+
+_REPO = pathlib.Path(__file__).resolve().parent.parent
+_SITE = re.compile(r'trace\.(?:span|instant|begin)\(\s*"([^"]+)"(\s*\+)?')
+
+
+def _names_in_the_code():
+    """Literal names at ``trace.span`` / ``instant`` / ``begin`` sites of the
+    package; a site that builds its name (``"chaos." + kind``) is a prefix."""
+    names, prefixes = set(), set()
+    for path in (_REPO / "go_ibft_tpu").rglob("*.py"):
+        for name, plus in _SITE.findall(path.read_text()):
+            (prefixes if plus else names).add(name)
+    return names, prefixes
+
+
+def _names_in_the_taxonomy():
+    """Every backticked name of the first column of the "Span taxonomy"
+    table; ``chaos.*`` is a prefix."""
+    text = (_REPO / "docs" / "OBSERVABILITY.md").read_text()
+    table = text.split("### Span taxonomy", 1)[1].split("\n\n", 2)[1]
+    names, prefixes = set(), set()
+    for row in table.splitlines()[2:]:
+        first = row.split("|")[1]
+        for name in re.findall(r"`([^`]+)`", first):
+            if name.endswith(".*"):
+                prefixes.add(name[:-1])
+            else:
+                names.add(name)
+    return names, prefixes
+
+
+def test_every_span_name_in_the_code_is_in_the_taxonomy():
+    code, built = _names_in_the_code()
+    documented, prefixes = _names_in_the_taxonomy()
+    assert len(code) > 60  # the pattern still finds the sites
+    missing = {
+        n for n in code - documented if not any(n.startswith(p) for p in prefixes)
+    }
+    assert not missing, f"span names with no taxonomy row: {sorted(missing)}"
+    assert built <= prefixes, f"built names with no `x.*` row: {built - prefixes}"
+
+
+def test_every_taxonomy_row_names_a_span_the_code_emits():
+    code, built = _names_in_the_code()
+    documented, prefixes = _names_in_the_taxonomy()
+    stale = documented - code
+    assert not stale, f"taxonomy rows for names nothing emits: {sorted(stale)}"
+    assert prefixes <= built
+
+
+@pytest.mark.parametrize(
+    "name",
+    ["ingress.wait", "engine.wake", "engine.store", "engine.finalize", "engine.send"],
+)
+def test_the_seam_spans_of_issue_37_are_emitted_and_documented(name):
+    code, _ = _names_in_the_code()
+    documented, _ = _names_in_the_taxonomy()
+    assert name in code and name in documented

@@ -13,8 +13,11 @@ scopes); this file holds the host half, on both routes, with the device
 programs replaced by stubs so that no ladder compiles.
 
 The second half pins what one finalized round-0 height costs in COUNTS on
-a loopback cluster (verifier calls, lanes, ingress flushes): integers the
-protocol implies, never wall times.
+a loopback cluster (verifier calls, lanes, ingress flushes, and since PR 37
+the spans at the engine's seams: one ``engine.finalize``, the node's
+``engine.send``s, an ``ingress.wait`` a flush, ``engine.wake`` at most once
+a drain, all carrying the height): integers the protocol implies, never
+wall times.
 """
 
 import asyncio
@@ -373,13 +376,19 @@ def _run_cluster(n: int) -> dict:
 
     metrics.reset()
     cost_ledger.enable()
+    assert not trace.enabled()
+    ring = trace.enable()
     try:
         asyncio.run(heights())
         rows = cost_ledger.snapshot()["dispatches"]
     finally:
         cost_ledger.disable()
+        trace.disable()
+    assert ring.dropped == 0
     out = {
         "rows": rows,
+        "records": ring.snapshot(),
+        "tracks": [core._obs_track for core, _ in nodes],
         "flush_sizes": dict(flush_sizes),
         "early_exit_drains": metrics.get_counter(EARLY_EXIT_DRAINS_KEY),
         "early_exit_skipped": metrics.get_counter(EARLY_EXIT_SKIPPED_KEY),
@@ -426,3 +435,107 @@ def test_ingress_flushes_per_height(cluster_counts):
     assert len(counts["flush_sizes"]) == n
     for sizes in counts["flush_sizes"].values():
         assert sizes == [1, n - 1, n] * HEIGHTS
+
+
+# -- the spans at the engine's seams (PR 37) --------------------------------
+
+ENGINE_SPANS = (
+    "proposal.drain",
+    "prepare.drain",
+    "commit.drain",
+    "engine.wake",
+    "engine.send",
+    "engine.teardown",
+    "engine.finalize",
+)
+DRAINS = ("proposal.drain", "prepare.drain", "commit.drain")
+
+
+def _by_track_and_height(counts):
+    """{track: {height: Counter(span name)}} over the engine's spans."""
+    out = collections.defaultdict(lambda: collections.defaultdict(collections.Counter))
+    for ph, name, track, _ts, _dur, args in counts["records"]:
+        if ph == "X" and name in ENGINE_SPANS:
+            out[track][args["height"]][name] += 1
+    return out
+
+
+def test_engine_spans_per_height(cluster_counts):
+    """A node's round-0 height: ONE ``engine.finalize`` (the idle report's
+    count of heights) after one ``engine.teardown``; ``engine.send`` for its
+    PREPARE and its COMMIT, TWO, but for the proposer, who sends no PREPARE;
+    at least one drain a phase, and never more wake-ups than drains."""
+    n, counts = cluster_counts
+    spans = _by_track_and_height(counts)
+    assert sorted(spans) == sorted(counts["tracks"])
+    sends = []
+    for track in counts["tracks"]:
+        assert sorted(spans[track]) == list(range(1, HEIGHTS + 1))
+        for height, names in spans[track].items():
+            assert names["engine.finalize"] == 1, (track, height, names)
+            assert names["engine.teardown"] == 1
+            assert names["engine.send"] in (1, 2)
+            sends.append(names["engine.send"])
+            drains = sum(names[d] for d in DRAINS)
+            # The proposer builds its proposal: it drains none.
+            proposer = names["engine.send"] == 1
+            assert (names["proposal.drain"] == 0) is proposer
+            assert names["prepare.drain"] >= 1 and names["commit.drain"] >= 1
+            assert 1 <= names["engine.wake"] <= drains, (track, height, names)
+    # One proposer a height.
+    assert sorted(sends) == [1] * HEIGHTS + [2] * (n - 1) * HEIGHTS
+    types = collections.Counter(
+        args["type"]
+        for ph, name, _t, _ts, _d, args in counts["records"]
+        if ph == "X" and name == "engine.send"
+    )
+    assert types == {1: (n - 1) * HEIGHTS, 2: n * HEIGHTS}  # PREPARE, COMMIT
+
+
+def test_every_engine_span_lies_in_the_sequence_of_the_height_it_carries(
+    cluster_counts,
+):
+    _, counts = cluster_counts
+    window = {}  # (track, height) -> [sequence.start, sequence.done]
+    for ph, name, track, ts, _dur, args in counts["records"]:
+        if name == "sequence.start":
+            window[(track, args["height"])] = [ts, None]
+        elif name == "sequence.done":
+            window[(track, args["height"])][1] = ts
+    checked = 0
+    for ph, name, track, ts, dur, args in counts["records"]:
+        if ph != "X":
+            continue
+        if name in ENGINE_SPANS:
+            lo, hi = window[(track, args["height"])]
+            assert lo <= ts and ts + dur <= hi + 1, (name, args)
+            checked += 1
+        elif name in ("ingress.batch", "engine.store"):
+            # The engine's height when the burst came: a burst of the NEXT
+            # height that reaches a node still finalizing carries this one.
+            assert 1 <= args["height"] <= HEIGHTS and track in counts["tracks"]
+        elif name in ("ingress.wait", "ingress.flush"):
+            # The ingress knows no engine: the height of the burst's first
+            # message.
+            assert 1 <= args["height"] <= HEIGHTS
+    assert checked >= 8 * len(counts["tracks"]) * HEIGHTS
+
+
+def test_an_ingress_wait_a_flush_with_its_why(cluster_counts):
+    """Every flush ends one ``ingress.wait`` that began with the burst's
+    first ``submit``: same lanes, same height, closed where the flush opens.
+    A host that keeps up never waits ``held_back`` (the fixture switches
+    the rule off), and a default ingress never cuts."""
+    n, counts = cluster_counts
+    spans = [r for r in counts["records"] if r[0] == "X"]
+    waits = sorted((r for r in spans if r[1] == "ingress.wait"), key=lambda r: r[3] + r[4])
+    flushes = sorted((r for r in spans if r[1] == "ingress.flush"), key=lambda r: r[3])
+    assert len(waits) == len(flushes) == 3 * n * HEIGHTS
+    for wait, flush in zip(waits, flushes):
+        assert wait[5]["lanes"] == flush[5]["lanes"]
+        assert wait[5]["height"] == flush[5]["height"]
+        assert wait[5]["why"] in ("soon", "window")
+        assert wait[3] + wait[4] <= flush[3] + 1
+    assert sorted(w[5]["lanes"] for w in waits) == sorted(
+        [1, n - 1, n] * n * HEIGHTS
+    )

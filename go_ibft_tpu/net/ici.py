@@ -464,9 +464,7 @@ class IciLockstepTransport:
                 from ..verify.batch import _digest_kernel
 
                 blocks, counts, r, s, v, senders, live = rows[2]
-                zw = np.asarray(
-                    _digest_kernel(jnp.asarray(blocks), jnp.asarray(counts))
-                )
+                zw = np.asarray(_digest_kernel(blocks, counts))
                 return staging, (zw, r, s, v, senders, live)
         key = (staging.shape, None if rows is None else rows[2][0].shape)
         prog = self._tick_program(key, rows is not None)

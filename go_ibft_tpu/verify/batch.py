@@ -175,6 +175,10 @@ JOINT_FAULTS_KEY = ("go-ibft", "seal_verdicts", "joint_faults")
 # ``IbftMessage.encode`` (any shape but a plain PREPARE / COMMIT); the ones
 # it built from its template are ``templated`` on the ``verify.pack`` span.
 PACK_GENERIC_KEY = ("go-ibft", "pack", "generic")
+# Host arrays a dispatch placed on the device one by one, ahead of its
+# compiled call (``jnp.asarray`` / ``jax.device_put``), instead of handing
+# them to the call: 0 on the device route, the sharded mesh's own placements.
+EAGER_PUTS_KEY = ("go-ibft", "dispatch", "eager_puts")
 
 # Sized like the engine's own seal-verdict cache (core/ibft.py
 # ``_seal_verdict_cap``): a constant, not an option.
@@ -597,7 +601,26 @@ class HostBatchVerifier:
 # aliases, so donate_argnums performs no reuse and instead emits a
 # "donated buffers were not usable" warning per compile.  The per-call
 # inputs are freed by Python refcount right after dispatch regardless.
-_digest_kernel = jax.jit(quorum.digest_words)
+def digest_words(blocks, nblocks, hz=None):
+    """:func:`go_ibft_tpu.ops.quorum.digest_words`, and behind its rows the
+    hash rows ``hz`` of the seals that ride a sender chunk's dispatch: one
+    batch's ``zw``, joined where the digest is computed (a launch of its own
+    for the join cost a flood one more dispatch a drain)."""
+    zw = quorum.digest_words(blocks, nblocks)
+    return zw if hz is None else jnp.concatenate([zw, hz], axis=0)
+
+
+_digest_kernel = jax.jit(digest_words)
+
+# How a dispatch's rows reach the device: as the numpy arrays the packers
+# wrote, handed to the compiled call itself, whose own argument path stages
+# them (an eager ``jnp.asarray`` round each was 0.15 ms more of host time an
+# array on the chip machine, the device idle through it: PERF.md section 6,
+# PR 38).
+# The runtime may read such an array after the call has returned, so nothing
+# writes to an array once a launch has it: every pack allocates its own
+# (:func:`_dead_rows`, the packers' ``blocks`` / ``counts`` / hash rows), and
+# the pack cache, which keeps a drain's rows by reference, only reads them.
 
 
 def _recover_fn(zw, r, s, v, claimed_w, table_w, live):
@@ -607,16 +630,6 @@ def _recover_fn(zw, r, s, v, claimed_w, table_w, live):
 
 
 _recover_kernel = jax.jit(_recover_fn)
-
-
-def _join_rows_fn(zw, hz):
-    return jnp.concatenate([zw, hz], axis=0)
-
-
-# A sender chunk's digest rows and, behind them, the hash rows of the seals
-# that ride its dispatch: one batch's ``zw`` (a program of its own so that
-# ``warmup`` can load it: nothing compiles at a node's first COMMIT flood).
-_join_rows = jax.jit(_join_rows_fn)
 
 
 def _certify_fn(zw, r, s, v, claimed_w, table_w, live, plo, phi, thr_lo, thr_hi):
@@ -686,6 +699,12 @@ def _address_rows(addresses: Sequence[bytes]) -> np.ndarray:
     """N length-checked 20-byte addresses -> ``(N, 5)`` uint32 words (the
     layout of ``ops/keccak.py::addresses_to_words``, without its loop)."""
     return np.frombuffer(b"".join(addresses), dtype="<u4").reshape(-1, 5)
+
+
+def _host_arrays(inputs) -> int:
+    """How many of a dispatch's rows are host arrays (``operands`` on its
+    ``verify.dispatch`` span): all but a sender drain's device ``zw``."""
+    return sum(isinstance(a, np.ndarray) for a in inputs)
 
 
 def _dead_rows(lanes: int):
@@ -1052,6 +1071,7 @@ def pack_sender_digest_rows(
     pad_lanes: int = 0,
     rows=None,
     span=None,
+    hz=None,
 ):
     """The device sender-route pack sequence: cache-hit reuse, oversize
     payloads digested on host, everything else on the device digest
@@ -1076,6 +1096,11 @@ def pack_sender_digest_rows(
     caller's open ``verify.pack`` span, is told how many payloads the bulk
     encoder built from its template (``templated``); those it handed to
     ``IbftMessage.encode`` are counted under :data:`PACK_GENERIC_KEY`.
+    ``hz`` are the hash rows of the seals that ride this chunk's dispatch
+    (as many as the pack has lanes): ``zw`` is then both halves' rows, joined
+    in the digest program (``joined`` on ``span``), or on the host where an
+    oversize payload brought the digest rows there.  The blocks, the counts
+    and ``hz`` go to the compiled call as the numpy arrays they are.
     Returns ``(zw, r, s, v, senders, live)``.
     """
     n = len(msgs)
@@ -1116,12 +1141,19 @@ def pack_sender_digest_rows(
         block=False,
         site="verify/batch.py:pack_sender_digest_rows",
     ):
-        zw = _digest_kernel(jnp.asarray(blocks), jnp.asarray(counts))
+        if hz is None or big:
+            zw = _digest_kernel(blocks, counts)
+        else:
+            zw = _digest_kernel(blocks, counts, hz)
+            if span is not None:
+                span.note(joined=len(hz))
     if big:
         zw = np.array(zw)  # writable host copy (np.asarray can be RO)
         digests = keccak256_many([payloads[i] for i in big])
         for i, digest in zip(big, digests):
             zw[i] = np.frombuffer(digest, ">u4")[::-1].astype(np.uint32)
+        if hz is not None:
+            zw = np.concatenate([zw, hz])
     return zw, r, s, v, senders, live
 
 
@@ -1207,66 +1239,64 @@ class DeviceBatchVerifier:
         once at node startup.  With the persistent cache, repeat processes
         pay only a cache load.
         """
+        table = jnp.zeros((table_rows, 5), jnp.uint32)
+        powers = jnp.zeros((table_rows,), jnp.int32)
         for bb in lanes:
-            # route="warmup": startup compiles must not pollute the
-            # production routes' occupancy, but their compile events ARE
-            # the cost the ledger exists to measure (the AOT-manifest
-            # baseline of ROADMAP item 5).
-            with cost_ledger.dispatch_span(
-                "ecdsa_recover",
-                route="warmup",
-                padded=bb,
-                kernels=(("ecdsa_recover", _recover_kernel),),
-                site="verify/batch.py:warmup",
-            ):
-                _recover_kernel(
-                    jnp.zeros((bb, 8), jnp.uint32),
-                    jnp.zeros((bb, 20), jnp.int32),
-                    jnp.zeros((bb, 20), jnp.int32),
-                    jnp.zeros((bb,), jnp.int32),
-                    jnp.zeros((bb, 5), jnp.uint32),
-                    jnp.zeros((table_rows, 5), jnp.uint32),
-                    jnp.zeros((bb,), bool),
-                ).block_until_ready()
-            with cost_ledger.dispatch_span(
-                "quorum_certify",
-                route="warmup",
-                padded=bb,
-                kernels=(("quorum_certify", _certify_kernel),),
-                site="verify/batch.py:warmup",
-            ):
-                jax.block_until_ready(
-                    _certify_kernel(
-                        jnp.zeros((bb, 8), jnp.uint32),
-                        jnp.zeros((bb, 20), jnp.int32),
-                        jnp.zeros((bb, 20), jnp.int32),
-                        jnp.zeros((bb,), jnp.int32),
-                        jnp.zeros((bb, 5), jnp.uint32),
-                        jnp.zeros((table_rows, 5), jnp.uint32),
-                        jnp.zeros((bb,), bool),
-                        jnp.zeros((table_rows,), jnp.int32),
-                        jnp.zeros((table_rows,), jnp.int32),
-                        jnp.int32(1),
-                        jnp.int32(0),
-                    )
-                )
-            for nb in blocks:
+            r, s, v, claimed, live = _dead_rows(bb)
+            hashes = np.zeros((bb, 8), dtype=np.uint32)
+            # Each program in the call forms the drains use: a dispatch's
+            # rows are host arrays; its ``zw`` is a host array too (a seal
+            # drain's hash rows) or the digest program's output (a sender
+            # drain's).  A form the compiled call has not seen takes its
+            # slow path once, and the cost ledger counts that as a compile.
+            for zw in (hashes, jnp.asarray(hashes)):
+                # route="warmup": startup compiles must not pollute the
+                # production routes' occupancy, but their compile events ARE
+                # the cost the ledger exists to measure (the AOT-manifest
+                # baseline of ROADMAP item 5).
                 with cost_ledger.dispatch_span(
-                    "digest_words",
+                    "ecdsa_recover",
                     route="warmup",
                     padded=bb,
-                    kernels=(("digest_words", _digest_kernel),),
+                    kernels=(("ecdsa_recover", _recover_kernel),),
                     site="verify/batch.py:warmup",
                 ):
-                    _digest_kernel(
-                        jnp.zeros((bb, nb, 17, 2), jnp.uint32),
-                        jnp.ones((bb,), jnp.int32),
+                    _recover_kernel(
+                        zw, r, s, v, claimed, table, live
                     ).block_until_ready()
-            if self._joint_lanes(bb // 2) == bb // 2:
+                with cost_ledger.dispatch_span(
+                    "quorum_certify",
+                    route="warmup",
+                    padded=bb,
+                    kernels=(("quorum_certify", _certify_kernel),),
+                    site="verify/batch.py:warmup",
+                ):
+                    jax.block_until_ready(
+                        _certify_kernel(
+                            zw, r, s, v, claimed, table, live, powers, powers,
+                            np.int32(1), np.int32(0),
+                        )
+                    )
+            digests = [(bb, ())]
+            half = bb // 2
+            if self._joint_lanes(half) == half:
                 # A sender flood's width (twice a rung under the fold
-                # width): its halves meet in ``_join_rows``.
-                half = jnp.zeros((bb // 2, 8), jnp.uint32)
-                _join_rows(half, half).block_until_ready()
+                # width): the digest program joins its halves.
+                digests.append((half, (np.zeros((half, 8), dtype=np.uint32),)))
+            for nb in blocks:
+                for width, hz in digests:
+                    with cost_ledger.dispatch_span(
+                        "digest_words",
+                        route="warmup",
+                        padded=width,
+                        kernels=(("digest_words", _digest_kernel),),
+                        site="verify/batch.py:warmup",
+                    ):
+                        _digest_kernel(
+                            np.zeros((width, nb, 17, 2), dtype=np.uint32),
+                            np.ones((width,), dtype=np.int32),
+                            *hz,
+                        ).block_until_ready()
 
     # -- validator table management ------------------------------------
 
@@ -1390,7 +1420,12 @@ class DeviceBatchVerifier:
     def _dispatch_async(self, inputs, table, quorum_args):
         """Queue the recover (mask-only) or certify (mask+quorum) kernel.
 
-        ``inputs`` = (zw, r, s, v, claimed, live) numpy/jax arrays;
+        ``inputs`` = (zw, r, s, v, claimed, live): the packers' numpy rows
+        (``zw`` the digest program's device rows in a sender drain), handed
+        to the compiled call as they are, like ``table`` and the power
+        vectors (device-resident, a height's); ``operands`` on the span
+        counts the host arrays among ``inputs``.  Nothing may write to one
+        of them from here on.
         ``quorum_args`` = None for the plain mask, or (plo, phi, thr).
         Returns ``(mask_dev, reached_dev_or_None)`` device futures WITHOUT
         blocking — JAX async dispatch lets the caller pack the next batch
@@ -1405,15 +1440,12 @@ class DeviceBatchVerifier:
             block=False,
             site="verify/batch.py:_dispatch_async",
         ):
-            with trace.span("verify.dispatch", route="device"):
-                zw, r, s, v, claimed, live = (jnp.asarray(a) for a in inputs)
+            zw, r, s, v, claimed, live = inputs
+            with trace.span(
+                "verify.dispatch", route="device", operands=_host_arrays(inputs)
+            ):
                 if quorum_args is None:
-                    return (
-                        _recover_kernel(
-                            zw, r, s, v, claimed, jnp.asarray(table), live
-                        ),
-                        None,
-                    )
+                    return _recover_kernel(zw, r, s, v, claimed, table, live), None
                 plo, phi, thr = quorum_args
                 mask, reached_dev, _, _ = _certify_kernel(
                     zw,
@@ -1421,12 +1453,12 @@ class DeviceBatchVerifier:
                     s,
                     v,
                     claimed,
-                    jnp.asarray(table),
+                    table,
                     live,
-                    jnp.asarray(plo),
-                    jnp.asarray(phi),
-                    jnp.int32(max(thr, 0) & 0xFFFF),
-                    jnp.int32(max(thr, 0) >> 16),
+                    plo,
+                    phi,
+                    np.int32(max(thr, 0) & 0xFFFF),
+                    np.int32(max(thr, 0) >> 16),
                 )
                 return mask, reached_dev
 
@@ -1462,7 +1494,9 @@ class DeviceBatchVerifier:
     # reserved for keccak padding in the last block.
     _MAX_DEVICE_PAYLOAD = MAX_DEVICE_PAYLOAD
 
-    def _sender_inputs(self, msgs: List[IbftMessage], pad_lanes: int = 0, rows=None):
+    def _sender_inputs(
+        self, msgs: List[IbftMessage], pad_lanes: int = 0, rows=None, hz=None
+    ):
         """Pack envelopes; digest on device, oversize payloads on host.
 
         Payload encodings and limb rows come from the pack cache when this
@@ -1470,8 +1504,9 @@ class DeviceBatchVerifier:
         per round-change wakeup over the same envelopes); fresh lanes pack
         in bulk and are stored back by reference.  Serves the per-phase
         dispatches and (via ``pad_lanes``, and ``rows`` for the slice of a
-        dispatch's arrays to write) the layouts that put envelopes and
-        seals in one dispatch; the sequence itself lives in
+        dispatch's arrays to write, ``hz`` for the seals' hash rows to join
+        behind the digest rows) the layouts that put envelopes and seals in
+        one dispatch; the sequence itself lives in
         :func:`pack_sender_digest_rows` (shared with the multi-tenant
         coalesced dispatcher).
         """
@@ -1483,6 +1518,7 @@ class DeviceBatchVerifier:
                 pad_lanes=pad_lanes,
                 rows=rows,
                 span=span,
+                hz=hz,
             )
 
     def _seal_inputs(
@@ -1501,7 +1537,7 @@ class DeviceBatchVerifier:
         table, plo, phi, quorum_size = pack
         thr = quorum_size if threshold is None else threshold
         # Device-resident handles: the table and power vectors upload once
-        # per height; jnp.asarray at the dispatch edge is then a no-op.
+        # per height, and every dispatch of the height is handed them.
         plo_dev, phi_dev = self._quorum_powers_dev(height, plo, phi)
         return self._table_dev(height), (plo_dev, phi_dev, thr), thr
 
@@ -1619,13 +1655,22 @@ class DeviceBatchVerifier:
         with trace.span(
             "verify.drain", route="device", kind="certify_round", lanes=lanes
         ):
-            zw1, r1, s1, v1, senders, live1 = self._sender_inputs(
-                [msgs[i] for i in midx], pad_lanes=lanes
-            )
+            # The joint layout of :meth:`_joint_inputs`: each half packed
+            # into its slice of the dispatch's rows, the seals' hash rows
+            # joined behind the digest rows by the digest launch.
+            rows = _dead_rows(2 * lanes)
             with trace.span("verify.pack", kind="seals", lanes=len(sidx)):
-                hz, r2, s2, v2, signers, live2 = pack_seal_batch(
-                    proposal_hash, [seals[i] for i in sidx], pad_lanes=lanes
-                )
+                hz = _seal_lane_rows(
+                    [proposal_hash] * len(sidx),
+                    [seals[i].signature for i in sidx],
+                    [seals[i].signer for i in sidx],
+                    lanes,
+                    tuple(a[lanes:] for a in rows),
+                )[0]
+            zw = self._sender_inputs(
+                [msgs[i] for i in midx], lanes, tuple(a[:lanes] for a in rows), hz
+            )[0]
+            inputs = (zw,) + rows
             with cost_ledger.dispatch_span(
                 "round_certify",
                 route=self._route,
@@ -1634,25 +1679,19 @@ class DeviceBatchVerifier:
                 kernels=(("round_certify", _round_kernel),),
                 site="verify/batch.py:certify_round",
             ):
-                with trace.span("verify.dispatch", route="device"):
+                with trace.span(
+                    "verify.dispatch", route="device", operands=_host_arrays(inputs)
+                ):
                     mask, p_reached, s_reached = _round_kernel(
-                        jnp.concatenate([jnp.asarray(zw1), jnp.asarray(hz)], axis=0),
-                        jnp.concatenate([jnp.asarray(r1), jnp.asarray(r2)], axis=0),
-                        jnp.concatenate([jnp.asarray(s1), jnp.asarray(s2)], axis=0),
-                        jnp.concatenate([jnp.asarray(v1), jnp.asarray(v2)], axis=0),
-                        jnp.concatenate(
-                            [jnp.asarray(senders), jnp.asarray(signers)], axis=0
-                        ),
-                        jnp.asarray(table),
-                        jnp.concatenate(
-                            [jnp.asarray(live1), jnp.asarray(live2)], axis=0
-                        ),
-                        jnp.asarray(plo),
-                        jnp.asarray(phi),
-                        jnp.int32(max(p_thr, 0) & 0xFFFF),
-                        jnp.int32(max(p_thr, 0) >> 16),
-                        jnp.int32(max(seal_thr, 0) & 0xFFFF),
-                        jnp.int32(max(seal_thr, 0) >> 16),
+                        *inputs[:5],
+                        table,
+                        inputs[5],
+                        plo,
+                        phi,
+                        np.int32(max(p_thr, 0) & 0xFFFF),
+                        np.int32(max(p_thr, 0) >> 16),
+                        np.int32(max(seal_thr, 0) & 0xFFFF),
+                        np.int32(max(seal_thr, 0) >> 16),
                     )
                 with trace.span("verify.device_wait", route="device"):
                     mask = np.asarray(mask)
@@ -1761,16 +1800,19 @@ class DeviceBatchVerifier:
         """Envelope rows in the first ``lanes`` lanes, the riders' seal rows
         in the second: the :meth:`certify_round` layout, for the mask-only
         program.  The rows are allocated once, at the dispatch's lane count,
-        and each half is packed into its slice."""
+        and each half is packed into its slice: the seals first, because
+        their hash rows go through the envelopes' digest launch, which joins
+        them behind its own rows (one launch, and no eager JAX operation
+        between the packers and the recover launch)."""
         rows = _dead_rows(2 * lanes)
-        zw = self._sender_inputs(sub, lanes, tuple(a[:lanes] for a in rows))[0]
         if riders:
             hz = self._rider_inputs(
                 [sub[j] for j in riders], lanes, tuple(a[lanes:] for a in rows)
             )[0]
         else:  # a dead half: no pack span
             hz = np.zeros((lanes, 8), dtype=np.uint32)
-        return (_join_rows(jnp.asarray(zw), jnp.asarray(hz)),) + rows
+        zw = self._sender_inputs(sub, lanes, tuple(a[:lanes] for a in rows), hz)[0]
+        return (zw,) + rows
 
     def cached_seal_verdicts(
         self, proposal_hash: bytes, seals: Sequence[CommittedSeal], height: int

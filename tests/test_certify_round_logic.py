@@ -39,8 +39,9 @@ def _fixture(n=4, height=3):
 def _stub_kernels(monkeypatch, mask_fn):
     """Replace the device programs: digest -> zeros, round kernel -> mask_fn."""
 
-    def fake_digest(blocks, counts):
-        return np.zeros((np.asarray(blocks).shape[0], 8), dtype=np.uint32)
+    def fake_digest(blocks, counts, hz=None):
+        zw = np.zeros((np.asarray(blocks).shape[0], 8), dtype=np.uint32)
+        return zw if hz is None else np.concatenate([zw, hz])
 
     def fake_round_kernel(zw, r, s, v, claimed, table, live, plo, phi,
                          p_lo, p_hi, s_lo, s_hi):

@@ -188,6 +188,80 @@ def test_envelope_mask_and_seal_verdicts_equal_the_host_oracle(committee, flood)
     assert report.reached is False
 
 
+def test_the_digest_program_joins_the_rows_the_parent_joined(
+    committee, flood, monkeypatch
+):
+    """The mixed batch again, through the real programs: the ``zw`` the
+    recover launch is handed is, word for word, what the parent built with a
+    launch of its own (``concatenate([digest_words(blocks, counts), hz])``
+    of eager device arrays), whether the digest program joined the halves or
+    (an oversize payload among the envelopes) the host did; the operands are
+    the packers' numpy arrays; the mask is still the host oracle's."""
+    import jax
+    import jax.numpy as jnp
+
+    from go_ibft_tpu.messages.wire import PrePrepareMessage, Proposal
+    from go_ibft_tpu.ops import quorum
+
+    c, t = committee, flood
+    batch, _ = _mixed_batch(c, t)
+    handed = {"digest": [], "zw": []}
+    digest, recover = vbatch._digest_kernel, vbatch._recover_kernel
+
+    def noting_digest(*operands):
+        assert all(type(a) is np.ndarray for a in operands)
+        handed["digest"].append(operands)
+        return digest(*operands)
+
+    def noting_recover(zw, *rows):
+        handed["zw"].append(zw)
+        return recover(zw, *rows)
+
+    monkeypatch.setattr(vbatch, "_digest_kernel", noting_digest)
+    monkeypatch.setattr(vbatch, "_recover_kernel", noting_recover)
+    host = HostBatchVerifier(c.src)
+    parent_digest = jax.jit(quorum.digest_words)
+
+    def parents_rows(blocks, counts, hz):
+        zw = parent_digest(jnp.asarray(blocks), jnp.asarray(counts))
+        return np.array(jnp.concatenate([zw, jnp.asarray(hz)], axis=0))
+
+    dev = DeviceBatchVerifier(c.src)
+    assert dev.verify_senders(batch).tolist() == host.verify_senders(batch).tolist()
+    ((blocks, counts, hz),) = handed["digest"]
+    (zw,) = handed["zw"]
+    assert not isinstance(zw, np.ndarray) and zw.shape == (64, 8)
+    assert hz.shape == (32, 8) and hz.any()
+    assert np.array_equal(np.asarray(zw), parents_rows(blocks, counts, hz))
+
+    # One envelope over the digest program's largest payload: its digest and
+    # the join are the host's, and the rows are the same rows.
+    big = IbftMessage(
+        view=View(height=HEIGHT, round=0),
+        sender=t.commits[0].sender,
+        type=MessageType.PREPREPARE,
+        preprepare_data=PrePrepareMessage(
+            proposal=Proposal(
+                raw_proposal=b"\x07" * (vbatch.MAX_DEVICE_PAYLOAD + 1), round=0
+            ),
+            proposal_hash=t.phash,
+        ),
+    )
+    digest_ = keccak256(big.encode(include_signature=False))
+    big.signature = encode_signature(*ec.sign(c.key_of[big.sender], digest_))
+    del handed["digest"][:], handed["zw"][:]
+    dev = DeviceBatchVerifier(c.src)
+    oversize = batch[:5] + [big] + batch[5:]
+    got = dev.verify_senders(oversize)
+    assert got.tolist() == host.verify_senders(oversize).tolist() and got[5]
+    ((blocks, counts),) = handed["digest"]  # no join on the device
+    (zw,) = handed["zw"]
+    assert type(zw) is np.ndarray and zw.shape == (64, 8)
+    want = parents_rows(blocks, counts, zw[32:])
+    want[5] = np.frombuffer(digest_, ">u4")[::-1]
+    assert zw[32:].any() and np.array_equal(zw, want)
+
+
 def test_a_seal_drain_after_the_joint_dispatch_dispatches_nothing(
     committee, flood, oracle, ledger, recorder
 ):
@@ -383,10 +457,10 @@ def test_the_ladder_bisects_a_poisoned_joint_dispatch_to_its_message(
     poison = next(m for m in t.commits if m.sender not in t.bad)
 
     class Poisoned(DeviceBatchVerifier):
-        def _sender_inputs(self, msgs, pad_lanes=0, rows=None):
+        def _sender_inputs(self, msgs, pad_lanes=0, rows=None, hz=None):
             if any(m is poison for m in msgs):
                 raise RuntimeError("this message crashes the device rung")
-            return super()._sender_inputs(msgs, pad_lanes, rows)
+            return super()._sender_inputs(msgs, pad_lanes, rows, hz)
 
     dev, host = Poisoned(c.src), HostBatchVerifier(c.src)
     quarantined = metrics.get_counter(vbatch.QUARANTINED_LANES_KEY)

@@ -213,6 +213,17 @@ def test_a_committee_just_over_the_old_ceiling_flushes_each_phase_once():
         assert live < n - corrupt // 2
 
 
+def _stand_in_digest(blocks, counts, hz=None):
+    """The digest program's stand-in: zero rows, ``hz`` joined behind them."""
+    import numpy as np
+
+    n = len(counts)
+    zw = np.zeros((n if hz is None else n + len(hz), 8), np.uint32)
+    if hz is not None:
+        zw[n:] = hz
+    return zw
+
+
 def test_what_pack_does_once_a_drain_at_100_validators(monkeypatch):
     """The headline deployment's round-0 heights on the device route, the
     recover and digest programs replaced by stand-ins (every live lane
@@ -233,11 +244,7 @@ def test_what_pack_does_once_a_drain_at_100_validators(monkeypatch):
     monkeypatch.setattr(
         batch, "_recover_kernel", lambda zw, r, s, v, c, t, live: np.asarray(live)
     )
-    monkeypatch.setattr(
-        batch,
-        "_digest_kernel",
-        lambda blocks, counts: np.zeros((len(counts), 8), np.uint32),
-    )
+    monkeypatch.setattr(batch, "_digest_kernel", _stand_in_digest)
     encoded, window = [], []
     encode, measure = IbftMessage.encode, driver.measure
 
@@ -315,13 +322,207 @@ def test_what_pack_does_once_a_drain_at_100_validators(monkeypatch):
     # Both floods of a height pack through the joint layout (256 lanes), and
     # it joins nothing on the host: rows are written where they are sent.
     assert joint == {"packs": 2 * HEIGHTS, "inside": False, "concatenates": 0}
+    # The seals pack first: their hash rows ride the envelopes' digest
+    # launch, which joins both halves' rows (ISSUE 38).
     packs = [
-        (r[5]["kind"], r[5]["lanes"], r[5].get("templated"))
+        (r[5]["kind"], r[5]["lanes"], r[5].get("templated"), r[5].get("joined"))
         for r in st.records
         if r[:2] == ("X", "verify.pack") and "kind" in r[5]
     ]
     assert packs == [
-        ("senders", n - 1, n - 1),
-        ("senders", n, n),
-        ("seals", n, None),
+        ("senders", n - 1, n - 1, 128),
+        ("seals", n, None, None),
+        ("senders", n, n, 128),
     ] * HEIGHTS
+
+
+# -- a drain's rows reach the device through the compiled calls alone ---------
+
+
+class _HandOver:
+    """The verifier's four programs replaced by stand-ins that note each call
+    (every live lane valid, zero digest rows with ``hz`` joined behind them;
+    the fused programs must not run), and ``jnp.asarray``,
+    ``jnp.concatenate`` and ``jax.device_put`` wrapped to note every call
+    that code under ``go_ibft_tpu/verify/`` makes on a host array.  Both
+    only while ``window`` is non-empty."""
+
+    def __init__(self, monkeypatch):
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+
+        from go_ibft_tpu.verify import batch
+
+        self.window, self.calls, self.live, self.eager = [True], [], [], []
+        asarray = jnp.asarray
+
+        def digest(blocks, counts, hz=None):
+            for a in (blocks, counts) + (() if hz is None else (hz,)):
+                assert type(a) is np.ndarray  # handed over as packed
+            assert (blocks.dtype, counts.dtype) == (np.uint32, np.int32)
+            if self.window:
+                self.calls.append(("digest", 2 if hz is None else 3, len(counts)))
+            zw = _stand_in_digest(blocks, counts, hz)
+            return asarray(zw)  # a device array, as the program's rows are
+
+        def recover(zw, r, s, v, claimed, table, live):
+            for a in (r, s, v, claimed, live):
+                assert type(a) is np.ndarray
+            assert not isinstance(table, np.ndarray)  # device-resident
+            assert [a.dtype for a in (r, s, v, claimed, live)] == [
+                np.int32, np.int32, np.int32, np.uint32, np.bool_
+            ]
+            assert zw.dtype == np.uint32 and zw.shape == (len(live), 8)
+            if self.window:
+                self.calls.append(("recover", type(zw) is np.ndarray, len(live)))
+                self.live.append(int(live.sum()))
+            return live
+
+        def fused(*a):
+            raise AssertionError("a fused program ran")
+
+        monkeypatch.setattr(batch, "_digest_kernel", digest)
+        monkeypatch.setattr(batch, "_recover_kernel", recover)
+        monkeypatch.setattr(batch, "_certify_kernel", fused)
+        monkeypatch.setattr(batch, "_round_kernel", fused)
+
+        def host_arrays(x):
+            if isinstance(x, (list, tuple)):
+                return any(host_arrays(a) for a in x)
+            return isinstance(x, np.ndarray)
+
+        def noting(name, orig):
+            def wrapped(x, *a, **kw):
+                caller = sys._getframe(1).f_code
+                where = caller.co_filename.replace(os.sep, "/")
+                if self.window and "go_ibft_tpu/verify/" in where and host_arrays(x):
+                    self.eager.append((name, caller.co_name))
+                return orig(x, *a, **kw)
+
+            return wrapped
+
+        monkeypatch.setattr(jnp, "asarray", noting("asarray", jnp.asarray))
+        monkeypatch.setattr(jnp, "concatenate", noting("concatenate", jnp.concatenate))
+        monkeypatch.setattr(jax, "device_put", noting("device_put", jax.device_put))
+
+
+def _dispatch_spans(records):
+    """``operands`` of the device route's ``verify.dispatch`` spans and
+    ``(kind, joined)`` of the ``verify.pack`` spans, in order."""
+    operands = [
+        r[5].get("operands")
+        for r in records
+        if r[:2] == ("X", "verify.dispatch") and r[5].get("route") == "device"
+    ]
+    packs = [
+        (r[5]["kind"], r[5].get("joined"))
+        for r in records
+        if r[:2] == ("X", "verify.pack") and "kind" in r[5]
+    ]
+    return operands, packs
+
+
+@pytest.mark.parametrize(
+    "n, calls, operands, packs",
+    [
+        (  # the joint layout: each flood one digest+join and one recover
+            100,
+            [("digest", 3, 128), ("recover", False, 256)] * 2,
+            [5, 5],
+            [("senders", 128), ("seals", None), ("senders", 128)],
+        ),
+        (  # from the fold width up: the COMMITs' seals are the next chunk
+            300,
+            [("digest", 2, 512), ("recover", False, 512)] * 2
+            + [("recover", True, 512)],
+            [5, 5, 6],
+            [("senders", None), ("senders", None), ("seals", None)],
+        ),
+    ],
+    ids=["joint-100v", "next-chunk-300v"],
+)
+def test_a_height_hands_its_rows_to_the_compiled_calls_and_nothing_else(
+    monkeypatch, n, calls, operands, packs
+):
+    """A round-0 height on the device route after its PREPREPARE (one lane,
+    on the host): exactly the compiled calls its layout owes, each handed
+    the packers' numpy arrays; no eager ``jnp`` call or ``device_put`` on a
+    host array anywhere in the verifier but the height's table upload
+    (ISSUE 38)."""
+    from go_ibft_tpu.utils import metrics
+    from go_ibft_tpu.verify import batch
+
+    over = _HandOver(monkeypatch)
+    measure = driver.measure
+
+    def measure_in_window(st, seconds):
+        over.window.append(True)
+        try:
+            return measure(st, seconds)
+        finally:
+            over.window.clear()
+
+    monkeypatch.setattr(driver, "measure", measure_in_window)
+    over.window.clear()  # ``warm`` runs a spare height's drains: not counted
+    eager_puts = metrics.get_counter(batch.EAGER_PUTS_KEY)
+    st = _run(n, 38, AdaptiveBatchVerifier, corrupt_share=0.0)
+    assert st.counts["failed"] == 0 and len(st.samples) == HEIGHTS
+    assert over.calls == calls * HEIGHTS
+    assert over.live == ([n - 1, 2 * n] if n == 100 else [n - 1, n, n]) * HEIGHTS
+    assert over.eager == [("asarray", "_table_dev")] * HEIGHTS
+    assert metrics.get_counter(batch.EAGER_PUTS_KEY) == eager_puts
+    assert _dispatch_spans(st.records) == (operands * HEIGHTS, packs * HEIGHTS)
+    by_program = {
+        r["program"]: r["dispatches"] for r in st.rows if r["route"] == "device"
+    }
+    assert by_program == {
+        "digest_words": 2 * HEIGHTS,
+        "ecdsa_recover": sum(c[0] == "recover" for c in calls) * HEIGHTS,
+    }
+
+
+@pytest.mark.parametrize("case", ["prepare-flood-dead-half", "one-oversize-payload"])
+def test_a_drain_hands_its_rows_to_the_compiled_calls_and_nothing_else(
+    monkeypatch, case
+):
+    """One 100-validator drain: a PREPARE flood (the joint layout with a
+    dead second half: still the digest+join program, zero hash rows), and a
+    COMMIT flood with one payload over the device digest's largest bucket
+    (its digest and the join made on the host: a two-operand digest launch,
+    still ONE recover launch, every row a host array)."""
+    from benchmark.lib.committee import Committee
+    from go_ibft_tpu.messages.wire import MessageType, PrePrepareMessage, Proposal
+    from go_ibft_tpu.verify import DeviceBatchVerifier, batch
+
+    c = Committee(100, 38)
+    t = c.traffic(1, 0)
+    over = _HandOver(monkeypatch)
+    dev = DeviceBatchVerifier(c.src)
+    rec = trace.enable()
+    try:
+        if case == "prepare-flood-dead-half":
+            msgs = t.prepares
+            assert dev.verify_senders(msgs).all()
+            want, live = [("digest", 3, 128), ("recover", False, 256)], len(msgs)
+            spans = ([5], [("senders", 128)])
+        else:
+            msgs = list(t.commits)
+            big = msgs[7]
+            big.type, big.commit_data = MessageType.PREPREPARE, None
+            big.preprepare_data = PrePrepareMessage(
+                proposal=Proposal(
+                    raw_proposal=b"\x07" * (batch.MAX_DEVICE_PAYLOAD + 1), round=0
+                ),
+                proposal_hash=t.phash,
+            )
+            assert dev.verify_senders(msgs).all()
+            want, live = [("digest", 2, 128), ("recover", True, 256)], 2 * len(msgs) - 1
+            spans = ([6], [("seals", None), ("senders", None)])
+            assert len(dev._seal_verdicts) == len(msgs) - 1
+        assert _dispatch_spans(rec.snapshot()) == spans
+    finally:
+        trace.disable()
+    assert over.calls == want
+    assert over.live == [live]
+    assert over.eager == [("asarray", "_table_dev")]

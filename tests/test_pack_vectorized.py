@@ -476,11 +476,13 @@ def captured(monkeypatch):
 
     from go_ibft_tpu.verify import DeviceBatchVerifier, batch
 
-    got = {"digests": [], "dispatches": []}
+    got = {"digests": [], "joined": [], "dispatches": []}
 
-    def digest(blocks, counts):
-        got["digests"].append((np.asarray(blocks), np.asarray(counts)))
-        return jnp.asarray(np.asarray(blocks).reshape(len(counts), -1)[:, :8])
+    def digest(blocks, counts, hz=None):
+        got["digests"].append((blocks, counts))
+        got["joined"].append(hz)
+        zw = np.asarray(blocks).reshape(len(counts), -1)[:, :8]
+        return jnp.asarray(zw if hz is None else np.concatenate([zw, hz]))
 
     def recover(zw, r, s, v, claimed, table, live):
         got["dispatches"].append(
@@ -669,6 +671,99 @@ def test_a_later_drain_leaves_an_earlier_drains_cached_rows_as_they_were(capture
     got = captured["dispatches"][-1]
     ref = _pack_sender_batch_reference(list(reversed(first)), pad_lanes=32)
     _assert_tuples_identical(tuple(a[:32] for a in got[1:]), ref[2:])
+
+
+def test_what_a_launch_was_handed_is_as_it_was_after_the_next_drains_packed(captured):
+    """The rows go to the compiled calls as the numpy arrays the packers
+    wrote, and the runtime may read one after the call returned (ISSUE 38):
+    every array a launch was handed (the digest's blocks, counts and hash
+    rows, a recover's five rows and its host ``zw``) is made read-only the
+    moment the stand-in has it, and the drains that follow (warm cache hits
+    among fresh lanes, a seal drain, the first drain again) pack without
+    writing to one."""
+    dev = captured["verifier"]
+    first, phash = _flood(11, "commit")
+    second, _ = _flood(6, "prepare", seed=8)
+    frozen = []
+
+    def freeze():
+        handed = [a for pair in captured["digests"] for a in pair]
+        handed += [a for a in captured["joined"] if a is not None]
+        handed += [a for rows in captured["dispatches"] for a in rows[1:]]
+        handed += [
+            rows[0] for rows in captured["dispatches"] if rows[0].flags.writeable
+        ]  # a host ``zw`` (the stand-in's copy of a device one is not)
+        for a in handed:
+            assert type(a) is np.ndarray and a.base is None  # whole, not a view
+            a.setflags(write=False)
+            frozen.append((a, a.copy()))
+        for got in ("digests", "joined", "dispatches"):
+            del captured[got][:]
+
+    dev.verify_senders(first)
+    freeze()
+    assert len(frozen) == 2 + 1 + 5  # blocks, counts; hz; r, s, v, claimed, live
+    hits = dev._pack_cache.hits
+    dev.verify_senders(first[3:8] + second)  # warm hits among fresh lanes
+    assert dev._pack_cache.hits == hits + 5
+    freeze()
+    others = _seals_of(_flood(9, "commit", seed=13)[0])  # no verdict: dispatched
+    dev.verify_committed_seals(phash, others, 3)
+    freeze()
+    assert len(frozen) == 2 * 8 + 6  # the seal drain: hash rows and five rows
+    dev.verify_senders(list(reversed(first)))
+    freeze()
+    for a, was in frozen:
+        assert np.array_equal(a, was)
+
+
+def test_a_drain_after_warm_up_meets_no_call_form_the_warm_up_did_not_load(
+    monkeypatch,
+):
+    """``warmup`` calls each program in the forms the drains use (host rows
+    as numpy, ``zw`` a host array or the digest program's output, the table
+    device-resident): at 128 + 128 lanes (a 100-validator flood), 512 (a
+    300-validator one, its seals the next chunk) and 2,048 (a sync chunk) no
+    drain grows a program's call cache, which is what the cost ledger
+    counts as a compile.  The recover and certify programs are cheap jits of
+    the same signature here (no ladder compiles); the digest is the real
+    function."""
+    import jax
+
+    from go_ibft_tpu.verify import DeviceBatchVerifier, batch
+
+    def recover(zw, r, s, v, claimed, table, live):
+        return live & (zw[:, 0] == r[:, 0].astype(np.uint32)) & (table[0, 0] == 0)
+
+    def certify(zw, r, s, v, claimed, table, live, plo, phi, lo, hi):
+        ok = recover(zw, r, s, v, claimed, table, live)
+        return ok, ok.sum() >= lo + hi + plo[0] + phi[0], lo, hi
+
+    monkeypatch.setattr(batch, "_recover_kernel", jax.jit(recover))
+    monkeypatch.setattr(batch, "_certify_kernel", jax.jit(certify))
+    # A function of its own, so that only this test's calls are in its cache
+    # (jits of one function share theirs).
+    monkeypatch.setattr(
+        batch, "_digest_kernel", jax.jit(lambda *a: batch.digest_words(*a))
+    )
+    powers = {bytes([i // 256, i % 256]) * 10: 1 for i in range(300)}
+    dev = DeviceBatchVerifier(lambda height: powers)
+    dev.warmup(lanes=(128, 256, 512, 2048), blocks=(2,), table_rows=512)
+    programs = (batch._recover_kernel, batch._certify_kernel, batch._digest_kernel)
+    loaded = [k._cache_size() for k in programs]
+    assert loaded == [2 * 4, 2 * 4, 4 + 1]  # two forms a width; one joined digest
+
+    commits, phash = _flood(99, "commit")
+    dev.verify_senders(commits)  # 128 + 128: the digest joins the halves
+    dev.verify_committed_seals(phash, _seals_of(_flood(99, "commit", seed=6)[0]), 3)
+    flood, _ = _flood(299, "commit", seed=7)
+    dev.verify_senders(flood)  # 512, and the seals' 512 behind it
+    lanes = [(phash, seal) for seal in _seals_of(flood)] * 7
+    assert len(lanes[:2048]) == 2048
+    dev.verify_seal_lanes(lanes[:2048], 3)
+    dev.certify_senders(commits, 3)
+    dev.certify_seals(phash, _seals_of(commits), 3)
+    assert [k._cache_size() for k in programs] == loaded
 
 
 def test_by_reference_entries_guard_a_recycled_id():

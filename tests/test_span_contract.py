@@ -85,8 +85,9 @@ def stub_kernels(monkeypatch):
     def recover(zw, r, s, v, claimed, table, live):
         return np.asarray(live, dtype=bool)
 
-    def digest(blocks, counts):
-        return np.zeros((np.shape(blocks)[0], 8), dtype=np.uint32)
+    def digest(blocks, counts, hz=None):
+        zw = np.zeros((np.shape(blocks)[0], 8), dtype=np.uint32)
+        return zw if hz is None else np.concatenate([zw, hz])
 
     monkeypatch.setattr(batch, "_recover_kernel", recover)
     monkeypatch.setattr(batch, "_digest_kernel", digest)
@@ -195,6 +196,42 @@ def test_one_outer_span_with_the_children_its_route_owes(
 
 
 @pytest.mark.parametrize("entry", list(ENTRY_POINTS))
+def test_a_device_dispatch_says_what_it_handed_over(
+    entry, signed_round, stub_kernels, recorder
+):
+    """ISSUE 38: ``verify.dispatch`` on the device route carries
+    ``operands``, the host arrays among the six rows the compiled call was
+    handed as they are (all six here: the stub's digest rows are a host
+    array too); a sender pack whose digest launch joined the riders' hash
+    rows behind its own carries ``joined`` (24 PREPAREs run at twice the
+    32-lane rung, the second half dead); nothing is placed eagerly."""
+    eager = metrics.get_counter(batch.EAGER_PUTS_KEY)
+    verifier = AdaptiveBatchVerifier(_validators(signed_round))
+    assert _call(verifier, entry, signed_round, N_VALIDATORS).all()
+    spans = {r[1]: r[5] for r in recorder.snapshot() if r[0] == "X"}
+    assert spans["verify.dispatch"]["route"] == "device"
+    assert spans["verify.dispatch"]["operands"] == 6
+    pack = spans["verify.pack"]
+    if entry == "verify_senders":
+        assert (pack["kind"], pack["joined"], pack["templated"]) == (
+            "senders",
+            32,
+            N_VALIDATORS,
+        )
+    else:
+        assert "joined" not in pack
+    assert metrics.get_counter(batch.EAGER_PUTS_KEY) == eager
+
+
+def test_a_host_dispatch_hands_nothing_over(signed_round, recorder):
+    HostBatchVerifier(_validators(signed_round)).verify_senders(
+        signed_round.prepares[:HOST_LANES]
+    )
+    (dispatch,) = [r[5] for r in recorder.snapshot() if r[1] == "verify.dispatch"]
+    assert "operands" not in dispatch
+
+
+@pytest.mark.parametrize("entry", list(ENTRY_POINTS))
 def test_verdicts_count_the_lanes_a_drain_refused(entry, recorder):
     """Six corrupted validators of 24, host route (real recovers): the
     instant's ``rejected`` is the generator's count of bad lanes among the
@@ -265,11 +302,16 @@ def test_mesh_route_owes_the_same_phases_inside_a_shard_span(
         return np.asarray(live, dtype=bool)
 
     verifier._mask_kernel = mask_kernel
+    eager = metrics.get_counter(batch.EAGER_PUTS_KEY)
     mask = verifier.verify_seal_lanes(w.lanes, w.height)
     assert mask.all() and len(mask) == 64
     outer, inside = _outer_and_children(recorder, "verify.drain")
     assert outer[5]["route"] == "mesh"
     assert inside == DRAIN_CHILDREN
+    # The sharded route still places its six rows itself, and says so.
+    assert metrics.get_counter(batch.EAGER_PUTS_KEY) == eager + 6
+    (dispatch,) = [r[5] for r in recorder.snapshot() if r[1] == "verify.dispatch"]
+    assert dispatch["route"] == "mesh" and "operands" not in dispatch
     shards = [r for r in recorder.snapshot() if r[1] == "verify.shard"]
     assert len(shards) == 1 and shards[0][5]["devices"] == 4
 

@@ -42,6 +42,7 @@ from typing import List, Optional
 
 from ..messages.helpers import CommittedSeal
 from ..messages.wire import PreparedCertificate, Proposal
+from ..obs import trace
 from ..utils import metrics
 
 # Fixed-bucket append latency (fsync included) for the /metrics endpoint;
@@ -167,12 +168,22 @@ class WriteAheadLog:
             if metrics.fixed_histograms_enabled()
             else None
         )
-        with self._lock:
-            fh = self._file()
-            fh.write(line.encode())
-            fh.flush()
-            if fsync:
-                os.fsync(fh.fileno())
+        # ``wal.append``: the append as the height's critical path sees it
+        # (lock wait, write, flush, fsync); ``fsync_ms`` is the fsync alone,
+        # 0 where this record does not force one.
+        with trace.span(
+            "wal.append", kind=record["kind"], height=record.get("height")
+        ) as sp:
+            fsync_ms = 0.0
+            with self._lock:
+                fh = self._file()
+                fh.write(line.encode())
+                fh.flush()
+                if fsync:
+                    t_sync = time.perf_counter()
+                    os.fsync(fh.fileno())
+                    fsync_ms = (time.perf_counter() - t_sync) * 1e3
+            sp.note(fsync_ms=round(fsync_ms, 4))
         if t0 is not None:
             metrics.observe_fixed(
                 WAL_APPEND_MS_KEY, (time.perf_counter() - t0) * 1e3

@@ -17,7 +17,7 @@ import time
 from collections import deque
 from typing import Callable, Optional, Protocol, Sequence
 
-from ..messages.wire import IbftMessage
+from ..messages.wire import IbftMessage, MessageType
 from ..obs import trace
 
 
@@ -125,6 +125,33 @@ class BatchingIngress:
     wait that is small against the flush that caused it, and one a
     sub-cutover host flush (fractions of a millisecond per lane) never
     triggers.
+
+    **A burst worth waiting for.**  Over a socket transport a phase trickles
+    in, an RPC a loop turn or two, and every flush blocks the loop under its
+    verification: an eager flush a message is what both detectors above then
+    see, since each flush holds the next arrival back for as long as it runs
+    (a 100-validator phase became ~99 one-lane host flushes, the chip idle by
+    policy).  An embedder whose verifier can reach a device, and that knows
+    how many votes a decision takes (a validator: the fewest of the others'
+    that complete a quorum with its own), passes that as ``burst_hint``.
+    Where it is device-sized (``>= eager_cutover``) a vote that finds the
+    buffer empty holds the flush, and what is held goes out at the first
+    pause in the arrivals: of ``BURST_QUIET_FACTOR`` windows while fewer
+    votes than the hint are held (``quiet``, whatever is held: the rest is
+    not coming now), of ``BURST_DECIDING_QUIET_FACTOR`` once as many as the
+    hint are (``full``: enough to decide, so with ``f`` peers silent a phase
+    waits for nobody, and where every peer votes the stragglers that follow
+    on the quorum's heels share its dispatch and not a second one), or when
+    it has been held ``BURST_HOLD_FACTOR`` windows (``capped``: a drip that
+    never pauses), whichever is first.  A PREPREPARE is not held (one
+    proposer a round, and everything waits for it): it flushes at the end of
+    its tick, with whatever is held.  The gaps are sized by the one socket
+    transport measured (TPU v5e host, 100 validators on loopback, PR 39:
+    RPCs 0.25-0.5 ms apart, every flush a device dispatch that blocks the
+    loop 8 ms or more; PERF.md §6 has the runs, with a third of the peers
+    silent among them); the cap only bounds what a peer that drips can cost.
+    ``burst_hint`` may be assigned later (a validator set that changes);
+    without it nothing changes.
     """
 
     # A flush that blocked the loop for more than this many ``max_delay``
@@ -133,6 +160,12 @@ class BatchingIngress:
     # The batch the calibrated window projects to fill where ``max_batch``
     # is left out.
     FILL_TARGET = 256
+    # With ``burst_hint``: a held burst is handed over after this many
+    # ``max_delay`` windows without an arrival (fewer votes than the hint
+    # held; as many), and held at most this many.
+    BURST_QUIET_FACTOR = 4.0
+    BURST_DECIDING_QUIET_FACTOR = 1.0
+    BURST_HOLD_FACTOR = 64.0
 
     def __init__(
         self,
@@ -142,6 +175,7 @@ class BatchingIngress:
         max_delay: float = 0.002,
         eager_cutover: Optional[int] = None,
         calibrate: bool = True,
+        burst_hint: Optional[int] = None,
     ) -> None:
         from ..utils import calibration
 
@@ -155,6 +189,7 @@ class BatchingIngress:
         self.max_batch = max_batch
         self.max_delay = max_delay
         self.eager_cutover = eager_cutover
+        self.burst_hint = burst_hint
         self.calibrator = (
             calibration.ArrivalCalibrator(max_window_s=max_delay)
             if calibrate
@@ -169,9 +204,15 @@ class BatchingIngress:
         self._recent: deque = deque()
         self._recent_n = 0
         self._held_back = False
+        # A burst held under ``burst_hint``: when its last message came, and
+        # until when it may be held.
+        self._holding = False
+        self._last_arrival = 0.0
+        self._hold_until = 0.0
         # ``ingress.wait``: from the submit that found the buffer empty to
         # the flush that hands the burst over, with why the flush came when
-        # it did (``soon`` / ``window`` / ``held_back``; ``cut`` at flush).
+        # it did (``soon`` / ``window`` / ``held_back``; ``full`` / ``quiet`` /
+        # ``capped`` under ``burst_hint``; ``cut`` at flush).
         self._wait = trace.NULL_SPAN
         self._why = "soon"
 
@@ -203,6 +244,19 @@ class BatchingIngress:
             self.calibrator.observe()
         if self.max_batch is not None and len(self._buffer) >= self.max_batch:
             self._flush(cut=True)
+        elif self._holding and message.type == MessageType.PREPREPARE:
+            # What the round waits for: out at the end of this tick, with
+            # whatever is held.
+            self._holding = False
+            self._handle.cancel()
+            self._why = "soon"
+            self._handle = asyncio.get_running_loop().call_soon(self.flush)
+        elif self._holding or (
+            self._burst_hint is not None
+            and self._handle is None
+            and message.type != MessageType.PREPREPARE
+        ):
+            self._hold()
         elif self._handle is None:
             loop = asyncio.get_running_loop()
             self._trim_recent(time.monotonic())
@@ -220,6 +274,48 @@ class BatchingIngress:
             else:
                 self._handle = loop.call_soon(self.flush)
 
+    @property
+    def burst_hint(self) -> Optional[int]:
+        return self._burst_hint
+
+    @burst_hint.setter
+    def burst_hint(self, votes: Optional[int]) -> None:
+        # Device-sized only: below the cutover the host serves the flush
+        # whatever its size, and a hold would be latency for nothing.
+        self._burst_hint = (
+            votes if votes and votes >= self.eager_cutover else None
+        )
+
+    def _hold(self) -> None:
+        """One more vote under ``burst_hint`` (class docstring)."""
+        now = self._last_arrival = time.monotonic()
+        if not self._holding:
+            self._holding = True
+            self._hold_until = now + self.BURST_HOLD_FACTOR * self.max_delay
+            self._handle = asyncio.get_running_loop().call_later(
+                self.max_delay, self._hold_timer
+            )
+
+    def _hold_timer(self) -> None:
+        """Once a window while a burst is held: hand it over if the
+        transport has been quiet long enough for what is held, or the hold
+        has reached its limit."""
+        now = time.monotonic()
+        enough = not self._burst_hint or len(self._buffer) >= self._burst_hint
+        gap = (
+            self.BURST_DECIDING_QUIET_FACTOR if enough else self.BURST_QUIET_FACTOR
+        ) * self.max_delay
+        if now - self._last_arrival >= gap:
+            self._why = "full" if enough else "quiet"
+        elif now >= self._hold_until:
+            self._why = "capped"
+        else:
+            self._handle = asyncio.get_running_loop().call_later(
+                self.max_delay, self._hold_timer
+            )
+            return
+        self._flush(cut=False)
+
     def flush(self) -> None:
         """Hand over what is buffered (the timer's callback; also called
         directly)."""
@@ -232,6 +328,7 @@ class BatchingIngress:
         if not self._buffer:
             return
         batch, self._buffer = self._buffer, []
+        self._holding = False
         # The burst's height, for the spans: that of its first message.
         view = getattr(batch[0], "view", None)
         height = view.height if view is not None else None
@@ -258,3 +355,4 @@ class BatchingIngress:
         if self._buffer:
             self._wait.end(lanes=len(self._buffer), why="closed")
         self._buffer.clear()
+        self._holding = False

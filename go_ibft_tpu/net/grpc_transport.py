@@ -46,6 +46,9 @@ _FULL_METHOD = f"/{_SERVICE}/{_METHOD}"
 RETRY_KEY = ("go-ibft", "transport", "retries")
 SEND_FAILURE_KEY = ("go-ibft", "transport", "send_failures")
 PEER_RECONNECT_KEY = ("go-ibft", "transport", "peer_reconnects")
+# Inbound unary RPCs handled (one a message: every vote a peer sends is
+# one), counted before the decode so a malformed one counts too.
+RPCS_IN_KEY = ("go-ibft", "transport", "rpcs_in")
 
 
 def _identity(b: bytes) -> bytes:
@@ -123,6 +126,7 @@ class GrpcTransport:
         server = grpc.aio.server()
 
         async def _handle(request: bytes, context) -> bytes:
+            metrics.inc_counter(RPCS_IN_KEY)
             raw, ctx = decode_traced(request)
             try:
                 message = IbftMessage.decode(raw)
@@ -150,6 +154,9 @@ class GrpcTransport:
                         span=ctx.span_id,
                         sent_us=ctx.sent_us,
                         transport="grpc",
+                        # The socket the RPC came in on, as gRPC names its
+                        # remote end: one per peer's channel.
+                        peer=context.peer(),
                     )
                     # Only suppress the engine's own record when this
                     # transport carries the engine's track: otherwise the

@@ -40,6 +40,7 @@ from typing import Optional
 
 from ..chain import ChainRunner, WriteAheadLog
 from ..core import IBFT, BatchingIngress
+from ..core.validator_manager import calculate_quorum
 from ..crypto import PrivateKey
 from ..crypto.backend import ECDSABackend
 from ..net import GrpcTransport
@@ -80,12 +81,27 @@ def build_block_fn(node_id: int):
     return build
 
 
+def votes_to_quorum(powers, own: bytes) -> int:
+    """The fewest OTHER validators whose votes, with ``own``'s, reach the
+    quorum: ``quorum - 1`` where power is equal."""
+    need = calculate_quorum(sum(powers.values())) - powers[own]
+    votes = 0
+    for power in sorted((p for a, p in powers.items() if a != own), reverse=True):
+        if need <= 0:
+            break
+        need -= power
+        votes += 1
+    return votes
+
+
 class ValidatorNode:
     """One validator process: see the module docstring.
 
     Construction wires everything but opens no sockets; :meth:`run`
-    owns the lifecycle.  ``install_signal_handlers=False`` lets tests
-    embed a node in a process that keeps its own handlers.
+    owns the lifecycle.  ``install_signal_handlers=False`` lets an embedder
+    (a test, the benchmark's harness) run a node in a process that keeps
+    its own handlers; :meth:`request_stop` is then what SIGTERM is to the
+    binary.
     """
 
     def __init__(
@@ -146,7 +162,18 @@ class ValidatorNode:
             self._log, backend, None, batch_verifier=batch_verifier
         )
         self.engine.set_base_round_timeout(config.consensus.base_round_timeout_s)
-        self.ingress = BatchingIngress(self.engine.add_messages)
+        # Votes arrive one RPC at a time.  Where a flush can reach the device
+        # the ingress holds them until enough are in to decide (a quorum with
+        # this node's own vote) or the sockets go quiet; the host route
+        # verifies as they come, beside the arrivals.
+        self.ingress = BatchingIngress(
+            self.engine.add_messages,
+            burst_hint=(
+                votes_to_quorum(powers, self.key.address)
+                if self.scheduler is not None and config.sched_route != "host"
+                else None
+            ),
+        )
         self.transport = GrpcTransport(
             config.consensus.listen,
             config.consensus.peers,
@@ -194,6 +221,7 @@ class ValidatorNode:
         self.telemetry = None
         self._ledger_owned = False
         self._drained = False
+        self._stop_requested: Optional[asyncio.Event] = None
         self._started_at = time.monotonic()
 
     # -- lifecycle ------------------------------------------------------
@@ -202,7 +230,7 @@ class ValidatorNode:
         """Boot, serve, run the chain, drain; returns the drain report."""
         cfg = self.config
         loop = asyncio.get_running_loop()
-        stop_requested = asyncio.Event()
+        stop_requested = self._stop_requested = asyncio.Event()
         if self._install_signals:
             for sig in (signal.SIGTERM, signal.SIGINT):
                 loop.add_signal_handler(sig, stop_requested.set)
@@ -222,8 +250,13 @@ class ValidatorNode:
                 # bucket, table bucket) this committee can make the
                 # dispatcher launch — derived from the validator count, so
                 # a 100-validator node never meets its first 128-lane
-                # program on the flush thread mid-round.
-                self.scheduler.warmup_committee(len(cfg.validators))
+                # program on the flush thread mid-round — and only those:
+                # each ladder program is 7-15 s of boot from a warm cache.
+                # The proof API's read tier coalesces up to a full dispatch,
+                # so with it every width is loaded.
+                self.scheduler.warmup_committee(
+                    len(cfg.validators), read_tier=self.proof_api is not None
+                )
         await self.transport.start()
         bound_consensus = self.transport.bound_port
 
@@ -269,6 +302,12 @@ class ValidatorNode:
             stop_task.cancel()
             report = await self._drain(chain_task)
         return report
+
+    def request_stop(self) -> None:
+        """Ask :meth:`run` for the graceful drain a SIGTERM asks for (call
+        it from the loop ``run`` is on; nothing to stop before ``run``)."""
+        if self._stop_requested is not None:
+            self._stop_requested.set()
 
     async def _drain(self, chain_task: Optional[asyncio.Task]) -> dict:
         """Graceful shutdown, in dependency order (see module docstring)."""

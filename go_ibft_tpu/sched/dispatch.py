@@ -198,6 +198,13 @@ class CoalescedDispatcher:
         # node really dispatching (and at which bucket), not a setting.
         self._served_lock = threading.Lock()
         self._served: Dict[str, int] = {}
+        # The recover widths the device route launches, ascending, and the
+        # table rows each is launched with where boot fixed them
+        # (:meth:`warmup_committee`): a kind's lanes go out in chunks of at
+        # most the widest, each at the narrowest that holds it, so a flush of
+        # ANY size lands on a program boot loaded.  Every bucket until then.
+        self._rungs: Tuple[int, ...] = _BATCH_BUCKETS
+        self._rung_tables: Dict[int, int] = {}
 
     def describe(self) -> dict:
         """Shape of this dispatcher (scheduler stats / resize evidence)."""
@@ -206,6 +213,9 @@ class CoalescedDispatcher:
             "dp": self.dp,
             "sharded": self.mesh is not None,
             "cutover": self.cutover,
+            # The recover widths the device route launches (every bucket
+            # until ``warmup_committee`` loaded the committee's).
+            "widths": list(self._rungs),
         }
 
     def served(self) -> Dict[str, int]:
@@ -220,11 +230,25 @@ class CoalescedDispatcher:
     def _pad_lanes(self, n: int) -> int:
         """Mesh dispatches pin the lane dim to ``bucket(ceil(n/dp)) x dp``
         (every shard gets an identical local shape; pad lanes are dead);
-        single-device dispatches keep the pack functions' own bucketing
-        (``pad_lanes=0``)."""
-        if self.mesh is None or n == 0:
+        single-device dispatches go out at the narrowest of ``_rungs`` that
+        holds them (the pack functions' own bucket until boot chose fewer)."""
+        if n == 0:
             return 0
+        if self.mesh is None:
+            return _bucket(n, self._rungs)
         return _bucket((n + self.dp - 1) // self.dp, _BATCH_BUCKETS) * self.dp
+
+    def _table(self, addresses: List[bytes], lanes: int) -> np.ndarray:
+        """The claimed-address table of one launch at ``lanes`` wide, padded
+        (row 0 again, as its own bucket pads: no new member) to the rows boot
+        loaded that width with."""
+        table = pack_validator_table(addresses)
+        rows = self._rung_tables.get(lanes, 0)
+        if rows <= table.shape[0]:
+            return table
+        return np.concatenate(
+            [table, np.broadcast_to(table[0], (rows - table.shape[0], 5))]
+        )
 
     # -- public ----------------------------------------------------------
 
@@ -282,18 +306,41 @@ class CoalescedDispatcher:
                         )
                     )
 
-    def warmup_committee(self, n_validators: int) -> None:
+    def committee_rungs(
+        self, n_validators: int, read_tier: bool = False
+    ) -> Tuple[int, ...]:
+        """The recover widths a committee of ``n_validators`` can make this
+        dispatcher launch.  The consensus tier flushes at most a phase's
+        worth of lanes of one kind: every bucket up to the committee's own,
+        and under ``route="auto"`` none that only a flush below the cutover
+        would pick (the host serves those).  A read tier coalesces proof
+        ranges up to a full dispatch: every bucket."""
+        if read_tier or self.mesh is not None:
+            return _BATCH_BUCKETS
+        top = _bucket(min(n_validators, _BATCH_BUCKETS[-1]), _BATCH_BUCKETS)
+        low = 0
+        if self.route == "auto":
+            low = _bucket(min(self.cutover, top), _BATCH_BUCKETS)
+        return tuple(bb for bb in _BATCH_BUCKETS if low <= bb <= top)
+
+    def warmup_committee(
+        self, n_validators: int, read_tier: bool = False
+    ) -> None:
         """Pre-compile what a committee of ``n_validators`` can make this
-        dispatcher launch: every lane bucket (the consensus tier flushes
-        up to one phase's worth of lanes, the read tier coalesces proof
-        ranges up to a full dispatch), each with the membership table its
-        flush would pack — one claimed address per lane, capped by the
-        committee size."""
-        for bb in _BATCH_BUCKETS:
-            self.warmup(
-                lanes=(bb,),
-                table_rows=_bucket(min(bb, n_validators), _TABLE_BUCKETS),
-            )
+        dispatcher launch (:meth:`committee_rungs`), each width with the
+        membership table its flush would pack (one claimed address per
+        lane, capped by the committee size), and launch nothing else from
+        here on: a cached ladder executable costs 7-15 s of boot to load, a
+        cold one most of a minute to compile, so a 100-validator committee
+        loads two or three and not all six."""
+        rungs = self.committee_rungs(n_validators, read_tier)
+        tables = {
+            bb: _bucket(min(bb, n_validators), _TABLE_BUCKETS) for bb in rungs
+        }
+        for bb in rungs:
+            self.warmup(lanes=(bb,), table_rows=tables[bb])
+        if self.mesh is None:
+            self._rungs, self._rung_tables = rungs, tables
 
     def dispatch(
         self,
@@ -347,6 +394,22 @@ class CoalescedDispatcher:
     # -- device route ----------------------------------------------------
 
     def _device(self, msgs, lanes, owners) -> Tuple[np.ndarray, np.ndarray]:
+        cap = self._rungs[-1] * (self.dp if self.mesh is not None else 1)
+        if max(len(msgs), len(lanes)) > cap:
+            # More lanes of a kind than the widest program boot loaded: in
+            # chunks of that width, never at a shape that would compile here.
+            sender_ok = [
+                self._device(msgs[i : i + cap], (), owners)[0]
+                for i in range(0, len(msgs), cap)
+            ]
+            seal_ok = [
+                self._device((), lanes[i : i + cap], owners)[1]
+                for i in range(0, len(lanes), cap)
+            ]
+            return (
+                np.concatenate(sender_ok) if sender_ok else np.zeros(0, bool),
+                np.concatenate(seal_ok) if seal_ok else np.zeros(0, bool),
+            )
         sender_ok = np.zeros(len(msgs), dtype=bool)
         seal_ok = np.zeros(len(lanes), dtype=bool)
         if msgs:
@@ -368,8 +431,8 @@ class CoalescedDispatcher:
             # member by construction, so the kernel's (sig & member) mask
             # reduces to signature validity — tenant membership stays on
             # host where each chain's own set applies.
-            table = pack_validator_table(
-                list(dict.fromkeys(m.sender for m in msgs))
+            table = self._table(
+                list(dict.fromkeys(m.sender for m in msgs)), live.shape[0]
             )
             sender_ok = self._sig_mask(zw, r, s, v, claimed, table, live)[
                 : len(msgs)
@@ -378,8 +441,9 @@ class CoalescedDispatcher:
             hz, r, s, v, signers, live = pack_seal_lanes(
                 list(lanes), pad_lanes=self._pad_lanes(len(lanes))
             )
-            table = pack_validator_table(
-                list(dict.fromkeys(seal.signer for _h, seal in lanes))
+            table = self._table(
+                list(dict.fromkeys(seal.signer for _h, seal in lanes)),
+                live.shape[0],
             )
             seal_ok = self._sig_mask(hz, r, s, v, signers, table, live)[
                 : len(lanes)

@@ -131,6 +131,9 @@ class _Request:
     done: threading.Event = field(default_factory=threading.Event)
     error: Optional[BaseException] = None
     cancelled: bool = False
+    # ``sched.queue``: opened where the handle enqueues the request, closed
+    # by the flush that takes it (on the scheduler's thread).
+    queue_span: object = trace.NULL_SPAN
 
     def __post_init__(self) -> None:
         self.lanes = len(self.items)
@@ -368,10 +371,14 @@ class TenantScheduler:
         """Pre-compile the shared kernels (node startup; never mid-round)."""
         self._dispatcher.warmup(**kw)
 
-    def warmup_committee(self, n_validators: int) -> None:
+    def warmup_committee(
+        self, n_validators: int, read_tier: bool = False
+    ) -> None:
         """Pre-compile every shape a committee of ``n_validators`` can make
-        the dispatcher launch (node startup, while ``/readyz`` is 503)."""
-        self._dispatcher.warmup_committee(n_validators)
+        the dispatcher launch (node startup, while ``/readyz`` is 503);
+        ``read_tier``: a proof-serving tenant coalesces up to a full
+        dispatch, so every width."""
+        self._dispatcher.warmup_committee(n_validators, read_tier)
 
     def reconfigure(
         self,
@@ -569,6 +576,9 @@ class TenantScheduler:
                     f"lanes (cap {cap})"
                 )
             req.submitted_at = time.monotonic()
+            req.queue_span = trace.begin(
+                "sched.queue", kind=kind, lanes=req.lanes, tier=tenant.priority
+            )
             if tenant.calibrator is not None:
                 tenant.calibrator.observe(req.lanes, now=req.submitted_at)
             tenant.queue.append(req)
@@ -590,6 +600,20 @@ class TenantScheduler:
     def _oldest_ts_locked(self) -> Optional[float]:
         ts = [t.queue[0].submitted_at for t in self._tenants.values() if t.queue]
         return min(ts) if ts else None
+
+    def _no_company_due_locked(self) -> bool:
+        """Whether the window can gain nothing for the consensus tier: a
+        consensus tenant is an engine, whose ONE loop thread blocks under
+        every request, so once every consensus tenant has work queued (a
+        validator's scheduler has one: its own engine) no consensus lane is
+        left to come, and each millisecond waited is a millisecond of the
+        round.  The read tier rides in what that flush leaves.  Read tenants
+        alone never end the window early: a proof server's workers call one
+        tenant concurrently, and the window is what coalesces them."""
+        consensus = [
+            t for t in self._tenants.values() if t.rank == PRIORITY_RANK["consensus"]
+        ]
+        return bool(consensus) and all(t.queue for t in consensus)
 
     def _window_locked(self) -> float:
         """The coalescing window for the current backlog, from the
@@ -642,6 +666,8 @@ class TenantScheduler:
                 # delay.
                 while self._running and not self._paused:
                     if self._pending_lanes >= self.max_dispatch_lanes:
+                        break
+                    if self._no_company_due_locked():
                         break
                     oldest = self._oldest_ts_locked()
                     if oldest is None:
@@ -741,6 +767,8 @@ class TenantScheduler:
         return batch
 
     def _flush(self, batch: List[_Request]) -> None:
+        for req in batch:
+            req.queue_span.end(requests=len(batch))
         sender_reqs = [r for r in batch if r.kind == "senders"]
         seal_reqs = [r for r in batch if r.kind == "seals"]
         msgs: List[IbftMessage] = []

@@ -1,0 +1,425 @@
+"""The cell ``ecdsa-100v-node.grpc-flood`` (PR 39): the real files through
+``run.load_cell``, the metric list ISSUE 39 gives it as an ordered list, the
+deployment the configuration states, the traffic mix's arithmetic, the span
+reader its metrics share, the peer hosts (off ``jax``), and a CPU rehearsal
+of the cell's control flow at four validators through ``run.run`` with the
+platform check patched HERE (no number of which is a device number)."""
+
+import contextlib
+import gc
+import io
+import json
+import math
+import os
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+HERE = os.path.join(ROOT, "benchmark")
+for p in (ROOT, HERE):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+import run as bench_run  # noqa: E402  (benchmark/run.py)
+from benchmark.drivers import node as node_driver  # noqa: E402
+from benchmark.drivers import round0  # noqa: E402
+from benchmark.lib import peer_hosts  # noqa: E402
+from benchmark.lib.readers import read_metric  # noqa: E402
+
+CELL = "ecdsa-100v-node.grpc-flood"
+END_TO_END = ["finalize_p50_ms", "finalize_p90_ms", "setup_s"]
+PER_LAYER = [
+    "compiles_in_window",
+    "device_lane_share",
+    "dispatches_per_height",
+    "lane_occupancy",
+    "node_flushes_per_height",
+    "node_lanes_per_sched_dispatch",
+    "node_msgs_per_flush",
+    "node_multicast_ms_per_height",
+    "node_recover_us_per_lane",
+    "node_sched_dispatches_per_height",
+    "node_sched_queue_ms_per_height",
+    "node_wal_fsync_ms_per_height",
+    "warm_s",
+]
+NEW_IN_PR_39 = [name for name in PER_LAYER if name.startswith("node_")]
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
+COMPARED = [
+    "operations_failed",
+    "compiles_in_window",
+    "guarantees_broken",
+    "data_dir_in_memory",
+    "heights_against_their_labels",
+    "wal_heights_off_the_window",
+    "wal_lock_records_missing",
+    "wal_records_torn",
+    "send_failures",
+    "peer_reconnects",
+    "peer_send_errors",
+    "rpcs_in_off_the_peers_sends",
+    "peer_hosts_with_jax",
+]
+
+
+@pytest.fixture(scope="module")
+def cell():
+    return bench_run.load_cell(CELL)
+
+
+def test_the_cell_selects_the_metrics_the_issue_lists_in_this_order(cell):
+    assert cell["cell"] == {
+        "name": CELL,
+        "config": "ecdsa-100v-node",
+        "traffic": "grpc-flood",
+        "chips": 1,
+        "why": cell["cell"]["why"],
+    }
+    assert len(cell["cell"]["why"]) <= 200
+    assert [m["name"] for _p, m in cell["end_to_end"]] == END_TO_END
+    assert [m["name"] for _p, m in cell["per_layer"]] == PER_LAYER
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    layers = {
+        "node_msgs_per_flush": "ingress",
+        "node_flushes_per_height": "ingress",
+        "node_multicast_ms_per_height": "ingress",
+        "node_sched_dispatches_per_height": "verifier policy",
+        "node_lanes_per_sched_dispatch": "verifier policy",
+        "node_sched_queue_ms_per_height": "engine",
+        "node_wal_fsync_ms_per_height": "storage",
+        "node_recover_us_per_lane": "kernels",
+    }
+    for _path, m in cell["per_layer"]:
+        if m["name"] in NEW_IN_PR_39:
+            assert declared[m["name"]]["workloads"] == [CELL]
+            assert m["moves"] == "finalize_p50_ms" and m["layer"] == layers[m["name"]]
+            assert "drivers" not in m and "workloads" not in m
+    # Appended, and at the end: the cell, its configuration, its new metrics.
+    assert bench["workloads"][-1]["name"] == CELL
+    assert bench["configs"][-1]["name"] == "ecdsa-100v-node"
+    assert [m["name"] for m in bench["per_layer"][-len(NEW_IN_PR_39):]] == [
+        "node_msgs_per_flush",
+        "node_sched_dispatches_per_height",
+        "node_lanes_per_sched_dispatch",
+        "node_sched_queue_ms_per_height",
+        "node_wal_fsync_ms_per_height",
+        "node_multicast_ms_per_height",
+        "node_recover_us_per_lane",
+        "node_flushes_per_height",
+    ]
+    for name in ("device_lane_share", "lane_occupancy", "dispatches_per_height"):
+        assert declared[name]["workloads"][-1] == CELL
+    # ``flushes_per_height`` keeps the one cell tests/benchmark/test_cell_300v_byz30.py
+    # pins it to; this cell reads the same reader under its own prefix.
+    assert declared["flushes_per_height"]["workloads"] == ["ecdsa-300v.flood-byz30"]
+    # The library cells get none of the node's metrics.
+    for other in ("ecdsa-100v.flood", "ecdsa-300v.flood-byz30", "ecdsa-100v.sync"):
+        got = [m["name"] for _p, m in bench_run.load_cell(other)["per_layer"]]
+        assert not set(got) & set(NEW_IN_PR_39)
+    assert sum(1 for w in bench["workloads"] if w["chips"] == 4) == 1
+
+
+@pytest.mark.parametrize("accepted", ["recover_us_per_lane", "flushes_per_height"])
+def test_two_metrics_are_accepted_readers_on_another_cell(cell, accepted):
+    files = {m["name"]: m for _p, m in cell["per_layer"]}
+    with open(os.path.join(HERE, "layer_metrics", accepted + ".json")) as fh:
+        old = json.load(fh)
+    new = files["node_" + accepted]
+    assert new["read"].get("module", "node_" + accepted) == old["read"].get("module", accepted)
+    for key in ("layer", "unit", "better", "source", "moves"):
+        assert new[key] == old[key], key
+
+
+def test_the_deployment_is_the_binary_s_and_states_its_guarantees(cell):
+    cfg, mix = cell["config"], cell["traffic"]
+    with open(os.path.join(HERE, "configs", "ecdsa-100v.json")) as fh:
+        library = json.load(fh)
+    for key in ("scheme", "validators", "quorum", "max_faulty", "voting_power", "base_round_timeout_s"):
+        assert cfg[key] == library[key], key  # the committee of ecdsa-100v, unchanged
+    assert cfg["architecture"] is None and cfg["reduced"] == ["peers"]
+    assert len(cfg["source"]) <= 200 and "docs/DEPLOYMENT.md" in cfg["source"]
+    toml = cfg["node_toml"]
+    assert toml["consensus"]["listen"] == "127.0.0.1:0"
+    assert toml["sched"] == {"enabled": True, "route": "auto"}
+    assert toml["proof_api"]["listen"] == "" and toml["telemetry"]["listen"] == ""
+    assert cfg["guarantees"]["finality"] == library["guarantees"]["finality"]
+    assert "fsynced" in cfg["guarantees"]["durability"]
+    assert "replayed from disk" in cfg["guarantees"]["durability"]
+    assert "TCP socket, once" in cfg["guarantees"]["delivery"]
+    assert cfg["assumed"]["peer_hosts"] == 4
+    assert cfg["assumed"]["corrupted_peers_per_height"] == round0.corrupted_peers(100, mix["corrupt_share"]) == 10
+    assert [len(s) for s in peer_hosts.split(list(range(1, 100)), 4)] == [25, 25, 25, 24]
+
+    with open(os.path.join(HERE, "traffic", "flood.json")) as fh:
+        flood = json.load(fh)
+    assert mix["driver"] == "node" and mix["delay_ms"] == 0
+    for key in ("corrupt_share", "throw_away_heights", "warm_heights", "presign_msgs_per_s"):
+        assert mix[key] == flood[key], key
+    assert round0.presigned_heights(mix, 100, 20.0) == 1 + 3 + math.ceil(20 * 8000 / 200) == 804
+    assert 0 < mix["trace_seconds"] <= 20 and mix["trace_seconds_why"]
+
+
+def test_the_span_reader_reads_each_stat_and_returns_nothing_without_the_span(cell):
+    files = {m["name"]: (p, m) for p, m in cell["per_layer"]}
+
+    def value(name, spans, heights=2):
+        path, metric = files[name]
+        return read_metric({"spans": spans, "counts": {"operations": heights}}, metric, path)
+
+    def span(name, ts, dur, **args):
+        return ("X", name, "t", ts, dur, args or None)
+
+    spans = [
+        span("ingress.flush", 0, 10, lanes=1, cut=False),
+        span("ingress.flush", 20, 10, lanes=60, cut=False),
+        span("ingress.flush", 40, 10, lanes=38, cut=False),
+        span("sched.dispatch", 0, 9000, route="device", lanes=60, senders=60, seals=0),
+        span("sched.dispatch", 0, 300, route="host", lanes=4, senders=4, seals=0),
+        span("sched.queue", 0, 1500, kind="senders", lanes=60, tier="consensus"),
+        span("sched.queue", 0, 500, kind="seals", lanes=4, tier="consensus"),
+        span("wal.append", 0, 900, kind="lock", height=1, fsync_ms=0.75),
+        span("wal.append", 0, 700, kind="finalize", height=1, fsync_ms=0.5),
+        span("net.multicast", 1000, 100, peers=99, type=1),
+        span("net.send", 1050, 2000, peer="v1", attempt=0),
+        span("net.send", 1060, 3000, peer="v2", attempt=0),
+        span("net.send", 9000, 1000, peer="v1", attempt=0),
+        ("i", "sched.queue", "t", 0, 0, None),
+    ]
+    assert value("node_msgs_per_flush", spans) == 38
+    assert value("node_sched_dispatches_per_height", spans) == 1.0
+    assert value("node_lanes_per_sched_dispatch", spans) == 32.0
+    assert value("node_sched_queue_ms_per_height", spans) == pytest.approx(1.0)
+    assert value("node_wal_fsync_ms_per_height", spans) == pytest.approx(0.625)
+    # 1000 -> 4060 us and 9000 -> 10000 us open, over two heights.
+    assert value("node_multicast_ms_per_height", spans) == pytest.approx(2.03)
+    assert value("node_flushes_per_height", spans) == 1.5  # the accepted reader, as it is
+    # The parent's program has none of the new spans: nothing, and no raise.
+    old = [s for s in spans if s[1] in ("ingress.flush", "sched.dispatch", "net.send")]
+    for name in ("node_sched_queue_ms_per_height", "node_wal_fsync_ms_per_height"):
+        assert value(name, old) is None
+    for name in NEW_IN_PR_39:
+        assert value(name, []) is None and value(name, None) is None
+    assert value("node_sched_queue_ms_per_height", spans, heights=0) is None
+    # ... and no trace, no kernel metric.
+    path, metric = files["node_recover_us_per_lane"]
+    assert read_metric({"trace": None, "ledger_traced": None, "counts": {}}, metric, path) is None
+
+
+def test_the_peer_hosts_stay_off_jax_and_answer_on_their_own_listeners():
+    """A peer-host process as the driver starts it: its listeners up, its
+    share of two signed heights loaded, ``jax`` never imported."""
+    code = (
+        "import sys, pickle; sys.path.insert(0, %r)\n"
+        "from benchmark.lib.committee import Committee\n"
+        "from benchmark.lib.peer_hosts import PeerHosts\n"
+        "if __name__ == '__main__':\n"
+        "    c = Committee(4, 1)\n"
+        "    hosts = PeerHosts(4, 1, 2)\n"
+        "    ports = hosts.listen()\n"
+        "    loaded = hosts.load([pickle.dumps(c.traffic(h, 1)) for h in (1, 2)])\n"
+        "    channels = hosts.connect('127.0.0.1:1')\n"
+        "    reports = hosts.report()\n"
+        "    hosts.close()\n"
+        "    print(sorted(ports), loaded, channels, [r['jax'] for r in reports],\n"
+        "          sum(r['sent'] for r in reports), 'jax' in sys.modules)\n"
+    ) % ROOT
+    path = os.path.join(ROOT, ".cache", "benchmark_peer_probe.py")
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as fh:
+        fh.write(code)
+    try:
+        proc = subprocess.run(
+            [sys.executable, path], capture_output=True, text=True, timeout=120, cwd=ROOT
+        )
+    finally:
+        os.remove(path)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    # Three peers on three ports, their six COMMITs loaded, three channels,
+    # nothing sent, and jax in neither host nor the parent.
+    assert proc.stdout.strip() == "[1, 2, 3] 6 3 [False, False] 0 False"
+
+
+def _tiny(validators: int):
+    real = bench_run.load_cell
+
+    def load(name, root=bench_run.ROOT):
+        got = real(name, root)
+        cfg, mix = got["config"], got["traffic"]
+        cfg["validators"] = validators
+        cfg["quorum"] = 2 * validators // 3 + 1
+        cfg["assumed"]["peer_hosts"] = 2
+        mix.update(presign_msgs_per_s=2400, trace_seconds=0.3)
+        return got
+
+    return load
+
+
+def _run(monkeypatch, trace: int, seconds: float, validators: int = 4):
+    import jax
+
+    monkeypatch.setattr(
+        bench_run,
+        "require_tpu",
+        lambda chips: {"platform": jax.devices()[0].platform, "kind": "TPU v5 lite", "count": 1},
+    )
+    monkeypatch.setattr(bench_run, "load_cell", _tiny(validators))
+    out = io.StringIO()
+    args = bench_run.argparse.Namespace(workload=CELL, seed=5, seconds=seconds, trace=trace)
+    try:
+        with contextlib.redirect_stdout(out):
+            rc = bench_run.run(args)
+    finally:
+        gc.unfreeze()
+        shutil.rmtree(os.path.join(ROOT, ".cache", "benchmark_trace", CELL), ignore_errors=True)
+    lines = [json.loads(line) for line in out.getvalue().splitlines() if line.startswith("{")]
+    for line in lines:  # this run's data_dir, and no other worker's
+        if "data_dir" in line:
+            shutil.rmtree(os.path.join(ROOT, line["data_dir"]), ignore_errors=True)
+    return rc, lines
+
+
+def test_the_node_at_four_validators_finalizes_over_sockets_and_reads_its_wal_back(monkeypatch):
+    rc, lines = _run(monkeypatch, 0, 1.0)
+    result, info = lines[-1], lines[-2]
+    assert rc == 0 and set(result) == RESULT_KEYS
+    assert result["correct"] is True and result["failed"] == 0 and result["attempted"] >= 5
+    assert set(result["metrics"]) == set(END_TO_END)
+    assert result["device"]["platform"] == "cpu"  # named, never passed off as a chip
+    assert list(result)[-1] == "compared" and list(result["compared"]) == COMPARED
+    assert all(c["value"] == 0 == c["limit"] for c in result["compared"].values())
+    assert info["driver"] == "node" and info["checked"] is True
+    assert info["heights_checked"] + len(info["heights_not_measured"]) >= result["attempted"]
+    assert info["heights_oracle_checked"] >= 1
+    assert info["cutover"] == 16 and info["cutover_source"] == "default"
+    assert info["sched_route"] == "auto" and info["recover_widths_loaded"] == [8]
+    assert info["peer_hosts"] == [2, 1] and info["peer_channels"] == 3
+    assert len(info["throw_away_heights_ms"]) == 1 and len(info["warm_heights_ms"]) == 3
+    assert info["throw_away_failed"] == 0 and info["warm_failed"] == 0
+    assert info["presigned_heights"] == 1 + 3 + 300
+    # Every vote crossed a socket once, and the log holds what was finalized.
+    assert info["rpcs_in"] == info["peer_sends"]["acked"] == info["peer_sends"]["sent"] > 0
+    assert info["wal"]["blocks_replayed"] == info["chain_height"] >= result["attempted"] + 4
+    assert info["wal"]["lock_records"] == info["wal"]["blocks_replayed"]
+    assert info["preprepare_hop_ms_p50"] > 0
+    assert info["data_dir"].startswith(".cache/benchmark_node/")
+    # The node's own boot line is an earlier line too, as the binary prints it.
+    boot = next(line for line in lines if "node_boot" in line)
+    assert boot["resumed_at_height"] == 1 and boot["proof_api_port"] is None
+
+
+def test_the_traced_node_reports_every_metric_its_spans_feed(monkeypatch):
+    rc, lines = _run(monkeypatch, 1, 1.0)
+    result = lines[-1]
+    assert rc == 0 and set(result) == RESULT_KEYS | {"breakdown"}
+    assert result["correct"] is True
+    got = result["metrics"]
+    # All but the two that need a device dispatch: four validators stay on the host.
+    assert set(got) == set(PER_LAYER) - {"node_recover_us_per_lane", "lane_occupancy"}
+    assert got["device_lane_share"]["value"] == 0.0
+    assert got["node_msgs_per_flush"]["value"] >= 1
+    assert got["node_sched_dispatches_per_height"]["value"] >= 4
+    assert got["node_wal_fsync_ms_per_height"]["value"] > 0
+    assert got["node_sched_queue_ms_per_height"]["value"] > 0
+    assert got["node_multicast_ms_per_height"]["value"] > 0
+    assert got["compiles_in_window"]["value"] == 0
+    assert "finalize_p50_ms" not in got
+    assert {"busy_s", "window_s"} <= set(result["device"])
+
+
+# -- the comparison can fail: a broken guarantee, planted, reads incorrect ----
+
+
+def _without_a_lock_record(st) -> None:
+    """The log as a node that skipped one ``append_lock`` would leave it."""
+    height = st.finalized[0][0].height
+    with open(st.node.wal_path, "rb") as fh:
+        lines = fh.read().splitlines(keepends=True)
+    kept = [
+        line for line in lines
+        if (json.loads(line)["kind"], json.loads(line)["height"]) != ("lock", height)
+    ]
+    assert len(kept) == len(lines) - 1
+    with open(st.node.wal_path, "wb") as fh:
+        fh.writelines(kept)
+
+
+def _with_a_torn_tail(st) -> None:
+    """The log as a crash in the middle of the last record's write (one
+    that no fsync had covered) would leave it."""
+    os.truncate(st.node.wal_path, os.path.getsize(st.node.wal_path) - 10)
+
+
+def _with_a_vote_delivered_twice(st) -> None:
+    from go_ibft_tpu.net import grpc_transport as wire
+    from go_ibft_tpu.utils import metrics
+
+    metrics.inc_counter(wire.RPCS_IN_KEY)
+
+
+def _on_a_tmpfs(st) -> None:
+    st.data_dir_mounts = os.path.join(st.data_dir, "mounts")
+    with open(st.data_dir_mounts, "w") as fh:
+        fh.write("/dev/root / ext4 rw 0 0\ntmpfs %s tmpfs rw,size=1g 0 0\n" % st.data_dir)
+
+
+@pytest.mark.parametrize(
+    "plant, reads",
+    [
+        (_without_a_lock_record, {"wal_lock_records_missing": 1}),
+        # The last height's ``finalize`` record is the torn one: its lock
+        # record has none after it.
+        (_with_a_torn_tail, {"wal_records_torn": 1, "wal_lock_records_missing": 1}),
+        (_with_a_vote_delivered_twice, {"rpcs_in_off_the_peers_sends": 1}),
+        (_on_a_tmpfs, {"data_dir_in_memory": 1}),
+    ],
+)
+def test_a_broken_guarantee_reads_incorrect_through_the_harness(monkeypatch, plant, reads):
+    """The controls: what the configuration guarantees, broken between the
+    window and ``check``, comes out as ``correct`` false, by the comparison
+    that guards it (the torn tail also loses the last height)."""
+    real_check, real_backed = node_driver.check, node_driver.memory_backed
+
+    def check(st):
+        plant(st)
+        return real_check(st)
+
+    def backed(path, mounts="/proc/mounts"):
+        planted = os.path.join(path, "mounts")
+        return real_backed(path, planted if os.path.exists(planted) else mounts)
+
+    monkeypatch.setattr(node_driver, "check", check)
+    monkeypatch.setattr(node_driver, "memory_backed", backed)
+    rc, lines = _run(monkeypatch, 0, 1.0)
+    result = lines[-1]
+    assert rc == 0 and result["correct"] is False and result["failed"] == 0
+    off = {k: c["value"] for k, c in result["compared"].items() if c["value"] != c["limit"]}
+    assert off.pop("guarantees_broken") == 1
+    off.pop("wal_heights_off_the_window", None)
+    assert off == reads
+
+
+@pytest.mark.parametrize(
+    "table, in_memory",
+    [
+        ("/dev/sda1 / ext4 rw 0 0\n", False),
+        ("/dev/sda1 / ext4 rw 0 0\nrepo /w 9p rw,trans=virtio 0 0\n", False),
+        ("/dev/sda1 / ext4 rw 0 0\ntmpfs /w tmpfs rw 0 0\n", True),
+        ("/dev/sda1 / ext4 rw 0 0\nnone /w/repo/.cache ramfs rw 0 0\n", True),
+        ("tmpfs / tmpfs rw 0 0\n/dev/sda1 /w ext4 rw 0 0\n", False),
+        # An overlay writes to its upper layer: on a tmpfs, on a disk, or
+        # somewhere this mount namespace does not show.
+        ("tmpfs /run tmpfs rw 0 0\noverlay /w overlay rw,lowerdir=/l,upperdir=/run/up,workdir=/run/wk 0 0\n", True),
+        ("/dev/sda1 /mnt ext4 rw 0 0\noverlay /w overlay rw,lowerdir=/l,upperdir=/mnt/up,workdir=/mnt/wk 0 0\n", False),
+        ("overlay / overlay rw,lowerdir=/l,upperdir=/var/lib/docker/up,workdir=/var/lib/docker/wk 0 0\n", False),
+        ("", False),
+    ],
+)
+def test_a_data_dir_in_memory_is_told_from_one_on_a_disk(tmp_path, table, in_memory):
+    mounts = tmp_path / "mounts"
+    mounts.write_text(table)
+    assert node_driver.memory_backed("/w/repo/.cache/benchmark_node/1-2", str(mounts)) is in_memory

@@ -254,7 +254,7 @@ def test_fleet_pins_only_host_route_children_to_the_cpu(tmp_path, monkeypatch):
 # ---------------------------------------------------------------------------
 
 
-def test_warmup_committee_derives_buckets_from_the_validator_count(monkeypatch):
+def _warmed_by(monkeypatch):
     from go_ibft_tpu.sched.dispatch import CoalescedDispatcher
 
     warmed = []
@@ -263,19 +263,43 @@ def test_warmup_committee_derives_buckets_from_the_validator_count(monkeypatch):
         "warmup",
         lambda self, lanes=(8,), table_rows=8: warmed.append((tuple(lanes), table_rows)),
     )
-    dispatcher = CoalescedDispatcher(route="device")
-    dispatcher.warmup_committee(100)
-    assert warmed == [
-        ((8,), 8),
-        ((32,), 128),
-        ((128,), 128),
-        ((512,), 128),
-        ((1024,), 128),
-        ((2048,), 128),
-    ]
-    warmed.clear()
-    dispatcher.warmup_committee(4)
-    assert {rows for _lanes, rows in warmed} == {8}
+    return CoalescedDispatcher, warmed
+
+
+ALL_SIX = [((8,), 8), ((32,), 128), ((128,), 128), ((512,), 128), ((1024,), 128), ((2048,), 128)]
+
+
+@pytest.mark.parametrize(
+    "route, validators, read_tier, want",
+    [
+        # A phase of 100 validators is at most 128 lanes of a kind: three
+        # ladder programs on the forced device route, and under "auto" none
+        # that only a flush below the 16-lane cutover would pick (PR 39).
+        ("device", 100, False, ALL_SIX[:3]),
+        ("auto", 100, False, ALL_SIX[1:3]),
+        ("auto", 300, False, [((32,), 128), ((128,), 128), ((512,), 512)]),
+        # The proof API's read tier coalesces up to a full dispatch: all six.
+        ("device", 100, True, ALL_SIX),
+        ("auto", 100, True, ALL_SIX),
+        ("device", 4, False, [((8,), 8)]),
+        ("auto", 4, False, [((8,), 8)]),
+    ],
+)
+def test_warmup_committee_derives_buckets_from_the_validator_count(
+    monkeypatch, route, validators, read_tier, want
+):
+    cls, warmed = _warmed_by(monkeypatch)
+    dispatcher = cls(route=route)
+    dispatcher.warmup_committee(validators, read_tier=read_tier)
+    assert warmed == want
+    # ... and launches nothing else from then on: a flush of any size goes
+    # out in chunks of the widest width loaded, each at the narrowest that
+    # holds it, with the table rows that width was loaded with.
+    assert dispatcher._rungs == tuple(lanes[0] for lanes, _rows in want)
+    assert dispatcher._pad_lanes(1) == want[0][0][0]
+    assert dispatcher._pad_lanes(want[-1][0][0]) == want[-1][0][0]
+    table = dispatcher._table([b"\x01" * 20], want[-1][0][0])
+    assert table.shape[0] == want[-1][1] and (table == table[0]).all()  # row 0 again
 
 
 def test_dispatcher_counts_what_served_each_flush():

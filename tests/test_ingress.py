@@ -186,3 +186,109 @@ async def test_the_tracing_predicate_runs_once_a_burst_not_once_a_message(
     ing.close()
     assert [len(b) for b in batches] == [300, 300, 300]
     assert asked == ["ingress.wait", "ingress.flush"] * 3
+
+
+def _typed(i: int, type_: MessageType) -> IbftMessage:
+    m = _msg(i)
+    m.type = type_
+    return m
+
+
+async def test_a_burst_hint_holds_the_first_vote_for_company():
+    """PR 39: a validator whose flushes can reach a device tells its ingress
+    how many votes a decision takes.  Votes that then trickle in one a loop
+    turn, each too few to trip either flow detector, land in ONE flush once
+    the transport goes quiet, not one flush each; a lone vote goes out after
+    the same quiet gap, never held to the cap; a PREPREPARE is not held and
+    flushes at the end of its tick with whatever is; without the hint, or
+    with a hint under the cutover, nothing changes."""
+    batches = []
+    hinted = BatchingIngress(
+        lambda b: batches.append(len(b)), max_delay=0.01, eager_cutover=16, burst_hint=66
+    )
+    assert hinted.burst_hint == 66
+    for i in range(20):
+        hinted.submit(_typed(i, MessageType.PREPARE))
+        await asyncio.sleep(0)
+        await asyncio.sleep(0)
+    assert batches == []  # twenty loop turns on, still held
+    await asyncio.sleep(0.15)  # quiet for BURST_QUIET_FACTOR windows, with room
+    assert batches == [20]
+    hinted.submit(_typed(9, MessageType.PREPREPARE))
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    assert batches == [20, 1]
+    # One vote and then silence (its peers are down): out after the quiet
+    # gap, long before the cap (64 windows = 0.64 s here).
+    hinted.submit(_typed(10, MessageType.COMMIT))
+    await asyncio.sleep(0.15)
+    assert batches == [20, 1, 1]
+    # A PREPREPARE takes what is held with it, at the end of its tick.
+    hinted.submit(_typed(11, MessageType.COMMIT))
+    hinted.submit(_typed(12, MessageType.PREPREPARE))
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    assert batches == [20, 1, 1, 2]
+    hinted.close()
+
+    for kwargs in ({}, {"burst_hint": 9}):  # no hint; a committee the host serves
+        batches.clear()
+        eager = BatchingIngress(
+            lambda b: batches.append(len(b)), max_delay=0.01, eager_cutover=16, **kwargs
+        )
+        assert eager.burst_hint is None
+        for i in range(3):
+            eager.submit(_typed(i, MessageType.PREPARE))
+            await asyncio.sleep(0)
+            await asyncio.sleep(0)
+        assert batches == [1, 1, 1]
+        eager.close()
+
+
+async def test_a_held_burst_is_handed_over_full_quiet_or_capped():
+    """The three ends of a burst held under ``burst_hint``, each named on
+    its ``ingress.wait``: enough to decide (as many votes as the hint, and
+    the transport quiet for ONE window: with a third of the peers silent a
+    phase waits for nobody, and stragglers on the quorum's heels share its
+    flush), the transport quiet for ``BURST_QUIET_FACTOR`` windows whatever
+    is held, or held ``BURST_HOLD_FACTOR`` windows while arrivals keep
+    coming."""
+    batches = []
+    rec = trace.enable()
+    try:
+        ing = BatchingIngress(
+            lambda b: batches.append(len(b)), max_delay=0.004, eager_cutover=16, burst_hint=32
+        )
+        quiet, hold = (f * ing.max_delay for f in (ing.BURST_QUIET_FACTOR, ing.BURST_HOLD_FACTOR))
+        for i in range(32):
+            ing.submit(_typed(i, MessageType.COMMIT))
+        assert batches == []  # enough to decide: held for what is on its heels
+        await asyncio.sleep(0)
+        for i in range(3):
+            ing.submit(_typed(i, MessageType.COMMIT))
+        await asyncio.sleep(3 * ing.max_delay)  # under the quiet gap of four
+        assert batches == [35]
+        for i in range(5):  # a few of the next (most peers are down), then silence
+            ing.submit(_typed(i, MessageType.COMMIT))
+        await asyncio.sleep(6 * quiet)
+        assert batches == [35, 5]
+        ing.burst_hint = 4  # the set shrank under the cutover: no hold any more
+        assert ing.burst_hint is None
+        ing.close()
+        drip = BatchingIngress(  # a drip that never pauses: quiet cannot end it
+            lambda b: batches.append(len(b)), max_delay=0.004, eager_cutover=10**5, burst_hint=10**6
+        )
+        t0 = time.monotonic()
+        while not batches[2:]:  # a steady drip, well inside the quiet gap
+            drip.submit(_typed(0, MessageType.PREPARE))
+            await asyncio.sleep(quiet / 8)
+            assert time.monotonic() - t0 < 5.0
+        held = time.monotonic() - t0
+        drip.close()
+    finally:
+        trace.disable()
+    waits = [r for r in rec.snapshot() if r[1] == "ingress.wait"]
+    assert [w[5]["why"] for w in waits] == ["full", "quiet", "capped"]
+    assert [w[5]["lanes"] for w in waits] == batches
+    assert waits[1][4] >= 0.9e6 * quiet  # quiet that long after its last arrival
+    assert 0.95 * hold <= held < 4 * hold  # held its limit, not for ever

@@ -522,3 +522,68 @@ def test_speculation_cache_owner_scoped_per_tenant():
     )
     spec_a.stop()
     spec_b.stop()
+
+
+@pytest.mark.parametrize(
+    "tenants, expect_wait",
+    [
+        # A validator's scheduler has ONE consensus tenant, and its handle
+        # blocks the engine's loop under every request: no company can come,
+        # so the flush does not wait out the window (PR 39).
+        ([("node", "consensus")], False),
+        # An idle read tier never holds the consensus tier back either.
+        ([("node", "consensus"), ("proofs", "read")], False),
+        # A second consensus tenant that has nothing queued MAY still add to
+        # the flush: the window is waited, as before.
+        ([("node", "consensus"), ("other", "consensus")], True),
+    ],
+)
+def test_a_flush_does_not_wait_for_company_that_cannot_come(tenants, expect_wait):
+    r = build_signed_round(4, seed=11)
+    window = 0.4
+    sched = TenantScheduler(window_s=window, route="host", calibrate=False)
+    handles = [
+        sched.register(name, _src(11, 4), priority=priority)
+        for name, priority in tenants
+    ]
+    with sched:
+        t0 = time.monotonic()
+        mask = handles[0].verify_senders(r.prepares)
+        elapsed = time.monotonic() - t0
+    assert mask.all()
+    if expect_wait:
+        assert elapsed >= window * 0.9, f"flushed after {elapsed:.3f}s, before the window"
+    else:
+        assert elapsed < window / 2, f"waited {elapsed:.3f}s for nobody"
+
+
+@pytest.mark.parametrize("consensus_registered", [True, False])
+def test_a_read_tenant_s_concurrent_callers_still_share_the_window(consensus_registered):
+    """The read tier's tenant is called by a pool of workers at once: with
+    the consensus tier idle (or none registered) its first request waits the
+    window out, and the second caller's lanes ride in the same flush."""
+    r = build_signed_round(4, seed=11)
+    window = 0.4
+    sched = TenantScheduler(window_s=window, route="host", calibrate=False)
+    if consensus_registered:
+        sched.register("node", _src(11, 4))
+    proofs = sched.register("proofs", _src(11, 4), priority="read")
+    masks = {}
+
+    def call(name, messages):
+        masks[name] = proofs.verify_senders(messages)
+
+    with sched:
+        t0 = time.monotonic()
+        first = threading.Thread(target=call, args=("first", r.prepares))
+        second = threading.Thread(target=call, args=("second", r.prepares))
+        first.start()
+        time.sleep(window / 4)
+        second.start()
+        first.join()
+        elapsed = time.monotonic() - t0
+        second.join()
+        stats = sched.stats()
+    assert masks["first"].all() and masks["second"].all()
+    assert elapsed >= window * 0.9, f"flushed after {elapsed:.3f}s, before the window"
+    assert stats["dispatches"] == 1 and stats["coalesced_requests"] == 2

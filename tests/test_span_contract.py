@@ -581,3 +581,74 @@ def test_an_ingress_wait_a_flush_with_its_why(cluster_counts):
     assert sorted(w[5]["lanes"] for w in waits) == sorted(
         [1, n - 1, n] * n * HEIGHTS
     )
+
+
+# ---------------------------------------------------------------------------
+# the deployed node's round-0 height (PR 39): the WAL's and the scheduler's
+# spans, at four validators on the host route, over real sockets
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def node_heights():
+    """Two heights of an embedded ``ValidatorNode`` (``route = "auto"``: at
+    four validators every flush is under the cutover), its three peers on
+    loopback sockets, as the benchmark's driver ``node`` runs them."""
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+    from test_node_deployment_reference import node_run
+
+    import shutil
+
+    st = node_run(4, 39, "auto")
+    shutil.rmtree(st.data_dir, ignore_errors=True)
+    assert st.checked is True, st.info.get("guarantee_broken")
+    return [r for r in st.records if r[0] == "X"]
+
+
+def _end(record) -> int:
+    return record[3] + record[4]
+
+
+def test_a_node_height_appends_its_lock_then_its_finalize_inside_engine_finalize(
+    node_heights,
+):
+    """``wal.append`` twice a height: ``kind`` lock when the PREPARE quorum
+    lands, then ``kind`` finalize, which is over (fsynced: ``fsync_ms``)
+    before ``engine.finalize`` returns."""
+    appends = [r for r in node_heights if r[1] == "wal.append"]
+    assert [(r[5]["kind"], r[5]["height"]) for r in appends] == [
+        (k, h) for h in range(1, HEIGHTS + 1) for k in ("lock", "finalize")
+    ]
+    for h in range(1, HEIGHTS + 1):
+        lock, finalize = (r for r in appends if r[5]["height"] == h)
+        (outer,) = [
+            r for r in node_heights if r[1] == "engine.finalize" and r[5]["height"] == h
+        ]
+        assert _end(lock) <= outer[3]  # locked before the height was final
+        assert outer[3] <= finalize[3] and _end(finalize) <= _end(outer) + 1
+        for record in (lock, finalize):
+            assert 0 < record[5]["fsync_ms"] * 1e3 <= record[4] + 1
+
+
+def test_a_node_never_dispatches_more_often_than_its_handle_queued(node_heights):
+    """One ``sched.queue`` a request of the engine's handle, from the enqueue
+    to the flush that takes it; a flush is one ``sched.dispatch``, which
+    starts when the last of its requests has left the queue and carries
+    their lanes."""
+    queued = sorted((r for r in node_heights if r[1] == "sched.queue"), key=_end)
+    dispatched = sorted((r for r in node_heights if r[1] == "sched.dispatch"), key=lambda r: r[3])
+    assert 0 < len(dispatched) <= len(queued)
+    assert sum(r[5]["lanes"] for r in queued) == sum(r[5]["lanes"] for r in dispatched)
+    assert {r[5]["kind"] for r in queued} == {"senders", "seals"}
+    assert all(r[5]["tier"] == "consensus" and r[5]["requests"] >= 1 for r in queued)
+    # One tenant whose loop blocks under every request: a flush takes one.
+    assert len(dispatched) == len(queued)
+    for wait, flush in zip(queued, dispatched):
+        assert _end(wait) <= flush[3] + 1 and wait[5]["lanes"] == flush[5]["lanes"]
+    # Four verifier calls a height at least: the PREPREPARE, the PREPAREs,
+    # the COMMITs' envelopes, their seals (more where the sockets spread a
+    # phase over several flushes).
+    assert len(queued) >= 4 * HEIGHTS

@@ -120,11 +120,23 @@ class BatchingIngress:
     per message turns a 100-validator flood into 100 one-lane dispatches.
     (Both detectors above read the wall clock, so from inside that loop a
     flood held back by slow flushes looks like a trickle.)  After a flush
-    that blocked for more than ``HELD_BACK_FACTOR`` windows the next flush
-    therefore waits the ``max_delay`` ceiling to let the backlog in — a
-    wait that is small against the flush that caused it, and one a
+    that blocked for more than ``HELD_BACK_FACTOR`` windows the next burst,
+    if it is still under ``eager_cutover`` when its turn ends, therefore
+    waits the ``max_delay`` ceiling to let the backlog in (``held_back``) —
+    a wait that is small against the flush that caused it, and one a
     sub-cutover host flush (fractions of a millisecond per lane) never
     triggers.
+
+    **Decided when the turn ends.**  Which of these a burst gets is decided
+    once, at the END of the loop turn its first message came in, from the
+    buffer as the turn left it, not guessed from that first message.  A
+    burst that is device-sized there (``>= eager_cutover``) was delivered
+    whole, so there is no trickle to wait for and it is handed over at once:
+    ``whole`` where the held-back ceiling would have been waited (a loopback
+    phase after a device dispatch: 2 ms a flood, twice a height, until PR
+    41), ``soon`` otherwise.  And a PREPREPARE is never timed, on any path
+    (one proposer a round, everything waits for it and nothing can overtake
+    it): it goes out at the end of its turn with whatever is buffered.
 
     **A burst worth waiting for.**  Over a socket transport a phase trickles
     in, an RPC a loop turn or two, and every flush blocks the loop under its
@@ -143,13 +155,13 @@ class BatchingIngress:
     waits for nobody, and where every peer votes the stragglers that follow
     on the quorum's heels share its dispatch and not a second one), or when
     it has been held ``BURST_HOLD_FACTOR`` windows (``capped``: a drip that
-    never pauses), whichever is first.  A PREPREPARE is not held (one
-    proposer a round, and everything waits for it): it flushes at the end of
-    its tick, with whatever is held.  The gaps are sized by the one socket
-    transport measured (TPU v5e host, 100 validators on loopback, PR 39:
-    RPCs 0.25-0.5 ms apart, every flush a device dispatch that blocks the
-    loop 8 ms or more; PERF.md §6 has the runs, with a third of the peers
-    silent among them); the cap only bounds what a peer that drips can cost.
+    never pauses), whichever is first.  A PREPREPARE is not held either: it
+    flushes at the end of its tick, with whatever is held (the paragraph
+    above).  The gaps are sized by the one socket transport measured (TPU
+    v5e host, 100 validators on loopback, PR 39: RPCs 0.25-0.5 ms apart,
+    every flush a device dispatch that blocks the loop 8 ms or more; PERF.md
+    §6 has the runs, with a third of the peers silent among them); the cap
+    only bounds what a peer that drips can cost.
     ``burst_hint`` may be assigned later (a validator set that changes);
     without it nothing changes.
     """
@@ -211,8 +223,8 @@ class BatchingIngress:
         self._hold_until = 0.0
         # ``ingress.wait``: from the submit that found the buffer empty to
         # the flush that hands the burst over, with why the flush came when
-        # it did (``soon`` / ``window`` / ``held_back``; ``full`` / ``quiet`` /
-        # ``capped`` under ``burst_hint``; ``cut`` at flush).
+        # it did (``soon`` / ``whole`` / ``window`` / ``held_back``; ``full`` /
+        # ``quiet`` / ``capped`` under ``burst_hint``; ``cut`` at flush).
         self._wait = trace.NULL_SPAN
         self._why = "soon"
 
@@ -238,41 +250,41 @@ class BatchingIngress:
     def submit(self, message: IbftMessage) -> None:
         if not self._buffer:
             # Once a burst, not once a message: a flood submits hundreds.
-            self._wait = trace.begin("ingress.wait")
+            self._wait, self._why = trace.begin("ingress.wait"), "soon"
         self._buffer.append(message)
         if self.calibrator is not None:
             self.calibrator.observe()
         if self.max_batch is not None and len(self._buffer) >= self.max_batch:
             self._flush(cut=True)
-        elif self._holding and message.type == MessageType.PREPREPARE:
-            # What the round waits for: out at the end of this tick, with
-            # whatever is held.
+        elif getattr(message, "type", None) == MessageType.PREPREPARE:
+            # What the round waits for, and nothing can overtake it: never
+            # timed or held, out at the end of this tick with what is buffered.
+            if self._handle is not None:
+                self._handle.cancel()
             self._holding = False
-            self._handle.cancel()
             self._why = "soon"
             self._handle = asyncio.get_running_loop().call_soon(self.flush)
-        elif self._holding or (
-            self._burst_hint is not None
-            and self._handle is None
-            and message.type != MessageType.PREPREPARE
-        ):
+        elif self._holding or (self._burst_hint is not None and self._handle is None):
             self._hold()
         elif self._handle is None:
-            loop = asyncio.get_running_loop()
-            self._trim_recent(time.monotonic())
-            self._why = "soon"
-            if self._held_back:
-                self._why = "held_back"
-                self._handle = loop.call_later(self.max_delay, self.flush)
-            elif self._recent_n + len(self._buffer) >= self.eager_cutover:
-                window = self._window()
-                if window > 0:
-                    self._why = "window"
-                    self._handle = loop.call_later(window, self.flush)
-                else:
-                    self._handle = loop.call_soon(self.flush)
-            else:
-                self._handle = loop.call_soon(self.flush)
+            self._handle = asyncio.get_running_loop().call_soon(self._decide)
+
+    def _decide(self) -> None:
+        """The end of the loop turn a burst began in: hand it over as the
+        turn left it, or wait for company (class docstring)."""
+        self._trim_recent(time.monotonic())
+        wait = 0.0
+        if len(self._buffer) >= self.eager_cutover:  # device-sized as it stands
+            self._why = "whole" if self._held_back else "soon"
+        elif self._held_back:
+            self._why, wait = "held_back", self.max_delay
+        elif self._recent_n + len(self._buffer) >= self.eager_cutover:
+            wait = self._window()
+            self._why = "window" if wait > 0 else "soon"
+        if wait > 0:
+            self._handle = asyncio.get_running_loop().call_later(wait, self.flush)
+        else:
+            self._flush(cut=False)
 
     @property
     def burst_hint(self) -> Optional[int]:

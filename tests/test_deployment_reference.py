@@ -336,6 +336,45 @@ def test_what_pack_does_once_a_drain_at_100_validators(monkeypatch):
     ] * HEIGHTS
 
 
+@pytest.mark.parametrize("n", [100, 300])
+def test_no_flood_of_a_height_waits_out_the_held_back_ceiling(monkeypatch, n):
+    """Round-0 heights through the driver's wiring with drains that block
+    the loop as a device dispatch does (stand-in programs that sleep past
+    ``HELD_BACK_FACTOR`` windows): three flushes a height of 1, n - 1, n,
+    and the ``ingress.wait`` of each says why it ended when it did: the
+    PREPREPARE ``soon`` (never timed, though it follows the COMMIT drain),
+    the PREPARE flood ``soon`` (it follows the PREPREPARE's quick host
+    flush), the COMMIT flood ``whole`` (it follows the PREPARE drain and is
+    device-sized when its turn ends); none ``held_back`` (ISSUE 41)."""
+    import time
+
+    import numpy as np
+
+    from go_ibft_tpu.core import BatchingIngress
+    from go_ibft_tpu.verify import batch
+
+    blocked = 1.5 * BatchingIngress.HELD_BACK_FACTOR * 0.002  # the default window
+
+    def recover(zw, r, s, v, c, t, live):
+        time.sleep(blocked)
+        return np.asarray(live)
+
+    monkeypatch.setattr(batch, "_recover_kernel", recover)
+    monkeypatch.setattr(batch, "_digest_kernel", _stand_in_digest)
+    st = _run(n, 41, AdaptiveBatchVerifier, corrupt_share=0.0)
+    assert st.ingress.max_delay == 0.002 and st.ingress.burst_hint is None
+    assert st.counts["failed"] == 0 and len(st.samples) == HEIGHTS
+    assert st.counts["flush_sizes"] == [1, n - 1, n] * HEIGHTS
+    waits = [r[5] for r in st.records if r[:2] == ("X", "ingress.wait")]
+    assert [(a["why"], a["lanes"]) for a in waits] == [
+        ("soon", 1),
+        ("soon", n - 1),
+        ("whole", n),
+    ] * HEIGHTS
+    device = {r["program"]: r["dispatches"] for r in st.rows if r["route"] == "device"}
+    assert device["ecdsa_recover"] == (2 if n == 100 else 3) * HEIGHTS
+
+
 # -- a drain's rows reach the device through the compiled calls alone ---------
 
 

@@ -151,11 +151,104 @@ async def test_wait_says_window_cut_and_closed(ring):
         cut.submit(_msg(i))
     cut.close()  # one message still buffered
     assert [w[:2] for w in _waits(ring)] == [
-        ("soon", 2),  # decided at the first message, under the cutover of 2
+        ("soon", 2),  # device-sized when its turn ends: nothing to wait for
         ("window", 1),
         ("cut", 2),
         ("closed", 1),
     ]
+
+
+@pytest.mark.parametrize(
+    "path, before, after",
+    [
+        ("held_back", 0, 0),  # alone, after a flush that blocked the loop
+        ("held_back", 0, 5),  # heading a sub-cutover burst
+        ("held_back", 3, 2),  # inside one
+        ("window", 0, 0),  # a device-sized flow: the timed window's path
+        ("window", 0, 5),
+        ("window", 3, 2),
+        ("timer", 2, 1),  # a ceiling already being waited: it is cut short
+    ],
+)
+async def test_a_preprepare_is_never_timed(path, before, after, ring):
+    """One proposer a round, and everything waits for it: on every path a
+    PREPREPARE goes out at the end of the turn it came in, with whatever is
+    buffered, and its ``ingress.wait`` says ``soon`` (PR 41; under
+    ``burst_hint`` since PR 39: the test below)."""
+    batches = []
+    ing = BatchingIngress(
+        lambda b: batches.append(len(b)), max_delay=0.05, eager_cutover=16, calibrate=False
+    )
+    if path == "window":
+        ing._recent.append((time.monotonic(), 16))
+        ing._recent_n = 16
+    else:
+        ing._held_back = True
+    if path == "timer":
+        ing.submit(_typed(9, MessageType.COMMIT))
+        await asyncio.sleep(0)
+        assert isinstance(ing._handle, asyncio.TimerHandle) and batches == []
+    for i in range(before):
+        ing.submit(_typed(i, MessageType.PREPARE))
+    ing.submit(_typed(20, MessageType.PREPREPARE))
+    for i in range(after):
+        ing.submit(_typed(30 + i, MessageType.PREPARE))
+    assert batches == [] and not isinstance(ing._handle, asyncio.TimerHandle)
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    total = before + 1 + after + (path == "timer")
+    assert batches == [total]
+    assert [w[:2] for w in _waits(ring)] == [("soon", total)]
+    # The same burst without the PREPREPARE waits, on each of these paths.
+    ing._held_back = path != "window"  # (the quick flush above cleared it)
+    for i in range(before + after + 1):
+        ing.submit(_typed(i, MessageType.PREPARE))
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    assert batches == [total] and isinstance(ing._handle, asyncio.TimerHandle)
+    ing.close()
+    assert _waits(ring)[-1][:2] == ("closed", before + after + 1)
+
+
+@pytest.mark.parametrize("trickle", [1, 6, 15])
+async def test_a_trickle_after_a_blocking_flush_is_one_held_back_batch(trickle, ring):
+    """What the held-back rule is for (beside ``tests/test_chip_smoke.py``):
+    a backlog handed over one message a loop turn after a flush that blocked
+    the loop is under the cutover when its first turn ends, so it waits the
+    ceiling, lands in ONE batch and says ``held_back``."""
+    batches = []
+
+    def slow_add(batch):
+        batches.append(len(batch))
+        time.sleep(0.02)  # a device dispatch: 10 windows
+
+    ing = BatchingIngress(slow_add, max_delay=0.002, eager_cutover=16)
+    ing.submit("first")
+    await asyncio.sleep(0)
+    await asyncio.sleep(0)
+    assert batches == [1]
+    for i in range(trickle):
+        ing.submit(f"m{i}")
+        await asyncio.sleep(0)  # one message per loop turn
+    await asyncio.sleep(0.05)
+    ing.close()
+    assert batches == [1, trickle]
+    (_first, second) = _waits(ring)
+    assert second[:2] == ("held_back", trickle) and second[2] >= 0.002 * 1e6 * 0.9
+
+
+async def test_a_direct_flush_does_not_repeat_the_last_bursts_why(ring):
+    """``flush()`` called before the turn's decision (``sim/cluster.py``
+    does, every tick) hands the burst over at once: ``soon``, whatever the
+    burst before it waited for."""
+    ing = BatchingIngress(lambda b: None, max_delay=0.002, eager_cutover=16)
+    ing._held_back = True
+    ing.submit(_msg(0))
+    await asyncio.sleep(0.01)  # waited the ceiling
+    ing.submit(_msg(1))
+    ing.flush()
+    ing.close()
+    assert [w[:2] for w in _waits(ring)] == [("held_back", 1), ("soon", 1)]
 
 
 async def test_the_tracing_predicate_runs_once_a_burst_not_once_a_message(

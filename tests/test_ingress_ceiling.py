@@ -103,24 +103,60 @@ def test_a_burst_in_one_turn(n, kwargs, want):
     assert timed is False
 
 
-def test_a_held_back_ingress_still_waits_the_window_and_takes_the_phase_whole():
-    """After a flush that blocked the loop (a device dispatch), the next
-    flush waits ``max_delay``: the timer path of a live 300-validator
-    height.  The phase is one batch on that path too."""
-    sizes = []
+@pytest.mark.parametrize(
+    "n, cutover, why",
+    [
+        # A live phase after a device dispatch (100 and 300 validators, the
+        # default cutover): whole in the buffer when its turn ends.
+        (99, 16, "whole"),
+        (299, 16, "whole"),
+        (2 * CAP + 1, 16, "whole"),
+        (16, 16, "whole"),  # at the cutover
+        (64, 64, "whole"),
+        # Under it the batch is not yet the device's: the backlog may still
+        # be coming a message a turn, so the ceiling is waited.
+        (15, 16, "held_back"),
+        (1, 16, "held_back"),
+        (63, 64, "held_back"),
+        (299, 1000, "held_back"),
+    ],
+)
+def test_a_held_back_ingress_decides_when_the_turn_ends(n, cutover, why):
+    """After a flush that blocked the loop (a device dispatch) the next
+    burst is judged at the END of the turn it began in (PR 41; at its first
+    message before): device-sized as it stands, it is handed over there and
+    no timer is armed; under the cutover it waits the ``max_delay`` ceiling.
+    One batch either way."""
+    sizes, max_delay = [], 0.005
 
     async def main():
-        ing = BatchingIngress(lambda b: sizes.append(len(b)))
+        ing = BatchingIngress(
+            lambda b: sizes.append(len(b)), max_delay=max_delay, eager_cutover=cutover
+        )
         ing._held_back = True
-        for _ in range(299):
+        for _ in range(n):
             ing.submit(object())
-        assert isinstance(ing._handle, asyncio.TimerHandle)
-        assert sizes == []
-        await asyncio.sleep(0.02)
+        # Nothing is decided inside the turn, on either path.
+        assert not isinstance(ing._handle, asyncio.TimerHandle) and sizes == []
+        await asyncio.sleep(0)
+        timed = isinstance(ing._handle, asyncio.TimerHandle)
+        at_turn_end = list(sizes)
+        await asyncio.sleep(6 * max_delay)
         ing.close()
+        return timed, at_turn_end
 
-    asyncio.run(main())
-    assert sizes == [299]
+    rec = trace.enable()
+    try:
+        timed, at_turn_end = asyncio.run(main())
+        waits = [r for r in rec.snapshot() if r[:2] == ("X", "ingress.wait")]
+    finally:
+        trace.disable()
+    assert sizes == [n]
+    assert [(w[5]["why"], w[5]["lanes"]) for w in waits] == [(why, n)]
+    assert timed is (why == "held_back")
+    assert at_turn_end == ([n] if why == "whole" else [])
+    if why == "held_back":
+        assert waits[0][4] >= max_delay * 1e6 * 0.9  # the ceiling WAS waited
 
 
 def test_no_flush_span_when_tracing_is_off():

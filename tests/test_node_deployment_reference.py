@@ -190,6 +190,11 @@ def test_a_third_of_the_peers_silent_and_no_phase_waits_out_the_cap():
         waits = [r for r in st.records if r[0] == "X" and r[1] == "ingress.wait"]
         whys = {r[5]["why"] for r in waits}
         assert whys & {"full", "quiet"} and "capped" not in whys, whys
+        # Held votes never meet the held-back rule, and since PR 41 neither
+        # does the PREPREPARE that follows a COMMIT phase's device flush: it
+        # goes out alone at the end of its turn.
+        assert whys <= {"soon", "full", "quiet"}, whys
+        assert [r[5]["lanes"] for r in waits if r[5]["why"] == "soon"] == [1, 1]
         cap_us = BatchingIngress.BURST_HOLD_FACTOR * st.node.ingress.max_delay * 1e6
         assert max(r[4] for r in waits) < cap_us / 2
         # 16 of 24 peers voted, twice a height; the proposers' PREPREPAREs besides.

@@ -11,7 +11,7 @@ The subsystem that turns the per-height consensus engine
   :meth:`ChainRunner.recover`.
 * :class:`SyncClient` / :class:`LoopbackSyncNetwork` — batched
   catch-up: all committed seals of a fetched height range verified in one
-  ``verify_seal_lanes`` drain per validator-set snapshot.
+  ``verify_seal_lanes`` drain, each lane at its own height's validator set.
 """
 
 from .runner import (

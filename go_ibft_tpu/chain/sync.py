@@ -7,7 +7,8 @@ as the embedder's job, core/ibft.go RunSequence contract).  This module
 is that job, done the TPU-native way: a stranded node fetches the missing
 ``(proposal, committed seals)`` range from any peer and verifies ALL
 committed seals across the whole range in ONE batched drain
-(``verify_seal_lanes`` — per-lane proposal hashes through the same
+(``verify_seal_lanes`` — per-lane proposal hashes and per-lane heights,
+so the range may cross validator-set boundaries, through the same
 recovery ladder as the live COMMIT path, with the
 ``ResilientBatchVerifier`` breaker ladder as the degraded route).  This
 is the light-client primitive ("Practical Light Clients for
@@ -55,11 +56,20 @@ __all__ = [
     "SYNCED_HEIGHTS_KEY",
     "SYNC_DRAINS_KEY",
     "SYNC_CERT_HEIGHTS_KEY",
+    "SYNC_REGROUPED_KEY",
 ]
 
 SYNCED_HEIGHTS_KEY = ("go-ibft", "chain", "synced_heights")
 SYNC_DRAINS_KEY = ("go-ibft", "chain", "sync_drains")
 SYNC_CERT_HEIGHTS_KEY = ("go-ibft", "chain", "sync_cert_heights")
+# Ranges whose validator sets together needed more table rows than one
+# drain's table holds, and were verified as more than one drain.
+SYNC_REGROUPED_KEY = ("go-ibft", "chain", "sync_regrouped")
+
+# Rows of the verifier's largest validator table (the last of
+# ``verify/batch.py``'s ``_TABLE_BUCKETS``; a test holds the two equal): what
+# the address sets of ONE drain may need together.
+MAX_TABLE_ROWS = 2048
 
 
 class SyncError(RuntimeError):
@@ -105,8 +115,9 @@ class LoopbackSyncNetwork:
 class SyncClient:
     """Fetch-and-verify catch-up for one node.
 
-    ``verifier`` is any object with ``verify_seal_lanes(lanes, height)``
-    (Host/Device/Mesh/Resilient/Adaptive all implement it); verdicts are
+    ``verifier`` is any object with ``verify_seal_lanes(lanes, heights)``,
+    a height a lane (Host/Device/Mesh/Resilient/Adaptive and the
+    scheduler's tenant handle all implement it); verdicts are
     pinned to the sequential host oracle by the conformance tests, so a
     device route can never accept a range the reference semantics would
     reject.  A :class:`~go_ibft_tpu.verify.mesh_batch.MeshBatchVerifier`
@@ -138,6 +149,7 @@ class SyncClient:
         # seal lanes per height through ``verifier``.
         self.cert_verifier = cert_verifier
         self.max_batch_heights = max_batch_heights
+        self.max_table_rows = MAX_TABLE_ROWS
 
     # -- peer observation ----------------------------------------------
 
@@ -194,14 +206,21 @@ class SyncClient:
         different proposal).  Requires ``cert_verifier``; a cert-carrying
         block without one is a :class:`SyncError`, never silently trusted.
 
-        Seal-carrying blocks keep the batched lane route: one
-        ``verify_seal_lanes`` drain per validator-set snapshot — with a
-        static validator set (the common case) the WHOLE height range is
-        a single drain.  Grouping by snapshot keeps the device's
-        one-table-per-drain shape exactly as honest as the sequential
-        oracle: every lane in a drain shares the validator set its own
-        height would select.  After the mask comes back, each height's
-        valid signers must reach that height's voting-power quorum.
+        Seal-carrying blocks keep the batched lane route: ONE
+        ``verify_seal_lanes`` drain for the whole range, whatever validator
+        sets its heights select.  Every lane carries its own height, and
+        the verifier judges it ``signature valid AND signer in the set of
+        that height`` (on the device: one table that holds the union of the
+        range's sets, the own-set test a host lookup), exactly as the
+        sequential oracle would block by block.  What decides a drain's
+        shape is the ADDRESS sets: stakes that move inside the same
+        addresses (the common epoch change) leave it alone, and a fixed
+        committee is the case of one set.  Only a range whose sets
+        together need more rows than the verifier's largest table
+        (``max_table_rows``) is cut into runs of blocks that fit, a drain
+        each (``sync_regrouped``).  After the mask comes back, each
+        height's valid signers must reach that height's voting-power
+        quorum, in exact integers.
         """
         cert_blocks = [b for b in blocks if b.cert is not None]
         if cert_blocks:
@@ -209,17 +228,34 @@ class SyncClient:
         blocks = [b for b in blocks if b.cert is None]
         if not blocks:
             return
-        groups: Dict[tuple, List[int]] = {}
-        snapshots: List[Mapping[bytes, int]] = []
-        heights: List[int] = []
-        for i, block in enumerate(blocks):
-            powers = self._validators(block.height)
-            key = tuple(sorted(powers.items()))
-            if key not in groups:
-                groups[key] = []
-            groups[key].append(i)
-            snapshots.append(powers)
-            heights.append(block.height)
+        snapshots = [self._validators(b.height) for b in blocks]
+        # Each block's height stands for its address set: the first height
+        # of the range that selected the same addresses (a lane's verdict
+        # depends on its height through that set alone, so the verifier
+        # looks up as many sets as the range has, not as many as blocks).
+        first_with: Dict[frozenset, int] = {}
+        stands_for = [
+            first_with.setdefault(frozenset(powers), block.height)
+            for block, powers in zip(blocks, snapshots)
+        ]
+        # Runs of blocks whose sets fit one table together: one run, but
+        # for a range wider than the largest table.
+        runs: List[List[int]] = [[]]
+        seen: set = set()
+        rows: set = set()
+        table_rows = 0
+        for i, height in enumerate(stands_for):
+            if height not in seen:
+                grown = rows | snapshots[i].keys()
+                if seen and len(grown) > self.max_table_rows:
+                    runs.append([])
+                    seen, grown = set(), set(snapshots[i])
+                seen.add(height)
+                rows = grown
+                table_rows = max(table_rows, len(rows))
+            runs[-1].append(i)
+        if len(runs) > 1:
+            metrics.inc_counter(SYNC_REGROUPED_KEY)
 
         masks: List[Optional[np.ndarray]] = [None] * len(blocks)
         total_lanes = sum(len(b.seals) for b in blocks)
@@ -227,10 +263,13 @@ class SyncClient:
             "chain.sync.verify",
             lanes=total_lanes,
             heights=len(blocks),
-            drains=len(groups),
+            drains=len(runs),
+            sets=len(first_with),
+            table_rows=table_rows,
         ):
-            for idxs in groups.values():
+            for idxs in runs:
                 lanes: List[Tuple[bytes, CommittedSeal]] = []
+                heights: List[int] = []
                 spans: List[Tuple[int, int, int]] = []  # (block idx, lo, hi)
                 for i in idxs:
                     block = blocks[i]
@@ -239,37 +278,51 @@ class SyncClient:
                     lanes.extend(
                         (proposal_hash, seal) for seal in block.seals
                     )
+                    heights.extend([stands_for[i]] * len(block.seals))
                     spans.append((i, lo, len(lanes)))
                 if not lanes:
                     for i in idxs:
                         masks[i] = np.zeros(0, dtype=bool)
                     continue
-                # ONE batched drain for the whole snapshot group; the
-                # representative height picks the (identical) table.
+                # ONE batched drain for the run, every lane at its height.
                 mask = np.asarray(
-                    self.verifier.verify_seal_lanes(
-                        lanes, heights[idxs[-1]]
-                    ),
+                    self.verifier.verify_seal_lanes(lanes, heights),
                     dtype=bool,
                 )
                 metrics.inc_counter(SYNC_DRAINS_KEY)
                 for i, lo, hi in spans:
                     masks[i] = mask[lo:hi]
 
-        for block, mask, powers in zip(blocks, masks, snapshots):
-            valid_signers = {
-                seal.signer
-                for seal, ok in zip(block.seals, mask)
-                if bool(ok)
-            }
-            quorum = calculate_quorum(sum(powers.values()))
-            got = sum(powers.get(a, 0) for a in valid_signers)
-            if got < quorum:
-                raise SyncError(
-                    f"height {block.height}: committed-seal power {got} < "
-                    f"quorum {quorum} ({int(mask.sum())}/{len(block.seals)} "
-                    "seals valid)"
+        # Per block: the power of its valid signers against its own
+        # height's quorum.  Python integers throughout (a stake in wei is
+        # above 2**60; nothing here may round or saturate); a snapshot the
+        # source hands out for many heights is summed once.
+        quorums: Dict[int, Tuple[int, bool]] = {}
+        for powers in snapshots:
+            if id(powers) not in quorums:
+                quorums[id(powers)] = (
+                    calculate_quorum(sum(powers.values())),
+                    max(powers.values(), default=0) >= 1 << 31,
                 )
+        with trace.span(
+            "chain.sync.tally",
+            blocks=len(blocks),
+            bigint=any(big for _quorum, big in quorums.values()),
+        ):
+            for block, mask, powers in zip(blocks, masks, snapshots):
+                valid_signers = {
+                    seal.signer
+                    for seal, ok in zip(block.seals, mask)
+                    if bool(ok)
+                }
+                quorum = quorums[id(powers)][0]
+                got = sum(powers.get(a, 0) for a in valid_signers)
+                if got < quorum:
+                    raise SyncError(
+                        f"height {block.height}: committed-seal power {got} < "
+                        f"quorum {quorum} ({int(mask.sum())}/{len(block.seals)} "
+                        "seals valid)"
+                    )
 
     def _verify_cert_blocks(self, blocks: Sequence[FinalizedBlock]) -> None:
         """Batched verification of certificate-carrying blocks.

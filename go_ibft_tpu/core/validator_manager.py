@@ -3,20 +3,12 @@
 Re-design of the reference's ValidatorManager
 (core/validator_manager.go:23-155).  Voting powers are arbitrary-precision
 Python ints (parity with Go's big.Int); quorum = floor(2·total/3) + 1.
-
-TPU note: alongside the host-side dict the manager maintains a *packed
-voting-power vector* (validator index -> weight, float64 ndarray) so the batch
-verifier can fuse the quorum reduction into device code: a quorum check over a
-verification mask becomes ``(weights @ mask) >= quorum``.  The host path below
-remains the source of truth for exact big-int arithmetic.
 """
 
 from __future__ import annotations
 
 import threading
 from typing import Iterable, Mapping, Optional, Protocol, Sequence
-
-import numpy as np
 
 from ..messages.wire import IbftMessage
 from .state import StateName
@@ -58,9 +50,6 @@ class ValidatorManager:
         self._lock = threading.RLock()
         self._quorum_size: int = 0
         self._voting_power: Optional[dict[bytes, int]] = None
-        # Packed mirror for device-side fused quorum checks.
-        self._index_of: dict[bytes, int] = {}
-        self._weights: Optional[np.ndarray] = None
 
     def init(self, height: int) -> None:
         """Load voting powers for a height (reference validator_manager.go:50-57).
@@ -77,12 +66,6 @@ class ValidatorManager:
         with self._lock:
             self._voting_power = voting_power
             self._quorum_size = calculate_quorum(total)
-            # Deterministic packed order: sorted by address.
-            addrs = sorted(voting_power)
-            self._index_of = {a: i for i, a in enumerate(addrs)}
-            self._weights = np.array(
-                [float(voting_power[a]) for a in addrs], dtype=np.float64
-            )
 
     @property
     def quorum_size(self) -> int:
@@ -139,19 +122,6 @@ class ValidatorManager:
             senders.add(message.sender)
 
         return self.has_quorum(senders)
-
-    # -- device mirror ------------------------------------------------------
-
-    def packed_weights(self) -> tuple[np.ndarray, dict[bytes, int], float]:
-        """(weights vector, address->index map, quorum) for device-side fusion.
-
-        The float64 mirror is exact for voting powers below 2^53; consumers
-        must fall back to the host big-int path for larger powers.
-        """
-        with self._lock:
-            if self._weights is None:
-                return np.zeros(0, dtype=np.float64), {}, float("inf")
-            return self._weights, dict(self._index_of), float(self._quorum_size)
 
 
 def senders_of(messages: Iterable[IbftMessage]) -> set[bytes]:

@@ -828,6 +828,8 @@ class TenantScheduler:
                     height, claimed = item.view.height, item.sender
                 else:
                     height, claimed = req.height, item[1].signer
+                    if isinstance(height, list):  # a height a lane
+                        height = height[i]
                 powers = powers_by_height.get(height)
                 if powers is None:
                     powers = powers_by_height[height] = validators(height)
@@ -1019,8 +1021,11 @@ class TenantVerifierHandle:
         return out
 
     def verify_seal_lanes(
-        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: int
+        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height
     ) -> np.ndarray:
+        """``height``: one for every lane, or one a lane (a sync range that
+        crosses validator-set boundaries); membership is this tenant's, on
+        the host, at each lane's own height."""
         lanes = list(lanes)
         out = np.zeros(len(lanes), dtype=bool)
         idxs = [
@@ -1029,6 +1034,8 @@ class TenantVerifierHandle:
             if well_formed_seal_lane(proposal_hash, seal)
         ]
         if idxs:
+            if not isinstance(height, int):
+                height = [height[i] for i in idxs]
             self._run("seals", [lanes[i] for i in idxs], height, idxs, out)
         return out
 
@@ -1047,14 +1054,15 @@ class TenantVerifierHandle:
         for start in range(0, len(items), cap):
             chunk = items[start : start + cap]
             chunk_idxs = idxs[start : start + cap]
+            at = height[start : start + cap] if isinstance(height, list) else height
             try:
                 req = self._sched.submit(
-                    self._tenant, kind, chunk, height, out, chunk_idxs
+                    self._tenant, kind, chunk, at, out, chunk_idxs
                 )
             except SchedQueueFull:
                 # Backpressure: serve locally, never block or drop.
                 self._sched.note_shed(self._tenant, len(chunk))
-                self._oracle_fill(kind, chunk, height, chunk_idxs, out)
+                self._oracle_fill(kind, chunk, at, chunk_idxs, out)
                 continue
             pending.append((req, chunk, chunk_idxs))
         for req, chunk, chunk_idxs in pending:
@@ -1064,9 +1072,9 @@ class TenantVerifierHandle:
                 # cannot write into an array the caller already owns.
                 req.cancelled = True
                 self._sched.note_shed(self._tenant, len(chunk))
-                self._oracle_fill(kind, chunk, height, chunk_idxs, out)
+                self._oracle_fill(kind, chunk, req.height, chunk_idxs, out)
             elif req.error is not None:
-                self._oracle_fill(kind, chunk, height, chunk_idxs, out)
+                self._oracle_fill(kind, chunk, req.height, chunk_idxs, out)
 
     def _oracle_fill(
         self,

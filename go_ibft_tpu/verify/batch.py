@@ -24,7 +24,19 @@ import threading
 import time
 from contextlib import ExitStack
 from dataclasses import dataclass
-from typing import Callable, Dict, Iterable, List, Mapping, Optional, Sequence, Tuple
+from collections import OrderedDict
+from typing import (
+    Callable,
+    Dict,
+    FrozenSet,
+    Iterable,
+    List,
+    Mapping,
+    Optional,
+    Sequence,
+    Tuple,
+    Union,
+)
 
 import jax
 import jax.numpy as jnp
@@ -54,6 +66,19 @@ from .pipeline import (
 SIG_BYTES = 65  # r(32) || s(32) || v(1)
 
 ADDRESS_BYTES = 20
+
+# The height(s) a sync drain's lanes are judged at: one for every lane, or
+# one a lane (a range that crosses validator-set boundaries).
+LaneHeights = Union[int, Sequence[int]]
+
+
+def _one_height(height: LaneHeights) -> bool:
+    return isinstance(height, (int, np.integer))
+
+
+def _heights_at(height: LaneHeights, idxs: Sequence[int]) -> LaneHeights:
+    """``height`` as it applies to the lanes ``idxs`` of a drain."""
+    return height if _one_height(height) else [height[i] for i in idxs]
 
 
 class MalformedLaneError(ValueError):
@@ -179,6 +204,13 @@ PACK_GENERIC_KEY = ("go-ibft", "pack", "generic")
 # compiled call (``jnp.asarray`` / ``jax.device_put``), instead of handing
 # them to the call: 0 on the device route, the sharded mesh's own placements.
 EAGER_PUTS_KEY = ("go-ibft", "dispatch", "eager_puts")
+# Validator tables copied host -> device: one a distinct address set (or a
+# distinct union of sets, block sync), whatever heights select it.
+TABLE_UPLOADS_KEY = ("go-ibft", "verify", "table_uploads")
+# Seal lanes of a sync drain whose signature held and whose signer is not in
+# the validator set of the lane's OWN height (though it may sit in a
+# neighbouring epoch's set, on the same device table).
+SYNC_OUT_OF_SET_LANES_KEY = ("go-ibft", "chain", "sync_out_of_set_lanes")
 
 # Sized like the engine's own seal-verdict cache (core/ibft.py
 # ``_seal_verdict_cap``): a constant, not an option.
@@ -194,17 +226,18 @@ class SealVerdictCache:
     A committed seal's verdict is a pure function of exactly that binding
     and the height's validator table, so a hit is the verdict the seal
     drain would compute and nothing else can reach it: another height,
-    hash, signer or signature is another key.  Bounded: a height the
-    verifier's table cache has dropped goes whole (:meth:`drop_height`),
-    on cap pressure heights below the newest go whole, oldest first, and
-    the newest sheds FIFO (a seal-rewrite flood mints keys there).
+    hash, signer or signature is another key.  Bounded: at most
+    ``heights`` heights are kept (the lowest goes whole), on cap pressure
+    heights below the newest go whole, oldest first, and the newest sheds
+    FIFO (a seal-rewrite flood mints keys there).
     Thread-safe, like :class:`~go_ibft_tpu.verify.pipeline.PackCache`.
     """
 
-    def __init__(self) -> None:
+    def __init__(self, heights: int = 4) -> None:
         self._lock = threading.Lock()
         self._by_height: Dict[int, Dict[SealKey, bool]] = {}
         self._count = 0
+        self._heights = heights
 
     def __len__(self) -> int:
         with self._lock:
@@ -224,6 +257,8 @@ class SealVerdictCache:
                 if key not in bucket:
                     self._count += 1
                 bucket[key] = bool(ok)
+            while len(self._by_height) > self._heights:
+                self._count -= len(self._by_height.pop(min(self._by_height)))
             while self._count > _SEAL_VERDICT_CAP:
                 oldest = min(self._by_height)
                 bucket = self._by_height[oldest]
@@ -449,17 +484,17 @@ class HostBatchVerifier:
         return out
 
     def verify_seal_lanes(
-        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: int
+        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: LaneHeights
     ) -> np.ndarray:
         """Per-lane-hash seal verification (the block-sync drain shape).
 
         Each lane is ``(proposal_hash, seal)`` — sequential per-lane
-        recovers against that lane's own hash, membership against
-        ``height``'s validator set.  This is the oracle the batched sync
-        drain (DeviceBatchVerifier.verify_seal_lanes) is pinned to; the
-        caller groups heights so that every lane's own validator set
-        equals ``height``'s (chain/sync.py does this by snapshot).
+        recovers against that lane's own hash, membership against the
+        validator set of that lane's own height: ``height`` is one height
+        for every lane or a height a lane.  This is the oracle the batched
+        sync drain (DeviceBatchVerifier.verify_seal_lanes) is pinned to.
         """
+        one = _one_height(height)
         out = np.zeros(len(lanes), dtype=bool)
         t0 = time.perf_counter() if metrics.fixed_histograms_enabled() else None
         with trace.span(
@@ -499,7 +534,9 @@ class HostBatchVerifier:
                         continue
                     out[i] = (
                         host_ecdsa.pubkey_to_address(*pub) == seal.signer
-                        and self._is_member(height, seal.signer)
+                        and self._is_member(
+                            height if one else height[i], seal.signer
+                        )
                     )
             _note_verdicts("seal_lanes", "host", out)
         if t0 is not None:
@@ -1162,7 +1199,9 @@ class DeviceBatchVerifier:
 
     ``validators_for_height`` supplies the voting-power map (the engine's
     ``ValidatorBackend.get_voting_powers`` works directly); validator
-    address tables are packed to device arrays once per height and cached.
+    address tables are packed to device arrays once per distinct address
+    set and cached; ``cache_heights`` bounds how many sets' tables (and how
+    many heights' seal verdicts) are kept.
     """
 
     def __init__(self, validators_for_height: ValidatorSource, cache_heights: int = 4):
@@ -1179,17 +1218,19 @@ class DeviceBatchVerifier:
         # Obs route label: the mesh subclass overrides to "mesh" so every
         # span a drain emits names the route that actually served it.
         self._route = "device"
-        self._tables: Dict[int, Tuple[np.ndarray, List[bytes]]] = {}
+        # What follows is keyed by what it HOLDS, never by a height: a table
+        # by its address set, the power vectors by the (address, power)
+        # pairs.  A set seen before costs no pack and no upload whatever
+        # height selects it (a fixed committee: one upload a process; stakes
+        # that move inside the same addresses: the same table), least
+        # recently used out first.
+        self._tables: "OrderedDict[FrozenSet[bytes], Tuple[np.ndarray, List[bytes]]]" = OrderedDict()
         # Device-resident twins of the packed tables/power vectors: uploaded
-        # once per height and reused by every dispatch of that height
-        # (re-uploading per call was a host->device copy of data that never
-        # changes within a height).
-        self._tables_dev: Dict[int, jnp.ndarray] = {}
-        self._quorum_packs: Dict[
-            int, Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]]
-        ] = {}
-        self._quorum_dev: Dict[int, Tuple[jnp.ndarray, jnp.ndarray]] = {}
-        self._cache_heights = cache_heights
+        # once a set and handed to every dispatch that is judged against it.
+        self._tables_dev: Dict[FrozenSet[bytes], jnp.ndarray] = {}
+        self._quorum_packs: "OrderedDict[frozenset, Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]]]" = OrderedDict()
+        self._quorum_dev: Dict[frozenset, Tuple[jnp.ndarray, jnp.ndarray]] = {}
+        self._cache_sets = cache_heights
         # Per-message pack cache (round-scoped, like the engine's
         # seal-verdict cache): engine wakeups that re-verify the same
         # messages (certificate validation re-runs per wakeup) skip the
@@ -1197,7 +1238,7 @@ class DeviceBatchVerifier:
         self._pack_cache = PackCache()
         # What the joint COMMIT dispatches of ``verify_senders`` decided
         # about the seals that rode them; the seal drains look here first.
-        self._seal_verdicts = SealVerdictCache()
+        self._seal_verdicts = SealVerdictCache(heights=cache_heights)
 
     def note_round(self, round_: int) -> None:
         """Engine hook: tag pack-cache entries with the live round (round
@@ -1300,61 +1341,64 @@ class DeviceBatchVerifier:
 
     # -- validator table management ------------------------------------
 
-    def _table_and_addrs(self, height: int) -> Tuple[np.ndarray, List[bytes]]:
-        """Packed address table + the filtered address list its rows follow
-        (one build + one cache for both the mask and fused-quorum paths)."""
-        hit = self._tables.get(height)
-        if hit is not None:
-            return hit
-        addrs = [
+    def _set_of(self, height: int) -> FrozenSet[bytes]:
+        """The address set ``height`` selects (well-formed addresses only:
+        no other can match a claimed signer)."""
+        return frozenset(
             a for a in self._validators(height) if len(a) == ADDRESS_BYTES
-        ]
-        table = pack_validator_table(addrs)
-        self._tables[height] = (table, addrs)
-        if len(self._tables) > self._cache_heights:
-            evicted = min(self._tables)
-            self._tables.pop(evicted)
-            self._tables_dev.pop(evicted, None)
-            self._seal_verdicts.drop_height(evicted)
-        return table, addrs
+        )
 
-    def _table(self, height: int) -> np.ndarray:
-        return self._table_and_addrs(height)[0]
+    def _table_and_addrs(
+        self, members: FrozenSet[bytes]
+    ) -> Tuple[np.ndarray, List[bytes]]:
+        """Packed address table of the set ``members`` + the address list
+        its rows follow (one build + one cache for both the mask and
+        fused-quorum paths)."""
+        hit = self._tables.get(members)
+        if hit is not None:
+            self._tables.move_to_end(members)
+            return hit
+        addrs = sorted(members)
+        hit = self._tables[members] = (pack_validator_table(addrs), addrs)
+        if len(self._tables) > self._cache_sets:
+            evicted, _ = self._tables.popitem(last=False)
+            self._tables_dev.pop(evicted, None)
+        return hit
+
+    def _put_table(self, table: np.ndarray) -> jnp.ndarray:
+        """One host -> device copy of a packed table."""
+        return jnp.asarray(table)
+
+    def _table_dev_of(self, members: FrozenSet[bytes]) -> jnp.ndarray:
+        """Device-resident packed table of ``members`` (uploaded once a set)."""
+        table, _ = self._table_and_addrs(members)
+        hit = self._tables_dev.get(members)
+        if hit is None:
+            hit = self._tables_dev[members] = self._put_table(table)
+            metrics.inc_counter(TABLE_UPLOADS_KEY)
+        return hit
 
     def _table_dev(self, height: int) -> jnp.ndarray:
-        """Device-resident packed table (uploaded once per height)."""
-        hit = self._tables_dev.get(height)
-        if hit is None:
-            hit = jnp.asarray(self._table(height))
-            self._tables_dev[height] = hit
-        return hit
-
-    def _quorum_powers_dev(
-        self, height: int, plo: np.ndarray, phi: np.ndarray
-    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-        """Device-resident power vectors for the fused-quorum kernels."""
-        hit = self._quorum_dev.get(height)
-        if hit is None:
-            hit = (jnp.asarray(plo), jnp.asarray(phi))
-            self._quorum_dev[height] = hit
-            if len(self._quorum_dev) > self._cache_heights:
-                self._quorum_dev.pop(min(self._quorum_dev))
-        return hit
+        """Device-resident table of the set ``height`` selects."""
+        return self._table_dev_of(self._set_of(height))
 
     def _quorum_pack(
         self, height: int
-    ) -> Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]]:
-        """Per-height fused-quorum arrays: (table, powers_lo, powers_hi,
+    ) -> Tuple[frozenset, Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]]]:
+        """Fused-quorum arrays of the powers ``height`` selects, under their
+        key (the ``(address, power)`` pairs): (table, powers_lo, powers_hi,
         quorum), or None when the device quorum path cannot represent the
         set exactly (power >= 2**31, total >= 2**31, or set larger than the
         biggest table bucket) — callers then fall back to host big-int
         quorum (the exactness contract of ops/quorum.py)."""
-        if height in self._quorum_packs:
-            return self._quorum_packs[height]
         powers_map = self._validators(height)
+        key = frozenset(powers_map.items())
+        if key in self._quorum_packs:
+            self._quorum_packs.move_to_end(key)
+            return key, self._quorum_packs[key]
         pack: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]] = None
         try:
-            table, addrs = self._table_and_addrs(height)
+            table, addrs = self._table_and_addrs(self._set_of(height))
         except ValueError:  # empty validator set
             addrs = []
         # Quorum must match the host ValidatorManager exactly: the total is
@@ -1372,15 +1416,16 @@ class DeviceBatchVerifier:
             for i, a in enumerate(addrs):
                 plo[i], phi[i] = quorum.split_power(powers_map[a])
             pack = (table, plo, phi, calculate_quorum(total))
-        self._quorum_packs[height] = pack
-        if len(self._quorum_packs) > self._cache_heights:
-            self._quorum_packs.pop(min(self._quorum_packs))
-        return pack
+        self._quorum_packs[key] = pack
+        if len(self._quorum_packs) > self._cache_sets:
+            evicted, _ = self._quorum_packs.popitem(last=False)
+            self._quorum_dev.pop(evicted, None)
+        return key, pack
 
     def supports_fused(self, height: int) -> bool:
         """True when the fused mask+quorum device path is exact for this
         height's validator set."""
-        return self._quorum_pack(height) is not None
+        return self._quorum_pack(height)[1] is not None
 
     # -- shared pack/dispatch scaffolding -------------------------------
     # One implementation of the idxs-filter -> pack -> kernel -> unpack ->
@@ -1423,7 +1468,7 @@ class DeviceBatchVerifier:
         ``inputs`` = (zw, r, s, v, claimed, live): the packers' numpy rows
         (``zw`` the digest program's device rows in a sender drain), handed
         to the compiled call as they are, like ``table`` and the power
-        vectors (device-resident, a height's); ``operands`` on the span
+        vectors (device-resident, a validator set's); ``operands`` on the span
         counts the host arrays among ``inputs``.  Nothing may write to one
         of them from here on.
         ``quorum_args`` = None for the plain mask, or (plo, phi, thr).
@@ -1531,15 +1576,17 @@ class DeviceBatchVerifier:
     # -- fused mask + quorum (the engine's phase hot path) --------------
 
     def _fused_pack(self, height: int, threshold: Optional[int]):
-        pack = self._quorum_pack(height)
+        key, pack = self._quorum_pack(height)
         if pack is None:
             raise ValueError(f"fused quorum unsupported for height {height}")
-        table, plo, phi, quorum_size = pack
+        _table, plo, phi, quorum_size = pack
         thr = quorum_size if threshold is None else threshold
         # Device-resident handles: the table and power vectors upload once
-        # per height, and every dispatch of the height is handed them.
-        plo_dev, phi_dev = self._quorum_powers_dev(height, plo, phi)
-        return self._table_dev(height), (plo_dev, phi_dev, thr), thr
+        # a set, and every dispatch judged against it is handed them.
+        powers_dev = self._quorum_dev.get(key)
+        if powers_dev is None:
+            powers_dev = self._quorum_dev[key] = (jnp.asarray(plo), jnp.asarray(phi))
+        return self._table_dev(height), (*powers_dev, thr), thr
 
     def certify_senders(
         self, msgs: Sequence[IbftMessage], height: int, threshold: Optional[int] = None
@@ -2035,7 +2082,7 @@ class DeviceBatchVerifier:
         return out
 
     def verify_seal_lanes(
-        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: int
+        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: LaneHeights
     ) -> np.ndarray:
         """Cross-height batched seal drain: per-lane proposal hashes.
 
@@ -2043,10 +2090,20 @@ class DeviceBatchVerifier:
         fetched height range in one drain — each height signs its own
         proposal hash, so lanes carry their own hash words
         (:func:`pack_seal_lanes`); the recovery ladder and membership
-        check are the same program as the single-hash drain.  All lanes
-        are checked against ``height``'s validator table (callers group
-        ranges by validator-set snapshot).  Chunks above the largest lane
-        bucket ride the double-buffered pipeline like every other flood.
+        check are the same program as the single-hash drain.
+
+        ``height`` is one height for every lane, or a height a lane: a range
+        that crosses validator-set boundaries still rides ONE drain of full
+        chunks, against ONE device table that holds the union of the sets
+        its heights select (the program's membership test is "the claimed
+        signer is a row of the table", and the claimed signer is a host
+        value).  A lane's verdict is then ``signature valid AND signer in
+        the set of the lane's OWN height``: where a height's set is narrower
+        than the table, that last test is a host set lookup, made while the
+        chunk packs (the previous one is on the device meanwhile).  A fixed
+        committee is the case of one set: its table, nothing narrower.
+        Chunks above the largest lane bucket ride the double-buffered
+        pipeline like every other flood.
         """
         out = np.zeros(len(lanes), dtype=bool)
         idxs = [
@@ -2056,6 +2113,12 @@ class DeviceBatchVerifier:
         ]
         if not idxs:
             return out
+        sets = {
+            h: self._set_of(h)
+            for h in ({height} if _one_height(height) else set(height))
+        }
+        table = frozenset().union(*sets.values())
+        narrow = any(len(members) < len(table) for members in sets.values())
         items = [
             idxs[start : start + self._dispatch_cap]
             for start in range(0, len(idxs), self._dispatch_cap)
@@ -2067,7 +2130,16 @@ class DeviceBatchVerifier:
                     [lanes[i] for i in chunk],
                     pad_lanes=self._pad_lanes(len(chunk)),
                 )
-            return chunk, inputs, self._table_dev(height)
+                # In the set of its own height, lane by lane, where some
+                # height's set is not the whole table.
+                own = None
+                if narrow:
+                    own = np.fromiter(
+                        (lanes[i][1].signer in sets[height[i]] for i in chunk),
+                        dtype=bool,
+                        count=len(chunk),
+                    )
+            return (chunk, own), inputs, self._table_dev_of(table)
 
         with trace.span(
             "verify.drain",
@@ -2079,8 +2151,15 @@ class DeviceBatchVerifier:
                 items, pack, "verify_seal_lanes_ms"
             )
             with trace.span("verify.quorum", route="mask"):
-                for chunk, mask in results:
-                    out[np.asarray(chunk)] = mask[: len(chunk)]
+                outside = 0
+                for (chunk, own), mask in results:
+                    mask = mask[: len(chunk)]
+                    if own is not None:
+                        outside += int((mask & ~own).sum())
+                        mask = mask & own
+                    out[np.asarray(chunk)] = mask
+                if outside:
+                    metrics.inc_counter(SYNC_OUT_OF_SET_LANES_KEY, outside)
             _note_verdicts("seal_lanes", self._route, out)
         return out
 
@@ -2394,7 +2473,7 @@ class ResilientBatchVerifier:
         )
 
     def verify_seal_lanes(
-        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: int
+        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: LaneHeights
     ) -> np.ndarray:
         """Cross-height sync drain through the degradation ladder: poison
         lanes quarantine by bisection, a faulting device demotes to the
@@ -2403,7 +2482,7 @@ class ResilientBatchVerifier:
         return self._drain(
             lanes,
             lambda rung, idxs: self._run_seal_lanes(
-                rung, [lanes[i] for i in idxs], height
+                rung, [lanes[i] for i in idxs], _heights_at(height, idxs)
             ),
         )
 
@@ -2481,16 +2560,19 @@ class ResilientBatchVerifier:
         # Rung without the per-lane-hash entry point (a bare BatchVerifier
         # protocol implementer): validate lane shapes FIRST so malformed
         # lanes raise with the drain-relative index the bisection expects,
-        # then group by hash and reuse the single-hash drain per group.
+        # then group by hash (and height) and reuse the single-hash drain
+        # per group.
         validate_seal_lanes(lanes)
         out = np.zeros(len(lanes), dtype=bool)
-        groups: Dict[bytes, List[int]] = {}
+        one = _one_height(height)
+        groups: Dict[Tuple[bytes, int], List[int]] = {}
         for i, (proposal_hash, _seal) in enumerate(lanes):
-            groups.setdefault(proposal_hash, []).append(i)
-        for proposal_hash, idxs in groups.items():
+            key = (proposal_hash, height if one else height[i])
+            groups.setdefault(key, []).append(i)
+        for (proposal_hash, at), idxs in groups.items():
             mask = np.asarray(
                 rung.verify_committed_seals(
-                    proposal_hash, [lanes[i][1] for i in idxs], height
+                    proposal_hash, [lanes[i][1] for i in idxs], at
                 ),
                 dtype=bool,
             )
@@ -2735,7 +2817,7 @@ class AdaptiveBatchVerifier:
         return self._resilient.verify_committed_seals(proposal_hash, seals, height)
 
     def verify_seal_lanes(
-        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: int
+        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: LaneHeights
     ) -> np.ndarray:
         """Cross-height sync drain, routed like any other seal drain: tiny
         ranges on the sequential host path, everything else through the
@@ -3129,7 +3211,7 @@ class EngineScope:
             )
 
     def verify_seal_lanes(
-        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: int
+        self, lanes: Sequence[Tuple[bytes, CommittedSeal]], height: LaneHeights
     ) -> np.ndarray:
         with self._owned():
             return self._parent.verify_seal_lanes(lanes, height)

@@ -193,18 +193,12 @@ class MeshBatchVerifier(DeviceBatchVerifier):
         is the parent's."""
         return [] if self.mesh is not None else super()._seal_riders(sub, height)
 
-    def _table_dev(self, height: int) -> jnp.ndarray:
-        """Validator table replicated across the mesh (uploaded once per
-        height, like the parent's single-device pin)."""
+    def _put_table(self, table: np.ndarray) -> jnp.ndarray:
+        """Validator table replicated across the mesh (uploaded once a
+        set, like the parent's single-device pin)."""
         if self.mesh is None:
-            return super()._table_dev(height)
-        hit = self._tables_dev.get(height)
-        if hit is None:
-            hit = jax.device_put(
-                self._table(height), NamedSharding(self.mesh, P())
-            )
-            self._tables_dev[height] = hit
-        return hit
+            return super()._put_table(table)
+        return jax.device_put(table, NamedSharding(self.mesh, P()))
 
     # -- dispatch -------------------------------------------------------
 

@@ -487,8 +487,9 @@ def test_a_height_hands_its_rows_to_the_compiled_calls_and_nothing_else(
     """A round-0 height on the device route after its PREPREPARE (one lane,
     on the host): exactly the compiled calls its layout owes, each handed
     the packers' numpy arrays; no eager ``jnp`` call or ``device_put`` on a
-    host array anywhere in the verifier but the height's table upload
-    (ISSUE 38)."""
+    host array anywhere in the verifier (ISSUE 38), the validator table's
+    upload included: the set's table went up once, in warm-up, and no height
+    of a fixed committee uploads it again (ISSUE 45)."""
     from go_ibft_tpu.utils import metrics
     from go_ibft_tpu.verify import batch
 
@@ -509,7 +510,7 @@ def test_a_height_hands_its_rows_to_the_compiled_calls_and_nothing_else(
     assert st.counts["failed"] == 0 and len(st.samples) == HEIGHTS
     assert over.calls == calls * HEIGHTS
     assert over.live == ([n - 1, 2 * n] if n == 100 else [n - 1, n, n]) * HEIGHTS
-    assert over.eager == [("asarray", "_table_dev")] * HEIGHTS
+    assert over.eager == []
     assert metrics.get_counter(batch.EAGER_PUTS_KEY) == eager_puts
     assert _dispatch_spans(st.records) == (operands * HEIGHTS, packs * HEIGHTS)
     by_program = {
@@ -564,4 +565,4 @@ def test_a_drain_hands_its_rows_to_the_compiled_calls_and_nothing_else(
         trace.disable()
     assert over.calls == want
     assert over.live == [live]
-    assert over.eager == [("asarray", "_table_dev")]
+    assert over.eager == [("asarray", "_put_table")]

@@ -353,6 +353,74 @@ def test_catch_up_on_the_host_route_is_one_drain_a_validator_set(recorder):
     (sync,) = [r for r in recorder.snapshot() if r[1] == "chain.sync.verify"]
     assert sync[5]["drains"] == 1
     assert sync[3] <= outer[3] and outer[3] + outer[4] <= sync[3] + sync[4] + 1
+    # One address set, four rows; the tally has a span of its own, after the
+    # drain and inside the call (equal votes: no power needs a big integer).
+    assert sync[5]["sets"] == 1 and sync[5]["table_rows"] == len(keys)
+    (tally,) = [r for r in recorder.snapshot() if r[1] == "chain.sync.tally"]
+    assert tally[5] == {"blocks": heights, "bigint": False}
+    assert outer[3] + outer[4] <= tally[3] + 1
+
+
+def test_catch_up_across_two_validator_sets_counts_what_it_did(recorder):
+    """A range whose heights select two address sets, on the device route:
+    ONE ``chain.sync.verify`` (``drains`` 1, ``sets`` 2, ``table_rows`` the
+    union's), one ``chain.sync.tally`` (``bigint``: a stake in wei), and the
+    three counters: one table upload for the union however often the range
+    comes, the lanes validly signed out of their own height's set, no
+    regrouping (that is for a range wider than the largest table)."""
+    from go_ibft_tpu.chain.sync import SYNC_REGROUPED_KEY
+    from go_ibft_tpu.verify import DeviceBatchVerifier
+
+    keys = [PrivateKey.from_seed(b"span-pos-%d" % i) for i in range(5)]
+    epochs = [
+        {k.address: 10**18 + i for i, k in enumerate(keys)},
+        {k.address: 10**18 + i for i, k in enumerate(keys[:4])},
+    ]
+    src = lambda height: epochs[(height - 1) // 2]  # noqa: E731
+    blocks = []
+    for h in range(1, 5):
+        proposal = Proposal(raw_proposal=b"span block %d" % h, round=0)
+        seals = [
+            extract_committed_seal(
+                ECDSABackend(k, src).build_commit_message(
+                    proposal_hash_of(proposal), View(h, 0)
+                )
+            )
+            for k in keys
+        ]
+        blocks.append(FinalizedBlock(h, proposal, seals))
+
+    class _Source:
+        def latest_height(self):
+            return 4
+
+        def get_blocks(self, start, end):
+            return blocks[start - 1 : end]
+
+    network = LoopbackSyncNetwork()
+    network.register(b"peer", _Source())
+    client = SyncClient(b"me", network, DeviceBatchVerifier(src), src)
+    before = {
+        key: metrics.get_counter(key)
+        for key in (
+            batch.TABLE_UPLOADS_KEY,
+            batch.SYNC_OUT_OF_SET_LANES_KEY,
+            SYNC_REGROUPED_KEY,
+        )
+    }
+    for _ in range(2):
+        assert len(client.catch_up(1, 4)) == 4
+    moved = {key[-1]: metrics.get_counter(key) - was for key, was in before.items()}
+    # keys[4] sealed heights 3 and 4, where it is no validator: twice two lanes.
+    assert moved == {"table_uploads": 1, "sync_out_of_set_lanes": 4, "sync_regrouped": 0}
+    syncs = [r[5] for r in recorder.snapshot() if r[1] == "chain.sync.verify"]
+    assert syncs == [
+        {"lanes": 20, "heights": 4, "drains": 1, "sets": 2, "table_rows": 5}
+    ] * 2
+    tallies = [r[5] for r in recorder.snapshot() if r[1] == "chain.sync.tally"]
+    assert tallies == [{"blocks": 4, "bigint": True}] * 2
+    drains = [r[5] for r in recorder.snapshot() if r[1] == "verify.drain"]
+    assert [d["kind"] for d in drains] == ["seal_lanes"] * 2
 
 
 # ---------------------------------------------------------------------------

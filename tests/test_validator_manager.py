@@ -142,11 +142,3 @@ def test_prepare_quorum_no_proposal():
     msgs = [_prepare_msg(bytes([i])) for i in range(4)]
     assert not vm.has_prepare_quorum(StateName.PREPARE, None, msgs)
     assert not vm.has_prepare_quorum(StateName.NEW_ROUND, None, msgs)
-
-
-def test_packed_weights_mirror():
-    vm = _vm({b"b": 3, b"a": 5, b"c": 1})
-    weights, index_of, quorum = vm.packed_weights()
-    assert quorum == 7.0
-    assert list(weights) == [5.0, 3.0, 1.0]  # sorted by address
-    assert index_of == {b"a": 0, b"b": 1, b"c": 2}

@@ -2,21 +2,50 @@
 
 TPUs have no native wide-integer types, so 256-bit field elements are
 represented as vectors of radix-2**13 limbs held in ``int32`` lanes
-(SURVEY.md §7 hard part (a)).  The radix is chosen so that schoolbook
-multiplication never overflows int32:
+(SURVEY.md §7 hard part (a)).  Two invariants hold, one at an op's edge
+and one inside it.
 
-* a limb product is < 2**26,
-* a convolution column sums at most ``nlimbs`` (= 20 for 256-bit fields)
-  such products, staying < 20 * 2**26 < 2**31.
+**At an op's edge** (what ``add`` / ``sub`` / ``mul`` / ``sqr`` / ``muli``
+take and return) values are **unsigned and "semi-reduced"**: limbs lie in
+``[0, 2**13]`` (the upper bound is *inclusive* — a lazy carry may leave a
+limb at exactly 2**13) and the represented value lies in ``[0, 2*p)``.
+Subtraction never produces negative limbs: ``a - b`` is computed as
+``a + F - b`` where ``F`` is a precomputed *fat* representation of ``K*p``
+whose every limb is >= 2**13, so the subtrahend's limbs must be <= 2**13:
+the edge invariant.  Exact canonicalization to ``[0, p)`` (sequential
+carry scans) happens only at the edges — final comparisons and host I/O —
+never inside hot loops.
 
-All values are kept **unsigned and "semi-reduced"**: limbs lie in
-``[0, 2**13]`` (the upper bound is *inclusive* — lazy carries may leave a
-limb at exactly 2**13, which the overflow analysis above still admits) and
-the represented value lies in ``[0, 2*p)``.  Subtraction never produces
-negative limbs: ``a - b`` is computed as ``a + F - b`` where ``F`` is a
-precomputed *fat* representation of ``K*p`` whose every limb is >= 2**13.
-Exact canonicalization to ``[0, p)`` (sequential carry scans) happens only
-at the edges — final comparisons and host I/O — never inside hot loops.
+**Inside an op** limbs are unsigned and under a bound that is TRACKED, as
+a plain Python integer at trace time, beside the value bound that sizes
+the fold schedule; nothing in between is normalised further than its
+consumer needs.  A carry pass takes limbs <= b to limbs
+<= 2**13 - 1 + (b >> 13) (:func:`_pass_bound`) and leaves the value as it
+was; :func:`_passes` counts the passes from one bound to another, and every
+product site asserts ``a_max * (the other operand's limbs that meet in a
+column, summed) + what is added < 2**31`` through :func:`_column_bound`
+(for two edge operands that is ``rows * 2**13 * 2**13``), so a modulus or
+a caller that breaks the arithmetic fails to TRACE and never computes
+wrongly.  For the two secp256k1 moduli that reads:
+
+* a limb product is <= 2**26 and a column of the 20 x 20 product sums 20 of
+  them: <= 20 * 2**26 < 2**31.  One pass leaves limbs <= 172,031, two
+  <= 8,211;
+* a column of a fold round's ``hi * c_fold`` is at most ``hi``'s limb bound
+  times the SUM of ``c_fold``'s limbs (8,465 for P's 3 rows, 34,145 for N's
+  11), so ``hi`` and ``lo`` may carry limbs up to (2**31 - 1) // (sum + 1)
+  (253,659 / 62,891: ONE pass after the product mod P, two mod N); the
+  round's product is added to ``lo`` uncarried, and the sum is carried only
+  as far as the NEXT round's ceiling (one pass: 185,975 / 42,416);
+* the final fold reads ``hi`` by shifts and ``lo`` by a mask, exact for
+  limbs of any size; what it asks of them is that ``lo + hi * c_fb`` stay
+  under 2p with ``lo``'s limbs at their bound (a ceiling of 4,201,982: one
+  pass after the last round, NONE for the sums of ``add`` / ``sub`` /
+  ``muli``, whose limbs are <= 2**14 / 3 * 2**13 / 2**17), and two passes
+  after it restore ``[0, 2**13]``.
+
+A ``mul`` is 5 passes mod P and 6 mod N (19 until PR 46, at fixed
+4 / 2 / 3 counts a site); ``add`` / ``sub`` / ``muli`` are 2 (5 / 6 / 6).
 
 Reduction uses generalized pseudo-Mersenne folding: ``2**(13*L) === c_fold``
 and ``2**bits === c_fb (mod p)``, with the fold schedule derived statically
@@ -33,7 +62,7 @@ over leading axes, so a whole round's worth of signatures reduces in one
 
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -159,13 +188,70 @@ class Modulus:
         return _single_limbs(value % self.p, self.nlimbs)
 
 
+_INT32 = 1 << 31
+_EDGE = LIMB_MASK + 1  # the limb bound at an op's edge, inclusive
+
+
+def _pass_bound(limb: int) -> int:
+    """Bound of the limbs one carry pass leaves of unsigned limbs <= ``limb``."""
+    return LIMB_MASK + (limb >> LIMB_BITS)
+
+
+def _passes(b_in: int, b_out: int) -> Tuple[int, int]:
+    """The fewest carry passes that take unsigned limbs <= ``b_in`` to limbs
+    <= ``b_out``, and the bound they reach.  2**13 is the passes' fixed
+    point: no count brings a limb under it."""
+    if b_out < _EDGE:
+        raise ValueError(
+            f"no carry pass brings limbs under 2**{LIMB_BITS}: asked for <= {b_out} "
+            "(the other operand of a product site is too heavy for this radix)"
+        )
+    if b_in >= _INT32:
+        raise ValueError(f"limb bound {b_in} does not fit int32")
+    n = 0
+    while b_in > b_out:
+        b_in, n = _pass_bound(b_in), n + 1
+    return n, b_in
+
+
+def _column_bound(site: str, a_max: int, b_sum: int, plus: int = 0) -> int:
+    """Bound of a product column: limbs <= ``a_max`` against limbs of the
+    other operand that sum to at most ``b_sum`` over the rows that meet in
+    one column, and ``plus`` added to it.  Raises at trace time where an
+    int32 column could overflow."""
+    cols = a_max * b_sum + plus
+    if cols >= _INT32:
+        raise ValueError(
+            f"{site}: limbs <= {a_max} x {b_sum} a column + {plus} = {cols} "
+            "overflows an int32 column (>= 2**31)"
+        )
+    return cols
+
+
+def _limb_ceiling(c_sum: int) -> int:
+    """Largest limb bound b with ``b * c_sum + b < 2**31``: what a fold
+    round's ``lo + hi * c_fold`` admits of the limbs it is given, ``c_sum``
+    the sum of ``c_fold``'s limbs (every column's products together)."""
+    return (_INT32 - 1) // (c_sum + 1)
+
+
+def _limb_value(limb: int, n: int) -> int:
+    """Largest value of ``n`` unsigned limbs each <= ``limb``."""
+    return limb * (((1 << (LIMB_BITS * n)) - 1) // LIMB_MASK)
+
+
 def _carry(z: jnp.ndarray, passes: int) -> jnp.ndarray:
     """Lazy parallel carry: each pass moves carries one limb up.
 
-    With unsigned inputs bounded < 2**31 the limb values converge to
-    ``[0, 2**13]`` in <= 4 passes (see module docstring).  The caller must
-    size ``z`` so the top limb never produces a carry.
+    A pass takes unsigned limbs <= b to limbs <= 2**13 - 1 + (b >> 13) and
+    leaves the value unchanged; the callers in this module derive ``passes``
+    from the bound they hold and the bound their consumer needs
+    (:func:`_passes`).  From any bound < 2**31 three passes end in
+    ``[0, 2**13]``.  The caller must size ``z`` so the top limb never
+    produces a carry (value < 2**(13 * len)).
     """
+    if not passes:
+        return z
     zero = jnp.zeros(z.shape[:-1] + (1,), dtype=z.dtype)
     for _ in range(passes):
         c = z >> LIMB_BITS
@@ -236,61 +322,87 @@ def _pad_to(z: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.pad(z, pad)
 
 
-def _fold_semi(m: Modulus, z: jnp.ndarray, bound: int) -> jnp.ndarray:
-    """Reduce a carried, unsigned limb vector of known value ``bound`` to a
-    semi-reduced (< 2p) ``nlimbs`` vector.  Fold schedule is static."""
+def _carry_to(
+    z: jnp.ndarray, bound: int, limb: int, ceiling: int
+) -> Tuple[jnp.ndarray, int]:
+    """Carry ``z`` (value < ``bound``, limbs <= ``limb``) as far as
+    ``ceiling`` and no further; returns it with the limb bound reached."""
+    passes, limb = _passes(limb, ceiling)
+    if passes and bound > 1 << (LIMB_BITS * z.shape[-1]):
+        raise ValueError(
+            f"a value < 2**{bound.bit_length()} can carry out of {z.shape[-1]} limbs"
+        )
+    return _carry(z, passes), limb
+
+
+def _fold_semi(m: Modulus, z: jnp.ndarray, bound: int, limb: int) -> jnp.ndarray:
+    """Reduce an unsigned limb vector of value < ``bound`` and limbs <=
+    ``limb`` (NOT carried: product columns, or a sum of edge operands) to
+    the edge invariant: ``nlimbs`` limbs in ``[0, 2**13]``, value < 2p.
+
+    The fold schedule is static, from the value bound; the carry schedule
+    is static too, from the limb bound: before each product ``z`` is
+    carried to the ceiling that product's int32 columns admit and no
+    further, ``hi * c_fold`` is added to ``lo`` as the columns it is, and
+    only the passes after the final fold go all the way to 2**13."""
     L = m.nlimbs
     lw = LIMB_BITS * L
     c_fold = jnp.asarray(m.c_fold_limbs)
+    c_len, c_sum = c_fold.shape[-1], int(m.c_fold_limbs.sum())
     while bound >= (1 << (lw + 6)):
-        lo, hi = z[..., :L], z[..., L:]
         hi_bound = (bound >> lw) + 1
         # Truncate provably-zero top limbs of hi (unsigned => value-bounded).
-        hi_len = min(hi.shape[-1], -(-hi_bound.bit_length() // LIMB_BITS) + 1)
-        hi = hi[..., :hi_len]
-        prod_bound = hi_bound * m.c_fold
-        out_len = max(L, hi_len + c_fold.shape[-1]) + 1
-        prod = _carry(_conv(hi, c_fold, out_len), 4)
-        z = _carry(_pad_to(lo, out_len) + prod, 2)
-        bound = (1 << lw) + prod_bound
-    # Final fold at bit position m.bits: v = lo + hi * 2**bits === lo + hi*c_fb.
-    z = _pad_to(z, L + 2)[..., : L + 2]
+        hi_len = min(z.shape[-1] - L, -(-hi_bound.bit_length() // LIMB_BITS) + 1)
+        z, limb = _carry_to(z, bound, limb, _limb_ceiling(c_sum))
+        lo, hi = z[..., :L], z[..., L : L + hi_len]
+        out_len = max(L, hi_len + c_len) + 1
+        z = _pad_to(lo, out_len) + _conv(hi, c_fold, out_len)
+        # lo alone may exceed 2**lw by the carries its limbs still hold.
+        bound = min(bound, _limb_value(limb, L) + 1) + hi_bound * m.c_fold
+        limb = _column_bound("fold", limb, c_sum, plus=limb)
+    # Final fold at bit position m.bits: v = lo + hi * 2**bits === lo + hi*c_fb,
+    # exact for limbs of any size (hi by shifts, lo by a mask).  hi <= v >> bits.
     fbl, fbs = m.fb_limb, m.fb_shift
+    hi_max = (bound - 1) >> m.bits
+    cf_max = int(m.c_fb_limbs.max())
+    # What 2p leaves for lo's fbl whole limbs, beside hi * c_fb and the fbs
+    # bits lo keeps of limb fbl.
+    room = 2 * m.p - 1 - hi_max * m.c_fb - (((1 << fbs) - 1) << (LIMB_BITS * fbl))
+    ceiling = min(_INT32 - 1 - hi_max * cf_max, room // _limb_value(1, fbl))
+    z, limb = _carry_to(z, bound, limb, ceiling)
+    # Limbs whose weight is over the value are zero: not read.
+    live = ((bound - 1).bit_length() - 1) // LIMB_BITS + 1
+    z = _pad_to(z, fbl + 1)[..., : max(fbl + 1, live)]
     hi = z[..., fbl] >> fbs
     for j in range(fbl + 1, z.shape[-1]):
         hi = hi + (z[..., j] << (LIMB_BITS * (j - fbl) - fbs))
-    lo = z[..., :L]
-    mask_col = jnp.asarray(
-        [(1 << fbs) - 1 if i == fbl else LIMB_MASK + 1 for i in range(L)],
+    keep_bits = jnp.asarray(
+        [(1 << fbs) - 1 if i == fbl else _INT32 - 1 for i in range(L)],
         dtype=jnp.int32,
     )
-    # (the +1 sentinel leaves limbs below fbl untouched: x & (2**13) is wrong —
-    #  so use a where instead of a mask for clarity)
-    keep = jnp.asarray([i < fbl for i in range(L)])
-    lo = jnp.where(keep, lo, lo & mask_col)
-    cf = jnp.asarray(m.c_fb_limbs)
-    prod = hi[..., None] * cf  # hi < 2**7, limb < 2**13 -> < 2**20, int32-safe
-    return _carry(lo + _pad_to(prod, L), 3)
+    lo = z[..., :L] & keep_bits  # fbl + 1 >= L limbs are there
+    prod = hi[..., None] * jnp.asarray(m.c_fb_limbs)
+    limb = _column_bound("final fold", hi_max, cf_max, plus=limb)
+    return _carry_to(lo + _pad_to(prod, L), 2 * m.p, limb, _EDGE)[0]
 
 
 def add(m: Modulus, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """(a + b) mod-class, semi-reduced output."""
-    z = _carry(_pad_to(a + b, m.nlimbs + 1), 2)
-    return _fold_semi(m, z, 4 * m.p)
+    return _fold_semi(m, a + b, 4 * m.p, 2 * _EDGE)
 
 
 def sub(m: Modulus, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """(a - b) mod-class via the borrow-free fat K*p trick."""
-    z = _carry(_pad_to(a + jnp.asarray(m.fat_kp) - b, m.nlimbs + 1), 3)
-    return _fold_semi(m, z, m.sub_bound)
+    z = a + jnp.asarray(m.fat_kp) - b
+    return _fold_semi(m, z, m.sub_bound, _EDGE + int(m.fat_kp.max()))
 
 
 def mul(m: Modulus, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """(a * b) mod-class: schoolbook conv + static fold schedule."""
     bound = (2 * m.p) ** 2
     out_len = -(-bound.bit_length() // LIMB_BITS) + 1
-    z = _carry(_conv(a, b, out_len), 4)
-    return _fold_semi(m, z, bound)
+    cols = _column_bound("mul", _EDGE, min(a.shape[-1], b.shape[-1]) * _EDGE)
+    return _fold_semi(m, _conv(a, b, out_len), bound, cols)
 
 
 def sqr(m: Modulus, a: jnp.ndarray) -> jnp.ndarray:
@@ -301,8 +413,7 @@ def muli(m: Modulus, a: jnp.ndarray, k: int) -> jnp.ndarray:
     """Multiply by a small constant 1 <= k <= 16."""
     if not 1 <= k <= 16:
         raise ValueError("k out of range")
-    z = _carry(_pad_to(a * k, m.nlimbs + 2), 3)
-    return _fold_semi(m, z, 2 * m.p * k)
+    return _fold_semi(m, a * k, 2 * m.p * k, k * _EDGE)
 
 
 def select(cond: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

@@ -17,7 +17,11 @@ the core): +83 lines on every ladder program (34,147 -> 34,230), one more
 zero test in the overlay; what the ladder stopped executing was never a
 second instantiation.  PR 30 folds the ladder's batch to full TPU tiles
 only where lanes % 256 == 0 (+35 lines at 2,048 lanes); every program
-pinned here is 8 lanes and did not move by a line.  Those wins regress silently — one refactor
+pinned here is 8 lanes and did not move by a line.  PR 46 derives
+``fields``' carry passes from tracked limb bounds (a ``mul`` 19 -> 5
+passes mod P, 6 mod N): every ladder program shrank by a third
+(ecdsa_recover 34,230 -> 21,669) and the snapshot was re-baselined so that
+the ratchet holds the new size.  Those wins regress silently — one refactor
 that unrolls a scan or forks a new shape instantiation quietly re-adds
 minutes of cold compile.  This script LOWERS (never compiles — it stays fast on
 any host) the programs that dominate the cold budget, counts their

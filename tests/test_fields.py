@@ -34,6 +34,7 @@ def _ops(p):
             "add": jax.jit(partial(F.add, m)),
             "sub": jax.jit(partial(F.sub, m)),
             "mul": jax.jit(partial(F.mul, m)),
+            "sqr": jax.jit(partial(F.sqr, m)),
             "canon": jax.jit(partial(F.canon, m)),
             "inv": jax.jit(partial(F.inv, m)),
             "is_zero": jax.jit(partial(F.is_zero, m)),
@@ -318,3 +319,139 @@ def test_mul_reshapes_keep_the_batch_behind_the_product_axes():
             )
             flattened += any(d >= m.nlimbs**2 for d in dims)
     assert flattened >= 1  # the field product's 20 x 39 rows, merged
+
+
+# ---- worst cases of the carry schedule (PR 46): an op carries only as far as
+# its limb bounds ask, so its operands are held at those bounds' extremes.
+
+WORST_OPS = {
+    "mul": (2, lambda x, y: x * y),
+    "sqr": (1, lambda x, y: x * x),
+    "add": (2, lambda x, y: x + y),
+    "sub": (2, lambda x, y: x - y),
+}
+MULI_KS = (1, 2, 3, 8, 16)
+
+
+def _worst_rows(p):
+    """``(limbs, values)`` of: the edge values of the semi-reduced range; the
+    fattest semi-reduced vector (every limb 2**13 below a top limb that keeps
+    the value under 2p); and, last, EVERY limb at 2**13, whose value (~2**260)
+    is beyond 2p: what ``sub``'s fat K*p is sized for, and a limb-bound stress
+    the other ops' value slack takes."""
+    m = _ops(p)["m"]
+    top = F.LIMB_MASK + 1
+    every = np.full(m.nlimbs, top, dtype=np.int32)
+    fattest = every.copy()
+    low = F.from_limbs(every[:-1])[0]
+    fattest[-1] = (2 * p - 1 - low) >> (F.LIMB_BITS * (m.nlimbs - 1))
+    rows = np.concatenate(
+        [F.to_limbs([0, 1, p - 1, p, p + 1, 2 * p - 1], m.nlimbs), [fattest, every]]
+    )
+    vals = F.from_limbs(rows)
+    assert vals[-2] < 2 * p <= vals[-1]
+    return rows, vals
+
+
+def _worst_pairs(p):
+    """Every row of :func:`_worst_rows` against every row, itself among them."""
+    rows, vals = _worst_rows(p)
+    n = len(rows)
+    i, j = np.repeat(np.arange(n), n), np.tile(np.arange(n), n)
+    return rows[i], rows[j], [vals[k] for k in i], [vals[k] for k in j]
+
+
+def _assert_edge_invariant(out, p, want):
+    arr = np.asarray(out)
+    assert arr.min() >= 0 and arr.max() <= 1 << F.LIMB_BITS
+    got = F.from_limbs(arr)
+    assert all(v < 2 * p for v in got)
+    assert [v % p for v in got] == [w % p for w in want]
+
+
+@pytest.mark.parametrize("p", MODULI)
+@pytest.mark.parametrize("op", WORST_OPS)
+def test_worst_case_operands(p, op):
+    nargs, ref = WORST_OPS[op]
+    a, b, a_int, b_int = _worst_pairs(p)
+    out = _ops(p)[op](*(jnp.asarray(a), jnp.asarray(b))[:nargs])
+    _assert_edge_invariant(out, p, [ref(x, y) for x, y in zip(a_int, b_int)])
+
+
+@pytest.mark.parametrize("p", MODULI)
+@pytest.mark.parametrize("k", MULI_KS)
+def test_worst_case_operands_muli(p, k):
+    rows, vals = _worst_rows(p)
+    out = _ops(p)["muli"][k](jnp.asarray(rows))
+    _assert_edge_invariant(out, p, [x * k for x in vals])
+
+
+@pytest.mark.parametrize("p", MODULI)
+def test_chain_of_fifty_mixed_ops(p):
+    """50 ops, each fed by the ones before it: every op's output is the next
+    one's operand, so an output off the edge invariant shows downstream."""
+    m = _ops(p)["m"]
+    rng = random.Random(46)
+    rows, vals = _worst_rows(p)
+    # without the all-2**13 row: a chain is held to the contract (< 2p)
+    a_int = vals[:-1] + [rng.randrange(2 * p) for _ in range(16)]
+    a = np.concatenate([rows[:-1], F.to_limbs(a_int[len(rows) - 1 :], m.nlimbs)])
+    ops = ["mul", "sqr", "add", "sub", "muli"]
+    plan = [(rng.choice(ops), rng.choice(MULI_KS)) for _ in range(50)]
+    assert set(ops) == {op for op, _ in plan}
+
+    @jax.jit
+    def chain(x):
+        acc, prev, outs = x, x, []
+        for op, k in plan:
+            nxt = {
+                "mul": lambda: F.mul(m, acc, prev),
+                "sqr": lambda: F.sqr(m, acc),
+                "add": lambda: F.add(m, acc, prev),
+                "sub": lambda: F.sub(m, prev, acc),
+                "muli": lambda: F.muli(m, acc, k),
+            }[op]()
+            acc, prev = nxt, acc
+            outs.append(nxt)
+        return jnp.stack(outs)
+
+    outs = np.asarray(chain(jnp.asarray(a)))
+    acc_int, prev_int = a_int, a_int
+    for (op, k), out in zip(plan, outs):
+        nxt = [
+            {"mul": x * y, "sqr": x * x, "add": x + y, "sub": y - x, "muli": x * k}[op] % p
+            for x, y in zip(acc_int, prev_int)
+        ]
+        acc_int, prev_int = nxt, acc_int
+        _assert_edge_invariant(out, p, nxt)
+
+
+# The carry schedule has no constant of P or N in it: for any modulus that
+# ``Modulus`` accepts it is derived from that modulus' own fold constants.
+OTHER_MODULI = {
+    "2**61-1": 2**61 - 1,
+    "goldilocks": 2**64 - 2**32 + 1,
+    "2**127-1": 2**127 - 1,
+    "p192": 2**192 - 2**64 - 1,
+    "p224": 2**224 - 2**96 + 1,
+    "25519": 2**255 - 19,
+    "p384": 2**384 - 2**128 - 2**96 + 2**32 - 1,
+}
+
+
+@pytest.mark.parametrize("name", OTHER_MODULI)
+def test_schedule_derives_for_other_moduli(name):
+    p = OTHER_MODULI[name]
+    m = F.Modulus(p)
+    rng = random.Random(p & 0xFFFF)
+    a_int = [0, 1, p - 1, p, p + 1, 2 * p - 1] + [rng.randrange(2 * p) for _ in range(26)]
+    b_int = a_int[::-1]
+    a = jnp.asarray(F.to_limbs(a_int, m.nlimbs))
+    b = jnp.asarray(F.to_limbs(b_int, m.nlimbs))
+    for fn, ref in (
+        (lambda x, y: F.mul(m, x, y), lambda x, y: x * y),
+        (lambda x, y: F.add(m, x, y), lambda x, y: x + y),
+        (lambda x, y: F.sub(m, x, y), lambda x, y: x - y),
+        (lambda x, y: F.muli(m, x, 16), lambda x, y: 16 * x),
+    ):
+        _assert_edge_invariant(jax.jit(fn)(a, b), p, [ref(x, y) for x, y in zip(a_int, b_int)])

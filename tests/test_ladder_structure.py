@@ -14,11 +14,13 @@ import collections
 import math
 import os
 import random
+from functools import partial
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from go_ibft_tpu.ops import fields as F
 from go_ibft_tpu.ops import secp256k1 as sec
 
 import ladder_cases as lc
@@ -243,3 +245,105 @@ def test_what_the_invariant_leans_on():
     assert lc.host.scalar_mul(sec.N - 1, lc.G) == lc.neg(lc.G)
     hasse = 2 * (math.isqrt(sec.P) + 1)
     assert abs(sec.N - (sec.P + 1)) <= hasse and sec.N > 2 * hasse
+
+
+# ---- the carry schedule of ``ops/fields.py`` (PR 46): pass counts come from
+# limb bounds tracked at trace time, so the jaxpr is where they are read.
+
+FIELD_OPS = {
+    "mul": (F.mul, 2),
+    "sqr": (F.sqr, 1),
+    "add": (F.add, 2),
+    "sub": (F.sub, 2),
+    "muli2": (partial(F.muli, k=2), 1),
+    "muli3": (partial(F.muli, k=3), 1),
+    "muli8": (partial(F.muli, k=8), 1),
+    "muli16": (partial(F.muli, k=16), 1),
+}
+# A product's passes, each site carried as far as its consumer's int32 columns
+# admit: after the 20 x 20 columns one mod P (limbs <= 172,031 against a
+# ceiling of 253,659: the sum of c_fold's 3 limbs is 8,465) and two mod N
+# (ceiling 62,891: 11 limbs that sum to 34,145), one after each fold round,
+# two after the final fold.  19 until PR 46.
+CARRY_PASSES = {"mul": {"p": 5, "n": 6}, "sqr": {"p": 5, "n": 6}}
+MODULI = {"p": sec.FIELD, "n": sec.ORDER}
+
+
+def _is_carry_pass(eqn, batch_rank=1) -> bool:
+    """``z >> 13`` over a limb axis (the final fold's ``hi`` is a shift of
+    ONE limb: the batch's rank)."""
+    return (
+        eqn.primitive.name == "shift_right_arithmetic"
+        and eqn.outvars[0].aval.ndim == batch_rank + 1
+    )
+
+
+@pytest.mark.parametrize("modulus", MODULI)
+@pytest.mark.parametrize("op", FIELD_OPS)
+def test_field_op_carries_as_often_as_its_bounds_ask(op, modulus):
+    fn, nargs = FIELD_OPS[op]
+    x = jax.ShapeDtypeStruct((LANES, L), jnp.int32)
+    jaxpr = jax.make_jaxpr(partial(fn, MODULI[modulus]))(*(x,) * nargs).jaxpr
+    want = CARRY_PASSES.get(op, {}).get(modulus, 2)
+    assert _count(jaxpr, _is_carry_pass) == want
+    assert [v.aval.shape for v in jaxpr.outvars] == [(LANES, L)]
+
+
+def test_ladder_step_carries_by_the_same_schedule(ladder_body):
+    """Every pass of the scan body belongs to a field op mod P: 5 a product
+    and at most 2 an additive op (``is_zero_fast``'s Kogge-Stone carry
+    shifts once a call: the four infinity tests)."""
+    passes = _count(ladder_body, lambda e: _is_carry_pass(e, 2))
+    products = _count(ladder_body, _is_field_product)
+    assert products == 44
+    additive = (passes - 5 * products - 4) / 2
+    assert additive == int(additive) and 40 <= additive <= 60, passes
+
+
+@pytest.mark.parametrize(
+    "b_in,b_out,want",
+    [
+        (20 << 26, 253_659, (1, 172_031)),  # the 20 x 20 columns to P's first fold round
+        (20 << 26, 62_891, (2, 8_211)),  # ... and to N's
+        (172_031 * 8_465 + 172_031, 253_659, (1, 185_975)),  # P: round 1's sum to round 2
+        (8_211 * 34_145 + 8_211, 62_891, (1, 42_416)),  # N: the same
+        (1 << 14, 4_201_982, (0, 1 << 14)),  # a sum of two edge operands to the final fold
+        (8_192 + 8_191, 8_192, (1, 8_192)),
+        ((1 << 31) - 1, 8_192, (3, 8_192)),  # from anything int32 holds, three
+        (8_192, 8_192, (0, 8_192)),
+    ],
+)
+def test_passes_between_two_limb_bounds(b_in, b_out, want):
+    assert F._passes(b_in, b_out) == want
+
+
+def test_limb_ceilings_of_the_two_moduli():
+    for m, rows, c_sum, ceiling in ((sec.FIELD, 3, 8_465, 253_659), (sec.ORDER, 11, 34_145, 62_891)):
+        assert len(m.c_fold_limbs) == rows and int(m.c_fold_limbs.sum()) == c_sum
+        assert F._limb_ceiling(c_sum) == ceiling
+        assert F._column_bound("fold", ceiling, c_sum, plus=ceiling) < 1 << 31
+
+
+def test_a_modulus_whose_columns_overflow_fails_to_trace():
+    """41 limbs make 41 products a column: 41 * 2**26 >= 2**31.  Until PR 46
+    this traced, compiled and summed wrongly."""
+    m = F.Modulus(2**521 - 1)
+    x = jax.ShapeDtypeStruct((LANES, m.nlimbs), jnp.int32)
+    with pytest.raises(ValueError, match=r"mul: limbs <= 8192 x 335872 a column .* overflows an int32 column"):
+        jax.eval_shape(partial(F.mul, m), x, x)
+    # the additive ops have no product of many rows: they still trace
+    assert jax.eval_shape(partial(F.add, m), x, x).shape == (LANES, m.nlimbs)
+
+
+@pytest.mark.parametrize(
+    "call,message",
+    [
+        (lambda: F._column_bound("fold", 70_000, 34_145, plus=70_000), "fold: limbs <= 70000 x 34145"),
+        (lambda: F._passes(1 << 20, 8_191), "no carry pass brings limbs under"),
+        (lambda: F._passes(1 << 31, 8_192), "does not fit int32"),
+        (lambda: F._passes(8_192, F._limb_ceiling(40 * 8_191)), "too heavy for this radix"),
+    ],
+)
+def test_bound_helpers_refuse_what_int32_cannot_hold(call, message):
+    with pytest.raises(ValueError, match=message):
+        call()

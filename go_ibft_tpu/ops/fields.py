@@ -62,7 +62,7 @@ over leading axes, so a whole round's worth of signatures reduces in one
 
 from __future__ import annotations
 
-from typing import List, Sequence, Tuple
+from typing import List, NamedTuple, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -322,41 +322,55 @@ def _pad_to(z: jnp.ndarray, n: int) -> jnp.ndarray:
     return jnp.pad(z, pad)
 
 
-def _carry_to(
-    z: jnp.ndarray, bound: int, limb: int, ceiling: int
-) -> Tuple[jnp.ndarray, int]:
-    """Carry ``z`` (value < ``bound``, limbs <= ``limb``) as far as
-    ``ceiling`` and no further; returns it with the limb bound reached."""
-    passes, limb = _passes(limb, ceiling)
-    if passes and bound > 1 << (LIMB_BITS * z.shape[-1]):
-        raise ValueError(
-            f"a value < 2**{bound.bit_length()} can carry out of {z.shape[-1]} limbs"
-        )
-    return _carry(z, passes), limb
+class _FoldRound(NamedTuple):
+    """One round of ``lo + hi * c_fold``."""
+
+    passes: int  # carry passes before the split, to the product's ceiling
+    hi_len: int  # limbs of ``hi`` that can be non-zero
+    out_len: int  # limbs of the round's sum
 
 
-def _fold_semi(m: Modulus, z: jnp.ndarray, bound: int, limb: int) -> jnp.ndarray:
-    """Reduce an unsigned limb vector of value < ``bound`` and limbs <=
-    ``limb`` (NOT carried: product columns, or a sum of edge operands) to
-    the edge invariant: ``nlimbs`` limbs in ``[0, 2**13]``, value < 2p.
+class _FoldSchedule(NamedTuple):
+    """What :func:`_fold_semi` does to a limb vector, as plain integers: the
+    one derivation that every executor of the reduction follows (the XLA
+    form below, limbs on the last axis; ``pallas_ladder``'s, limbs leading)."""
 
-    The fold schedule is static, from the value bound; the carry schedule
-    is static too, from the limb bound: before each product ``z`` is
-    carried to the ceiling that product's int32 columns admit and no
-    further, ``hi * c_fold`` is added to ``lo`` as the columns it is, and
-    only the passes after the final fold go all the way to 2**13."""
+    rounds: Tuple[_FoldRound, ...]
+    passes: int  # carry passes before the final fold
+    width: int  # limbs the final fold reads
+    hi_max: int  # bound of the final fold's ``hi``
+    last_passes: int  # carry passes after it, to the edge
+
+
+def _fold_schedule(m: Modulus, width: int, bound: int, limb: int) -> _FoldSchedule:
+    """The static schedule that reduces ``width`` unsigned limbs <= ``limb``
+    of value < ``bound`` to the edge invariant.  The fold rounds come from
+    the value bound; the carry passes from the limb bound: before each
+    product the limbs are carried to the ceiling that product's int32
+    columns admit and no further, ``hi * c_fold`` is added to ``lo`` as the
+    columns it is, and only the passes after the final fold go all the way
+    to 2**13.  Raises where an int32 column could overflow."""
     L = m.nlimbs
     lw = LIMB_BITS * L
-    c_fold = jnp.asarray(m.c_fold_limbs)
-    c_len, c_sum = c_fold.shape[-1], int(m.c_fold_limbs.sum())
+    c_len, c_sum = len(m.c_fold_limbs), int(m.c_fold_limbs.sum())
+
+    def carry_to(ceiling: int, bound: int, width: int) -> int:
+        nonlocal limb
+        passes, limb = _passes(limb, ceiling)
+        if passes and bound > 1 << (LIMB_BITS * width):
+            raise ValueError(
+                f"a value < 2**{bound.bit_length()} can carry out of {width} limbs"
+            )
+        return passes
+
+    rounds = []
     while bound >= (1 << (lw + 6)):
         hi_bound = (bound >> lw) + 1
         # Truncate provably-zero top limbs of hi (unsigned => value-bounded).
-        hi_len = min(z.shape[-1] - L, -(-hi_bound.bit_length() // LIMB_BITS) + 1)
-        z, limb = _carry_to(z, bound, limb, _limb_ceiling(c_sum))
-        lo, hi = z[..., :L], z[..., L : L + hi_len]
-        out_len = max(L, hi_len + c_len) + 1
-        z = _pad_to(lo, out_len) + _conv(hi, c_fold, out_len)
+        hi_len = min(width - L, -(-hi_bound.bit_length() // LIMB_BITS) + 1)
+        passes = carry_to(_limb_ceiling(c_sum), bound, width)
+        width = max(L, hi_len + c_len) + 1
+        rounds.append(_FoldRound(passes, hi_len, width))
         # lo alone may exceed 2**lw by the carries its limbs still hold.
         bound = min(bound, _limb_value(limb, L) + 1) + hi_bound * m.c_fold
         limb = _column_bound("fold", limb, c_sum, plus=limb)
@@ -369,10 +383,30 @@ def _fold_semi(m: Modulus, z: jnp.ndarray, bound: int, limb: int) -> jnp.ndarray
     # bits lo keeps of limb fbl.
     room = 2 * m.p - 1 - hi_max * m.c_fb - (((1 << fbs) - 1) << (LIMB_BITS * fbl))
     ceiling = min(_INT32 - 1 - hi_max * cf_max, room // _limb_value(1, fbl))
-    z, limb = _carry_to(z, bound, limb, ceiling)
+    passes = carry_to(ceiling, bound, width)
     # Limbs whose weight is over the value are zero: not read.
     live = ((bound - 1).bit_length() - 1) // LIMB_BITS + 1
-    z = _pad_to(z, fbl + 1)[..., : max(fbl + 1, live)]
+    width = min(max(width, fbl + 1), max(fbl + 1, live))
+    limb = _column_bound("final fold", hi_max, cf_max, plus=limb)
+    return _FoldSchedule(
+        tuple(rounds), passes, width, hi_max, carry_to(_EDGE, 2 * m.p, L)
+    )
+
+
+def _fold_semi(m: Modulus, z: jnp.ndarray, bound: int, limb: int) -> jnp.ndarray:
+    """Reduce an unsigned limb vector of value < ``bound`` and limbs <=
+    ``limb`` (NOT carried: product columns, or a sum of edge operands) to
+    the edge invariant: ``nlimbs`` limbs in ``[0, 2**13]``, value < 2p, by
+    :func:`_fold_schedule`."""
+    L = m.nlimbs
+    plan = _fold_schedule(m, z.shape[-1], bound, limb)
+    c_fold = jnp.asarray(m.c_fold_limbs)
+    for rnd in plan.rounds:
+        z = _carry(z, rnd.passes)
+        lo, hi = z[..., :L], z[..., L : L + rnd.hi_len]
+        z = _pad_to(lo, rnd.out_len) + _conv(hi, c_fold, rnd.out_len)
+    fbl, fbs = m.fb_limb, m.fb_shift
+    z = _pad_to(_carry(z, plan.passes), fbl + 1)[..., : plan.width]
     hi = z[..., fbl] >> fbs
     for j in range(fbl + 1, z.shape[-1]):
         hi = hi + (z[..., j] << (LIMB_BITS * (j - fbl) - fbs))
@@ -382,8 +416,7 @@ def _fold_semi(m: Modulus, z: jnp.ndarray, bound: int, limb: int) -> jnp.ndarray
     )
     lo = z[..., :L] & keep_bits  # fbl + 1 >= L limbs are there
     prod = hi[..., None] * jnp.asarray(m.c_fb_limbs)
-    limb = _column_bound("final fold", hi_max, cf_max, plus=limb)
-    return _carry_to(lo + _pad_to(prod, L), 2 * m.p, limb, _EDGE)[0]
+    return _carry(lo + _pad_to(prod, L), plan.last_passes)
 
 
 def add(m: Modulus, a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import fields
+from . import fields, pallas_ladder
 from .fields import LIMB_BITS, LIMB_MASK, Modulus
 
 __all__ = [
@@ -604,6 +604,13 @@ def ecmul2_base(
     the combine stays in the folded shape so that its two adds share the
     ladder body's one instance of :func:`_point_add_core`.
 
+    **Where the batch folds and the backend is a TPU the 33 steps are ONE
+    Pallas kernel** (:mod:`.pallas_ladder`, whose ``kind`` is the rule): the
+    same steps on the same folded operands, a tile's accumulator and tables
+    resident in VMEM, the accumulator it returns the scan's limb for limb.
+    The scan below stays for every other shape and backend, and is what the
+    kernel is checked against.
+
     ``k1``/``k2`` are semi-reduced scalars mod N; ``qx``/``qy`` affine
     field elements.  ``(qx, qy)`` is on the curve, or the lane's result is
     unspecified: off it the group law, and with it the invariant above,
@@ -676,7 +683,12 @@ def ecmul2_base(
         return _point_add_core(acc, addend), None
 
     with jax.named_scope("recover.glv_ladder"):
-        acc, _ = jax.lax.scan(body, point_infinity(ladder), digits)
+        if pallas_ladder.kind(lanes) == pallas_ladder.PALLAS:
+            acc = JacobianPoint(
+                *pallas_ladder.glv_ladder(digits, neg, tx, ty, tz, field=FIELD)
+            )
+        else:
+            acc, _ = jax.lax.scan(body, point_infinity(ladder), digits)
     # Combine the four lanes with two COMPLETE adds (these operands can
     # coincide) at the SAME batch as the ladder body's core (``ladder``),
     # padding spent lanes with infinity.  Nested-jit point ops dedup per

@@ -467,7 +467,14 @@ class CoalescedDispatcher:
         ):
             # The packers' rows as they are (``zw`` the digest program's
             # rows on the sender side): the compiled call stages them.
-            mask = np.asarray(kernel(zw, r, s, v, claimed, table, live))
+            with trace.span(
+                "verify.dispatch",
+                route="mesh" if sharded else "device",
+                # the sharded program keeps the scan (ops/pallas_ladder.py)
+                ladder="scan" if sharded else vbatch.ladder_of(live.shape[0]),
+            ):
+                launched = kernel(zw, r, s, v, claimed, table, live)
+            mask = np.asarray(launched)
         self._note_served(f"{'mesh' if sharded else 'device'}/{mask.shape[0]}")
         return mask
 

@@ -51,6 +51,7 @@ from ..messages.helpers import CommittedSeal
 from ..messages.wire import IbftMessage, MessageType, payloads_no_sig
 from ..ops import fields
 from ..ops import keccak as dk
+from ..ops import pallas_ladder
 from ..ops import quorum
 from ..ops import secp256k1 as sec
 from ..ops.fields import LIMB_BITS, LIMB_MASK
@@ -207,6 +208,10 @@ EAGER_PUTS_KEY = ("go-ibft", "dispatch", "eager_puts")
 # Validator tables copied host -> device: one a distinct address set (or a
 # distinct union of sets, block sync), whatever heights select it.
 TABLE_UPLOADS_KEY = ("go-ibft", "verify", "table_uploads")
+# Launches of a program whose ladder is the Pallas kernel
+# (``ops/pallas_ladder.py``); the ``verify.dispatch`` span of every device
+# launch says which ladder its program has (``ladder``: "pallas" | "scan").
+PALLAS_LADDER_DISPATCHES_KEY = ("go-ibft", "kernel", "pallas_ladder_dispatches")
 # Seal lanes of a sync drain whose signature held and whose signer is not in
 # the validator set of the lane's OWN height (though it may sit in a
 # neighbouring epoch's set, on the same device table).
@@ -736,6 +741,16 @@ def _address_rows(addresses: Sequence[bytes]) -> np.ndarray:
     """N length-checked 20-byte addresses -> ``(N, 5)`` uint32 words (the
     layout of ``ops/keccak.py::addresses_to_words``, without its loop)."""
     return np.frombuffer(b"".join(addresses), dtype="<u4").reshape(-1, 5)
+
+
+def ladder_of(lanes: int) -> str:
+    """``ladder`` of a ``verify.dispatch`` span: which ladder the recover
+    program of ``lanes`` lanes was traced with, by the rule it was traced by;
+    counted where it is the kernel."""
+    ladder = pallas_ladder.kind(lanes)
+    if ladder == pallas_ladder.PALLAS:
+        metrics.inc_counter(PALLAS_LADDER_DISPATCHES_KEY)
+    return ladder
 
 
 def _host_arrays(inputs) -> int:
@@ -1487,7 +1502,10 @@ class DeviceBatchVerifier:
         ):
             zw, r, s, v, claimed, live = inputs
             with trace.span(
-                "verify.dispatch", route="device", operands=_host_arrays(inputs)
+                "verify.dispatch",
+                route="device",
+                operands=_host_arrays(inputs),
+                ladder=ladder_of(live.shape[0]),
             ):
                 if quorum_args is None:
                     return _recover_kernel(zw, r, s, v, claimed, table, live), None
@@ -1727,7 +1745,10 @@ class DeviceBatchVerifier:
                 site="verify/batch.py:certify_round",
             ):
                 with trace.span(
-                    "verify.dispatch", route="device", operands=_host_arrays(inputs)
+                    "verify.dispatch",
+                    route="device",
+                    operands=_host_arrays(inputs),
+                    ladder=ladder_of(2 * lanes),
                 ):
                     mask, p_reached, s_reached = _round_kernel(
                         *inputs[:5],

@@ -136,3 +136,21 @@ def test_certify_program_still_lowers_with_every_stage():
     text = lowered.as_text(debug_info=True)
     for stage in sr.STAGES:
         assert stage in text, stage
+
+
+def test_all_nine_scope_names_reach_the_folded_program_on_the_kernel_path():
+    """The other path (PR 48): where the ladder is the Pallas kernel the nine
+    scopes are still in the program, and the kernel's custom call is named
+    under ``recover.glv_ladder``, which is how ``stage_reduce`` charges it to
+    the ladder stage.  Lowered for the TPU platform, no chip (last in the
+    file: it drops the trace caches)."""
+    import pallas_cases as pc
+
+    with pc.traced_for_tpu():
+        text = pc.Lowered(256).text
+    locs = [line for line in text.splitlines() if line.startswith("#loc")]
+    seen = collections.Counter(sr.stage_of(m) for line in locs for m in re.findall(r'loc\("([^"]+)"', line))
+    for stage in sr.STAGES:
+        assert seen[stage] > 0, stage
+    (call,) = [line for line in locs if "pallas_call" in line]
+    assert sr.stage_of(re.search(r'loc\("([^"]+)"', call).group(1)) == "recover.glv_ladder"

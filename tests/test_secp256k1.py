@@ -354,6 +354,46 @@ def test_glv_ladder_edge_cases_cover_what_they_name():
             assert host.scalar_mul(k1, lc.G) == host.scalar_mul(k2, q)
 
 
+def _ladder_operands(lanes):
+    """ECMUL2_CASES tiled to ``lanes``, then random scalars on random points
+    where the tiling would only repeat itself."""
+    rng = random.Random(lanes)
+    cases = [(c[1], c[2], c[3]) for c in ECMUL2_CASES[:lanes]]
+    while len(cases) < lanes:
+        q = host.scalar_mul(rng.randrange(1, sec.N), lc.G)
+        cases.append((rng.randrange(sec.N), rng.randrange(sec.N), q))
+    k1, k2, q = zip(*cases)
+    return pack(k1), pack(k2), pack(p[0] for p in q), pack(p[1] for p in q)
+
+
+@pytest.mark.parametrize("lanes", (256, 512, 1024, 2048), ids=lambda n: f"{n}l")
+def test_pallas_ladder_equals_the_scan_lane_by_lane_on_the_chip(lanes, monkeypatch):
+    """The whole kernel (``ops/pallas_ladder.py``: the path every folded
+    program takes on a TPU) against the scan it replaces, on the same
+    operands: the Jacobian accumulator after ``recover.combine``, limb for
+    limb, in every lane."""
+    if not _on_tpu():
+        pytest.skip(f"{lanes} lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
+    from go_ibft_tpu.ops import pallas_ladder
+
+    args = _ladder_operands(lanes)
+    assert pallas_ladder.kind(lanes) == pallas_ladder.PALLAS
+    text = sec.ecmul2_base.lower(*args).as_text()
+    assert text.count("tpu_custom_call") == 1
+    got = [np.asarray(c) for c in sec.ecmul2_base(*args)]
+    try:
+        monkeypatch.setattr(pallas_ladder, "supported", lambda: False)
+        jax.clear_caches()  # the traces above chose their ladder
+        assert "tpu_custom_call" not in sec.ecmul2_base.lower(*args).as_text()
+        want = [np.asarray(c) for c in sec.ecmul2_base(*args)]
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    assert int(np.asarray(sec.is_infinity(sec.JacobianPoint(*map(jnp.asarray, got)))).sum()) < lanes // 4
+
+
 @pytest.mark.parametrize("lanes", CHIP_LANES, ids=lambda n: f"{n}l")
 def test_glv_ladder_edge_cases_at_wide_lanes_on_the_chip(lanes):
     if not _on_tpu():

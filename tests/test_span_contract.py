@@ -223,6 +223,25 @@ def test_a_device_dispatch_says_what_it_handed_over(
     assert metrics.get_counter(batch.EAGER_PUTS_KEY) == eager
 
 
+def test_a_device_dispatch_says_which_ladder_its_program_has(
+    signed_round, stub_kernels, recorder, monkeypatch
+):
+    """ISSUE 48: ``ladder`` on ``verify.dispatch`` is the rule the program
+    was traced by (``ops/pallas_ladder.py::kind``): the scan off the TPU at
+    any width; the kernel on one where the lanes fold, counted."""
+    from go_ibft_tpu.ops import pallas_ladder
+
+    key = batch.PALLAS_LADDER_DISPATCHES_KEY
+    before = metrics.get_counter(key)
+    verifier = AdaptiveBatchVerifier(_validators(signed_round))
+    assert _call(verifier, "verify_senders", signed_round, N_VALIDATORS).all()
+    (dispatch,) = [r[5] for r in recorder.snapshot() if r[1] == "verify.dispatch"]
+    assert dispatch["ladder"] == "scan" and metrics.get_counter(key) == before
+    monkeypatch.setattr(pallas_ladder, "supported", lambda: True)
+    assert [batch.ladder_of(n) for n in (32, 128, 256, 2048)] == ["scan", "scan", "pallas", "pallas"]
+    assert metrics.get_counter(key) == before + 2
+
+
 def test_a_host_dispatch_hands_nothing_over(signed_round, recorder):
     HostBatchVerifier(_validators(signed_round)).verify_senders(
         signed_round.prepares[:HOST_LANES]

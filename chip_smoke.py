@@ -14,9 +14,8 @@ all sharing one compile cache (``JAX_COMPILATION_CACHE_DIR`` where set, else
 ``<checkout>/.cache/xla`` — ``go_ibft_tpu/utils/jaxcache.py``):
 
 * ``kernels`` — differential gate before anything else: the 8-lane and
-  committee-width ``digest_words`` / ``ecdsa_recover`` / ``quorum_certify``
-  programs against the sequential host oracle, lane for lane, corrupted
-  lanes included; and the Pallas keccak COMPILED against its uint64 oracle.
+  committee-width ``digest_words`` / ``ecdsa_recover`` programs against the
+  sequential host oracle, lane for lane, corrupted lanes included; and the Pallas keccak COMPILED against its uint64 oracle.
 * ``engine`` — one ``IBFT`` with a default-constructed
   ``AdaptiveBatchVerifier`` and ``BatchingIngress`` finalizes heights in
   round 0 with corrupted peers every height; the cost ledger and the
@@ -364,27 +363,6 @@ def kernels_phase(
             f"{np.nonzero(smask != want_seals)[0].tolist()} disagree with the oracle",
         )
 
-        ok, reached, lo, hi = first_call(
-            f"quorum_certify_{tag}",
-            vbatch._certify_kernel,
-            *args,
-            jnp.asarray(w.powers_lo),
-            jnp.asarray(w.powers_hi),
-            jnp.int32(w.thr_lo),
-            jnp.int32(w.thr_hi),
-        )
-        power = int(want_prepare.sum())  # equal power 1
-        threshold = (w.thr_hi << 16) + w.thr_lo
-        _check(
-            (np.asarray(ok) == want_prepare).all(),
-            f"quorum_certify_{tag}: mask disagrees with the oracle",
-        )
-        _check(
-            (int(hi) << 16) + int(lo) == power
-            and bool(reached) == (power >= threshold),
-            f"quorum_certify_{tag}: power {(int(hi) << 16) + int(lo)} / reached "
-            f"{bool(reached)}, oracle {power} vs threshold {threshold}",
-        )
         checked[tag] = {
             "validators": n,
             "lanes": lanes,
@@ -497,7 +475,6 @@ def engine_phase(
         warm = cost_ledger.snapshot()
         warm_events = events.snapshot()
         ladder_keys = {
-            "certify_fallback": ("go-ibft", "resilient", "certify_fallback"),
             "drain_faults": vbatch.DRAIN_FAULTS_KEY,
             "quarantined_lanes": vbatch.QUARANTINED_LANES_KEY,
             "breaker_transitions": BREAKER_TRANSITIONS_KEY,

@@ -116,7 +116,7 @@ def build_cluster(
             # lane-parallel across every visible device (forced host
             # devices work too: XLA_FLAGS=--xla_force_host_platform_
             # device_count=8).  Degrades transparently to the plain
-            # device ladder on a 1-device host.  The engine certify
+            # device ladder on a 1-device host.  The engine's phase
             # drains AND (in --chain mode) the overlap/sync drains all
             # route through the same ladder.
             from go_ibft_tpu.verify import (
@@ -575,7 +575,7 @@ if __name__ == "__main__":
     ap.add_argument(
         "--device",
         action="store_true",
-        help="verify PREPARE/COMMIT phases through the fused device kernels",
+        help="verify PREPARE/COMMIT phases through the device kernels",
     )
     ap.add_argument(
         "--mesh",

@@ -3,7 +3,7 @@
 Builds what a real IBFT round at height ``h`` produces (BASELINE.md
 configs): one PREPARE envelope and one COMMIT seal per validator, all
 genuinely ECDSA-signed, packed into the static-shape device arrays the
-fused quorum kernels consume.  A ``corrupt_frac`` knob flips signature
+recover program consumes.  A ``corrupt_frac`` knob flips signature
 bytes on a deterministic subset — the Byzantine-mix config — whose lanes
 the kernels must mask out.
 """
@@ -13,14 +13,12 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, Tuple
 
-import jax.numpy as jnp
 import numpy as np
 
 from ..crypto import PrivateKey
 from ..crypto.backend import ECDSABackend, proposal_hash_of
 from ..messages.helpers import CommittedSeal, extract_committed_seal
 from ..messages.wire import Proposal, View
-from ..ops.quorum import split_power
 from ..verify.batch import (
     pack_seal_batch,
     pack_sender_batch,
@@ -56,10 +54,6 @@ class SignedRound:
     seals: list
     proposal_hash: bytes
     table: np.ndarray  # (V, 5) uint32
-    powers_lo: np.ndarray
-    powers_hi: np.ndarray
-    thr_lo: int
-    thr_hi: int
     expected_prepare_mask: np.ndarray
     expected_seal_mask: np.ndarray
 
@@ -71,10 +65,6 @@ class SignedRound:
             prepare=pack_sender_batch(self.prepares, pad_lanes),
             seals=pack_seal_batch(self.proposal_hash, self.seals, pad_lanes),
             table=self.table,
-            powers_lo=self.powers_lo,
-            powers_hi=self.powers_hi,
-            thr_lo=self.thr_lo,
-            thr_hi=self.thr_hi,
             expected_prepare_mask=self.expected_prepare_mask,
             expected_seal_mask=self.expected_seal_mask,
         )
@@ -91,42 +81,8 @@ class RoundWorkload:
     # commit-seal phase: (hash_words, r, s, v, signers, live)
     seals: tuple
     table: np.ndarray  # (V, 5) uint32
-    powers_lo: np.ndarray
-    powers_hi: np.ndarray
-    thr_lo: int
-    thr_hi: int
     expected_prepare_mask: np.ndarray
     expected_seal_mask: np.ndarray
-
-
-def _quorum_tail(w: RoundWorkload) -> tuple:
-    """Voting powers and threshold, as every fused certify program ends."""
-    return (
-        jnp.asarray(w.powers_lo),
-        jnp.asarray(w.powers_hi),
-        jnp.int32(w.thr_lo),
-        jnp.int32(w.thr_hi),
-    )
-
-
-def prep_args(w: RoundWorkload) -> tuple:
-    """The PREPARE phase as ``ops.quorum.quorum_certify`` takes it."""
-    blocks, counts, r, s, v, senders, live = w.prepare
-    lanes = (blocks, counts, r, s, v, senders, w.table, live)
-    return tuple(jnp.asarray(a) for a in lanes) + _quorum_tail(w)
-
-
-def seal_args(w: RoundWorkload) -> tuple:
-    """The COMMIT-seal phase as ``ops.quorum.seal_quorum_certify`` takes it."""
-    hz, r, s, v, signers, live = w.seals
-    lanes = (hz, r, s, v, signers, w.table, live)
-    return tuple(jnp.asarray(a) for a in lanes) + _quorum_tail(w)
-
-
-def round_args(w: RoundWorkload) -> tuple:
-    """Both phases packed for the single-dispatch ``ops.quorum.round_certify``."""
-    lanes = (*w.prepare, *w.seals, w.table)
-    return tuple(jnp.asarray(a) for a in lanes) + _quorum_tail(w)
 
 
 def build_signed_round(
@@ -166,15 +122,6 @@ def build_signed_round(
         expected_seal[i] = False
 
     table = pack_validator_table([k.address for k in keys])
-    lo_hi = [split_power(powers[k.address]) for k in keys]
-    v = table.shape[0]
-    powers_lo = np.zeros(v, dtype=np.int32)
-    powers_hi = np.zeros(v, dtype=np.int32)
-    powers_lo[:n_validators] = [lh[0] for lh in lo_hi]
-    powers_hi[:n_validators] = [lh[1] for lh in lo_hi]
-    total = sum(powers.values())
-    threshold = (2 * total) // 3 + 1
-    thr_lo, thr_hi = threshold & 0xFFFF, threshold >> 16
 
     return SignedRound(
         n_validators=n_validators,
@@ -183,10 +130,6 @@ def build_signed_round(
         seals=seals,
         proposal_hash=phash,
         table=table,
-        powers_lo=powers_lo,
-        powers_hi=powers_hi,
-        thr_lo=thr_lo,
-        thr_hi=thr_hi,
         expected_prepare_mask=expected_prepare,
         expected_seal_mask=expected_seal,
     )

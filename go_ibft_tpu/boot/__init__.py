@@ -1,7 +1,7 @@
 """The production boot layer (ROADMAP item 5: kill the cold boot).
 
 A restarted node historically paid minutes of XLA:CPU compile before its
-first round (~3 minutes for ``quorum_certify`` alone in round 4) — fatal
+first round (~3 minutes for one ladder program alone in round 4) — fatal
 for fleet operations where nodes restart constantly.  This package makes
 restart cost a cache load instead:
 

@@ -38,7 +38,7 @@ import os
 import re
 import tempfile
 import time
-from typing import Dict, Iterable, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 from ..obs import ledger as cost_ledger
 from ..utils.jaxcache import enable_persistent_cache, resolve_cache_dir
@@ -285,19 +285,13 @@ class AOTStore:
 # -- boot consumes) -----------------------------------------------------
 
 
-def write_manifest(
-    path: str,
-    programs: Dict[str, dict],
-    *,
-    sizes: Iterable[int] = (),
-) -> dict:
+def write_manifest(path: str, programs: Dict[str, dict]) -> dict:
     """Write the AOT manifest: measured per-family compile cost under a
     fingerprint.  ``programs`` maps family -> ``{"compile_ms": float,
     "events": int}`` (the cost-ledger snapshot's compile table)."""
     doc = {
         "fingerprint": fingerprint(),
         "generated_ts": time.time(),
-        "sizes": sorted(int(s) for s in sizes),
         "programs": {
             name: {
                 "compile_ms": round(float(acc.get("compile_ms", 0.0)), 3),

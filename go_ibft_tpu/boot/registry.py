@@ -47,12 +47,6 @@ def _engine_shapes() -> dict:
         "counts": jnp.ones((B,), jnp.int32),
         "limbs": jnp.zeros((B, L), jnp.int32),
         "v": jnp.zeros((B,), jnp.int32),
-        "addr": jnp.zeros((B, 5), jnp.uint32),
-        "table": jnp.zeros((8, 5), jnp.uint32),
-        "live": jnp.zeros((B,), bool),
-        "power": jnp.zeros((8,), jnp.int32),
-        "hash_zw": jnp.zeros((B, 8), jnp.uint32),
-        "thr": jnp.int32(1),
     }
 
 
@@ -108,32 +102,6 @@ def _build_multipair_miller():
     return _multi_miller_stage, (mm, mm, mm, mm, mm, mm)
 
 
-def _build_quorum_certify():
-    import jax
-
-    from ..ops import quorum
-
-    s = _engine_shapes()
-    return jax.jit(quorum.quorum_certify), (
-        s["blocks"], s["counts"], s["limbs"], s["limbs"], s["v"], s["addr"],
-        s["table"], s["live"], s["power"], s["power"], s["thr"], s["thr"],
-    )
-
-
-def _build_round_certify():
-    import jax
-
-    from ..ops import quorum
-
-    s = _engine_shapes()
-    return jax.jit(quorum.round_certify), (
-        s["blocks"], s["counts"], s["limbs"], s["limbs"], s["v"], s["addr"],
-        s["live"],
-        s["hash_zw"], s["limbs"], s["limbs"], s["v"], s["addr"], s["live"],
-        s["table"], s["power"], s["power"], s["thr"], s["thr"],
-    )
-
-
 def _build_ecdsa_recover():
     import jax
 
@@ -164,19 +132,6 @@ def _cpu_devices(dp: int):
             f"mesh family needs {dp} devices, host has {len(cpu)}"
         )
     return cpu[:dp]
-
-
-def _build_mesh_quorum_certify(dp: int):
-    import jax
-
-    from ..parallel import make_mesh, mesh_quorum_certify
-
-    mesh = make_mesh(dp, devices=_cpu_devices(dp))
-    s = _engine_shapes()
-    return jax.jit(mesh_quorum_certify(mesh)), (
-        s["blocks"], s["counts"], s["limbs"], s["limbs"], s["v"], s["addr"],
-        s["table"], s["live"], s["power"], s["power"], s["thr"], s["thr"],
-    )
 
 
 def _build_mesh_verify_mask(dp: int):
@@ -247,17 +202,12 @@ def program_registry(
             ("bls_g1_merge_tree_128v", _build_g1_merge_tree),
             ("digest_words_8l", _build_digest_words),
             ("bls_multipair_miller_8l", _build_multipair_miller),
-            ("quorum_certify_8l", _build_quorum_certify),
-            ("round_certify_8l", _build_round_certify),
             ("ecdsa_recover_8l", _build_ecdsa_recover),
             ("ecmul2_base_8l", _build_ecmul2_base),
             ("ici_tick_8n", lambda: _build_ici_tick(8)),
         )
     )
     for dp in MESH_DPS:
-        defs[f"mesh_quorum_certify_8l_dp{dp}"] = (
-            lambda dp=dp: _build_mesh_quorum_certify(dp)
-        )
         defs[f"mesh_verify_mask_8l_dp{dp}"] = (
             lambda dp=dp: _build_mesh_verify_mask(dp)
         )

@@ -154,8 +154,8 @@ class ChaoticVerifier:
     This is what a dead/flaky accelerator looks like to the engine — the
     exact input :class:`~go_ibft_tpu.verify.ResilientBatchVerifier` and the
     circuit breaker are built to absorb.  Everything not fault-gated
-    (``note_round``, ``warmup``, ``supports_fused``, ``quarantine``, the
-    certify entry points, ...) forwards to the inner verifier untouched.
+    (``note_round``, ``warmup``, ``quarantine``, ...) forwards to the inner
+    verifier untouched.
     """
 
     def __init__(self, inner, injector: FaultInjector, site: str = "verify") -> None:
@@ -186,20 +186,6 @@ class ChaoticVerifier:
         # would forward it to the inner verifier chaos-free).
         self._gate()
         return self.inner.verify_seal_lanes(lanes, height)
-
-    def certify_senders(self, msgs, height, threshold=None):
-        self._gate()
-        return self.inner.certify_senders(msgs, height, threshold)
-
-    def certify_seals(self, proposal_hash, seals, height, threshold=None):
-        self._gate()
-        return self.inner.certify_seals(proposal_hash, seals, height, threshold)
-
-    def certify_round(self, msgs, proposal_hash, seals, height, prepare_threshold=None):
-        self._gate()
-        return self.inner.certify_round(
-            msgs, proposal_hash, seals, height, prepare_threshold
-        )
 
     def __getattr__(self, name):
         return getattr(self.inner, name)

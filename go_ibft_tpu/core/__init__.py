@@ -8,7 +8,6 @@ delegated to a BatchVerifier draining device batches.
 from .backend import (
     Backend,
     BatchVerifier,
-    FusedBatchVerifier,
     MessageConstructor,
     Notifier,
     ValidatorBackend,
@@ -35,7 +34,6 @@ __all__ = [
     "BatchingIngress",
     "BatchVerifier",
     "DEFAULT_BASE_ROUND_TIMEOUT",
-    "FusedBatchVerifier",
     "IBFT",
     "Logger",
     "LoopbackTransport",

@@ -123,46 +123,6 @@ class BatchVerifier(Protocol):
         ...
 
 
-@runtime_checkable
-class FusedBatchVerifier(BatchVerifier, Protocol):
-    """BatchVerifier that can ALSO certify quorum on device.
-
-    The flagship fusion (SURVEY.md §2 #2/#3, ops/quorum.py): one compiled
-    program per phase returns both the validity mask and the voting-power
-    quorum verdict, so the reduction never leaves the device.  The engine
-    uses these for its PREPARE/COMMIT hot path when
-    :meth:`supports_fused` says the height's powers fit the device's exact
-    integer range; otherwise it falls back to mask-on-device +
-    big-int-quorum-on-host.
-    """
-
-    def supports_fused(self, height: int) -> bool: ...
-
-    def certify_senders(
-        self,
-        msgs: Sequence[IbftMessage],
-        height: int,
-        threshold: Optional[int] = None,
-    ) -> tuple[np.ndarray, bool]:
-        """(validity mask, quorum reached) for one view's envelopes.
-
-        ``threshold`` overrides the height's quorum size — the engine
-        passes ``quorum - proposer_power`` to credit the proposer's
-        proposal in the prepare phase (reference
-        core/validator_manager.go:99-127)."""
-        ...
-
-    def certify_seals(
-        self,
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        threshold: Optional[int] = None,
-    ) -> tuple[np.ndarray, bool]:
-        """(validity mask, quorum reached) for one view's committed seals."""
-        ...
-
-
 class Backend(
     MessageConstructor, Verifier, ValidatorBackend, Notifier, Protocol
 ):

@@ -10,7 +10,7 @@ ISSUE 14 tentpole.  Nine rounds could say *what* ran but not *where
 device time or padding went*: the verify plane buckets lanes to power-of-
 two shapes (padding waste unmeasured), every subsystem keeps its own
 one-off dispatch counter, and a cold XLA compile — the single worst
-production number (round 4's ~3-minute quorum_certify build) — leaves
+production number (round 4's ~3-minute ladder-program build) — leaves
 no record of which program compiled, when, or for how long.  This module
 is the one attributed accounting plane behind all of it:
 
@@ -24,8 +24,8 @@ is the one attributed accounting plane behind all of it:
 
 * **Program identity IS the compile-budget key space.**  Dispatch records
   use the family names of the ``scripts/compile_budget.py`` registry
-  (``quorum_certify``, ``round_certify``, ``ecdsa_recover``,
-  ``mesh_verify_mask``, ``bls_aggregate_verify``, ``bls_g2_merge_tree``,
+  (``ecdsa_recover``, ``digest_words``, ``mesh_verify_mask``,
+  ``bls_aggregate_verify``, ``bls_g2_merge_tree``,
   ``bls_multipair_miller``, ...) with the shape suffix dropped — so
   ``scripts/cost_report.py`` can attribute recorded dispatches straight
   onto the pinned program set, and the AOT manifest of ROADMAP item 5

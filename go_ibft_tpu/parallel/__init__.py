@@ -1,29 +1,19 @@
-"""Multi-chip parallelism: sharded quorum verification over a device mesh.
+"""Multi-chip parallelism: device meshes for lane-sharded verification.
 
 The reference's only scaling dimension is validator-set size N — O(N)
 sequential signature verifies per phase (SURVEY.md §5 "long-context").
-Here that dimension is laid out over a ``jax.sharding.Mesh``:
-
-* ``dp`` — message lanes (the batch axis) sharded across chips;
-* ``vp`` — the validator table sharded across chips for very large sets
-  (the (B, V) membership/equality matrix is the framework's "attention
-  score" analogue — ``dp x vp`` tiles it like 2-D attention sharding).
-
-XLA GSPMD inserts the cross-chip collectives (an all-reduce for the
-voting-power sum riding ICI) from sharding annotations alone — no
-hand-written NCCL analogue, per the scaling-book recipe.
+Here that dimension is laid out over a ``jax.sharding.Mesh`` whose ``dp``
+axis shards the message lanes (the batch axis) across chips
+(``verify/mesh_batch.py``); ``vp`` is kept in the mesh's shape for a
+validator table sharded across chips, which no program uses at present.
 """
 
 from .mesh import (
     make_mesh,
     mesh_context,
-    mesh_quorum_certify,
-    mesh_seal_quorum_certify,
 )
 
 __all__ = [
     "make_mesh",
     "mesh_context",
-    "mesh_quorum_certify",
-    "mesh_seal_quorum_certify",
 ]

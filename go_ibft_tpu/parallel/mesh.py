@@ -1,44 +1,22 @@
-"""Sharded fused quorum kernels over a ``jax.sharding.Mesh`` (shard_map).
+"""``(dp, vp)`` device meshes for the lane-sharded verification program.
 
-Single-program multi-chip via ``shard_map`` with *explicit* collectives —
-each chip verifies its slice of the message lanes (``dp`` axis) against its
-slice of the validator table (``vp`` axis), then three small ``psum``s
-assemble the global answer over ICI:
-
-1. membership: a sender is a validator if *any* table shard matches
-   (psum over ``vp``);
-2. counted-validators: a validator is counted if *any* lane shard carried
-   its valid message (psum over ``dp``);
-3. voting power: the exact split-halves sum over table shards
-   (psum over ``vp``).
-
-shard_map (not GSPMD auto-partitioning) is deliberate: the 256-step EC
-ladder compiles once for the *local* shard shape — partitioning the whole
-program would re-run SPMD propagation through the scan and multiply
-compile time; the collectives here are three scalar-ish psums, trivially
-placed by hand.  This mirrors the scaling-book recipe: pick the mesh,
-annotate the data, let the per-shard program stay identical to the
-single-chip one.
+``verify/mesh_batch.py::mesh_verify_mask`` lays message lanes over the ``dp``
+axis with ``shard_map`` (not GSPMD auto-partitioning: the EC ladder compiles
+once for the *local* shard shape, and the per-shard program stays identical
+to the single-chip one).  This module is the ONE place such a mesh is built.
 """
 
 from __future__ import annotations
 
-from functools import partial
 from typing import Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
-from jax import shard_map
-from jax.sharding import Mesh, PartitionSpec as P
-
-from ..ops import quorum
+from jax.sharding import Mesh
 
 __all__ = [
     "make_mesh",
     "mesh_context",
-    "mesh_quorum_certify",
-    "mesh_seal_quorum_certify",
 ]
 
 
@@ -105,70 +83,3 @@ def mesh_context(
     if n // max(vp, 1) < 2:
         return None
     return make_mesh(n, vp=vp, devices=devices[:n])
-
-
-def _finish(reached_inputs):
-    ok, eq, powers_lo, powers_hi, thr_lo, thr_hi = reached_inputs
-    # a validator row is counted if any of *this* lane-shard's valid
-    # messages matched it; then OR across lane shards.
-    counted_local = jnp.any(eq & ok[:, None], axis=0).astype(jnp.int32)
-    counted = jax.lax.psum(counted_local, "dp") > 0  # (V_local,)
-    lo = jax.lax.psum(jnp.sum(jnp.where(counted, powers_lo, 0)), "vp")
-    hi = jax.lax.psum(jnp.sum(jnp.where(counted, powers_hi, 0)), "vp")
-    hi = hi + (lo >> 16)
-    lo = lo & 0xFFFF
-    reached = (hi > thr_hi) | ((hi == thr_hi) & (lo >= thr_lo))
-    return reached, lo, hi
-
-
-def mesh_quorum_certify(mesh: Mesh):
-    """Sharded :func:`~go_ibft_tpu.ops.quorum.quorum_certify` (same
-    signature/outputs, bit-identical results)."""
-
-    lane = P("dp")
-    vrow = P("vp")
-    rep = P()
-
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(lane, lane, lane, lane, lane, lane, vrow, lane, vrow, vrow, rep, rep),
-        out_specs=(lane, rep, rep, rep),
-        check_vma=False,
-    )
-    def step(blocks, nblocks, r, s, v, sender_w, table_w, live,
-             powers_lo, powers_hi, thr_lo, thr_hi):
-        sig_ok = quorum.sender_sig_checks(blocks, nblocks, r, s, v, sender_w, live)
-        eq = quorum.membership_eq(sender_w, table_w)  # (B_loc, V_loc)
-        member = jax.lax.psum(jnp.any(eq, axis=-1).astype(jnp.int32), "vp") > 0
-        ok = sig_ok & member
-        reached, lo, hi = _finish((ok, eq, powers_lo, powers_hi, thr_lo, thr_hi))
-        return ok, reached, lo, hi
-
-    return jax.jit(step)
-
-
-def mesh_seal_quorum_certify(mesh: Mesh):
-    """Sharded :func:`~go_ibft_tpu.ops.quorum.seal_quorum_certify`."""
-
-    lane = P("dp")
-    vrow = P("vp")
-    rep = P()
-
-    @partial(
-        shard_map,
-        mesh=mesh,
-        in_specs=(lane, lane, lane, lane, lane, vrow, lane, vrow, vrow, rep, rep),
-        out_specs=(lane, rep, rep, rep),
-        check_vma=False,
-    )
-    def step(hash_zw, r, s, v, signer_w, table_w, live,
-             powers_lo, powers_hi, thr_lo, thr_hi):
-        sig_ok = quorum.seal_sig_checks(hash_zw, r, s, v, signer_w, live)
-        eq = quorum.membership_eq(signer_w, table_w)
-        member = jax.lax.psum(jnp.any(eq, axis=-1).astype(jnp.int32), "vp") > 0
-        ok = sig_ok & member
-        reached, lo, hi = _finish((ok, eq, powers_lo, powers_hi, thr_lo, thr_hi))
-        return ok, reached, lo, hi
-
-    return jax.jit(step)

@@ -11,7 +11,7 @@ stage 4):
   JAX backend is active (TPU in production, CPU in tests); the framework's
   headline capability.
 * :class:`MeshBatchVerifier` — the same drains sharded lane-parallel
-  across the device mesh (shard_map, host-side quorum reduce); degrades
+  across the device mesh (shard_map, collective-free); degrades
   transparently to :class:`DeviceBatchVerifier` on a 1-device host.
 * :class:`AdaptiveBatchVerifier` — routes tiny batches to the host path
   and big ones to the device kernels (the dispatch-latency floor makes

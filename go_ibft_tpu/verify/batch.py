@@ -166,11 +166,9 @@ def host_quorum_reached(
 ) -> bool:
     """Exact host-int voting-power quorum over a drain's valid addresses.
 
-    The ONE host-side quorum reduction shared by
-    :class:`AdaptiveBatchVerifier`'s fallback routes and the mesh
-    verifier's sharded certify paths (``ops/quorum.py`` ``power_reduce``
-    semantics: distinct validators counted once, exact Python ints for any
-    power range)."""
+    The host-side quorum reduction beside a mask (the resilient ladder's
+    early-exit fallback): distinct validators counted once, exact Python
+    ints for any power range."""
     powers = validators_for_height(height)
     thr = (
         calculate_quorum(sum(powers.values()))
@@ -672,44 +670,6 @@ def _recover_fn(zw, r, s, v, claimed_w, table_w, live):
 
 
 _recover_kernel = jax.jit(_recover_fn)
-
-
-def _certify_fn(zw, r, s, v, claimed_w, table_w, live, plo, phi, thr_lo, thr_hi):
-    """Fused mask + voting-power quorum in ONE program (the engine's hot
-    path): recovery ladder, membership, and the power reduction of
-    :func:`go_ibft_tpu.ops.quorum.power_reduce` never leave the device.
-    Serves both envelope senders (``zw`` = payload digests) and committed
-    seals (``zw`` = broadcast proposal hash), like :func:`_recover_kernel`.
-    ``thr_lo``/``thr_hi`` are traced scalars, so per-call thresholds (e.g.
-    the prepare-phase proposer credit) do not recompile."""
-    ok = quorum.sig_checks_zw(zw, r, s, v, claimed_w, live)
-    eq = quorum.membership_eq(claimed_w, table_w)
-    ok = ok & jnp.any(eq, axis=-1)
-    reached, lo, hi = quorum.power_reduce(ok, eq, plo, phi, thr_lo, thr_hi)
-    return ok, reached, lo, hi
-
-
-_certify_kernel = jax.jit(_certify_fn)
-
-
-def _round_fn(
-    zw, r, s, v, claimed_w, table_w, live, plo, phi, p_lo, p_hi, s_lo, s_hi
-):
-    """BOTH phases of a round in ONE dispatch (ops.quorum.round_certify
-    shape): the first half of the lanes are PREPARE envelopes (payload
-    digests), the second half COMMIT seals (broadcast proposal hash); one
-    shared recovery ladder, two separate quorum reductions with their own
-    thresholds (prepare carries the proposer credit)."""
-    ok = quorum.sig_checks_zw(zw, r, s, v, claimed_w, live)
-    eq = quorum.membership_eq(claimed_w, table_w)
-    ok = ok & jnp.any(eq, axis=-1)
-    b = zw.shape[0] // 2
-    p_reached, _, _ = quorum.power_reduce(ok[:b], eq[:b], plo, phi, p_lo, p_hi)
-    s_reached, _, _ = quorum.power_reduce(ok[b:], eq[b:], plo, phi, s_lo, s_hi)
-    return ok, p_reached, s_reached
-
-
-_round_kernel = jax.jit(_round_fn)
 
 
 def _pack_scalars(values: List[int], pad_to: int) -> jnp.ndarray:
@@ -1234,17 +1194,14 @@ class DeviceBatchVerifier:
         # span a drain emits names the route that actually served it.
         self._route = "device"
         # What follows is keyed by what it HOLDS, never by a height: a table
-        # by its address set, the power vectors by the (address, power)
-        # pairs.  A set seen before costs no pack and no upload whatever
-        # height selects it (a fixed committee: one upload a process; stakes
-        # that move inside the same addresses: the same table), least
-        # recently used out first.
+        # by its address set.  A set seen before costs no pack and no upload
+        # whatever height selects it (a fixed committee: one upload a
+        # process; stakes that move inside the same addresses: the same
+        # table), least recently used out first.
         self._tables: "OrderedDict[FrozenSet[bytes], Tuple[np.ndarray, List[bytes]]]" = OrderedDict()
-        # Device-resident twins of the packed tables/power vectors: uploaded
-        # once a set and handed to every dispatch that is judged against it.
+        # Device-resident twins of the packed tables: uploaded once a set
+        # and handed to every dispatch that is judged against it.
         self._tables_dev: Dict[FrozenSet[bytes], jnp.ndarray] = {}
-        self._quorum_packs: "OrderedDict[frozenset, Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]]]" = OrderedDict()
-        self._quorum_dev: Dict[frozenset, Tuple[jnp.ndarray, jnp.ndarray]] = {}
         self._cache_sets = cache_heights
         # Per-message pack cache (round-scoped, like the engine's
         # seal-verdict cache): engine wakeups that re-verify the same
@@ -1296,7 +1253,6 @@ class DeviceBatchVerifier:
         pay only a cache load.
         """
         table = jnp.zeros((table_rows, 5), jnp.uint32)
-        powers = jnp.zeros((table_rows,), jnp.int32)
         for bb in lanes:
             r, s, v, claimed, live = _dead_rows(bb)
             hashes = np.zeros((bb, 8), dtype=np.uint32)
@@ -1320,19 +1276,6 @@ class DeviceBatchVerifier:
                     _recover_kernel(
                         zw, r, s, v, claimed, table, live
                     ).block_until_ready()
-                with cost_ledger.dispatch_span(
-                    "quorum_certify",
-                    route="warmup",
-                    padded=bb,
-                    kernels=(("quorum_certify", _certify_kernel),),
-                    site="verify/batch.py:warmup",
-                ):
-                    jax.block_until_ready(
-                        _certify_kernel(
-                            zw, r, s, v, claimed, table, live, powers, powers,
-                            np.int32(1), np.int32(0),
-                        )
-                    )
             digests = [(bb, ())]
             half = bb // 2
             if self._joint_lanes(half) == half:
@@ -1367,8 +1310,7 @@ class DeviceBatchVerifier:
         self, members: FrozenSet[bytes]
     ) -> Tuple[np.ndarray, List[bytes]]:
         """Packed address table of the set ``members`` + the address list
-        its rows follow (one build + one cache for both the mask and
-        fused-quorum paths)."""
+        its rows follow."""
         hit = self._tables.get(members)
         if hit is not None:
             self._tables.move_to_end(members)
@@ -1397,55 +1339,9 @@ class DeviceBatchVerifier:
         """Device-resident table of the set ``height`` selects."""
         return self._table_dev_of(self._set_of(height))
 
-    def _quorum_pack(
-        self, height: int
-    ) -> Tuple[frozenset, Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]]]:
-        """Fused-quorum arrays of the powers ``height`` selects, under their
-        key (the ``(address, power)`` pairs): (table, powers_lo, powers_hi,
-        quorum), or None when the device quorum path cannot represent the
-        set exactly (power >= 2**31, total >= 2**31, or set larger than the
-        biggest table bucket) — callers then fall back to host big-int
-        quorum (the exactness contract of ops/quorum.py)."""
-        powers_map = self._validators(height)
-        key = frozenset(powers_map.items())
-        if key in self._quorum_packs:
-            self._quorum_packs.move_to_end(key)
-            return key, self._quorum_packs[key]
-        pack: Optional[Tuple[np.ndarray, np.ndarray, np.ndarray, int]] = None
-        try:
-            table, addrs = self._table_and_addrs(self._set_of(height))
-        except ValueError:  # empty validator set
-            addrs = []
-        # Quorum must match the host ValidatorManager exactly: the total is
-        # over the FULL voting-power map (a malformed address can never
-        # match a sender, but its power still raises the threshold).
-        total = sum(powers_map.values())
-        if (
-            addrs
-            and 0 < total < (1 << 31)
-            and all(0 <= p < (1 << 31) for p in powers_map.values())
-        ):
-            v = table.shape[0]
-            plo = np.zeros(v, dtype=np.int32)
-            phi = np.zeros(v, dtype=np.int32)
-            for i, a in enumerate(addrs):
-                plo[i], phi[i] = quorum.split_power(powers_map[a])
-            pack = (table, plo, phi, calculate_quorum(total))
-        self._quorum_packs[key] = pack
-        if len(self._quorum_packs) > self._cache_sets:
-            evicted, _ = self._quorum_packs.popitem(last=False)
-            self._quorum_dev.pop(evicted, None)
-        return key, pack
-
-    def supports_fused(self, height: int) -> bool:
-        """True when the fused mask+quorum device path is exact for this
-        height's validator set."""
-        return self._quorum_pack(height)[1] is not None
-
     # -- shared pack/dispatch scaffolding -------------------------------
     # One implementation of the idxs-filter -> pack -> kernel -> unpack ->
-    # metrics pipeline serves all four public entry points, so the fused
-    # and non-fused masks can never drift apart.
+    # metrics pipeline serves all the public entry points.
 
     @staticmethod
     def _well_formed_sender(m: IbftMessage, height: Optional[int]) -> bool:
@@ -1471,32 +1367,27 @@ class DeviceBatchVerifier:
         device count so every shard gets an identical local shape."""
         return 0
 
-    def _program_of(self, quorum_args) -> str:
-        """Cost-ledger program identity for one dispatch (the
-        compile-budget family names — the mesh subclass renames the
-        mask-only program to its sharded twin)."""
-        return "ecdsa_recover" if quorum_args is None else "quorum_certify"
+    # Cost-ledger program identity of a dispatch (the compile-budget family
+    # names; the mesh subclass renames it to the sharded twin).
+    _program = "ecdsa_recover"
 
-    def _dispatch_async(self, inputs, table, quorum_args):
-        """Queue the recover (mask-only) or certify (mask+quorum) kernel.
+    def _dispatch_async(self, inputs, table):
+        """Queue the recover kernel.
 
         ``inputs`` = (zw, r, s, v, claimed, live): the packers' numpy rows
         (``zw`` the digest program's device rows in a sender drain), handed
-        to the compiled call as they are, like ``table`` and the power
-        vectors (device-resident, a validator set's); ``operands`` on the span
-        counts the host arrays among ``inputs``.  Nothing may write to one
-        of them from here on.
-        ``quorum_args`` = None for the plain mask, or (plo, phi, thr).
-        Returns ``(mask_dev, reached_dev_or_None)`` device futures WITHOUT
-        blocking — JAX async dispatch lets the caller pack the next batch
-        while this one executes (:mod:`go_ibft_tpu.verify.pipeline`).
+        to the compiled call as they are, like ``table`` (device-resident,
+        a validator set's); ``operands`` on the span counts the host arrays
+        among ``inputs``.  Nothing may write to one of them from here on.
+        Returns the mask as a device future WITHOUT blocking — JAX async
+        dispatch lets the caller pack the next batch while this one
+        executes (:mod:`go_ibft_tpu.verify.pipeline`).
         """
-        kernel = _recover_kernel if quorum_args is None else _certify_kernel
         with cost_ledger.dispatch_span(
-            self._program_of(quorum_args),
+            self._program,
             route=self._route,
             live_mask=inputs[5],
-            kernels=((self._program_of(quorum_args), kernel),),
+            kernels=((self._program, _recover_kernel),),
             block=False,
             site="verify/batch.py:_dispatch_async",
         ):
@@ -1507,51 +1398,26 @@ class DeviceBatchVerifier:
                 operands=_host_arrays(inputs),
                 ladder=ladder_of(live.shape[0]),
             ):
-                if quorum_args is None:
-                    return _recover_kernel(zw, r, s, v, claimed, table, live), None
-                plo, phi, thr = quorum_args
-                mask, reached_dev, _, _ = _certify_kernel(
-                    zw,
-                    r,
-                    s,
-                    v,
-                    claimed,
-                    table,
-                    live,
-                    plo,
-                    phi,
-                    np.int32(max(thr, 0) & 0xFFFF),
-                    np.int32(max(thr, 0) >> 16),
-                )
-                return mask, reached_dev
+                return _recover_kernel(zw, r, s, v, claimed, table, live)
 
     @staticmethod
-    def _readback(handle) -> Tuple[np.ndarray, Optional[bool]]:
-        """Block on one :meth:`_dispatch_async` handle -> host results."""
-        mask_dev, reached_dev = handle
+    def _readback(mask_dev) -> np.ndarray:
+        """Block on one :meth:`_dispatch_async` handle -> the host mask."""
         with trace.span("verify.device_wait", route="device"):
-            mask = np.asarray(mask_dev)
-            reached = (
-                None if reached_dev is None else bool(np.asarray(reached_dev))
-            )
-        return mask, reached
+            return np.asarray(mask_dev)
 
-    def _dispatch(self, inputs, table, quorum_args, metric: str):
+    def _dispatch(self, inputs, table, metric: str) -> np.ndarray:
         """Synchronous pack->kernel->readback (single-batch callers)."""
         t0 = time.perf_counter()
-        mask, reached = self._readback(
-            self._dispatch_async(inputs, table, quorum_args)
-        )
+        mask = self._readback(self._dispatch_async(inputs, table))
         dt_ms = (time.perf_counter() - t0) * 1e3
         metrics.observe(("go-ibft", "device", metric), dt_ms)
         metrics.observe_fixed(VERIFY_DRAIN_MS_KEY + ("device",), dt_ms)
         # The dispatch record itself landed in _dispatch_async (block=False
         # — queue time only); the synchronous path knows the full
         # block-until-ready wall, so attribute it here.
-        cost_ledger.add_wall_ms(
-            self._program_of(quorum_args), self._route, dt_ms
-        )
-        return mask, reached
+        cost_ledger.add_wall_ms(self._program, self._route, dt_ms)
+        return mask
 
     # Largest payload the device digest path can absorb; one byte is
     # reserved for keccak padding in the last block.
@@ -1591,189 +1457,6 @@ class DeviceBatchVerifier:
         with trace.span("verify.pack", kind="seals", lanes=len(seals)):
             return pack_seal_batch(proposal_hash, seals, pad_lanes=pad_lanes)
 
-    # -- fused mask + quorum (the engine's phase hot path) --------------
-
-    def _fused_pack(self, height: int, threshold: Optional[int]):
-        key, pack = self._quorum_pack(height)
-        if pack is None:
-            raise ValueError(f"fused quorum unsupported for height {height}")
-        _table, plo, phi, quorum_size = pack
-        thr = quorum_size if threshold is None else threshold
-        # Device-resident handles: the table and power vectors upload once
-        # a set, and every dispatch judged against it is handed them.
-        powers_dev = self._quorum_dev.get(key)
-        if powers_dev is None:
-            powers_dev = self._quorum_dev[key] = (jnp.asarray(plo), jnp.asarray(phi))
-        return self._table_dev(height), (*powers_dev, thr), thr
-
-    def certify_senders(
-        self, msgs: Sequence[IbftMessage], height: int, threshold: Optional[int] = None
-    ) -> Tuple[np.ndarray, bool]:
-        """One device program: envelope recovery + membership + voting-power
-        quorum (ops/quorum.py ``quorum_certify`` semantics).  All messages
-        must share ``height``.  ``threshold`` overrides the quorum size
-        (the engine passes ``quorum - proposer_power`` for the prepare
-        phase's proposer credit); ``None`` means the height's quorum.
-
-        Returns ``(mask, reached)``; requires :meth:`supports_fused`.
-        """
-        table, qargs, thr = self._fused_pack(height, threshold)
-        out = np.zeros(len(msgs), dtype=bool)
-        idxs = [
-            i
-            for i, m in enumerate(msgs)
-            if self._well_formed_sender(m, height)
-        ]
-        if not idxs:
-            return out, thr <= 0
-        with trace.span(
-            "verify.drain", route="device", kind="certify_senders", lanes=len(idxs)
-        ):
-            mask, reached = self._dispatch(
-                self._sender_inputs([msgs[i] for i in idxs]),
-                table,
-                qargs,
-                "certify_senders_ms",
-            )
-            with trace.span("verify.quorum", route="device-fused"):
-                out[np.asarray(idxs)] = mask[: len(idxs)]
-        return out, reached
-
-    def certify_seals(
-        self,
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool]:
-        """Fused COMMIT-phase check: seal recovery + membership + quorum in
-        one device program (ops/quorum.py ``seal_quorum_certify``
-        semantics).  Returns ``(mask, reached)``."""
-        table, qargs, thr = self._fused_pack(height, threshold)
-        out = np.zeros(len(seals), dtype=bool)
-        idxs = [i for i, s in enumerate(seals) if self._well_formed_seal(s)]
-        if not idxs or len(proposal_hash) != 32:
-            return out, thr <= 0
-        with trace.span(
-            "verify.drain", route="device", kind="certify_seals", lanes=len(idxs)
-        ):
-            mask, reached = self._dispatch(
-                self._seal_inputs(proposal_hash, [seals[i] for i in idxs]),
-                table,
-                qargs,
-                "certify_seals_ms",
-            )
-            with trace.span("verify.quorum", route="device-fused"):
-                out[np.asarray(idxs)] = mask[: len(idxs)]
-        return out, reached
-
-    def certify_round(
-        self,
-        msgs: Sequence[IbftMessage],
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        prepare_threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool, np.ndarray, bool]:
-        """Certify BOTH phases of a round in ONE device dispatch.
-
-        PREPARE envelopes and COMMIT seals share the recovery ladder, so
-        their lanes are concatenated (padded to one common bucket) and run
-        as a single program with two quorum reductions — the whole-round
-        certification shape (validating a prepared certificate plus its
-        committed seals at once; reference core/ibft.go:1161-1231).
-
-        Returns ``(sender_mask, prepare_reached, seal_mask, commit_reached)``.
-        Requires :meth:`supports_fused`.
-        """
-        table, (plo, phi, seal_thr), _ = self._fused_pack(height, None)
-        p_thr = seal_thr if prepare_threshold is None else prepare_threshold
-        sender_mask = np.zeros(len(msgs), dtype=bool)
-        seal_mask = np.zeros(len(seals), dtype=bool)
-        midx = [
-            i for i, m in enumerate(msgs) if self._well_formed_sender(m, height)
-        ]
-        sidx = [i for i, s in enumerate(seals) if self._well_formed_seal(s)]
-        if not midx or not sidx or len(proposal_hash) != 32:
-            # Degenerate rounds fall back to the per-phase paths (an empty
-            # half would break the kernel's split-at-half contract).
-            if midx:
-                sm, pr = self.certify_senders(
-                    msgs, height, threshold=prepare_threshold
-                )
-                sender_mask, p_ok = sm, pr
-            else:
-                p_ok = p_thr <= 0
-            if sidx:
-                cm, cr = self.certify_seals(proposal_hash, seals, height)
-                seal_mask, s_ok = cm, cr
-            else:
-                s_ok = seal_thr <= 0
-            return sender_mask, p_ok, seal_mask, s_ok
-
-        # Pack both halves to ONE common lane bucket so the kernel can
-        # split at half statically.
-        lanes = max(
-            _bucket(len(midx), _BATCH_BUCKETS), _bucket(len(sidx), _BATCH_BUCKETS)
-        )
-        t0 = time.perf_counter()
-        with trace.span(
-            "verify.drain", route="device", kind="certify_round", lanes=lanes
-        ):
-            # The joint layout of :meth:`_joint_inputs`: each half packed
-            # into its slice of the dispatch's rows, the seals' hash rows
-            # joined behind the digest rows by the digest launch.
-            rows = _dead_rows(2 * lanes)
-            with trace.span("verify.pack", kind="seals", lanes=len(sidx)):
-                hz = _seal_lane_rows(
-                    [proposal_hash] * len(sidx),
-                    [seals[i].signature for i in sidx],
-                    [seals[i].signer for i in sidx],
-                    lanes,
-                    tuple(a[lanes:] for a in rows),
-                )[0]
-            zw = self._sender_inputs(
-                [msgs[i] for i in midx], lanes, tuple(a[:lanes] for a in rows), hz
-            )[0]
-            inputs = (zw,) + rows
-            with cost_ledger.dispatch_span(
-                "round_certify",
-                route=self._route,
-                live=len(midx) + len(sidx),
-                padded=2 * lanes,
-                kernels=(("round_certify", _round_kernel),),
-                site="verify/batch.py:certify_round",
-            ):
-                with trace.span(
-                    "verify.dispatch",
-                    route="device",
-                    operands=_host_arrays(inputs),
-                    ladder=ladder_of(2 * lanes),
-                ):
-                    mask, p_reached, s_reached = _round_kernel(
-                        *inputs[:5],
-                        table,
-                        inputs[5],
-                        plo,
-                        phi,
-                        np.int32(max(p_thr, 0) & 0xFFFF),
-                        np.int32(max(p_thr, 0) >> 16),
-                        np.int32(max(seal_thr, 0) & 0xFFFF),
-                        np.int32(max(seal_thr, 0) >> 16),
-                    )
-                with trace.span("verify.device_wait", route="device"):
-                    mask = np.asarray(mask)
-            with trace.span("verify.quorum", route="device-fused"):
-                sender_mask[np.asarray(midx)] = mask[: len(midx)]
-                seal_mask[np.asarray(sidx)] = mask[lanes : lanes + len(sidx)]
-                p_ok = bool(np.asarray(p_reached))
-                s_ok = bool(np.asarray(s_reached))
-        metrics.observe(
-            ("go-ibft", "device", "certify_round_ms"),
-            (time.perf_counter() - t0) * 1e3,
-        )
-        return sender_mask, p_ok, seal_mask, s_ok
-
     # -- BatchVerifier protocol ----------------------------------------
 
     def _run_chunk_pipeline(self, items, pack, metric: str):
@@ -1791,12 +1474,12 @@ class DeviceBatchVerifier:
         # _dispatch_async; the wait is the only timing the async path
         # cannot observe itself).
         report = VerifyPipeline(
-            depth=2, ledger_key=(self._program_of(None), self._route)
+            depth=2, ledger_key=(self._program, self._route)
         ).run(
             items,
             pack,
-            dispatch=lambda p: (p[0], self._dispatch_async(p[1], p[2], None)),
-            readback=lambda h: (h[0], self._readback(h[1])[0]),
+            dispatch=lambda p: (p[0], self._dispatch_async(p[1], p[2])),
+            readback=lambda h: (h[0], self._readback(h[1])),
         )
         metrics.observe(
             ("go-ibft", "device", metric), (time.perf_counter() - t0) * 1e3
@@ -1866,9 +1549,9 @@ class DeviceBatchVerifier:
 
     def _joint_inputs(self, sub: List[IbftMessage], riders: List[int], lanes: int):
         """Envelope rows in the first ``lanes`` lanes, the riders' seal rows
-        in the second: the :meth:`certify_round` layout, for the mask-only
-        program.  The rows are allocated once, at the dispatch's lane count,
-        and each half is packed into its slice: the seals first, because
+        in the second, in the riders' order: the joint layout.  The rows are
+        allocated once, at the dispatch's lane count, and each half is packed
+        into its slice: the seals first, because
         their hash rows go through the envelopes' digest launch, which joins
         them behind its own rows (one launch, and no eager JAX operation
         between the packers and the recover launch)."""
@@ -2051,10 +1734,9 @@ class DeviceBatchVerifier:
                 [claimed, np.zeros((pad,) + claimed.shape[1:], claimed.dtype)]
             )
             live = np.concatenate([live, np.zeros((pad,), dtype=bool)])
-        mask, _ = self._dispatch(
+        mask = self._dispatch(
             (zw, r, s, v, claimed, live),
             self._table_dev(height),
-            None,
             "verify_sender_rows_ms",
         )
         return np.asarray(mask[:n], dtype=bool)
@@ -2262,12 +1944,11 @@ class DeviceBatchVerifier:
         ):
             while pos < len(order) and not tally.reached:
                 take = order[pos : pos + chunk]
-                cmask, _ = self._dispatch(
+                cmask = self._dispatch(
                     self._seal_inputs(
                         proposal_hash, [seals[i] for i in take]
                     ),
                     self._table_dev(height),
-                    None,
                     "early_exit_ms",
                 )
                 for j, i in enumerate(take):
@@ -2285,66 +1966,6 @@ class DeviceBatchVerifier:
         if skipped:
             metrics.inc_counter(EARLY_EXIT_SKIPPED_KEY, skipped)
         return EarlyExitReport(mask, verified, tally.reached, skipped)
-
-    def verify_round_chunked(
-        self,
-        msgs: Sequence[IbftMessage],
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """BOTH phases' drains through ONE pipeline (oversize rounds).
-
-        PREPARE-envelope chunks and COMMIT-seal chunks share the in-flight
-        window, so the seal packing overlaps the tail envelope dispatches —
-        the multi-phase drain shape ``AdaptiveBatchVerifier.certify_round``
-        routes floods above the fused-dispatch bucket through.  Masks only;
-        the quorum reduction stays with the caller (exact host ints).
-        Envelopes are height-gated like the certify paths.
-        """
-        sender_mask = np.zeros(len(msgs), dtype=bool)
-        seal_mask = np.zeros(len(seals), dtype=bool)
-        cap = self._dispatch_cap
-        midx = [
-            i for i, m in enumerate(msgs) if self._well_formed_sender(m, height)
-        ]
-        sidx = (
-            [i for i, s in enumerate(seals) if self._well_formed_seal(s)]
-            if len(proposal_hash) == 32
-            else []
-        )
-        items = [
-            ("sender", midx[start : start + cap])
-            for start in range(0, len(midx), cap)
-        ] + [
-            ("seal", sidx[start : start + cap])
-            for start in range(0, len(sidx), cap)
-        ]
-        if not items:
-            return sender_mask, seal_mask
-
-        def pack(item):
-            kind, chunk = item
-            if kind == "sender":
-                inputs = self._sender_inputs([msgs[i] for i in chunk])
-            else:
-                inputs = self._seal_inputs(
-                    proposal_hash, [seals[i] for i in chunk]
-                )
-            return item, inputs, self._table_dev(height)
-
-        with trace.span(
-            "verify.drain",
-            route=self._route,
-            kind="round_chunked",
-            chunks=len(items),
-        ):
-            results = self._run_chunk_pipeline(items, pack, "round_drain_ms")
-            with trace.span("verify.quorum", route="mask"):
-                for (kind, chunk), mask in results:
-                    target = sender_mask if kind == "sender" else seal_mask
-                    target[np.asarray(chunk)] = mask[: len(chunk)]
-        return sender_mask, seal_mask
 
 
 QUARANTINED_LANES_KEY = ("go-ibft", "resilient", "quarantined_lanes")
@@ -2688,14 +2309,10 @@ class AdaptiveBatchVerifier:
     a 4-validator cluster should never pay it — while a 100-validator
     quorum drain absolutely should.  Batches with fewer than
     ``cutover_lanes`` items run the sequential host path (native C++
-    ecrecover); everything else dispatches the fused device kernels.  Both
+    ecrecover); everything else dispatches the device kernels.  Both
     paths produce identical accept-sets (the differential suites pin this),
-    so the route is invisible to the engine.
-
-    Implements BOTH engine protocols (BatchVerifier + FusedBatchVerifier);
-    the host fallback computes the voting-power quorum with exact Python
-    ints, mirroring ops/quorum.py ``power_reduce`` semantics (distinct
-    validators counted once).
+    so the route is invisible to the engine, which decides the quorum from
+    the mask in exact Python ints.
 
     An optional ``mesh`` route (a
     :class:`~go_ibft_tpu.verify.mesh_batch.MeshBatchVerifier`) adds a
@@ -2708,10 +2325,8 @@ class AdaptiveBatchVerifier:
     poison batch (device raising mid-dispatch, a lane whose packing blows
     up) is bisected/quarantined instead of crashing the drain, and the
     shared circuit breaker demotes to the host rungs after repeated device
-    faults (restoring after cooldown).  The fused certify paths fall back
-    to the exact host-int route on any device exception — counted under
-    ``("go-ibft", "resilient", "certify_fallback")`` — so a consensus
-    phase never loses its verdict to a device fault.
+    faults (restoring after cooldown), so a consensus phase never loses
+    its verdict to a device fault.
     """
 
     def __init__(
@@ -2746,7 +2361,6 @@ class AdaptiveBatchVerifier:
         # auto-constructed — sharding is an explicit deployment decision
         # (embedders/bench opt in), and a surprise shard_map compile must
         # never land in a default engine.
-        self._mesh = mesh
         self._resilient = ResilientBatchVerifier(
             self.device,
             host=self.host,
@@ -2756,9 +2370,6 @@ class AdaptiveBatchVerifier:
             breaker=breaker,
         )
         self.mesh_cutover = self._resilient.mesh_cutover
-        # The single-device rung's breaker level: 0 without a mesh, 1 with
-        # one (the mesh occupies level 0).
-        self._device_level = 0 if mesh is None else 1
         self.breaker = self._resilient.breaker
 
     def warmup(self, **kw) -> None:
@@ -2779,14 +2390,6 @@ class AdaptiveBatchVerifier:
         return EngineScope(self, owner)
 
     # -- host-side quorum (exact big ints) ------------------------------
-
-    def _host_reached(
-        self, valid_addrs: Iterable[bytes], height: int, threshold: Optional[int]
-    ) -> bool:
-        with trace.span("verify.quorum", route="host-int"):
-            return host_quorum_reached(
-                self._validators, valid_addrs, height, threshold
-            )
 
     # -- BatchVerifier ---------------------------------------------------
 
@@ -2891,274 +2494,6 @@ class AdaptiveBatchVerifier:
             proposal_hash, seals, height, threshold=threshold
         )
 
-    # -- FusedBatchVerifier ---------------------------------------------
-
-    def supports_fused(self, height: int) -> bool:
-        """Always true: batches the device range cannot represent exactly
-        (powers >= 2**31) are routed to the host big-int path instead."""
-        return True
-
-    def _route_device(self, n: int, height: int) -> bool:
-        # Single fused dispatch (mask + quorum in one program) fits one
-        # lane bucket; larger floods use chunked device crypto with the
-        # quorum reduced on host ints (_chunked route below).
-        return (
-            self.cutover <= n <= _BATCH_BUCKETS[-1]
-            and self.device.supports_fused(height)
-        )
-
-    def _breaker_gate(self) -> Tuple[bool, Optional[int]]:
-        """Consult the breaker before a fused single-device dispatch.
-
-        Returns ``(use_device, acquired_level)``: when the ladder is
-        demoted below the device rung the fused dispatch is suppressed and
-        the caller's fallback serves the call.  An acquisition that does
-        not end up running the device MUST be released with
-        ``breaker.abort_probe(acquired_level)`` once the call completes —
-        never answered with success for a rung that did not run (the
-        ladder would restore on no evidence), and a pending probe must
-        not leak (``_probing`` would wedge and no probe would ever be
-        offered again).  With a mesh rung present the device sits at
-        level 1; an active-or-probed mesh level is NOT consumable by a
-        single-device dispatch — a mesh probe stays pending through the
-        ladder fallback (same deferred-release discipline as a demoted
-        level), while a healthy mesh level simply lets the device run
-        without recording evidence against the mesh rung."""
-        level, probe = self.breaker.acquire()
-        if level == self._device_level:
-            # Plain dispatch at the device rung, or the device rung's own
-            # cooldown probe — either way success/fault at
-            # ``self._device_level`` is the correct answer.
-            return True, None
-        if level < self._device_level:
-            if probe:
-                return False, level
-            return True, None
-        return False, level
-
-    def _device_faulted(self) -> None:
-        metrics.inc_counter(("go-ibft", "resilient", "certify_fallback"))
-        self.breaker.record_fault(self._device_level)
-
-    def _mesh_gate(self, n: int) -> bool:
-        """Route a certify call to the sharded mesh rung?  True only when
-        a mesh exists, the drain clears the lane cutover, and the breaker
-        has not demoted the mesh."""
-        return self._mesh is not None and n >= self.mesh_cutover
-
-    def _try_mesh(self, n: int, call):
-        """One fused dispatch on the mesh rung, breaker-accounted.
-
-        Returns the call's result, or ``None`` when the mesh route was
-        unavailable (breaker demoted), faulted (recorded; the caller's
-        single-device/ladder fallback serves the drain), or the input was
-        poison (probe released; the ladder fallback quarantines)."""
-        if not self._mesh_gate(n):
-            return None
-        level, probe = self.breaker.acquire()
-        if level != 0:
-            if probe:
-                # A probe for a SLOWER rung (device/host) cannot be
-                # answered by a mesh dispatch that will not run: release
-                # it immediately — the cooldown has elapsed, so the very
-                # next gate (the single-device route below, or the
-                # resilient fallback) re-acquires and runs it with real
-                # evidence.
-                self.breaker.abort_probe(level)
-            return None
-        try:
-            result = call(self._mesh)
-        except MalformedLaneError:
-            # Input poison, not a mesh fault: release a pending probe and
-            # let the ladder-aware fallback quarantine the lane.
-            self.breaker.abort_probe(0)
-            return None
-        except Exception:  # noqa: BLE001 - demote mesh -> device
-            metrics.inc_counter(("go-ibft", "resilient", "certify_fallback"))
-            self.breaker.record_fault(0)
-            return None
-        self.breaker.record_success(0)
-        return result
-
-    def _chunked_device(self, n: int, height: int) -> bool:
-        # No supports_fused gate: the chunked route never touches the
-        # device quorum pack (mask from verify_*, quorum from host ints),
-        # so it is exact for ANY voting-power range.
-        return n > _BATCH_BUCKETS[-1]
-
-    def certify_senders(
-        self, msgs: Sequence[IbftMessage], height: int, threshold: Optional[int] = None
-    ) -> Tuple[np.ndarray, bool]:
-        # Sharded route first: big drains go to the mesh rung (its quorum
-        # reduce runs on exact host ints, so it is exact for any power
-        # range); a mesh fault falls through to the single-device routes.
-        result = self._try_mesh(
-            len(msgs), lambda m: m.certify_senders(msgs, height, threshold)
-        )
-        if result is not None:
-            return result
-        fallback_level = None
-        device_route = self._route_device(len(msgs), height)
-        if device_route:
-            use_device, fallback_level = self._breaker_gate()
-            if use_device:
-                try:
-                    result = self.device.certify_senders(msgs, height, threshold)
-                    self.breaker.record_success(self._device_level)
-                    return result
-                except MalformedLaneError:
-                    # Input poison, not a device fault: the rung is
-                    # healthy (same rule as the resilient drain), so no
-                    # breaker fault — a pending probe is released, not
-                    # failed, and the ladder-aware fallback below
-                    # quarantines the lane.
-                    self.breaker.abort_probe(self._device_level)
-                except Exception:
-                    # Device fault mid-phase: the fallback below still
-                    # produces the verdict (no exception escapes a
-                    # certify call).
-                    self._device_faulted()
-        if device_route or self._chunked_device(len(msgs), height):
-            # Ladder-aware fallback: quarantines poison lanes, respects
-            # the breaker's demotion, never raises, and carries its own
-            # breaker accounting (oversize floods keep crypto on device
-            # in full-bucket chunks; only the quorum reduction moves to
-            # exact host ints).
-            mask = self._resilient.verify_senders(msgs)
-        else:
-            mask = self.host.verify_senders(msgs)
-        # Same height gate as the device path (certify is per-view).
-        for i, m in enumerate(msgs):
-            if m.view is None or m.view.height != height:
-                mask[i] = False
-        valid = [m.sender for m, ok in zip(msgs, mask) if ok]
-        if fallback_level is not None:
-            # The gate's acquisition did not run the device: release it
-            # (a pending probe must neither leak nor count as evidence).
-            self.breaker.abort_probe(fallback_level)
-        return mask, self._host_reached(valid, height, threshold)
-
-    def certify_seals(
-        self,
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool]:
-        result = self._try_mesh(
-            len(seals),
-            lambda m: m.certify_seals(proposal_hash, seals, height, threshold),
-        )
-        if result is not None:
-            return result
-        fallback_level = None
-        device_route = self._route_device(len(seals), height)
-        if device_route:
-            use_device, fallback_level = self._breaker_gate()
-            if use_device:
-                try:
-                    result = self.device.certify_seals(
-                        proposal_hash, seals, height, threshold
-                    )
-                    self.breaker.record_success(self._device_level)
-                    return result
-                except MalformedLaneError:
-                    self.breaker.abort_probe(self._device_level)
-                except Exception:
-                    self._device_faulted()
-        if device_route or self._chunked_device(len(seals), height):
-            mask = self._resilient.verify_committed_seals(
-                proposal_hash, seals, height
-            )
-        else:
-            mask = self.host.verify_committed_seals(proposal_hash, seals, height)
-        valid = [s.signer for s, ok in zip(seals, mask) if ok]
-        if fallback_level is not None:
-            self.breaker.abort_probe(fallback_level)
-        return mask, self._host_reached(valid, height, threshold)
-
-    def certify_round(
-        self,
-        msgs: Sequence[IbftMessage],
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        prepare_threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool, np.ndarray, bool]:
-        if msgs and seals and len(proposal_hash) == 32:
-            result = self._try_mesh(
-                max(len(msgs), len(seals)),
-                lambda m: m.certify_round(
-                    msgs, proposal_hash, seals, height, prepare_threshold
-                ),
-            )
-            if result is not None:
-                return result
-        fallback_level = None
-        if (
-            self._route_device(max(len(msgs), len(seals)), height)
-            and msgs
-            and seals
-        ):
-            use_device, fallback_level = self._breaker_gate()
-            if use_device:
-                try:
-                    result = self.device.certify_round(
-                        msgs, proposal_hash, seals, height, prepare_threshold
-                    )
-                    self.breaker.record_success(self._device_level)
-                    return result
-                except MalformedLaneError:
-                    self.breaker.abort_probe(self._device_level)
-                except Exception:
-                    # Fall through to the per-phase routes, which carry
-                    # their own breaker accounting and ladder fallbacks.
-                    self._device_faulted()
-        if (
-            msgs
-            and seals
-            and len(proposal_hash) == 32
-            and self._chunked_device(max(len(msgs), len(seals)), height)
-            and min(len(msgs), len(seals)) >= self.cutover
-            # injected device stubs (tests, embedders) may predate the
-            # cross-phase drain; fall back to the per-phase routes then
-            and hasattr(self.device, "verify_round_chunked")
-        ):
-            # Oversize round: BOTH phases drain through one device pipeline
-            # (seal packing overlaps the tail envelope dispatches); quorum
-            # reduces on exact host ints like every chunked route.
-            try:
-                sender_mask, seal_mask = self.device.verify_round_chunked(
-                    msgs, proposal_hash, seals, height
-                )
-            except Exception:
-                # Cross-phase pipeline faulted: the per-phase resilient
-                # drains below still produce both verdicts.
-                self._device_faulted()
-            else:
-                p_ok = self._host_reached(
-                    [m.sender for m, ok in zip(msgs, sender_mask) if ok],
-                    height,
-                    prepare_threshold,
-                )
-                s_ok = self._host_reached(
-                    [s.signer for s, ok in zip(seals, seal_mask) if ok],
-                    height,
-                    None,
-                )
-                if fallback_level is not None:
-                    self.breaker.abort_probe(fallback_level)
-                return sender_mask, p_ok, seal_mask, s_ok
-        sender_mask, p_ok = self.certify_senders(
-            msgs, height, threshold=prepare_threshold
-        )
-        seal_mask, s_ok = self.certify_seals(proposal_hash, seals, height)
-        if fallback_level is not None:
-            # Released AFTER the per-phase routes: their own gates see the
-            # probe as still pending and cannot double-acquire it.
-            self.breaker.abort_probe(fallback_level)
-        return sender_mask, p_ok, seal_mask, s_ok
-
 
 class EngineScope:
     """Per-engine lifecycle facade over a SHARED verifier ladder.
@@ -3177,7 +2512,7 @@ class EngineScope:
     (:meth:`PackCache.owned`) and whose ``note_round`` /
     ``reset_pack_cache`` rotate/drop ONLY the owner's entries; every
     other attribute (``quarantine`` — already per-message — ``warmup``,
-    the certify surface, breaker state) delegates to the shared parent.
+    breaker state) delegates to the shared parent.
     The :class:`~go_ibft_tpu.sched.TenantScheduler`'s handles are the
     fully-managed version of this (per-tenant queues, fairness and
     backpressure on top); a bare shared ladder with scopes is the

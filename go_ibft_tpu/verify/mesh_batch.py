@@ -1,10 +1,6 @@
 """MeshBatchVerifier: the verify data plane sharded across the device mesh.
 
-Every production drain so far — engine quorum certification, the pipeline's
-double-buffered chunks, chain/sync seal verification — executed on ONE
-device, while ``parallel/mesh.py`` proved an 8-device shard_map
-quorum-certify program and nothing routed traffic through it.  This module
-closes that gap: :class:`MeshBatchVerifier` is a
+:class:`MeshBatchVerifier` is a
 :class:`~go_ibft_tpu.verify.batch.DeviceBatchVerifier` whose dispatches
 place packed lanes across a ``(dp, vp)`` mesh, with
 
@@ -23,11 +19,9 @@ place packed lanes across a ``(dp, vp)`` mesh, with
   chains' lanes) that used to cost dp sequential single-device dispatches
   is ONE sharded launch, still riding the double-buffered
   :class:`~go_ibft_tpu.verify.pipeline.VerifyPipeline`;
-* **host-side quorum reduce** — the certify entry points compute the
-  voting-power quorum from the sharded mask on exact host ints
-  (:func:`~go_ibft_tpu.verify.batch.host_quorum_reached`), keeping the
-  sharded program collective-free AND exact for any power range (no
-  ``supports_fused`` representability gate);
+* **collective-free** — the sharded program returns the mask alone; the
+  voting-power quorum is the caller's, on exact host ints, like every
+  other route's;
 * **transparent 1-device degradation** — when
   :func:`~go_ibft_tpu.parallel.mesh.mesh_context` finds a single device
   (or a dead backend) the instance behaves exactly as its
@@ -47,8 +41,7 @@ nothing.
 
 from __future__ import annotations
 
-import time
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -56,8 +49,6 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..messages.helpers import CommittedSeal
-from ..messages.wire import IbftMessage
 from ..obs import ledger as cost_ledger
 from ..obs import trace
 from ..ops import quorum
@@ -70,21 +61,16 @@ from .batch import (
     EAGER_PUTS_KEY,
     DeviceBatchVerifier,
     ValidatorSource,
-    host_quorum_reached,
 )
 
-__all__ = ["MeshBatchVerifier", "mesh_verify_mask", "REDUCE_MS_KEY"]
-
-# Host-side quorum-reduce cost per sharded certify (the "reduce" leg the
-# bench evidence reports as reduce_ms).
-REDUCE_MS_KEY = ("go-ibft", "mesh", "reduce_ms")
+__all__ = ["MeshBatchVerifier", "mesh_verify_mask"]
 
 
 def _mask_fn(zw, r, s, v, claimed, table, live):
     """Per-shard verification mask: the single-chip recovery ladder +
     membership compare, identical to ``batch._recover_fn`` — kept
     collective-free so the sharded program is embarrassingly parallel
-    (quorum reduction happens on host)."""
+    (the quorum is decided on the host)."""
     ok = quorum.sig_checks_zw(zw, r, s, v, claimed, live)
     member = jnp.any(quorum.membership_eq(claimed, table), axis=-1)
     return ok & member
@@ -131,10 +117,9 @@ class MeshBatchVerifier(DeviceBatchVerifier):
 
     Drop-in wherever a :class:`DeviceBatchVerifier` goes: the
     ``BatchVerifier`` protocol entry points (``verify_senders``,
-    ``verify_committed_seals``, ``verify_seal_lanes``,
-    ``verify_round_chunked``) inherit the parent's chunking/pipeline
-    machinery and only the dispatch seam changes; the fused certify entry
-    points compute their quorum on host ints from the sharded mask.
+    ``verify_committed_seals``, ``verify_seal_lanes``) inherit the
+    parent's chunking/pipeline machinery and only the dispatch seam
+    changes.
 
     ``mesh`` wins when given; otherwise :func:`mesh_context` enumerates
     devices (``dp``/``devices`` forwarded).  With one visible device the
@@ -162,6 +147,9 @@ class MeshBatchVerifier(DeviceBatchVerifier):
             self._mask_kernel = mesh_verify_mask(mesh)
             self._dispatch_cap = _BATCH_BUCKETS[-1] * self.dp
             self._route = "mesh"
+            # The sharded mask program has its own compile-budget family
+            # (the ``mesh_verify_mask_8l_dp*`` pins).
+            self._program = "mesh_verify_mask"
 
     @property
     def sharded(self) -> bool:
@@ -202,30 +190,17 @@ class MeshBatchVerifier(DeviceBatchVerifier):
 
     # -- dispatch -------------------------------------------------------
 
-    def _program_of(self, quorum_args) -> str:
-        """The sharded mask program has its own compile-budget family
-        (``mesh_verify_mask_8l_dp*`` pins); fused dispatches delegate to
-        the parent's single-chip names."""
-        if self.mesh is not None and quorum_args is None:
-            return "mesh_verify_mask"
-        return super()._program_of(quorum_args)
-
-    def _dispatch_async(self, inputs, table, quorum_args):
-        """Queue one sharded mask dispatch (mask-only route).
-
-        The fused single-device kernels (``quorum_args`` set) never run
-        here — the certify overrides below reduce quorum on host — but the
-        seam stays delegating for safety if a caller reaches it.
-        """
-        if self.mesh is None or quorum_args is not None:
-            return super()._dispatch_async(inputs, table, quorum_args)
+    def _dispatch_async(self, inputs, table):
+        """Queue one sharded mask dispatch."""
+        if self.mesh is None:
+            return super()._dispatch_async(inputs, table)
         zw, r, s, v, claimed, live = inputs
         lanes = int(np.shape(live)[0])
         with cost_ledger.dispatch_span(
-            "mesh_verify_mask",
+            self._program,
             route=self._route,
             live_mask=live,
-            kernels=(("mesh_verify_mask", self._mask_kernel),),
+            kernels=((self._program, self._mask_kernel),),
             block=False,
             site="verify/mesh_batch.py:_dispatch_async",
         ):
@@ -239,7 +214,7 @@ class MeshBatchVerifier(DeviceBatchVerifier):
                     # Placed one by one ahead of the call: the sharded
                     # route's own staging, not timed on a chip (counted).
                     metrics.inc_counter(EAGER_PUTS_KEY, 6)
-                    mask = self._mask_kernel(
+                    return self._mask_kernel(
                         jnp.asarray(zw),
                         jnp.asarray(r),
                         jnp.asarray(s),
@@ -248,7 +223,6 @@ class MeshBatchVerifier(DeviceBatchVerifier):
                         table,
                         jnp.asarray(live),
                     )
-        return mask, None
 
     def warmup(
         self,
@@ -266,10 +240,10 @@ class MeshBatchVerifier(DeviceBatchVerifier):
         for bb in lanes:
             g = _bucket(bb, _BATCH_BUCKETS) * self.dp
             with cost_ledger.dispatch_span(
-                "mesh_verify_mask",
+                self._program,
                 route="warmup",
                 padded=g,
-                kernels=(("mesh_verify_mask", self._mask_kernel),),
+                kernels=((self._program, self._mask_kernel),),
                 site="verify/mesh_batch.py:warmup",
             ):
                 self._mask_kernel(
@@ -284,88 +258,3 @@ class MeshBatchVerifier(DeviceBatchVerifier):
                     ),
                     jnp.zeros((g,), bool),
                 ).block_until_ready()
-
-    # -- fused certify: sharded mask + host-int quorum reduce ------------
-
-    def supports_fused(self, height: int) -> bool:
-        """Always true on the sharded route: the quorum reduction runs on
-        exact host ints, so there is no device-representability gate."""
-        if self.mesh is None:
-            return super().supports_fused(height)
-        return True
-
-    def _reduce(
-        self, valid_addrs, height: int, threshold: Optional[int]
-    ) -> bool:
-        t0 = time.perf_counter()
-        with trace.span("verify.quorum", route="host-int", shard="reduce"):
-            reached = host_quorum_reached(
-                self._validators, valid_addrs, height, threshold
-            )
-        metrics.observe(REDUCE_MS_KEY, (time.perf_counter() - t0) * 1e3)
-        return reached
-
-    def certify_senders(
-        self,
-        msgs: Sequence[IbftMessage],
-        height: int,
-        threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool]:
-        if self.mesh is None:
-            return super().certify_senders(msgs, height, threshold)
-        out = np.zeros(len(msgs), dtype=bool)
-        idxs = [
-            i for i, m in enumerate(msgs) if self._well_formed_sender(m, height)
-        ]
-        if not idxs:
-            return out, self._reduce((), height, threshold)
-        sub = [msgs[i] for i in idxs]
-        mask = self.verify_senders(sub)
-        out[np.asarray(idxs)] = mask[: len(idxs)]
-        reached = self._reduce(
-            [m.sender for m, ok in zip(sub, mask) if ok], height, threshold
-        )
-        return out, reached
-
-    def certify_seals(
-        self,
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool]:
-        if self.mesh is None:
-            return super().certify_seals(proposal_hash, seals, height, threshold)
-        mask = self.verify_committed_seals(proposal_hash, seals, height)
-        reached = self._reduce(
-            [s.signer for s, ok in zip(seals, mask) if ok], height, threshold
-        )
-        return mask, reached
-
-    def certify_round(
-        self,
-        msgs: Sequence[IbftMessage],
-        proposal_hash: bytes,
-        seals: Sequence[CommittedSeal],
-        height: int,
-        prepare_threshold: Optional[int] = None,
-    ) -> Tuple[np.ndarray, bool, np.ndarray, bool]:
-        if self.mesh is None:
-            return super().certify_round(
-                msgs, proposal_hash, seals, height, prepare_threshold
-            )
-        # Both phases drain through ONE pipeline of sharded dispatches
-        # (seal packing overlaps the tail envelope dispatches); each
-        # phase's quorum reduces on host ints.
-        sender_mask, seal_mask = self.verify_round_chunked(
-            msgs, proposal_hash, seals, height
-        )
-        p_ok = self._reduce(
-            [m.sender for m, ok in zip(msgs, sender_mask) if ok],
-            height,
-            prepare_threshold,
-        )
-        s_ok = self._reduce(
-            [s.signer for s, ok in zip(seals, seal_mask) if ok], height, None
-        )
-        return sender_mask, p_ok, seal_mask, s_ok

@@ -1,7 +1,7 @@
 """Compile-budget regression guard: stablehlo line counts of the hot programs.
 
 Trace size IS compile time on XLA:CPU: the r04->r05
-rounds cut the 8-lane fused certify cold compile 265s -> 55s almost
+rounds cut the 8-lane ladder program's cold compile 265s -> 55s almost
 entirely by shrinking the traced program (mul 811 -> 316 lines,
 shear-reshape conv), and r06 cut it again (~-31%) by deduplicating
 point-op instantiations.  Since PR 25 the conv keeps its limb axes
@@ -77,14 +77,13 @@ def _programs() -> dict:
     ratchet and the warm-start plane can never pin different programs).
     The why of each pin, kept from the original in-line registry:
 
-    * The multi-chip programs (shard_map meshes at dp = 2/4/8) pin two
-      families per dp — ``mesh_quorum_certify`` (the fused dryrun
-      program, 8 GLOBAL lanes, keeping the 27,370-line mark comparable)
-      and ``mesh_verify_mask`` (the MeshBatchVerifier drain program at 8
-      LOCAL lanes per shard, so the per-dp delta isolates the shard_map
-      wrapper).  Both must stay thin shells around the single-chip
-      program — SPMD propagation or a collective regression that
-      re-traces the EC ladder per shard shows up as per-dp growth first.
+    * The multi-chip program (shard_map meshes at dp = 2/4/8) pins one
+      family per dp — ``mesh_verify_mask`` (the MeshBatchVerifier drain
+      program at 8 LOCAL lanes per shard, so the per-dp delta isolates
+      the shard_map wrapper).  It must stay a thin shell around the
+      single-chip program — SPMD propagation or a collective regression
+      that re-traces the EC ladder per shard shows up as per-dp growth
+      first.
     * ``bls_aggregate_verify_8v`` (ISSUE 7): the largest trace in the
       repo (~414k stablehlo lines at 8 lanes on jax 0.4.37), the most
       cold-compile-sensitive — a tower-arithmetic refactor that

@@ -3,7 +3,8 @@
 The reference's reproducible-build CI job builds the binary twice and
 compares hashes (.github/workflows/main.yml:48-67).  The analogue for a
 verification framework is result determinism: two fresh processes running
-the same workload must produce byte-identical masks and quorum sums.
+the same workload must produce byte-identical digests and masks (the
+quorum is host integers over the mask).
 Printed as canonical JSON; CI `cmp`s two runs.
 """
 
@@ -19,74 +20,22 @@ import numpy as np  # noqa: E402
 
 def main() -> None:
     from go_ibft_tpu.bench import build_round_workload
-    from go_ibft_tpu.ops.quorum import quorum_certify, seal_quorum_certify
     from go_ibft_tpu.utils.jaxcache import enable_persistent_cache
+    from go_ibft_tpu.verify.batch import _digest_kernel, _recover_kernel
 
     enable_persistent_cache()
-    import jax.numpy as jnp
 
     w = build_round_workload(8, corrupt_frac=0.25, seed=11)
     blocks, counts, r, s, v, senders, live = w.prepare
-    mask, reached, lo, hi = quorum_certify(
-        jnp.asarray(blocks),
-        jnp.asarray(counts),
-        jnp.asarray(r),
-        jnp.asarray(s),
-        jnp.asarray(v),
-        jnp.asarray(senders),
-        jnp.asarray(w.table),
-        jnp.asarray(live),
-        jnp.asarray(w.powers_lo),
-        jnp.asarray(w.powers_hi),
-        jnp.int32(w.thr_lo),
-        jnp.int32(w.thr_hi),
-    )
+    zw = _digest_kernel(blocks, counts)
+    mask = _recover_kernel(zw, r, s, v, senders, w.table, live)
     hz, sr, ss_, sv, signers, slive = w.seals
-    smask, sreached, slo, shi = seal_quorum_certify(
-        jnp.asarray(hz),
-        jnp.asarray(sr),
-        jnp.asarray(ss_),
-        jnp.asarray(sv),
-        jnp.asarray(signers),
-        jnp.asarray(w.table),
-        jnp.asarray(slive),
-        jnp.asarray(w.powers_lo),
-        jnp.asarray(w.powers_hi),
-        jnp.int32(w.thr_lo),
-        jnp.int32(w.thr_hi),
-    )
-    # The single-dispatch whole-round program must be just as bit-stable.
-    from go_ibft_tpu.ops.quorum import round_certify
-
-    fmask, freached, fsmask, fsreached = round_certify(
-        jnp.asarray(blocks),
-        jnp.asarray(counts),
-        jnp.asarray(r),
-        jnp.asarray(s),
-        jnp.asarray(v),
-        jnp.asarray(senders),
-        jnp.asarray(live),
-        jnp.asarray(hz),
-        jnp.asarray(sr),
-        jnp.asarray(ss_),
-        jnp.asarray(sv),
-        jnp.asarray(signers),
-        jnp.asarray(slive),
-        jnp.asarray(w.table),
-        jnp.asarray(w.powers_lo),
-        jnp.asarray(w.powers_hi),
-        jnp.int32(w.thr_lo),
-        jnp.int32(w.thr_hi),
-    )
+    smask = _recover_kernel(hz, sr, ss_, sv, signers, w.table, slive)
     json.dump(
         {
+            "prepare_digests": np.asarray(zw).tolist(),
             "prepare_mask": np.asarray(mask).tolist(),
-            "prepare": [bool(np.asarray(reached)), int(lo), int(hi)],
             "seal_mask": np.asarray(smask).tolist(),
-            "seal": [bool(np.asarray(sreached)), int(slo), int(shi)],
-            "round_masks": np.asarray(fmask).tolist()
-            + np.asarray(fsmask).tolist(),
-            "round": [bool(np.asarray(freached)), bool(np.asarray(fsreached))],
         },
         sys.stdout,
         sort_keys=True,

@@ -22,7 +22,7 @@ loads, which is exactly the cheap path.
 
 Usage: ``python scripts/warm_kernels.py [--skip-bls] [--skip-mesh]
 [--skip-aot] [--aot-only] [--no-skip] [--programs a,b] [--assert-warm]
-[--manifest out.json] [--sizes 8,100,...]``
+[--manifest out.json]``
 
 * ``--manifest out.json`` — write the machine-readable AOT manifest
   (fingerprint + per-family measured compile cost) that
@@ -48,14 +48,6 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-# Default sizes cover the slow-tier suites (8-lane engine bucket,
-# 100-validator bucket).  The 300/1000 committees
-# only run on a live TPU, where compiles happen on-chip against
-# the TPU cache key — CPU-warming them costs ~an hour each for nothing;
-# opt in with --sizes 8,100,300,1000 when needed.
-_DEFAULT_SIZES = (8, 100)
-
-
 def _argval(flag: str) -> str:
     for i, arg in enumerate(sys.argv):
         if arg == flag and i + 1 < len(sys.argv):
@@ -63,17 +55,10 @@ def _argval(flag: str) -> str:
     return ""
 
 
-def _sizes() -> tuple:
-    val = _argval("--sizes")
-    if val:
-        return tuple(int(s) for s in val.split(","))
-    return _DEFAULT_SIZES
-
-
 def _stamp(label: str, t0: float, program: str = None) -> None:
     """Print the step duration; with ``program`` set, ALSO record it to
     the compile ledger — used ONLY for steps whose kernels this script
-    cannot introspect (the mesh dryrun, the Pallas kernel).  Every other
+    cannot introspect (the Pallas kernel).  Every other
     step's true compiles are recorded by the seam-level cache
     introspection inside the warmup()/entry-point it drives, so stamping
     those here would double-count each cold build (and record cache
@@ -176,7 +161,7 @@ def _finish(cold: int) -> int:
                 )
                 fam["compile_ms"] += acc["ms"]
                 fam["events"] += acc["count"]
-        write_manifest(manifest_path, programs, sizes=_sizes())
+        write_manifest(manifest_path, programs)
         print(
             f"[warm] aot manifest: {manifest_path} "
             f"({len(programs)} families)",
@@ -219,30 +204,18 @@ def main() -> int:
 
     import jax.numpy as jnp
 
-    from go_ibft_tpu.bench import build_round_workload
-    from go_ibft_tpu.bench.workload import prep_args, round_args, seal_args
-    from go_ibft_tpu.ops.quorum import (
-        quorum_certify,
-        round_certify,
-        seal_quorum_certify,
-    )
     from go_ibft_tpu.verify import DeviceBatchVerifier
     from go_ibft_tpu.verify.batch import committee_lanes
 
-    # Mesh FIRST: the dryrun programs are what a cold cache costs most;
+    # Mesh FIRST: the shard_map program is what a cold cache costs most;
     # everything after this line is cheaper to lose to a time limit.
     if "--skip-mesh" not in sys.argv:
-        t0 = time.perf_counter()
-        from __graft_entry__ import dryrun_multichip
-
-        dryrun_multichip(8)
-        _stamp("mesh dryrun programs (8-device (dp, vp))", t0, "mesh_quorum_certify")
-
         # MeshBatchVerifier's sharded mask program at the tier-1 test
         # shapes (dp=2 and dp=8, 8 local lanes, 8-row table): the oracle-
-        # parity suite dispatches exactly these, and a cold shard_map
-        # compile inside a test timeout is the same failure mode as the
-        # dryrun's.
+        # parity suite dispatches exactly these (and the dp=8 one is what
+        # ``__graft_entry__.dryrun_multichip(8)`` runs), and a cold
+        # shard_map compile inside a test timeout is the failure mode this
+        # script exists to prevent.
         import jax
 
         from go_ibft_tpu.parallel import mesh_context
@@ -324,24 +297,6 @@ def main() -> int:
             )
             _prog(_staging).block_until_ready()
         _stamp("ici lock-step tick (100-node lite gather)", t0)
-
-    for n in _sizes():
-        t0 = time.perf_counter()
-        w = build_round_workload(n)
-        # The fused quorum programs are jit objects: the compile watch
-        # records true first compiles (cache loads record nothing).
-        with cost_ledger.compile_watch(
-            (
-                ("quorum_certify", quorum_certify),
-                ("seal_quorum_certify", seal_quorum_certify),
-                ("round_certify", round_certify),
-            ),
-            site="scripts/warm_kernels.py",
-        ):
-            quorum_certify(*prep_args(w))[0].block_until_ready()
-            seal_quorum_certify(*seal_args(w))[0].block_until_ready()
-            round_certify(*round_args(w))[0].block_until_ready()
-        _stamp(f"quorum kernels @{n} validators", t0)
 
     t0 = time.perf_counter()
     from go_ibft_tpu.ops.pallas_keccak import keccak_f_pallas, pallas_supported

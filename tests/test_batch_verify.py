@@ -182,48 +182,32 @@ def test_empty_batches(cluster_keys):
     assert device.verify_committed_seals(b"\x00" * 32, [], height=0).shape == (0,)
 
 
-def test_certify_round_single_dispatch_matches_split(cluster_keys):
-    """certify_round (both phases, one dispatch) must agree with
-    certify_senders + certify_seals and the host oracle, including
-    corrupted lanes and separate prepare/commit thresholds."""
+def test_joint_commit_dispatch_matches_split_drains(cluster_keys):
+    """A COMMIT flood through ``verify_senders`` (both phases, one dispatch:
+    each seal beside its envelope) must leave the verdicts the split seal
+    drain computes and the host oracle's masks, corrupted lanes included."""
     keys, powers, backends = cluster_keys
     view = View(height=9, round=0)
     phash = proposal_hash_of(Proposal(raw_proposal=b"round block", round=0))
-    msgs = [b.build_prepare_message(phash, view) for b in backends]
-    seals = []
-    for b in backends:
-        commit = b.build_commit_message(phash, view)
-        seals.append(
-            CommittedSeal(
-                signer=commit.sender,
-                signature=commit.commit_data.committed_seal,
-            )
-        )
-    # corrupt one lane on each side
-    msgs[1].signature = msgs[1].signature[:5] + bytes(
-        [msgs[1].signature[5] ^ 0xFF]
-    ) + msgs[1].signature[6:]
-    seals[2] = CommittedSeal(
-        signer=seals[2].signer,
-        signature=seals[2].signature[:5]
-        + bytes([seals[2].signature[5] ^ 0xFF])
-        + seals[2].signature[6:],
-    )
+    commits = [b.build_commit_message(phash, view) for b in backends]
+    # corrupt one envelope and another message's seal
+    commits[1].signature = commits[1].signature[:5] + bytes(
+        [commits[1].signature[5] ^ 0xFF]
+    ) + commits[1].signature[6:]
+    seal = commits[2].commit_data.committed_seal
+    commits[2].commit_data.committed_seal = seal[:5] + bytes([seal[5] ^ 0xFF]) + seal[6:]
+    commits[2] = backends[2]._sign_envelope(commits[2])  # a good envelope over it
+    seals = [
+        CommittedSeal(signer=m.sender, signature=m.commit_data.committed_seal)
+        for m in commits
+    ]
 
     host, device = _verifiers(powers)
-    sm, p_ok, cm, c_ok = device.certify_round(
-        msgs, phash, seals, height=9, prepare_threshold=2
-    )
-    sm2, p_ok2 = device.certify_senders(msgs, height=9, threshold=2)
-    cm2, c_ok2 = device.certify_seals(phash, seals, height=9)
-    assert np.array_equal(sm, sm2) and np.array_equal(cm, cm2)
-    assert p_ok == p_ok2 and c_ok == c_ok2
-    assert np.array_equal(sm, host.verify_senders(msgs))
-    assert np.array_equal(cm, host.verify_committed_seals(phash, seals, 9))
-    # 3 valid lanes: prepare threshold 2 reached; commit quorum 3 reached
-    assert p_ok and c_ok
-
-    # degenerate: no seals at all -> falls back to the per-phase path
-    sm3, p3, cm3, c3 = device.certify_round(msgs, phash, [], height=9)
-    assert np.array_equal(sm3, sm2) and p3 == p_ok2
-    assert cm3.size == 0 and c3 is False
+    _, split = _verifiers(powers)
+    sm = device.verify_senders(commits)
+    assert np.array_equal(sm, host.verify_senders(commits))
+    want = host.verify_committed_seals(phash, seals, 9)
+    assert device.cached_seal_verdicts(phash, seals, 9) == want.tolist()
+    assert np.array_equal(device.verify_committed_seals(phash, seals, 9), want)
+    assert np.array_equal(split.verify_committed_seals(phash, seals, 9), want)
+    assert not want[2] and want.sum() == len(seals) - 1

@@ -75,9 +75,7 @@ def test_fingerprint_carries_the_artifact_validity_key():
 def test_manifest_roundtrip_and_staleness(tmp_path):
     path = str(tmp_path / "aot_manifest.json")
     doc = aot.write_manifest(
-        path,
-        {"digest_words": {"compile_ms": 430.5, "events": 1}},
-        sizes=[8, 64],
+        path, {"digest_words": {"compile_ms": 430.5, "events": 1}}
     )
     assert doc["programs"]["digest_words"]["compile_ms"] == 430.5
     loaded = aot.load_manifest(path)

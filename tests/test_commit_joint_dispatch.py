@@ -11,6 +11,7 @@ sequential host oracle lane for lane: the envelope mask, the verdicts, the
 drains that follow, what a fault leaves behind, and what the trace counts.
 """
 
+import copy
 import os
 import sys
 
@@ -423,6 +424,93 @@ def test_adaptive_routes_a_drain_by_the_lanes_still_to_verify(
     assert report.reached and report.skipped == 2
     assert report.verified.tolist() == [True] * 9 + [False] * 2
     assert (_recovers("device"), _recovers("host")) == ((1, 18, 64), (3, 6, 6))
+
+
+def _stub_kernels(monkeypatch, mask_fn):
+    """The device programs replaced, so that the pack / scatter logic runs
+    alone: digests are zeros (joined with ``hz`` as the real program joins
+    them), the recover mask is ``mask_fn(live)``.  Returns the ``live`` rows
+    the recover launches were handed."""
+    handed = []
+
+    def digest(blocks, counts, hz=None):
+        zw = np.zeros((blocks.shape[0], 8), dtype=np.uint32)
+        return zw if hz is None else np.concatenate([zw, hz])
+
+    def recover(zw, r, s, v, claimed, table, live):
+        handed.append(np.array(live))
+        return mask_fn(np.array(live))
+
+    monkeypatch.setattr(vbatch, "_digest_kernel", digest)
+    monkeypatch.setattr(vbatch, "_recover_kernel", recover)
+    return handed
+
+
+@pytest.mark.parametrize("case", ["malformed_lanes", "rejected_lanes"])
+def test_the_joint_layout_keeps_every_lane_at_its_index(
+    committee, flood, monkeypatch, case
+):
+    """Four COMMITs in one 16-lane dispatch (envelopes in lanes 0-7, their
+    seals in 8-15).  A malformed envelope never reaches the program and
+    stays False at its own index, and a malformed seal does not ride; a lane
+    the program refuses is refused at the position its message (its seal:
+    its COMMIT) had in the caller's batch."""
+    c, t = committee, flood
+    commits = [copy.copy(m) for m in t.commits if m.sender not in t.bad][:4]
+    dev = DeviceBatchVerifier(c.src)
+    if case == "malformed_lanes":
+        commits[1].signature = b"\x01" * 10
+        commits[2] = _commit(
+            c, commits[2].sender, t.phash, commits[2].commit_data.committed_seal[:64]
+        )
+        handed = _stub_kernels(monkeypatch, lambda live: live)
+        want_envelopes = [True, False, True, True]
+        want_seals = [True, None, None, True]
+        want_live = [True] * 3 + [False] * 5 + [True] * 2 + [False] * 6
+    else:
+
+        def refuse(live):
+            live[0] = live[8 + 1] = False  # first envelope, second seal
+            return live
+
+        handed = _stub_kernels(monkeypatch, refuse)
+        want_envelopes = [False, True, True, True]
+        want_seals = [True, False, True, True]
+        want_live = ([True] * 4 + [False] * 4) * 2
+    assert dev.verify_senders(commits).tolist() == want_envelopes
+    (live,) = handed
+    assert live.tolist() == want_live
+    assert dev.cached_seal_verdicts(t.phash, _seals(commits), HEIGHT) == want_seals
+
+
+@pytest.mark.parametrize("half", ["no_seals", "no_messages"])
+def test_an_empty_half_of_a_round_is_no_special_case(
+    committee, flood, monkeypatch, half
+):
+    """PREPAREs alone run in the same joint program with its second half
+    dead and leave no verdict; seals whose COMMITs never came are a drain of
+    their own at the rung, and no messages at all are no dispatch."""
+    c, t = committee, flood
+    dev = DeviceBatchVerifier(c.src)
+
+    def refuse_first(live):
+        live[0] = False
+        return live
+
+    handed = _stub_kernels(monkeypatch, refuse_first)
+    if half == "no_seals":
+        prepares = t.prepares[:4]
+        assert dev.verify_senders(prepares).tolist() == [False, True, True, True]
+        (live,) = handed
+        assert live.tolist() == [True] * 4 + [False] * 12
+        assert len(dev._seal_verdicts) == 0
+    else:
+        assert dev.verify_senders([]).tolist() == [] and handed == []
+        seals = _seals(t.commits[:4])
+        mask = dev.verify_committed_seals(t.phash, seals, HEIGHT)
+        assert mask.tolist() == [False, True, True, True]
+        (live,) = handed
+        assert live.tolist() == [True] * 4 + [False] * 4
 
 
 class _JointFaults(DeviceBatchVerifier):

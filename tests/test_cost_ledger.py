@@ -56,9 +56,9 @@ class FakeJit:
 
 def test_disabled_mode_records_nothing_and_shares_one_null_span():
     assert not ledger.enabled()
-    ledger.record_dispatch("quorum_certify", "device", live=4, padded=8)
-    ledger.add_wall_ms("quorum_certify", "device", 5.0)
-    ledger.record_compile("quorum_certify", 100.0)
+    ledger.record_dispatch("ecdsa_recover", "device", live=4, padded=8)
+    ledger.add_wall_ms("ecdsa_recover", "device", 5.0)
+    ledger.record_compile("ecdsa_recover", 100.0)
     assert ledger.snapshot() is None
     assert ledger.totals() is None
     assert ledger.status() is None
@@ -70,12 +70,12 @@ def test_disabled_mode_records_nothing_and_shares_one_null_span():
 
 def test_dispatch_records_accumulate_with_occupancy():
     ledger.enable()
-    ledger.record_dispatch("quorum_certify", "device", live=4, padded=8, ms=2.0)
-    ledger.record_dispatch("quorum_certify", "device", live=8, padded=8, ms=1.0)
+    ledger.record_dispatch("ecdsa_recover", "device", live=4, padded=8, ms=2.0)
+    ledger.record_dispatch("ecdsa_recover", "device", live=8, padded=8, ms=1.0)
     ledger.record_dispatch("ecdsa_recover", "host", live=3, padded=3)
     snap = ledger.snapshot()
     by_key = {(r["program"], r["route"]): r for r in snap["dispatches"]}
-    qc = by_key[("quorum_certify", "device")]
+    qc = by_key[("ecdsa_recover", "device")]
     assert qc["dispatches"] == 2
     assert qc["live_lanes"] == 12 and qc["padded_lanes"] == 16
     assert qc["occupancy"] == pytest.approx(0.75)
@@ -86,14 +86,14 @@ def test_dispatch_records_accumulate_with_occupancy():
     assert totals["live_lanes"] == 15 and totals["padded_lanes"] == 19
     status = ledger.status()
     assert status["programs"] == 2
-    assert status["top_program"]["program"] == "quorum_certify"
+    assert status["top_program"]["program"] == "ecdsa_recover"
 
 
 def test_totals_exclude_warmup_routes_from_occupancy():
     """Warmup lanes are all-dead by design; totals()/status() occupancy
     must not be dragged toward 0 by a warmup having run."""
     ledger.enable()
-    ledger.record_dispatch("quorum_certify", "device", live=6, padded=8, ms=1.0)
+    ledger.record_dispatch("ecdsa_recover", "device", live=6, padded=8, ms=1.0)
     ledger.record_dispatch("ecdsa_recover", "warmup", live=0, padded=2048, ms=900.0)
     with ledger.route_tag("serve"):
         ledger.record_dispatch("ecdsa_recover", "warmup", live=0, padded=128)
@@ -102,7 +102,7 @@ def test_totals_exclude_warmup_routes_from_occupancy():
     assert totals["padded_lanes"] == 8
     status = ledger.status()
     assert status["occupancy"] == pytest.approx(0.75)
-    assert status["top_program"]["program"] == "quorum_certify"
+    assert status["top_program"]["program"] == "ecdsa_recover"
     # The per-route snapshot still shows the warmup rows themselves.
     routes = {r["route"] for r in ledger.snapshot()["dispatches"]}
     assert "warmup" in routes and "serve/warmup" in routes
@@ -134,13 +134,13 @@ def test_compile_ledger_record_schema_is_pinned(tmp_path):
     programs split one timed span."""
     log = tmp_path / "cl.jsonl"
     ledger.enable(compile_log=str(log))
-    ledger.record_compile("quorum_certify", 120.5, site="tests/schema")
+    ledger.record_compile("ecdsa_recover", 120.5, site="tests/schema")
     ledger.record_compile("digest_words", 10.0, site="s2", shared_span=2)
     ledger.disable()
     records = [json.loads(ln) for ln in log.read_text().splitlines()]
     assert len(records) == 2
     assert set(records[0]) == {"program", "ms", "site", "ts"}
-    assert records[0]["program"] == "quorum_certify"
+    assert records[0]["program"] == "ecdsa_recover"
     assert records[0]["ms"] == 120.5
     assert records[0]["site"] == "tests/schema"
     assert isinstance(records[0]["ts"], float)
@@ -186,32 +186,32 @@ def test_dispatch_span_detects_compiles_and_logs_jsonl(tmp_path):
     warm = FakeJit()
     cold = FakeJit()
     with ledger.dispatch_span(
-        "round_certify",
+        "digest_words",
         route="device",
         padded=8,
-        kernels=(("round_certify", cold), ("ecdsa_recover", warm)),
+        kernels=(("digest_words", cold), ("ecdsa_recover", warm)),
         site="tests/test_cost_ledger.py",
     ):
         cold.n += 1  # only this kernel "compiled" inside the span
     snap = ledger.snapshot()
-    assert set(snap["compiles"]) == {"round_certify"}
-    assert snap["compiles"]["round_certify"]["count"] == 1
+    assert set(snap["compiles"]) == {"digest_words"}
+    assert snap["compiles"]["digest_words"]["count"] == 1
     events = [json.loads(line) for line in log.read_text().splitlines()]
     assert len(events) == 1
     event = events[0]
-    assert event["program"] == "round_certify"
+    assert event["program"] == "digest_words"
     assert event["ms"] > 0
     assert event["site"] == "tests/test_cost_ledger.py"
     assert "ts" in event
     # Warm re-dispatch: no new compile event.
     with ledger.dispatch_span(
-        "round_certify",
+        "digest_words",
         route="device",
         padded=8,
-        kernels=(("round_certify", cold),),
+        kernels=(("digest_words", cold),),
     ):
         pass
-    assert ledger.snapshot()["compiles"]["round_certify"]["count"] == 1
+    assert ledger.snapshot()["compiles"]["digest_words"]["count"] == 1
 
 
 def test_shared_span_flag_when_staged_pipeline_compiles_together(tmp_path):
@@ -375,20 +375,20 @@ def test_metrics_exposition_renders_ledger_families():
     from go_ibft_tpu.obs import metrics_export
 
     ledger.enable()
-    ledger.record_dispatch("quorum_certify", "device", live=6, padded=8, ms=2.5)
-    ledger.record_compile("quorum_certify", 120.0, site="x")
+    ledger.record_dispatch("ecdsa_recover", "device", live=6, padded=8, ms=2.5)
+    ledger.record_compile("ecdsa_recover", 120.0, site="x")
     series = metrics_export.parse_exposition(
         metrics_export.render_prometheus()
     )
-    labels = '{program="quorum_certify",route="device"}'
+    labels = '{program="ecdsa_recover",route="device"}'
     assert series[f"go_ibft_ledger_dispatches_total{labels}"] == 1
     assert series[f"go_ibft_ledger_lanes_live_total{labels}"] == 6
     assert series[f"go_ibft_ledger_lanes_padded_total{labels}"] == 8
     assert series[f"go_ibft_ledger_occupancy{labels}"] == 0.75
     assert series[f"go_ibft_ledger_wall_ms_total{labels}"] == 2.5
-    assert series['go_ibft_ledger_compiles_total{program="quorum_certify"}'] == 1
+    assert series['go_ibft_ledger_compiles_total{program="ecdsa_recover"}'] == 1
     assert (
-        series['go_ibft_ledger_compile_ms_total{program="quorum_certify"}']
+        series['go_ibft_ledger_compile_ms_total{program="ecdsa_recover"}']
         == 120.0
     )
 
@@ -400,9 +400,8 @@ def test_cost_report_renderer_and_attribution():
     # The registry families the seams record under must be pinned —
     # this IS the "registry names are the key space" contract.
     assert {
-        "quorum_certify",
-        "round_certify",
         "ecdsa_recover",
+        "digest_words",
         "mesh_verify_mask",
         "bls_aggregate_verify",
         "bls_g2_merge_tree",
@@ -411,7 +410,7 @@ def test_cost_report_renderer_and_attribution():
     snap = {
         "dispatches": [
             {
-                "program": "quorum_certify",
+                "program": "ecdsa_recover",
                 "route": "device",
                 "dispatches": 19,
                 "live_lanes": 100,
@@ -429,11 +428,11 @@ def test_cost_report_renderer_and_attribution():
                 "occupancy": 1.0,
             },
         ],
-        "compiles": {"quorum_certify": {"count": 1, "ms": 38000.0}},
+        "compiles": {"ecdsa_recover": {"count": 1, "ms": 38000.0}},
         "overflowed": 0,
     }
     report = cost_report.render_snapshot(snap, families=families)
-    assert "quorum_certify" in report
+    assert "ecdsa_recover" in report
     assert "mystery_kernel" in report
     assert "95.0%" in report  # 19/20 attributed
     assert "unpinned programs: mystery_kernel" in report
@@ -472,7 +471,7 @@ def test_statusz_carries_cost_ledger_block():
     from go_ibft_tpu.obs.httpd import TelemetryServer
 
     ledger.enable()
-    ledger.record_dispatch("quorum_certify", "device", live=4, padded=8, ms=1.0)
+    ledger.record_dispatch("ecdsa_recover", "device", live=4, padded=8, ms=1.0)
     server = TelemetryServer(status_fn=lambda: {"height": 3})
     port = server.start()
     try:

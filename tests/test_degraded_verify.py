@@ -381,49 +381,42 @@ def test_breaker_abort_probe_releases_without_restoring():
     assert brk.level == 1
 
 
-def test_certify_fallback_releases_consumed_probe():
-    """Regression: a fused-certify call made while the ladder is demoted
-    past host consumes the breaker acquisition on its fallback route; the
-    probe must be released afterwards, or _probing wedges and no probe is
-    ever offered again (the ladder would stay at the slowest rung for the
-    life of the process)."""
-    from go_ibft_tpu.verify import AdaptiveBatchVerifier
-
-    prepares, _, _, src = _signed(2, seed=8)
+@pytest.mark.parametrize("drain", ["verify_senders", "verify_seals_early_exit"])
+def test_a_demoted_ladder_releases_a_probe_its_drain_cannot_answer(drain):
+    """A ladder demoted off its mesh rung is offered the mesh probe after
+    the cooldown; a drain under the mesh cutover consumes that acquisition
+    and will not run the mesh.  It must release the probe, not answer it:
+    no restore on no evidence, and no wedged ``_probing`` (no probe would
+    ever be offered again and the ladder would stay demoted for the life of
+    the process).  The next drain big enough for the mesh gets the probe."""
+    prepares, seals, phash, src = _signed(8, seed=8)
     now = [0.0]
     brk = CircuitBreaker(
-        ("device", "host", "python"), k=1, cooldown_s=1.0, clock=lambda: now[0]
+        ("mesh", "device", "host", "python"), k=1, cooldown_s=1.0, clock=lambda: now[0]
     )
-
-    class _FusedStub:
-        calls = 0
-
-        def supports_fused(self, height):
-            return True
-
-        def verify_senders(self, msgs):
-            _FusedStub.calls += 1
-            raise RuntimeError("dead device")
-
-        def verify_committed_seals(self, proposal_hash, seals, height):
-            _FusedStub.calls += 1
-            raise RuntimeError("dead device")
-
-        def certify_senders(self, msgs, height, threshold=None):
-            _FusedStub.calls += 1
-            raise RuntimeError("dead device")
-
-    adaptive = AdaptiveBatchVerifier(
-        src, cutover_lanes=2, device=_FusedStub(), breaker=brk
+    mesh, device = HostBatchVerifier(src), _FastRung(src)
+    mesh_calls = []
+    for name in ("verify_senders", "verify_seals_early_exit"):
+        fn = getattr(mesh, name)
+        setattr(mesh, name, lambda *a, _fn=fn, **kw: mesh_calls.append(1) or _fn(*a, **kw))
+    resilient = ResilientBatchVerifier(
+        device, mesh=mesh, mesh_cutover_lanes=6, validators_for_height=src, breaker=brk
     )
     brk.record_fault(0)
-    brk.record_fault(1)
-    assert brk.level == 2  # demoted past host
-    now[0] += 1.5  # cooldown elapsed: next acquire offers the host probe
+    assert brk.level == 1  # demoted to the single-device rung
+    now[0] += 1.5  # cooldown elapsed: the next acquire offers the mesh probe
 
-    mask, reached = adaptive.certify_senders(prepares, height=1)
-    assert mask.all() and reached  # verdicts correct via the ladder
-    # the consumed probe was released: the breaker still offers it
-    assert brk.acquire() == (1, True)
-    brk.record_success(1)
-    assert brk.level == 1
+    def run(n):
+        """Every verdict the drain gave is True (an early exit stops at
+        the quorum of 6 and leaves the rest unverified)."""
+        if drain == "verify_senders":
+            return resilient.verify_senders(prepares[:n]).all()
+        report = resilient.verify_seals_early_exit(phash, seals[:n], 1)
+        return (report.mask == report.verified).all() and report.verified.any()
+
+    assert run(4) and not mesh_calls  # 4 < 6: served one rung down
+    assert brk.level == 1  # the mesh did not run: nothing restored
+    assert brk.acquire() == (0, True)  # the probe is offered again
+    brk.abort_probe(0)
+    assert run(8) and mesh_calls  # 8 >= 6: this drain answers it
+    assert brk.level == 0

@@ -163,3 +163,83 @@ def test_environment_variables_documented_are_the_ones_read():
         [*(REPO / "tests").rglob("*.py"), *REPO.glob("*.py"), REPO / "Makefile"]
     )
     assert sorted(documented - read_anywhere) == []
+
+
+# ---------------------------------------------------------------------------
+# One verifier surface: masks from the device, quorum from host integers
+# ---------------------------------------------------------------------------
+
+
+def _exported_verifiers() -> list:
+    """Every class a subpackage exports that has the ``BatchVerifier``
+    surface (``verify_senders``), the protocol itself included."""
+    import importlib
+    import inspect
+
+    found = {}
+    for sub in ("core", "verify", "chaos", "sched", "crypto", "net", "serve"):
+        module = importlib.import_module(f"go_ibft_tpu.{sub}")
+        for name in getattr(module, "__all__", ()):
+            obj = getattr(module, name)
+            if inspect.isclass(obj) and hasattr(obj, "verify_senders"):
+                found[f"{sub}.{name}"] = obj
+    return sorted(found.items())
+
+
+VERIFIERS = _exported_verifiers()
+
+
+def test_the_exported_verifiers_are_found():
+    names = {name for name, _ in VERIFIERS}
+    assert {
+        "core.BatchVerifier",
+        "verify.HostBatchVerifier",
+        "verify.DeviceBatchVerifier",
+        "verify.MeshBatchVerifier",
+        "verify.ResilientBatchVerifier",
+        "verify.AdaptiveBatchVerifier",
+        "verify.EngineScope",
+        "chaos.ChaoticVerifier",
+    } <= names
+
+
+@pytest.mark.parametrize("name, cls", VERIFIERS, ids=[n for n, _ in VERIFIERS])
+def test_no_exported_verifier_has_a_fused_certify_surface(name, cls):
+    """A verifier returns masks.  The fused mask-and-quorum family
+    (``certify_*``, ``supports_fused``) could not hold one real stake (31
+    bits) and went in PR 49; nothing grows it back on a class by forwarding."""
+    grown = [
+        attr
+        for attr in dir(cls)
+        if attr.startswith("certify_") or attr == "supports_fused"
+    ]
+    assert grown == []
+
+
+def test_the_program_registry_is_the_compile_budgets_rows():
+    """``docs/compile_budget.json`` pins exactly the programs the boot
+    registry can build, in the registry's order: a family deleted from one
+    and left in the other is a row nothing lowers, or a program no ratchet
+    holds."""
+    import json
+
+    from go_ibft_tpu.boot.registry import program_registry
+
+    budget = json.loads((REPO / "docs" / "compile_budget.json").read_text())
+    rows = [name for name in budget if not name.startswith("_")]
+    assert list(program_registry()) == rows
+    assert len(rows) == 11 and "ecdsa_recover_8l" in rows and "digest_words_8l" in rows
+
+
+_GONE = re.compile(
+    r"certify_(senders|seals|round)|quorum_certify|round_certify|supports_fused"
+    r"|verify_round_chunked|FusedBatchVerifier|power_reduce|split_power|profile_decompose"
+)
+
+
+def test_no_document_draws_the_fused_certify_family():
+    drawn = {
+        doc: sorted({m.group(0) for m in _GONE.finditer((REPO / doc).read_text())})
+        for doc in DOCS + [".claude/skills/verify/SKILL.md"]
+    }
+    assert {doc: names for doc, names in drawn.items() if names} == {}

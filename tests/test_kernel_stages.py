@@ -127,17 +127,6 @@ def test_ecdsa_verify_is_not_scoped_beyond_ecmul2_base(verify_text):
         assert stage not in verify_text
 
 
-def test_certify_program_still_lowers_with_every_stage():
-    scalar = jax.ShapeDtypeStruct((), jnp.int32)
-    powers = jax.ShapeDtypeStruct((LANES,), jnp.int32)
-    lowered = batch._certify_kernel.lower(
-        *sr.recover_shapes(LANES, LANES), powers, powers, scalar, scalar
-    )
-    text = lowered.as_text(debug_info=True)
-    for stage in sr.STAGES:
-        assert stage in text, stage
-
-
 def test_all_nine_scope_names_reach_the_folded_program_on_the_kernel_path():
     """The other path (PR 48): where the ladder is the Pallas kernel the nine
     scopes are still in the program, and the kernel's custom call is named

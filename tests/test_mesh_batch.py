@@ -188,33 +188,33 @@ def test_sharded_seal_lanes_parity_multi_height_dp2(eight, mesh2):
     assert np.array_equal(got, oracle)
 
 
-def test_sharded_certify_host_reduce_parity_dp2(eight, mesh2):
-    """certify_* on the mesh route: sharded mask + host-int quorum reduce
-    must agree with the host oracle's mask AND quorum verdict."""
+def test_sharded_mask_equals_single_device_mask_dp2_quorum_on_host(eight, mesh2):
+    """Both phases on the mesh route: the sharded masks are the
+    single-device program's and the host oracle's, lane for lane, and the
+    quorum over them is reduced by the host in exact ints (nothing on the
+    mesh holds a power, so there is no collective and no power range)."""
+    from go_ibft_tpu.verify import DeviceBatchVerifier
+
     src, rounds = eight
     phash, prepares, seals = rounds[1]
     msgs = list(prepares)
     msgs[1] = _flip(msgs[1])
-    host = HostBatchVerifier(src)
+    host, single = HostBatchVerifier(src), DeviceBatchVerifier(src)
     oracle = host.verify_senders(msgs)
+    assert not oracle[1] and oracle.sum() == 7
 
-    mask, reached = mesh2.certify_senders(msgs, height=1)
+    mask = mesh2.verify_senders(msgs)
+    assert np.array_equal(mask, single.verify_senders(msgs))
     assert np.array_equal(mask, oracle)
-    # 7 of 8 valid >= quorum 6
-    assert reached == host_quorum_reached(
-        src, [m.sender for m, ok in zip(msgs, oracle) if ok], 1, None
-    )
-    assert reached
+    valid = [m.sender for m, ok in zip(msgs, mask) if ok]
+    assert host_quorum_reached(src, valid, 1, None)  # 7 of 8 >= quorum 6
+    assert not host_quorum_reached(src, valid[:5], 1, None)
 
-    smask, sreached = mesh2.certify_seals(phash, seals, height=1)
-    assert smask.all() and sreached
-
-    rm, p_ok, sm, s_ok = mesh2.certify_round(msgs, phash, seals, height=1)
-    assert np.array_equal(rm, oracle) and sm.all()
-    assert p_ok and s_ok
-    assert mesh2.supports_fused(1)
-    # the reduce leg records its cost (bench reduce_ms evidence)
-    assert metrics.summarize(("go-ibft", "mesh", "reduce_ms")) is not None
+    smask = mesh2.verify_committed_seals(phash, seals, height=1)
+    assert np.array_equal(smask, single.verify_committed_seals(phash, seals, 1))
+    assert smask.all()
+    assert host_quorum_reached(src, [s.signer for s in seals], 1, None)
+    assert mesh2._program == "mesh_verify_mask" and single._program == "ecdsa_recover"
 
 
 def test_sharded_parity_dp8(eight, mesh8):
@@ -246,9 +246,9 @@ def test_malformed_lane_quarantine_through_sharded_route(eight, mesh2):
     assert not oracle[4]
 
     class _Strict:
-        """Strict-packing mesh rung: the vectorized pack runs up front (as
-        the certify paths do), so a malformed lane raises the lane-named
-        error instead of being silently well-formed-filtered."""
+        """Strict-packing mesh rung: the vectorized pack runs up front, so
+        a malformed lane raises the lane-named error instead of being
+        silently well-formed-filtered."""
 
         def __init__(self, inner):
             self.inner = inner
@@ -313,10 +313,10 @@ def test_coalesced_multi_drain_dispatch_shapes(eight, mesh2):
     cap would have cost three."""
     calls = []
 
-    def fake_dispatch(inputs, table, quorum_args):
+    def fake_dispatch(inputs, table):
         live = inputs[-1]
         calls.append(int(np.shape(live)[0]))
-        return np.asarray(live), None
+        return np.asarray(live)
 
     mv = copy.copy(mesh2)
     mv._dispatch_async = fake_dispatch
@@ -346,9 +346,6 @@ class _StubRung:
         self._host = HostBatchVerifier(src)
         self.dead = dead
         self.calls = 0
-
-    def supports_fused(self, height):
-        return False
 
     def verify_senders(self, msgs):
         self.calls += 1
@@ -427,52 +424,37 @@ def test_mesh_cutover_routes_small_drains_to_device(eight):
     assert mesh_rung.calls == 1 and device_rung.calls == 1
 
 
-def test_adaptive_mesh_route_certify_and_fallback(eight):
-    """AdaptiveBatchVerifier with a mesh: big certifies ride the mesh
-    route; a mesh fault falls back (verdict intact) and k faults demote
-    the ladder so traffic stops touching the mesh."""
+def test_adaptive_mesh_route_and_fallback(eight):
+    """AdaptiveBatchVerifier with a mesh: big drains ride the mesh rung; a
+    mesh fault falls back (verdict intact) and k faults demote the ladder
+    so traffic stops touching the mesh."""
     from go_ibft_tpu.verify import AdaptiveBatchVerifier
 
     src, rounds = eight
     phash, prepares, seals = rounds[1]
-
-    class _CertifyMesh(_StubRung):
-        sharded = True
-
-        def certify_senders(self, msgs, height, threshold=None):
-            self.calls += 1
-            if self.dead:
-                raise RuntimeError("simulated mesh fault")
-            mask = self._host.verify_senders(msgs)
-            return mask, host_quorum_reached(
-                src, [m.sender for m, ok in zip(msgs, mask) if ok], height,
-                threshold,
-            )
-
-    mesh_rung = _CertifyMesh(src)
+    mesh_rung, device_rung = _StubRung(src), _StubRung(src)
     brk = CircuitBreaker(("mesh", "device", "host", "python"), k=2)
     adaptive = AdaptiveBatchVerifier(
         src,
         cutover_lanes=2,
-        device=_StubRung(src),
+        device=device_rung,
         mesh=mesh_rung,
         mesh_cutover_lanes=4,
         breaker=brk,
     )
-    mask, reached = adaptive.certify_senders(prepares, height=1)
-    assert mask.all() and reached
-    assert mesh_rung.calls == 1  # the mesh route served it
+    assert adaptive.verify_senders(prepares).all()
+    assert adaptive.verify_committed_seals(phash, seals, 1).all()
+    assert mesh_rung.calls == 2 and device_rung.calls == 0  # the mesh served both
+    assert adaptive.verify_senders(prepares[:3]).all()  # 3 < 4: single device
+    assert mesh_rung.calls == 2 and device_rung.calls == 1
 
     mesh_rung.dead = True
-    mask, reached = adaptive.certify_senders(prepares, height=1)
-    assert mask.all() and reached  # fallback verdict intact
-    mask, reached = adaptive.certify_senders(prepares, height=1)
-    assert mask.all() and reached
+    assert adaptive.verify_senders(prepares).all()  # fallback verdict intact
+    assert adaptive.verify_committed_seals(phash, seals, 1).all()
     assert brk.level >= 1  # k=2 mesh faults demoted the ladder
 
     calls_before = mesh_rung.calls
-    mask, reached = adaptive.certify_senders(prepares, height=1)
-    assert mask.all() and reached
+    assert adaptive.verify_senders(prepares).all()
     assert mesh_rung.calls == calls_before  # demoted: mesh not touched
 
 
@@ -487,9 +469,9 @@ def test_sync_client_coalesces_range_through_mesh(eight, mesh2):
     real_dispatch = type(mesh2)._dispatch_async
     mv = copy.copy(mesh2)
 
-    def counting_dispatch(inputs, table, quorum_args):
+    def counting_dispatch(inputs, table):
         calls.append(int(np.shape(inputs[-1])[0]))
-        return real_dispatch(mv, inputs, table, quorum_args)
+        return real_dispatch(mv, inputs, table)
 
     mv._dispatch_async = counting_dispatch
 

@@ -1,4 +1,4 @@
-"""Sharded quorum verification over a multi-device mesh.
+"""Sharded mask verification over a multi-device mesh.
 
 Runs on the 8 virtual CPU devices (conftest forces
 ``--xla_force_host_platform_device_count=8``); asserts the sharded result
@@ -7,35 +7,17 @@ partitionings.
 """
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from go_ibft_tpu.bench import build_round_workload
-from go_ibft_tpu.ops.quorum import quorum_certify
-from go_ibft_tpu.parallel import make_mesh, mesh_quorum_certify
+from go_ibft_tpu.parallel import make_mesh
+from go_ibft_tpu.verify.batch import _recover_kernel
+from go_ibft_tpu.verify.mesh_batch import mesh_verify_mask
 
 # The shard_map mesh program is one of the largest compiles in the tree
 # (tens of minutes cold on a CI runner); keep it out of the fast tier.
 pytestmark = pytest.mark.slow
-
-
-def _args(w):
-    blocks, counts, r, s, v, senders, live = w.prepare
-    return (
-        jnp.asarray(blocks),
-        jnp.asarray(counts),
-        jnp.asarray(r),
-        jnp.asarray(s),
-        jnp.asarray(v),
-        jnp.asarray(senders),
-        jnp.asarray(w.table),
-        jnp.asarray(live),
-        jnp.asarray(w.powers_lo),
-        jnp.asarray(w.powers_hi),
-        jnp.int32(w.thr_lo),
-        jnp.int32(w.thr_hi),
-    )
 
 
 @pytest.fixture(scope="module")
@@ -49,18 +31,14 @@ def cpu8():
 @pytest.mark.parametrize("vp", [1, 2])
 def test_mesh_matches_single_device(cpu8, vp):
     w = build_round_workload(8, corrupt_frac=0.25, seed=5, pad_lanes=8)
-    args = _args(w)
-    mesh = make_mesh(8, vp=vp, devices=cpu8)
-    sharded = mesh_quorum_certify(mesh)
-    # single-CPU-device reference (same platform as the sharded run)
-    ref_mesh = make_mesh(1, devices=cpu8[:1])
-    ref = mesh_quorum_certify(ref_mesh)
-    got = [np.asarray(x) for x in sharded(*args)]
-    want = [np.asarray(x) for x in ref(*args)]
-    for g, x in zip(got, want):
-        assert np.array_equal(g, x)
+    hz, r, s, v, signers, live = w.seals
+    rows = (hz, r, s, v, signers, w.table, live)
+    mesh = make_mesh(8, vp=vp, devices=cpu8)  # lanes over dp, replicated over vp
+    got = np.asarray(mesh_verify_mask(mesh)(*rows))
+    want = np.asarray(_recover_kernel(*rows))  # the single-device program
+    assert np.array_equal(got, want)
     n = w.n_validators
-    assert np.array_equal(got[0][:n], w.expected_prepare_mask)
+    assert np.array_equal(got[:n], w.expected_seal_mask) and not got[n:].any()
 
 
 def test_mesh_device_count_validation(cpu8):

@@ -725,9 +725,8 @@ def test_a_drain_after_warm_up_meets_no_call_form_the_warm_up_did_not_load(
     device-resident): at 128 + 128 lanes (a 100-validator flood), 512 (a
     300-validator one, its seals the next chunk) and 2,048 (a sync chunk) no
     drain grows a program's call cache, which is what the cost ledger
-    counts as a compile.  The recover and certify programs are cheap jits of
-    the same signature here (no ladder compiles); the digest is the real
-    function."""
+    counts as a compile.  The recover program is a cheap jit of the same
+    signature here (no ladder compiles); the digest is the real function."""
     import jax
 
     from go_ibft_tpu.verify import DeviceBatchVerifier, batch
@@ -735,12 +734,7 @@ def test_a_drain_after_warm_up_meets_no_call_form_the_warm_up_did_not_load(
     def recover(zw, r, s, v, claimed, table, live):
         return live & (zw[:, 0] == r[:, 0].astype(np.uint32)) & (table[0, 0] == 0)
 
-    def certify(zw, r, s, v, claimed, table, live, plo, phi, lo, hi):
-        ok = recover(zw, r, s, v, claimed, table, live)
-        return ok, ok.sum() >= lo + hi + plo[0] + phi[0], lo, hi
-
     monkeypatch.setattr(batch, "_recover_kernel", jax.jit(recover))
-    monkeypatch.setattr(batch, "_certify_kernel", jax.jit(certify))
     # A function of its own, so that only this test's calls are in its cache
     # (jits of one function share theirs).
     monkeypatch.setattr(
@@ -749,9 +743,9 @@ def test_a_drain_after_warm_up_meets_no_call_form_the_warm_up_did_not_load(
     powers = {bytes([i // 256, i % 256]) * 10: 1 for i in range(300)}
     dev = DeviceBatchVerifier(lambda height: powers)
     dev.warmup(lanes=(128, 256, 512, 2048), blocks=(2,), table_rows=512)
-    programs = (batch._recover_kernel, batch._certify_kernel, batch._digest_kernel)
+    programs = (batch._recover_kernel, batch._digest_kernel)
     loaded = [k._cache_size() for k in programs]
-    assert loaded == [2 * 4, 2 * 4, 4 + 1]  # two forms a width; one joined digest
+    assert loaded == [2 * 4, 4 + 1]  # two forms a width; one joined digest
 
     commits, phash = _flood(99, "commit")
     dev.verify_senders(commits)  # 128 + 128: the digest joins the halves
@@ -761,8 +755,6 @@ def test_a_drain_after_warm_up_meets_no_call_form_the_warm_up_did_not_load(
     lanes = [(phash, seal) for seal in _seals_of(flood)] * 7
     assert len(lanes[:2048]) == 2048
     dev.verify_seal_lanes(lanes[:2048], 3)
-    dev.certify_senders(commits, 3)
-    dev.certify_seals(phash, _seals_of(commits), 3)
     assert [k._cache_size() for k in programs] == loaded
 
 

@@ -143,101 +143,62 @@ def test_pipeline_records_first_class_metrics():
 # -- device verifier drains through the pipeline -----------------------------
 
 
-def test_verify_round_chunked_scatters_both_phases(monkeypatch):
-    """Cross-phase chunk drain: PREPARE and COMMIT-seal chunks share one
-    pipeline; masks scatter back per phase (dispatch stubbed — the real-
-    kernel differential lives in the slow tier)."""
+def test_commit_flood_drains_envelopes_and_seals_through_one_pipeline(monkeypatch):
+    """From the fold width up a COMMIT flood's seals are the NEXT CHUNK of
+    the envelopes' drain: both phases share one pipeline run (the seals pack
+    while the envelopes execute) and each chunk's mask scatters to its own
+    phase, the envelopes' to the caller's rows, the seals' to the verdict
+    cache (dispatch stubbed; the real-kernel differential is
+    ``tests/test_commit_joint_dispatch.py``)."""
     from go_ibft_tpu.crypto import PrivateKey
     from go_ibft_tpu.crypto.backend import ECDSABackend, proposal_hash_of
     from go_ibft_tpu.messages.helpers import extract_committed_seal
     from go_ibft_tpu.messages.wire import Proposal, View
     from go_ibft_tpu.verify import DeviceBatchVerifier
+    from go_ibft_tpu.verify import batch as vbatch
 
     keys = [PrivateKey.from_seed(b"vrc-%d" % i) for i in range(4)]
     src = ECDSABackend.static_validators({k.address: 1 for k in keys})
     backends = [ECDSABackend(k, src) for k in keys]
     view = View(height=2, round=0)
     phash = proposal_hash_of(Proposal(raw_proposal=b"vrc block", round=0))
-    msgs = [b.build_prepare_message(phash, view) for b in backends]
-    seals = [
-        extract_committed_seal(b.build_commit_message(phash, view))
-        for b in backends
-    ]
-    # one wrong-height envelope: filtered out (mask False), never dispatched
-    msgs.append(backends[0].build_prepare_message(phash, View(height=9, round=0)))
+    commits = [b.build_commit_message(phash, view) for b in backends]
+    seals = [extract_committed_seal(m) for m in commits]
+    # one wrong-length envelope: filtered out (mask False), never dispatched,
+    # and its seal does not ride
+    stray = backends[0].build_commit_message(phash, view)
+    stray.signature = stray.signature[:30]
+    msgs = commits + [stray]
 
+    monkeypatch.setattr(vbatch, "_FOLD_LANES", 8)  # the 8-lane rung folds
     dev = DeviceBatchVerifier(src)
-    kinds = []
+    kinds, runs = [], []
 
-    def fake_async(inputs, table, quorum_args):
+    def fake_async(inputs, table):
         live = np.asarray(inputs[-1])
         kinds.append(int(live.sum()))
-        mask = np.zeros(len(live), dtype=bool)
-        mask[: int(live.sum())] = True
+        mask = live.copy()
         mask[0] = False  # first lane of each chunk rejected
-        return mask, None
+        return mask
 
+    real_run = vbatch.VerifyPipeline.run
+
+    def counting_run(self, items, *args, **kwargs):
+        runs.append(len(items))
+        return real_run(self, items, *args, **kwargs)
+
+    def rows(n, lanes=8):
+        live = np.zeros(lanes, dtype=bool)
+        live[:n] = True
+        return (None,) * 5 + (live,)
+
+    monkeypatch.setattr(vbatch.VerifyPipeline, "run", counting_run)
     monkeypatch.setattr(dev, "_dispatch_async", fake_async)
+    monkeypatch.setattr(dev, "_sender_inputs", lambda ms: rows(len(ms)))
     monkeypatch.setattr(
-        dev, "_sender_inputs", lambda ms: (None,) * 5 + (np.ones(len(ms), bool),)
+        dev, "_rider_inputs", lambda ms, lanes, rows_=None: rows(len(ms), lanes)
     )
-    monkeypatch.setattr(
-        dev,
-        "_seal_inputs",
-        lambda ph, ss: (None,) * 5 + (np.ones(len(ss), bool),),
-    )
-    sender_mask, seal_mask = dev.verify_round_chunked(msgs, phash, seals, height=2)
-    assert kinds == [4, 4]  # one sender chunk + one seal chunk
+    sender_mask = dev.verify_senders(msgs)
+    assert runs == [2] and kinds == [4, 4]  # one envelope chunk + one seal chunk
     assert list(sender_mask) == [False, True, True, True, False]
-    assert list(seal_mask) == [False, True, True, True]
-
-    # malformed hash: seals never dispatch, envelopes still drain
-    kinds.clear()
-    sender_mask, seal_mask = dev.verify_round_chunked(msgs, b"", seals, height=2)
-    assert kinds == [4]
-    assert not seal_mask.any()
-
-
-def test_adaptive_oversize_round_routes_cross_phase_pipeline():
-    """An oversize (chunked) round drains both phases through ONE pipeline
-    call on the device stub, with quorum reduced on exact host ints."""
-    from go_ibft_tpu.crypto import PrivateKey
-    from go_ibft_tpu.crypto.backend import ECDSABackend, proposal_hash_of
-    from go_ibft_tpu.messages.helpers import CommittedSeal
-    from go_ibft_tpu.verify import AdaptiveBatchVerifier
-    from go_ibft_tpu.verify.batch import _BATCH_BUCKETS
-
-    keys = [PrivateKey.from_seed(b"ovr-%d" % i) for i in range(4)]
-    src = ECDSABackend.static_validators({k.address: 1 for k in keys})
-    backends = [ECDSABackend(k, src) for k in keys]
-    from go_ibft_tpu.messages.wire import Proposal, View
-
-    view = View(height=2, round=0)
-    phash = proposal_hash_of(Proposal(raw_proposal=b"ovr block", round=0))
-    msgs = [b.build_prepare_message(phash, view) for b in backends]
-    seals = [
-        CommittedSeal(signer=m.sender, signature=m.commit_data.committed_seal)
-        for m in [b.build_commit_message(phash, view) for b in backends]
-    ]
-    big_n = _BATCH_BUCKETS[-1] + 1
-
-    class _Stub:
-        calls = []
-
-        def supports_fused(self, height):
-            return True
-
-        def verify_round_chunked(self, msgs, ph, seals, height):
-            self.calls.append(("round_chunked", len(msgs), len(seals)))
-            return np.ones(len(msgs), bool), np.ones(len(seals), bool)
-
-    stub = _Stub()
-    av = AdaptiveBatchVerifier(src, cutover_lanes=3, device=stub)
-    sm, p_ok, cm, s_ok = av.certify_round(
-        (msgs * (big_n // 4 + 1))[:big_n],
-        phash,
-        (seals * (big_n // 4 + 1))[:big_n],
-        height=2,
-    )
-    assert stub.calls == [("round_chunked", big_n, big_n)]
-    assert sm.all() and cm.all() and p_ok and s_ok
+    assert dev.cached_seal_verdicts(phash, seals, 2) == [False, True, True, True]

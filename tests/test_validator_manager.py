@@ -69,6 +69,43 @@ def test_unknown_senders_contribute_zero():
     assert vm.has_quorum({b"a", b"b", b"ghost"})
 
 
+def _reduce(powers, how):
+    """``senders -> reached`` by the engine's manager or by the verify
+    plane's one host-side reduction (the ladder's early-exit fallback)."""
+    if how == "has_quorum":
+        return _vm(powers).has_quorum
+    from go_ibft_tpu.verify.batch import host_quorum_reached
+
+    return lambda senders: host_quorum_reached(lambda h: powers, senders, 0, None)
+
+
+@pytest.mark.parametrize("how", ["has_quorum", "host_quorum_reached"])
+def test_duplicate_sender_counts_power_once(how):
+    """A batch may carry one validator's message many times (a mask lane
+    each): its power counts once, in Python ints, at any scale."""
+    for unit in (1, 10**24):
+        reached = _reduce({bytes([i]): unit for i in range(4)}, how)  # quorum 3
+        a, b, c = bytes([0]), bytes([1]), bytes([2])
+        assert not reached([a, a, a, b])  # distinct power 2 < 3
+        assert reached([a, a, a, b, c])
+
+
+def test_host_quorum_reached_threshold_override_and_zero_edge():
+    """The explicit threshold (the prepare phase's ``quorum - proposer``
+    credit) replaces the height's quorum; a threshold <= 0 is reached by
+    an empty set."""
+    from go_ibft_tpu.verify.batch import host_quorum_reached
+
+    powers = {bytes([i]): 1 for i in range(4)}
+    src = lambda height: powers  # noqa: E731
+    three = [bytes([i]) for i in range(3)]
+    assert host_quorum_reached(src, three, 0, None)
+    assert not host_quorum_reached(src, three, 0, 4)
+    assert host_quorum_reached(src, three[:2], 0, 2)
+    assert host_quorum_reached(src, [], 0, 0)
+    assert not host_quorum_reached(src, [], 0, None)
+
+
 def test_zero_total_voting_power_rejected():
     vm = ValidatorManager(_VP({}), NullLogger())
     with pytest.raises(VotingPowerError):

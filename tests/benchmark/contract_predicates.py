@@ -4,12 +4,19 @@ statements that are true wherever a later PR appends its entries (PR 43).
 Each predicate takes the ``root`` of a checkout (``BENCHMARK.json`` and
 ``benchmark/`` under it) and raises ``AssertionError``; the cells' test files
 call them on the repo, and ``test_benchmark_contract.py`` calls all of them on
-a copy with a sixth cell appended, and on one with it put before the node's.
+a copy with a further cell appended, flood side and sync side, and on one with
+it put before an accepted cell's entries (the node's, the PoS cell's).
 A cell's statement: it and its configuration ARE declared; it still selects,
 in order, every metric it selected (an ordered subset: a later PR may give an
 accepted cell one more reader); a reader it shares still CONTAINS it; the
 metrics it brought as its own are still its own.  Nothing here asks where in
-a list an entry stands.
+a list an entry stands, or what else a list holds (PR 50: the PoS cell's
+statement too, which PR 45 had written with pins in its own test file).
+
+A cell's own test file says what it holds of ``BENCHMARK.json`` through ONE
+function, ``declared(root=ROOT)``, that calls the cell's predicate here: the
+copy test finds every ``test_cell_*.py`` by glob and calls it on the copy, so
+a later cell's file is held to the rule by a test it cannot edit.
 """
 
 import json
@@ -165,6 +172,31 @@ NODE_LAYERS = {
 }
 LIBRARY_CELLS = ("ecdsa-100v.flood", "ecdsa-300v.flood-byz30", "ecdsa-100v.sync")
 
+# The PoS sync cell (PR 45).
+POS_SYNC = "ecdsa-100v-pos.sync-epochs"
+POS_SYNC_END_TO_END = ["setup_s", "sync_sigs_per_s"]
+POS_ENTRIES_AS_PR_45_APPENDED_THEM = [
+    "pos_drains_per_call",
+    "pos_sets_per_call",
+    "pos_table_uploads_per_call",
+    "pos_out_of_set_lane_share",
+    "pos_tally_ms_per_call",
+]
+NEW_IN_PR_45 = sorted(POS_ENTRIES_AS_PR_45_APPENDED_THEM)
+POS_LAYERS = {
+    "pos_drains_per_call": "engine",
+    "pos_sets_per_call": "engine",
+    "pos_tally_ms_per_call": "engine",
+    "pos_table_uploads_per_call": "dispatch and wait",
+    "pos_out_of_set_lane_share": "verifier policy",
+}
+# The sync readers it joined, not copied: each says nothing of one cell.
+JOINED_BY_PR_45 = [
+    name for name in SELECTION_OF_PR_32["ecdsa-100v.sync"]["per_layer"]
+    if name == "ecdsa_recover_roofline" or name.startswith("sync_")
+]
+POS_SYNC_PER_LAYER = sorted(["compiles_in_window", "warm_s"] + JOINED_BY_PR_45 + NEW_IN_PR_45)
+
 
 def bench_of(root: str = ROOT) -> dict:
     with open(os.path.join(root, "BENCHMARK.json")) as fh:
@@ -251,8 +283,38 @@ def node(root: str = ROOT) -> None:
     # The library cells get none of the node's metrics.
     for other in LIBRARY_CELLS:
         assert not set(selection(other, root)["per_layer"]) & set(NEW_IN_PR_39)
-    # Four chips cost four times the chip time in every later check: these two, for steadiness alone.
-    assert {w["name"] for w in bench["workloads"] if w["chips"] == 4} == {FLOOD_300V, NODE}
+    # Four chips cost four times the chip time in every later check: these two
+    # hold four, for steadiness alone (a later cell that exists only across
+    # chips may hold four beside them: the contract's half counts, not this).
+    assert {FLOOD_300V, NODE} <= {w["name"] for w in bench["workloads"] if w["chips"] == 4}
+
+
+def pos_sync(root: str = ROOT) -> None:
+    bench = bench_of(root)
+    declares(
+        bench,
+        {"name": POS_SYNC, "config": "ecdsa-100v-pos", "traffic": "sync-epochs", "chips": 1},
+    )
+    got = selection(POS_SYNC, root)
+    assert in_order(POS_SYNC_END_TO_END, got["end_to_end"])
+    assert in_order(POS_SYNC_PER_LAYER, got["per_layer"])
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    # Its own metrics: declared, in the order PR 45 appended them, for the sync
+    # rate.  Each lists this cell; a later sync cell may join one whose reader
+    # says nothing of one cell (``pos_tally_ms_per_call``, ``pos_table_uploads_per_call``).
+    assert in_order(POS_ENTRIES_AS_PR_45_APPENDED_THEM, list(declared))
+    for name in NEW_IN_PR_45:
+        assert POS_SYNC in declared[name]["workloads"]
+        assert declared[name]["moves"] == "sync_sigs_per_s"
+        assert declared[name]["layer"] == POS_LAYERS[name]
+    # The sync readers it joined, and the rate, still list it.
+    for name in JOINED_BY_PR_45:
+        assert POS_SYNC in declared[name]["workloads"]
+    rate = next(m for m in bench["end_to_end"] if m["name"] == "sync_sigs_per_s")
+    assert POS_SYNC in rate["workloads"]
+    # The cells accepted before it get none of its metrics.
+    for other in (*SELECTION_OF_PR_32, NODE):
+        assert not set(selection(other, root)["per_layer"]) & set(NEW_IN_PR_45), other
 
 
 def every_accepted_cell(root: str = ROOT) -> None:
@@ -261,3 +323,4 @@ def every_accepted_cell(root: str = ROOT) -> None:
         pr_32_selection(cell, root)
     flood_300v(root)
     node(root)
+    pos_sync(root)

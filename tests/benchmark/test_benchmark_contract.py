@@ -2,6 +2,8 @@
 limits of the benchmark's contract that can be checked without a chip."""
 
 import glob
+import importlib
+import inspect
 import json
 import os
 import re
@@ -202,16 +204,71 @@ def test_the_four_cells_select_the_metrics_they_selected_before_pr_33(cell):
     contract.pr_32_selection(cell)
 
 
-@pytest.mark.parametrize("where", ["appended_at_the_end", "put_before_the_nodes"])
-def test_a_sixth_cell_breaks_no_accepted_cells_contract(tmp_path, where):
-    """What a later ``model_config`` PR does, on a copy (PR 43): a
-    configuration (``ecdsa-100v`` under a second name), a cell on the mix
-    ``flood``, a per-layer entry of its own, its name on ``finalize_p50_ms`` /
-    ``finalize_p90_ms``'s lists and on the shared reader ``flushes_per_height``'s.
+CELL_TEST_FILES = sorted(glob.glob(os.path.join(os.path.dirname(os.path.abspath(__file__)), "test_cell_*.py")))
+
+
+def _cell_test_module(path):
+    """A cell's own test file, as pytest imported it (this directory is on
+    ``sys.path``: the files import each other by bare name)."""
+    return importlib.import_module(os.path.splitext(os.path.basename(path))[0])
+
+
+@pytest.mark.parametrize("path", CELL_TEST_FILES, ids=os.path.basename)
+def test_every_cells_own_test_file_says_what_it_holds_of_benchmark_json_through_declared(path):
+    """``declared(root=ROOT)``: all the file asserts of ``BENCHMARK.json``, so
+    that the copy test below can hold it to a tree with a further cell in it.
+    A later ``model_config`` PR's new ``test_cell_*.py`` is found by the glob."""
+    fn = getattr(_cell_test_module(path), "declared", None)
+    assert callable(fn), f"{os.path.basename(path)} has no declared(root)"
+    root = inspect.signature(fn).parameters.get("root")
+    assert root is not None and root.default == ROOT
+    fn()  # and it holds of this checkout
+
+
+# A further cell on either side, as a later ``model_config`` PR would bring it:
+# the mix it runs, the lists it joins, the metric file its own per-layer entry
+# copies, the accepted cell whose entries ``put_before`` puts it in front of
+# (by configuration, cell and first per-layer entry), and what it must select.
+FURTHER = {
+    "flood": {
+        "traffic": "flood",
+        "joins": ["finalize_p50_ms", "finalize_p90_ms", "flushes_per_height"],
+        "own": ("node_flushes_per_height", "again_flushes_per_height"),
+        "before": ("ecdsa-100v-node", contract.NODE, "node_msgs_per_flush"),
+        "selects": {
+            "end_to_end": ["finalize_p50_ms", "finalize_p90_ms", "setup_s"],
+            "per_layer": ["again_flushes_per_height", "compiles_in_window", "flushes_per_height", "warm_s"],
+        },
+    },
+    "sync": {
+        "traffic": "sync",
+        "joins": ["sync_sigs_per_s"] + contract.JOINED_BY_PR_45,
+        "own": ("sync_host_ms_per_call", "again_host_ms_per_call"),
+        "before": ("ecdsa-100v-pos", contract.POS_SYNC, "pos_drains_per_call"),
+        "selects": {
+            "end_to_end": ["setup_s", "sync_sigs_per_s"],
+            "per_layer": sorted(
+                ["again_host_ms_per_call", "compiles_in_window", "warm_s"] + contract.JOINED_BY_PR_45
+            ),
+        },
+    },
+}
+
+
+@pytest.mark.parametrize("where", ["appended_at_the_end", "put_before_an_accepted_cells"])
+@pytest.mark.parametrize("side", sorted(FURTHER))
+def test_a_further_cell_breaks_no_accepted_cells_contract(tmp_path, side, where):
+    """What a later ``model_config`` PR does, on a copy (PR 43; both sides
+    since PR 50): a configuration (``ecdsa-100v`` under a second name), a cell
+    on the mix ``flood`` or ``sync``, a per-layer entry of its own, its name on
+    the lists of the end-to-end metrics and shared readers of its side (``sync``:
+    ``sync_sigs_per_s``, ``ecdsa_recover_roofline`` and every ``sync_*`` reader).
     Appended at the END of every list, as the benchmark's check wants new
-    entries, or put immediately before the node's, as ISSUE 42 first asked:
-    every accepted cell's contract holds either way, and each of the five
-    cells selects exactly what it selects today."""
+    entries, or put immediately before an accepted cell's (the node's as ISSUE
+    42 first asked; PR 45's): every accepted cell's contract holds either way,
+    what every ``test_cell_*.py`` declares holds, and each accepted cell
+    selects exactly what it selects in the checkout."""
+    spec = FURTHER[side]
     root = tmp_path / "copy"
     shutil.copytree(HERE, root / "benchmark", ignore=shutil.ignore_patterns("__pycache__"))
     bench = contract.bench_of()
@@ -219,46 +276,51 @@ def test_a_sixth_cell_breaks_no_accepted_cells_contract(tmp_path, where):
     cfg["name"] = "ecdsa-100v-again"
     with open(root / "benchmark/configs/ecdsa-100v-again.json", "w") as fh:
         json.dump(cfg, fh)
-    metric = _load(os.path.join(HERE, "layer_metrics", "node_flushes_per_height.json"))
-    metric["name"] = "again_flushes_per_height"
-    with open(root / "benchmark/layer_metrics/again_flushes_per_height.json", "w") as fh:
+    copied, own_name = spec["own"]
+    metric = _load(os.path.join(HERE, "layer_metrics", copied + ".json"))
+    metric["name"] = own_name
+    with open(root / f"benchmark/layer_metrics/{own_name}.json", "w") as fh:
         json.dump(metric, fh)
-    sixth = "ecdsa-100v-again.flood"
+    further = f"ecdsa-100v-again.{spec['traffic']}"
+    before_config, before_cell, before_metric = spec["before"]
 
     def add(entries: list, entry, before) -> None:
         """``entry`` at the end, or before the first entry ``before`` names."""
         names = [e["name"] if isinstance(e, dict) else e for e in entries]
-        at = len(entries) if where == "appended_at_the_end" else names.index(before)
+        at = len(entries) if where == "appended_at_the_end" or before not in names else names.index(before)
         entries.insert(at, entry)
 
     config_entry = dict(bench["configs"][0], name=cfg["name"], file="benchmark/configs/ecdsa-100v-again.json")
-    add(bench["configs"], config_entry, "ecdsa-100v-node")
-    cell = {"name": sixth, "config": cfg["name"], "traffic": "flood", "chips": 1, "why": "x"}
-    add(bench["workloads"], cell, contract.NODE)
+    add(bench["configs"], config_entry, before_config)
+    cell = {"name": further, "config": cfg["name"], "traffic": spec["traffic"], "chips": 1, "why": "x"}
+    add(bench["workloads"], cell, before_cell)
     own = {k: metric[k] for k in ("name", "unit", "better", "source", "layer", "moves")}
-    add(bench["per_layer"], dict(own, workloads=[sixth]), "node_msgs_per_flush")
+    add(bench["per_layer"], dict(own, workloads=[further]), before_metric)
     listed = {m["name"]: m for m in bench["end_to_end"] + bench["per_layer"]}
-    for name in ("finalize_p50_ms", "finalize_p90_ms"):
-        add(listed[name]["workloads"], sixth, contract.NODE)
-    add(listed["flushes_per_height"]["workloads"], sixth, contract.FLOOD_300V)
+    for name in spec["joins"]:
+        # ``flushes_per_height`` never listed the node: there the end is the only place.
+        add(listed[name]["workloads"], further, before_cell)
     with open(root / "BENCHMARK.json", "w") as fh:
         json.dump(bench, fh)
 
     contract.every_accepted_cell(str(root))
+    for path in CELL_TEST_FILES:
+        _cell_test_module(path).declared(str(root))
     for w in contract.bench_of()["workloads"]:
         assert contract.selection(w["name"], str(root)) == contract.selection(w["name"]), w["name"]
-    assert contract.selection(sixth, str(root)) == {
-        "end_to_end": ["finalize_p50_ms", "finalize_p90_ms", "setup_s"],
-        "per_layer": ["again_flushes_per_height", "compiles_in_window", "flushes_per_height", "warm_s"],
-    }
-    # ... and the predicates can fail: the node's cell gone, or one of its metrics
-    # handed to a library cell, is refused.
-    broken = json.loads(json.dumps(bench))
-    next(m for m in broken["per_layer"] if m["name"] == "node_msgs_per_flush")["workloads"].append(sixth)
-    with open(root / "BENCHMARK.json", "w") as fh:
-        json.dump(broken, fh)
-    with pytest.raises(AssertionError):
-        contract.node(str(root))
+    assert contract.selection(further, str(root)) == spec["selects"]
+    # ... and the predicates can fail: one of the node's metrics handed to the
+    # further cell, or one of the PoS cell's handed to ``ecdsa-100v.sync``, is refused.
+    for name, to, predicate in (
+        ("node_msgs_per_flush", further, contract.node),
+        ("pos_drains_per_call", "ecdsa-100v.sync", contract.pos_sync),
+    ):
+        broken = json.loads(json.dumps(bench))
+        next(m for m in broken["per_layer"] if m["name"] == name)["workloads"].append(to)
+        with open(root / "BENCHMARK.json", "w") as fh:
+            json.dump(broken, fh)
+        with pytest.raises(AssertionError):
+            predicate(str(root))
 
 
 def test_peaks_table_is_keyed_by_device_kind_and_refuses_the_unknown():

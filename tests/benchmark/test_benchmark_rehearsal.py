@@ -64,7 +64,8 @@ def _run(monkeypatch, workload: str, trace: int, seconds: float, validators=4, l
             rc = bench_run.run(args)
     finally:
         gc.unfreeze()
-        shutil.rmtree(os.path.join(ROOT, ".cache", "benchmark_trace"), ignore_errors=True)
+        # This run's trace and no other worker's: another file may be mid-trace.
+        shutil.rmtree(os.path.join(ROOT, ".cache", "benchmark_trace", workload), ignore_errors=True)
     lines = [json.loads(line) for line in out.getvalue().splitlines() if line.startswith("{")]
     return rc, lines
 

@@ -472,6 +472,7 @@ class CoalescedDispatcher:
                 route="mesh" if sharded else "device",
                 # the sharded program keeps the scan (ops/pallas_ladder.py)
                 ladder="scan" if sharded else vbatch.ladder_of(live.shape[0]),
+                table_rows=table.shape[0],
             ):
                 launched = kernel(zw, r, s, v, claimed, table, live)
             mask = np.asarray(launched)

@@ -1378,7 +1378,8 @@ class DeviceBatchVerifier:
         (``zw`` the digest program's device rows in a sender drain), handed
         to the compiled call as they are, like ``table`` (device-resident,
         a validator set's); ``operands`` on the span counts the host arrays
-        among ``inputs``.  Nothing may write to one of them from here on.
+        among ``inputs``, ``table_rows`` is the table rung the executable was
+        built for.  Nothing may write to one of them from here on.
         Returns the mask as a device future WITHOUT blocking — JAX async
         dispatch lets the caller pack the next batch while this one
         executes (:mod:`go_ibft_tpu.verify.pipeline`).
@@ -1397,6 +1398,7 @@ class DeviceBatchVerifier:
                 route="device",
                 operands=_host_arrays(inputs),
                 ladder=ladder_of(live.shape[0]),
+                table_rows=table.shape[0],
             ):
                 return _recover_kernel(zw, r, s, v, claimed, table, live)
 

@@ -247,7 +247,27 @@ def test_a_host_dispatch_hands_nothing_over(signed_round, recorder):
         signed_round.prepares[:HOST_LANES]
     )
     (dispatch,) = [r[5] for r in recorder.snapshot() if r[1] == "verify.dispatch"]
-    assert "operands" not in dispatch
+    assert "operands" not in dispatch and "table_rows" not in dispatch
+
+
+@pytest.mark.parametrize("validators, rung", [(100, 128), (1000, 2048)])
+def test_a_device_dispatch_says_which_table_rung_its_executable_was_built_for(
+    validators, rung, signed_round, stub_kernels, recorder
+):
+    """ISSUE 51: ``table_rows`` on the device route's ``verify.dispatch`` is
+    the rung of the validator table the launched executable was built for
+    (``table.shape[0]``), not the set's size, which ``chain.sync.verify``
+    carries: 128 rows for a 100-validator source, 2,048, the last rung, for a
+    1,000-validator one (the kernel stubbed: no ladder compiles)."""
+    members = {m.sender: 1 for m in signed_round.prepares}
+    for i in range(validators - len(members)):
+        members[b"rung" + i.to_bytes(16, "big")] = 1
+    assert len(members) == validators
+    verifier = DeviceBatchVerifier(ECDSABackend.static_validators(members))
+    assert _call(verifier, "verify_seal_lanes", signed_round, N_VALIDATORS).all()
+    (dispatch,) = [r[5] for r in recorder.snapshot() if r[1] == "verify.dispatch"]
+    assert dispatch["route"] == "device" and dispatch["table_rows"] == rung
+    assert set(dispatch) == {"route", "operands", "ladder", "table_rows"}
 
 
 @pytest.mark.parametrize("entry", list(ENTRY_POINTS))

@@ -1,4 +1,5 @@
-"""The GLV ladder's 33 steps as ONE Pallas TPU kernel.
+"""The recover program's Pallas TPU kernels: the GLV ladder's 33 steps as
+ONE kernel, and each fixed-exponent Fermat chain as one.
 
 ``secp256k1.ecmul2_base`` runs its ladder as a ``lax.scan`` whose body XLA
 cuts into some hundred fusions a step, every field product written to memory
@@ -12,6 +13,16 @@ int32 limbs, same carry schedule (:func:`fields._fold_schedule`, derived from
 limb bounds: nothing here restates its numbers), so the accumulator that
 leaves the kernel equals the scan's limb for limb.
 
+**The powers** (:func:`fixed_powers`).  With the ladder in a kernel the two
+``while`` loops left were most of a dispatch: ``recover.lift_x``'s square
+root mod P and ``r**-1`` mod N (``fields.pow_fixed2``) and
+``recover.to_affine``'s inversion, 329 products a chain, each a handful of
+fusions with a ``moveaxis`` into and out of it.  :func:`power_call` runs such
+chains whole: a tile's accumulators and 16-entry window tables stay in VMEM
+for all 63 windows, the public digit indexes the table's leading axis (no
+select tree), two chains advance in the same iteration.  Same table build,
+same windows, same schedule: limb for limb ``pow_fixed2``'s accumulators.
+
 **Limbs lead.**  A field element is ``(..., 20, 8, 128)``: one vector
 register a limb, the limb axis third from last, any axes before it a stack
 of independent elements.  Every op is a ``jax.lax`` primitive on such slabs:
@@ -22,30 +33,31 @@ zeros is folded by it.  The point formulas are LEVELLED for the same reason:
 products that do not depend on each other are stacked and multiplied by one
 call (the doubling's 7 products are 3 calls, the add's 16 are 6), and the
 four doublings are a ``fori_loop`` of one.  What this buys is set-up, not
-speed: a process traces this body in Python and lowers it to Mosaic MLIR
+speed: a process traces these bodies in Python and lowers them to Mosaic MLIR
 before the compile cache's key exists, so those seconds are paid by every
 process, cache or no cache (PR 47's register-by-register body cost six of
-them and was refused for it; this one is some 1,500 equations;
-``scripts/setup_budget.py`` measures them, ``tests/test_pallas_ladder.py``
-holds the count).
+them and was refused for it; the ladder's is some 1,500 equations, the
+powers' 900 and 300; ``scripts/setup_budget.py`` measures them,
+``tests/test_pallas_ladder.py`` holds the counts).
 
 **Two paths, chosen by what the code can see** (:func:`kind`): the ladder
-batch's shape, the backend, and whether a mapped axis is in scope.  Mosaic
-does not run on XLA:CPU and a ``(4, 128)`` batch is half a tile whatever
-holds it, so the scan stays for the unfolded programs (8, 32 and 128 lanes
-lower to the text they always did) and for every backend that is not a TPU,
-and it stays the reference this kernel is checked against
-(``tests/test_pallas_ladder.py``; on the chip ``tests/test_secp256k1.py``).
-Under ``shard_map`` the scan stays as well: no four-chip run has checked the
-kernel there (``PERF.md`` section 7).
+batch's shape, the backend, and whether a mapped axis is in scope; one rule
+for the ladder and the powers.  Mosaic does not run on XLA:CPU and a
+``(4, 128)`` batch is half a tile whatever holds it, so the scans stay for
+the unfolded programs (8, 32 and 128 lanes lower to the text they always did)
+and for every backend that is not a TPU, and they stay the reference these
+kernels are checked against (``tests/test_pallas_ladder.py``; on the chip
+``tests/test_secp256k1.py``).  Under ``shard_map`` the scans stay as well: no
+four-chip run has checked the kernels there (``PERF.md`` section 7).
 
-``jax.experimental.pallas`` is imported where the kernel is first traced,
+``jax.experimental.pallas`` is imported where a kernel is first traced,
 not with this module.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 import sys
 from typing import Sequence, Tuple
 
@@ -57,7 +69,7 @@ from jax import lax
 from . import fields
 from .fields import LIMB_BITS, LIMB_MASK, Modulus
 
-__all__ = ["kind", "glv_ladder", "PALLAS", "SCAN"]
+__all__ = ["kind", "glv_ladder", "fixed_powers", "PALLAS", "SCAN"]
 
 PALLAS, SCAN = "pallas", "scan"
 _TILE = (8, 128)  # one int32 vector register
@@ -74,8 +86,9 @@ def kind(lanes: int) -> str:
     """Which ladder a program of ``lanes`` signatures runs: ``"pallas"``
     where the ladder batch folds to whole ``(8, 128)`` tiles, the backend is
     a TPU and no mapped axis is in scope, else ``"scan"``.  The one rule:
-    ``secp256k1.ecmul2_base`` traces by it, and the verifiers' dispatch
-    spans report it."""
+    ``secp256k1.ecmul2_base`` traces by it, ``ecdsa_recover`` and
+    ``to_affine`` choose their Fermat chains by it, and the verifiers'
+    dispatch spans report it."""
     from jax._src import core  # no public reader of the axis environment
 
     mapped = bool(core.get_axis_env().axis_sizes)
@@ -445,3 +458,97 @@ def glv_ladder(digits, neg, tx, ty, tz, *, field: Modulus):
         field=field,
     )
     return tuple(jnp.moveaxis(acc[i], 0, -1) for i in range(3))
+
+
+# ---------------------------------------------------------------------------
+# Fixed-exponent powers: ``fields.pow_fixed`` / ``pow_fixed2`` (the square
+# root and r**-1 of ``recover.lift_x``, the inversion of
+# ``recover.to_affine``), a whole chain in one kernel.
+# ---------------------------------------------------------------------------
+
+
+def _power_kernel(moduli, nwin, digits_ref, a_ref, out_ref, table_ref):
+    """``a_ref[c] ** e_c mod moduli[c]`` for every chain ``c`` of a tile, the
+    way ``fields.pow_fixed2`` goes: the window table ``a**0 .. a**15`` by 14
+    products, then a window at a time four squarings and one product by the
+    table's entry, every chain advanced in the same iteration.  The exponents
+    are public and one for all lanes: ``digits_ref`` (SMEM, chain ``c``'s
+    ``nwin`` digits from ``c * nwin``, most significant first) indexes the
+    leading axis of the chain's table in VMEM."""
+    chains = range(len(moduli))
+    fs = [SlabField(m) for m in moduli]
+
+    def entry(c, i):
+        return table_ref[c * 16 + digits_ref[c * nwin + i]]
+
+    def build(d, prev):
+        nxt = tuple(fs[c].mul(prev[c], a_ref[c]) for c in chains)
+        for c in chains:
+            table_ref[c * 16 + d] = nxt[c]
+        return nxt
+
+    def window(i, accs):
+        accs = lax.fori_loop(
+            0, 4, lambda _, q: tuple(fs[c].sqr(q[c]) for c in chains), accs
+        )
+        return tuple(fs[c].mul(accs[c], entry(c, i)) for c in chains)
+
+    for c in chains:
+        table_ref[c * 16] = fs[c].const(1)
+        table_ref[c * 16 + 1] = a_ref[c]
+    lax.fori_loop(2, 16, build, tuple(a_ref[c] for c in chains))
+    accs = lax.fori_loop(1, nwin, window, tuple(entry(c, 0) for c in chains))
+    for c in chains:
+        out_ref[c] = accs[c]
+
+
+def power_call(a, *, moduli: Sequence[Modulus], exponents: Sequence[int], name: str, interpret: bool = False):
+    """The kernel on limb-leading operands: ``a`` ``(chains, L, rows, 128)``,
+    chain ``c`` an element mod ``moduli[c]`` raised to the public
+    ``exponents[c]`` (4-bit windows, most significant first, the shorter
+    exponent padded with leading zero digits as ``pow_fixed2`` pads it);
+    returns the powers, shaped as ``a``.  One grid step a row tile, a tile's
+    ``16 * chains`` table entries (1.3 MB a chain) in VMEM scratch."""
+    if min(exponents) <= 0:
+        raise ValueError("power_call requires positive exponents")
+    pl, pltpu = _pallas()
+    chains, L, rows, lane = a.shape
+    sub = _TILE[0]
+    nwin = max(-(-e.bit_length() // 4) for e in exponents)
+    digits = np.concatenate([fields._pow_digits(e, nwin) for e in exponents])
+    block = pl.BlockSpec((chains, L, sub, lane), lambda i, _: (0, 0, i, 0))
+    return pl.pallas_call(
+        functools.partial(_power_kernel, tuple(moduli), nwin),
+        name=name,
+        out_shape=jax.ShapeDtypeStruct(a.shape, jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows // sub,),
+            in_specs=[block],
+            out_specs=block,
+            scratch_shapes=[pltpu.VMEM((16 * chains, L, sub, lane), jnp.int32)],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM_LIMIT
+        ),
+        interpret=interpret,
+    )(digits, a)
+
+
+def fixed_powers(name: str, *chains: Tuple[Modulus, jnp.ndarray, int]):
+    """``fields.pow_fixed2`` of every lane as one kernel called ``name``:
+    each chain is ``(modulus, a, exponent)``, the ``a`` of one shape
+    ``batch + (L,)``; returns each ``a ** exponent``, semi-reduced and limb
+    for limb what the scan gives (one chain: ``fields.pow_fixed``'s).  The
+    lanes are padded with zeros (``0 ** e == 0``) to whole ``(8, 128)`` tiles
+    and the limb axis leads inside; both relayouts are made here, once."""
+    moduli, elements, exponents = zip(*chains)
+    batch, L = elements[0].shape[:-1], elements[0].shape[-1]
+    lanes = math.prod(batch)
+    per_tile = _TILE[0] * _TILE[1]
+    a = jnp.stack([e.reshape(lanes, L) for e in elements])
+    a = jnp.pad(a, ((0, 0), (0, -lanes % per_tile), (0, 0)))
+    a = jnp.moveaxis(a.reshape(len(chains), -1, _TILE[1], L), -1, 1)
+    out = power_call(a, moduli=moduli, exponents=exponents, name=name)
+    out = jnp.moveaxis(out, 1, -1).reshape(len(chains), -1, L)[:, :lanes]
+    return tuple(out[c].reshape(batch + (L,)) for c in range(len(chains)))

@@ -184,9 +184,19 @@ def _inv_lanes(m: fields.Modulus, a: jnp.ndarray) -> jnp.ndarray:
 
 @jax.jit
 def to_affine(p: JacobianPoint) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Canonical affine ``(x, y)``; infinity maps to ``(0, 0)``."""
+    """Canonical affine ``(x, y)``; infinity maps to ``(0, 0)``.
+
+    Where :func:`pallas_ladder.kind` says ``pallas`` every lane is inverted
+    by its own Fermat chain in one kernel (``to_affine_inv``): the product
+    tree amortizes a scan that costs a millisecond whatever its width, and a
+    329-product chain on a whole tile costs what the root's alone would.
+    The coordinates are ``canon``ed, so both paths give the same bits."""
     f = FIELD
-    zinv = _inv_lanes(f, p.z)  # inv(0) == 0, so infinity folds to (0, 0)
+    # inv(0) == 0 either way (0 ** (P - 2)), so infinity folds to (0, 0)
+    if pallas_ladder.kind(math.prod(p.z.shape[:-1])) == pallas_ladder.PALLAS:
+        (zinv,) = pallas_ladder.fixed_powers("to_affine_inv", (f, p.z, P - 2))
+    else:
+        zinv = _inv_lanes(f, p.z)
     zi2 = fields.sqr(f, zinv)
     x = fields.mul(f, p.x, zi2)
     y = fields.mul(f, p.y, fields.mul(f, zi2, zinv))
@@ -802,7 +812,14 @@ def ecdsa_recover(
         # ride ONE merged scan — two sequential ~64-window chains would double
         # the pre-ladder latency (fields.pow_fixed2).
         y2 = fields.add(f, fields.mul(f, fields.sqr(f, x), x), jnp.asarray(f.const(7)))
-        y, rinv = fields.pow_fixed2(f, y2, _SQRT_EXP, ORDER, x, N - 2)
+        # Where the ladder is the Pallas kernel the two chains are one too
+        # (``lift_x_pow``), limb for limb the scan's: one rule, ``kind``.
+        if pallas_ladder.kind(math.prod(x.shape[:-1])) == pallas_ladder.PALLAS:
+            y, rinv = pallas_ladder.fixed_powers(
+                "lift_x_pow", (f, y2, _SQRT_EXP), (ORDER, x, N - 2)
+            )
+        else:
+            y, rinv = fields.pow_fixed2(f, y2, _SQRT_EXP, ORDER, x, N - 2)
         # r was a valid x-coord: (x, y_sel) is on the curve exactly where this
         # holds, which is what ecmul2_base's incomplete ladder add asks for.
         ok = ok & fields.eq_mod(f, fields.sqr(f, y), y2)

@@ -207,8 +207,9 @@ EAGER_PUTS_KEY = ("go-ibft", "dispatch", "eager_puts")
 # distinct union of sets, block sync), whatever heights select it.
 TABLE_UPLOADS_KEY = ("go-ibft", "verify", "table_uploads")
 # Launches of a program whose ladder is the Pallas kernel
-# (``ops/pallas_ladder.py``); the ``verify.dispatch`` span of every device
-# launch says which ladder its program has (``ladder``: "pallas" | "scan").
+# (``ops/pallas_ladder.py``; its two Fermat chains are kernels by the same
+# rule); the ``verify.dispatch`` span of every device launch says which
+# ladder its program has (``ladder``: "pallas" | "scan").
 PALLAS_LADDER_DISPATCHES_KEY = ("go-ibft", "kernel", "pallas_ladder_dispatches")
 # Seal lanes of a sync drain whose signature held and whose signer is not in
 # the validator set of the lane's OWN height (though it may sit in a

@@ -18,8 +18,10 @@ Each child prints one JSON line: ``import_s``, ``trace_s``, ``lower_s``,
 ``compile_s`` (``how`` says ``compiled`` or ``cache_load``, from
 ``jax.monitoring``), ``dispatch_s`` (first call with zero rows, read back),
 ``hlo_lines`` / ``hlo_bytes`` of the lowered text, ``tpu_custom_calls`` in it
-and ``platform``.  ``events`` holds jax's own durations by name (they count the
-same seconds from inside; ``jaxpr_trace_duration`` is one entry a jitted
+and ``platform``; ``kernels`` holds, for each Pallas body of a folded program
+(``glv_ladder``, ``lift_x_pow``, ``to_affine_inv``) jitted alone, ``trace_s``,
+``lower_s`` and ``compile_s``.  ``events`` holds jax's own durations by name
+(they count the same seconds from inside; ``jaxpr_trace_duration`` is one entry a jitted
 function, nested ones included).
 
 With a TPU the program is lowered, compiled and run there.  Without one
@@ -110,7 +112,57 @@ def _child(lanes: int, compile_it: bool) -> dict:
             np.asarray(compiled(*rows))
             out["dispatch_s"] = time.perf_counter() - t
     out["events"] = {name.rsplit("/", 1)[1]: secs for name, secs in events.items()}
+    out["kernels"] = _kernels(lanes, shapes[0].sharding, on_chip, compile_it)
     out["total_s"] = time.perf_counter() - _T0
+    return out
+
+
+def _kernels(lanes: int, sharding, on_chip: bool, compile_it: bool) -> dict:
+    """The program's Pallas bodies one by one, each jitted alone on operands
+    of the program's shapes: seconds to trace, to lower (Mosaic MLIR) and to
+    compile (a second child finds them in the compile cache, as it finds the
+    program).  Read after the program, so Pallas is imported."""
+    import jax
+    import jax.numpy as jnp
+
+    from go_ibft_tpu.ops import pallas_ladder as pk
+    from go_ibft_tpu.ops import secp256k1 as sec
+
+    if lanes % 256:
+        return {}
+
+    def s(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
+
+    L, ladder_rows, power_rows = sec.FIELD.nlimbs, lanes // 32, 8 * -(-lanes // 1024)
+
+    def power(name, *chains):
+        moduli, exponents = zip(*chains)
+        call = lambda a: pk.power_call(a, moduli=moduli, exponents=exponents, name=name)  # noqa: E731
+        return call, (s(len(chains), L, power_rows, 128),)
+
+    calls = {
+        "glv_ladder": (
+            lambda *a: pk.ladder_call(*a, field=sec.FIELD),
+            (s(33, ladder_rows, 128), s(ladder_rows, 128)) + (s(16, L, ladder_rows, 128),) * 3,
+        ),
+        "lift_x_pow": power("lift_x_pow", (sec.FIELD, sec._SQRT_EXP), (sec.ORDER, sec.N - 2)),
+        "to_affine_inv": power("to_affine_inv", (sec.FIELD, sec.P - 2)),
+    }
+    out = {}
+    for name, (call, shapes) in calls.items():
+        t = time.perf_counter()
+        traced = jax.jit(call).trace(*shapes)
+        row = {"trace_s": time.perf_counter() - t}
+        t = time.perf_counter()
+        lowered = traced.lower() if on_chip else traced.lower(lowering_platforms=("tpu",))
+        row["lower_s"] = time.perf_counter() - t
+        row["compile_s"] = None
+        if compile_it:
+            t = time.perf_counter()
+            lowered.compile()
+            row["compile_s"] = time.perf_counter() - t
+        out[name] = row
     return out
 
 

@@ -1,6 +1,6 @@
 """Readers of the folded recover program as a TPU process traces it, shared
 by ``test_pallas_ladder.py`` and ``test_kernel_stages.py``: the jaxpr, the
-Pallas kernel's body inside it, and the module lowered for the TPU platform
+Pallas kernels' bodies inside it, and the module lowered for the TPU platform
 (Mosaic lowering included; no chip, nothing compiles).
 """
 
@@ -76,6 +76,20 @@ class Lowered:
         lowered = traced.lower(lowering_platforms=("tpu",))
         self.text = lowered.as_text(debug_info=True)
         self.bare_text = lowered.as_text()
-        self.kernels = [
-            e.params["jaxpr"] for e in eqns_of(self.jaxpr) if e.primitive.name == "pallas_call"
-        ]
+        # A Pallas body by its kernel's name, and where its call stands: the
+        # name stack down to it through every nested jit, which is the
+        # ``op_name`` XLA gives the custom call and ``stage_reduce`` reads.
+        self.kernels, self.kernel_scopes = {}, {}
+        for scope, e in _scoped_eqns(self.jaxpr):
+            if e.primitive.name == "pallas_call":
+                name = e.params["name"]
+                self.kernels[name], self.kernel_scopes[name] = e.params["jaxpr"], scope
+
+
+def _scoped_eqns(jaxpr, outer=""):
+    for eqn in jaxpr.eqns:
+        scope = "/".join(filter(None, (outer, str(eqn.source_info.name_stack))))
+        yield scope, eqn
+        if eqn.primitive.name != "pallas_call":
+            for inner in sub_jaxprs(eqn):
+                yield from _scoped_eqns(inner, scope)

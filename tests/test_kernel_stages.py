@@ -128,18 +128,23 @@ def test_ecdsa_verify_is_not_scoped_beyond_ecmul2_base(verify_text):
 
 
 def test_all_nine_scope_names_reach_the_folded_program_on_the_kernel_path():
-    """The other path (PR 48): where the ladder is the Pallas kernel the nine
-    scopes are still in the program, and the kernel's custom call is named
-    under ``recover.glv_ladder``, which is how ``stage_reduce`` charges it to
-    the ladder stage.  Lowered for the TPU platform, no chip (last in the
-    file: it drops the trace caches)."""
+    """The other path (PR 48, PR 52): where the ladder and the two Fermat
+    chains are Pallas kernels the nine scopes are still in the program, and
+    each kernel's custom call stands under its stage's scope (down through
+    ``to_affine``'s nested jit), which is how ``stage_reduce`` charges it to
+    the stage.  Lowered for the TPU platform, no chip (last in the file: it
+    drops the trace caches)."""
     import pallas_cases as pc
 
     with pc.traced_for_tpu():
-        text = pc.Lowered(256).text
-    locs = [line for line in text.splitlines() if line.startswith("#loc")]
+        lowered = pc.Lowered(256)
+    locs = [line for line in lowered.text.splitlines() if line.startswith("#loc")]
     seen = collections.Counter(sr.stage_of(m) for line in locs for m in re.findall(r'loc\("([^"]+)"', line))
     for stage in sr.STAGES:
         assert seen[stage] > 0, stage
-    (call,) = [line for line in locs if "pallas_call" in line]
-    assert sr.stage_of(re.search(r'loc\("([^"]+)"', call).group(1)) == "recover.glv_ladder"
+    assert len([line for line in locs if "pallas_call" in line]) == 3
+    assert {k: sr.stage_of(scope) for k, scope in lowered.kernel_scopes.items()} == {
+        "lift_x_pow": "recover.lift_x",
+        "glv_ladder": "recover.glv_ladder",
+        "to_affine_inv": "recover.to_affine",
+    }

@@ -188,6 +188,102 @@ def test_two_kernel_steps_against_integers(tile, stepped):
     assert len(seen) == 4 and want.count(None) > 0  # infinity met, and left
 
 
+# ---- the fixed-exponent powers (``lift_x_pow``, ``to_affine_inv``) -----------
+#
+# The body keeps its window table in a VMEM scratch it indexes by the public
+# digit, so "plain jax" is the real ``pallas_call`` in interpret mode: the
+# same jaxpr on XLA:CPU, a whole tile at the three real exponents.
+
+N = sec.N
+SQRT, RINV, ZINV = (sec.FIELD, sec._SQRT_EXP), (sec.ORDER, N - 2), (sec.FIELD, P - 2)
+
+
+def _elements(m: F.Modulus, count: int, seed: int):
+    """Semi-reduced elements mod ``m`` as integers and ``(count, L)`` limbs,
+    loose limbs among them: the modulus's edges, then random ones."""
+    rng = random.Random(seed)
+    edges = [0, 1, 2, m.p - 1, m.p, m.p + 1, 2 * m.p - 1, (1 << 256) - 1]
+    vals = (edges + [rng.randrange(2 * m.p) for _ in range(count)])[:count]
+    return vals, jnp.asarray(np.stack([_loose(v, rng) for v in vals]))
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """``fixed_powers`` through the real ``pallas_call``, interpreted."""
+    import functools
+
+    monkeypatch.setattr(pk, "power_call", functools.partial(pk.power_call, interpret=True))
+
+
+POWER_CASES = {
+    "sqrt": ("lift_x_pow", (SQRT,)),
+    "r_inverse": ("lift_x_pow", (RINV,)),
+    "z_inverse": ("to_affine_inv", (ZINV,)),
+    "sqrt_and_r_inverse": ("lift_x_pow", (SQRT, RINV)),
+}
+
+
+@pytest.mark.parametrize("case", POWER_CASES)
+def test_power_kernel_at_the_real_exponents_against_integers_and_the_scan(interpreted, case):
+    """A whole tile, every chain's accumulator limb for limb what
+    ``fields.pow_fixed`` (one chain) / ``pow_fixed2`` (two) leaves."""
+    name, chains = POWER_CASES[case]
+    vals, limbs = zip(*[_elements(m, B, seed) for seed, (m, _) in enumerate(chains)])
+    got = pk.fixed_powers(name, *[(m, a, e) for (m, e), a in zip(chains, limbs)])
+    if len(chains) == 1:
+        want = (jax.jit(lambda a: F.pow_fixed(chains[0][0], a, chains[0][1]))(*limbs),)
+    else:
+        (m1, e1), (m2, e2) = chains
+        want = jax.jit(lambda a, b: F.pow_fixed2(m1, a, e1, m2, b, e2))(*limbs)
+    for (m, e), g, w, v in zip(chains, got, want, vals):
+        g = np.asarray(g)
+        assert g.min() >= 0 and g.max() <= 1 << 13
+        assert np.array_equal(g, np.asarray(w))
+        assert [x % m.p for x in F.from_limbs(g)] == [pow(x, e, m.p) for x in v]
+
+
+SHORT = 0xA07  # three windows, a zero digit among them
+
+
+@pytest.mark.parametrize("shape", [(300,), (2, 640), (1,), (1024,)], ids=str)
+def test_fixed_powers_pads_any_batch_to_whole_tiles_and_slices_it_back(interpreted, shape):
+    lanes = int(np.prod(shape))
+    (av, a), (bv, b) = _elements(sec.FIELD, lanes, 3), _elements(sec.ORDER, lanes, 4)
+    got = pk.fixed_powers(
+        "lift_x_pow", (sec.FIELD, a.reshape(shape + (L,)), SHORT), (sec.ORDER, b.reshape(shape + (L,)), SHORT + 1)
+    )
+    want = F.pow_fixed2(sec.FIELD, a, SHORT, sec.ORDER, b, SHORT + 1)
+    for g, w in zip(got, want):
+        assert g.shape == shape + (L,)
+        assert np.array_equal(np.asarray(g).reshape(lanes, L), np.asarray(w))
+
+
+def test_zero_goes_to_zero_and_a_padded_tile_holds_zeros_in_its_pad():
+    """One tile and a half of lanes, as ``fixed_powers`` pads them: the pad
+    lanes are zeros going in, and ``0 ** e`` is the canonical zero coming out
+    (which is how ``to_affine`` sends infinity to ``(0, 0)``)."""
+    lanes = B + B // 2
+    vals, limbs = _elements(sec.FIELD, lanes, 5)
+    a = jnp.pad(limbs, ((0, 2 * B - lanes), (0, 0)))
+    a = jnp.moveaxis(a.reshape(1, 2 * ROWS, LANES, L), -1, 1)
+    out = pk.power_call(a, moduli=(sec.FIELD,), exponents=(SHORT,), name="to_affine_inv", interpret=True)
+    got = np.moveaxis(np.asarray(out), 1, -1).reshape(2 * B, L)
+    assert not got[lanes:].any() and not got[0].any() and vals[0] == 0
+    assert [g % P for g in F.from_limbs(got[:lanes])] == [pow(v, SHORT, P) for v in vals]
+
+
+@pytest.mark.parametrize("lanes", (256, 1024))
+def test_per_lane_inversion_is_the_product_trees_after_canon(interpreted, lanes):
+    """``to_affine``'s two inversions, the kernel's in every lane and
+    ``fields.batch_inv``'s tree: the same canonical limbs, ``inv(0) == 0``."""
+    vals, z = _elements(sec.FIELD, lanes, 6)
+    (got,) = pk.fixed_powers("to_affine_inv", (sec.FIELD, z, P - 2))
+    canon = jax.jit(lambda a: F.canon(sec.FIELD, a))
+    got, want = np.asarray(canon(got)), np.asarray(canon(F.batch_inv(sec.FIELD, z)))
+    assert np.array_equal(got, want)
+    assert F.from_limbs(got) == [pow(v, P - 2, P) for v in vals] and vals.count(0) == 1
+
+
 # ---- the dispatch rule ------------------------------------------------------
 
 
@@ -251,23 +347,48 @@ def test_unfolded_programs_lower_with_no_custom_call(lanes):
         assert "tpu_custom_call" not in pc.Lowered(lanes).text
 
 
-def test_one_custom_call_under_the_ladder_scope(folded):
+KERNEL_SCOPES = {
+    "lift_x_pow": "recover.lift_x/lift_x_pow",
+    "glv_ladder": "recover.glv_ladder/glv_ladder",
+    "to_affine_inv": "recover.to_affine/to_affine_inv",
+}
+
+
+@pytest.mark.parametrize("kernel", KERNEL_SCOPES)
+def test_three_custom_calls_each_under_its_own_scope(folded, kernel):
     calls = [line for line in folded.text.splitlines() if "@tpu_custom_call" in line]
-    assert len(calls) == 1 and 'kernel_name = "glv_ladder"' in calls[0]
-    (loc,) = [line for line in folded.text.splitlines() if line.startswith(calls[0].rsplit("loc(", 1)[1][:-1] + " ")]
-    assert "recover.glv_ladder/glv_ladder/pallas_call" in loc
-    assert len(folded.kernels) == 1
-    # The 33 steps are in the kernel and nowhere else: the XLA scan is gone
-    # from the folded program.
-    steps = lambda j: [e for e in pc.scans(j) if e.params["length"] == sec._GLV_NWIN]  # noqa: E731
-    assert len(steps(folded.jaxpr)) == len(steps(folded.kernels[0])) == 1
+    assert len(calls) == 3 == len(folded.kernels)
+    (call,) = [line for line in calls if f'kernel_name = "{kernel}"' in line]
+    (loc,) = [line for line in folded.text.splitlines() if line.startswith(call.rsplit("loc(", 1)[1][:-1] + " ")]
+    assert f"{kernel}/pallas_call" in loc
+    # The name stack down to the call, through ``to_affine``'s nested jit: the
+    # ``op_name`` XLA gives the custom call, which ``stage_reduce`` reads.
+    assert folded.kernel_scopes[kernel] == KERNEL_SCOPES[kernel]
+
+
+def test_no_ladder_steps_and_no_power_windows_outside_the_kernels(folded):
+    """The 33 steps and the chains' 63 windows are in the kernels and nowhere
+    else: the XLA scans are gone from the folded program."""
+    inside = sum((pc.scans(k) for k in folded.kernels.values()), [])
+    by_length = lambda es, n: [e for e in es if e.params["length"] == n]  # noqa: E731
+    for length, kernels in ((sec._GLV_NWIN, 1), (63, 2)):
+        assert len(by_length(pc.scans(folded.jaxpr), length)) == len(by_length(inside, length)) == kernels
+
+
+@pytest.mark.parametrize("kernel,chains", [("lift_x_pow", 2), ("to_affine_inv", 1)])
+def test_power_kernel_is_329_products_a_chain_in_its_own_terms(folded, kernel, chains):
+    """14 to build the table, 63 windows of 4 squarings and one multiply:
+    ``pow_fixed2``'s count; three product CALLS a chain are traced."""
+    body = folded.kernels[kernel]
+    assert pc.slab_products(body) == chains * (14 + 63 * 5)
+    assert pc.slab_products(body, sites=True) == chains * 3
 
 
 def test_kernel_step_is_44_products_in_its_own_terms(folded):
     """A product call is one outer-product multiply, ``k`` products where
     ``k`` operands are stacked: 4 doublings x (3 + 3 + 1) + one add's
     2 + 5 + 2 + 3 + 2 + 2."""
-    (step,) = [e for e in pc.scans(folded.kernels[0]) if e.params["length"] == sec._GLV_NWIN]
+    (step,) = [e for e in pc.scans(folded.kernels["glv_ladder"]) if e.params["length"] == sec._GLV_NWIN]
     body = step.params["jaxpr"].jaxpr
     assert pc.slab_products(body) == 4 * 7 + 16
     sites = pc.slab_products(body, sites=True)
@@ -279,16 +400,24 @@ def test_kernel_step_is_44_products_in_its_own_terms(folded):
 # line of some 74,000.  Ceilings ~1.3x.  The same body written as twenty slab
 # multiply-adds a product on ``jnp`` read 3,034 equations; PR 47's
 # register-by-register body, at 400 Python operations a product, would read
-# some 40,000.
-KERNEL_EQNS_CEILING = 2_000
+# some 40,000.  PR 52's readings: ``lift_x_pow`` 897 equations and a line of
+# 40,127, ``to_affine_inv`` 309 and 19,711, and the module SHRINKS to
+# 1,661,676 bytes (two 63-window scans of multi-fusion bodies and the
+# product tree's two scans left it): the module's ceiling stays where it was.
+KERNEL_EQNS = {"glv_ladder": (1_000, 2_000), "lift_x_pow": (600, 1_200), "to_affine_inv": (200, 400)}
 KERNEL_LINE_CEILING = 100_000
 MODULE_BYTES_CEILING = 2_650_000
 
 
-def test_set_up_stays_under_its_ceilings(folded):
-    eqns = pc.count_eqns(folded.kernels[0])
-    (call,) = [line for line in folded.text.splitlines() if "@tpu_custom_call" in line]
-    assert 1_000 < eqns <= KERNEL_EQNS_CEILING, eqns
+@pytest.mark.parametrize("kernel", KERNEL_EQNS)
+def test_set_up_stays_under_its_ceilings(folded, kernel):
+    eqns = pc.count_eqns(folded.kernels[kernel])
+    (call,) = [
+        line for line in folded.text.splitlines()
+        if "@tpu_custom_call" in line and f'kernel_name = "{kernel}"' in line
+    ]
+    floor, ceiling = KERNEL_EQNS[kernel]
+    assert floor < eqns <= ceiling, eqns
     assert len(call) <= KERNEL_LINE_CEILING, len(call)
     assert len(folded.bare_text) <= MODULE_BYTES_CEILING, len(folded.bare_text)
 
@@ -310,24 +439,39 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compile_for(one_chip, call, *shapes):
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        operands = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip) for s in shapes]
+        return jax.jit(call).trace(*operands).lower(lowering_platforms=("tpu",)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
 def test_mosaic_compiles_the_kernel_for_a_v5e(one_chip):
     """What interpret mode cannot show: that Mosaic takes every op of the
     body (the merging reshapes, the sum over a leading axis, ``pad``) and
     that eight row tiles' worth (2,048 lanes) fits the VMEM it is given."""
     rows = 64
-
-    def s(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
-
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    try:
-        compiled = (
-            jax.jit(lambda *a: pk.ladder_call(*a, field=sec.FIELD))
-            .trace(s(33, rows, LANES), s(rows, LANES), *(s(16, L, rows, LANES),) * 3)
-            .lower(lowering_platforms=("tpu",))
-            .compile()
-        )
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was)
+    compiled = _compile_for(
+        one_chip,
+        lambda *a: pk.ladder_call(*a, field=sec.FIELD),
+        (33, rows, LANES), (rows, LANES), *((16, L, rows, LANES),) * 3,
+    )  # fmt: skip
     assert compiled.memory_analysis().output_size_in_bytes == 3 * L * rows * LANES * 4
+
+
+@pytest.mark.parametrize("tiles", (1, 2))
+@pytest.mark.parametrize("kernel", ("lift_x_pow", "to_affine_inv"))
+def test_mosaic_compiles_the_power_kernels_for_a_v5e(one_chip, kernel, tiles):
+    """And of the powers: the table entry read and written at a dynamic
+    index on the scratch's leading axis, the digits from SMEM, a table a
+    chain (1.3 MB) in VMEM; one tile (up to 1,024 lanes) and two (2,048)."""
+    chains = {"lift_x_pow": (SQRT, RINV), "to_affine_inv": (ZINV,)}[kernel]
+    moduli, exponents = zip(*chains)
+    shape = (len(chains), L, tiles * ROWS, LANES)
+    compiled = _compile_for(
+        one_chip, lambda a: pk.power_call(a, moduli=moduli, exponents=exponents, name=kernel), shape
+    )
+    assert compiled.memory_analysis().output_size_in_bytes == int(np.prod(shape)) * 4

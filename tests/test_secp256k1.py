@@ -394,6 +394,61 @@ def test_pallas_ladder_equals_the_scan_lane_by_lane_on_the_chip(lanes, monkeypat
     assert int(np.asarray(sec.is_infinity(sec.JacobianPoint(*map(jnp.asarray, got)))).sum()) < lanes // 4
 
 
+def _power_operands(lanes, modulus):
+    """Semi-reduced elements mod ``modulus``: its edges, then random ones."""
+    rng = random.Random(lanes + modulus % 97)
+    edges = [0, 1, 2, modulus - 1, modulus, modulus + 1, 2 * modulus - 1, (1 << 256) - 1]
+    return (edges + [rng.randrange(2 * modulus) for _ in range(lanes)])[:lanes]
+
+
+@pytest.mark.parametrize("lanes", (256, 512, 1024, 2048), ids=lambda n: f"{n}l")
+def test_pallas_powers_equal_the_scans_lane_by_lane_on_the_chip(lanes, monkeypatch):
+    """The two power kernels (``pallas_ladder.fixed_powers``: the path every
+    folded program takes on a TPU) against the scans they replace: the two
+    accumulators of ``recover.lift_x`` limb for limb what
+    ``fields.pow_fixed2`` gives and the powers Python's, in every lane;
+    ``to_affine`` bit for bit the product tree's, infinity to ``(0, 0)``."""
+    if not _on_tpu():
+        pytest.skip(f"{lanes} lanes: on the chip only (GO_IBFT_TPU_TESTS=1)")
+    from go_ibft_tpu.ops import pallas_ladder
+
+    assert pallas_ladder.kind(lanes) == pallas_ladder.PALLAS
+    f, o = sec.FIELD, sec.ORDER
+    av, bv = _power_operands(lanes, sec.P), _power_operands(lanes, sec.N)
+    a, b = pack(av), pack(bv)
+    chains = ((f, sec._SQRT_EXP), (o, sec.N - 2))
+    got = jax.jit(
+        lambda a, b: pallas_ladder.fixed_powers("lift_x_pow", (f, a, chains[0][1]), (o, b, chains[1][1]))
+    )(a, b)
+    want = jax.jit(lambda a, b: fields.pow_fixed2(f, a, chains[0][1], o, b, chains[1][1]))(a, b)
+    for g, w, vals, (m, e) in zip(got, want, (av, bv), chains):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+        assert [v % m.p for v in fields.from_limbs(np.asarray(g))] == [pow(v, e, m.p) for v in vals]
+
+    rng = random.Random(lanes)
+    pts = [host.scalar_mul(rng.randrange(1, sec.N), lc.G) for _ in range(16)]
+    zs = [0 if i % 7 == 3 else rng.randrange(1, sec.P) for i in range(lanes)]
+    jac = sec.JacobianPoint(
+        pack(pts[i % 16][0] * z * z % sec.P for i, z in enumerate(zs)),
+        pack(pts[i % 16][1] * z**3 % sec.P for i, z in enumerate(zs)),
+        pack(zs),
+    )
+    assert sec.to_affine.lower(jac).as_text().count("tpu_custom_call") == 1
+    got = [np.asarray(c) for c in sec.to_affine(jac)]
+    try:
+        monkeypatch.setattr(pallas_ladder, "supported", lambda: False)
+        jax.clear_caches()  # the trace above chose its inversion
+        assert "tpu_custom_call" not in sec.to_affine.lower(jac).as_text()
+        want = [np.asarray(c) for c in sec.to_affine(jac)]
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    for g, w in zip(got, want):
+        assert np.array_equal(g, w)
+    xy = list(zip(fields.from_limbs(got[0]), fields.from_limbs(got[1])))
+    assert xy == [(0, 0) if z == 0 else pts[i % 16] for i, z in enumerate(zs)]
+
+
 @pytest.mark.parametrize("lanes", CHIP_LANES, ids=lambda n: f"{n}l")
 def test_glv_ladder_edge_cases_at_wide_lanes_on_the_chip(lanes):
     if not _on_tpu():

@@ -11,7 +11,9 @@ The subsystem that turns the per-height consensus engine
   :meth:`ChainRunner.recover`.
 * :class:`SyncClient` / :class:`LoopbackSyncNetwork` — batched
   catch-up: all committed seals of a fetched height range verified in one
-  ``verify_seal_lanes`` drain, each lane at its own height's validator set.
+  seal-lane drain (streamed through ``verify_seal_lane_stream`` where the
+  verifier has it, one ``verify_seal_lanes`` call where not), each lane at
+  its own height's validator set.
 """
 
 from .runner import (

@@ -6,11 +6,16 @@ consensus (its peers have left them; the reference documents block sync
 as the embedder's job, core/ibft.go RunSequence contract).  This module
 is that job, done the TPU-native way: a stranded node fetches the missing
 ``(proposal, committed seals)`` range from any peer and verifies ALL
-committed seals across the whole range in ONE batched drain
-(``verify_seal_lanes`` — per-lane proposal hashes and per-lane heights,
-so the range may cross validator-set boundaries, through the same
-recovery ladder as the live COMMIT path, with the
-``ResilientBatchVerifier`` breaker ladder as the degraded route).  This
+committed seals across the whole range in ONE batched drain — per-lane
+proposal hashes and per-lane heights, so the range may cross
+validator-set boundaries, through the same recovery ladder as the live
+COMMIT path, with the ``ResilientBatchVerifier`` breaker ladder as the
+degraded route.  Where the verifier offers it the drain is a STREAM
+(``verify_seal_lane_stream``): the client makes a chunk's lanes while
+the chunk before is on the device and tallies a block while the chunk
+after is, so the chip does not wait for the host at either end of a
+range; any other verifier gets the whole range in one
+``verify_seal_lanes`` call.  This
 is the light-client primitive ("Practical Light Clients for
 Committee-Based Blockchains", PAPERS.md): trust nothing from the peer,
 re-derive every height's commit quorum from the seals alone.
@@ -37,7 +42,19 @@ implementation slots in for multi-host deployments exactly like
 from __future__ import annotations
 
 import threading
-from typing import Callable, Dict, List, Mapping, Optional, Protocol, Sequence, Tuple
+from collections import deque
+from contextlib import closing
+from typing import (
+    Callable,
+    Deque,
+    Dict,
+    Iterator,
+    List,
+    Mapping,
+    Protocol,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -57,6 +74,7 @@ __all__ = [
     "SYNC_DRAINS_KEY",
     "SYNC_CERT_HEIGHTS_KEY",
     "SYNC_REGROUPED_KEY",
+    "SYNC_STREAMED_KEY",
 ]
 
 SYNCED_HEIGHTS_KEY = ("go-ibft", "chain", "synced_heights")
@@ -65,6 +83,15 @@ SYNC_CERT_HEIGHTS_KEY = ("go-ibft", "chain", "sync_cert_heights")
 # Ranges whose validator sets together needed more table rows than one
 # drain's table holds, and were verified as more than one drain.
 SYNC_REGROUPED_KEY = ("go-ibft", "chain", "sync_regrouped")
+
+# Runs (drains) whose masks came through the verifier's stream: lanes made,
+# chunks run and blocks tallied side by side.
+SYNC_STREAMED_KEY = ("go-ibft", "chain", "sync_streamed")
+
+# Lanes the producer makes at a time (whole blocks, one at least): one
+# ``chain.sync.produce`` span and one mask.  An eighth of the largest lane
+# bucket: the first launch waits for at most that much more than its chunk.
+_PRODUCE_LANES = 256
 
 # Rows of the verifier's largest validator table (the last of
 # ``verify/batch.py``'s ``_TABLE_BUCKETS``; a test holds the two equal): what
@@ -120,7 +147,11 @@ class SyncClient:
     scheduler's tenant handle all implement it); verdicts are
     pinned to the sequential host oracle by the conformance tests, so a
     device route can never accept a range the reference semantics would
-    reject.  A :class:`~go_ibft_tpu.verify.mesh_batch.MeshBatchVerifier`
+    reject.  One that also has ``verify_seal_lane_stream(blocks, heights,
+    lanes)`` (Device/Mesh, and Resilient/Adaptive over them) is fed
+    lazily and answers as its chunks come back; it may return ``None``
+    for a range it would not stream (under the adaptive cutover, a ladder
+    on its host rungs), and gets the list call then.  A :class:`~go_ibft_tpu.verify.mesh_batch.MeshBatchVerifier`
     (or an Adaptive ladder carrying one) coalesces a whole multi-height
     range into ONE sharded dispatch — its chunk capacity is ``largest
     lane bucket x device count`` — so catch-up cost scales down with the
@@ -206,9 +237,10 @@ class SyncClient:
         different proposal).  Requires ``cert_verifier``; a cert-carrying
         block without one is a :class:`SyncError`, never silently trusted.
 
-        Seal-carrying blocks keep the batched lane route: ONE
-        ``verify_seal_lanes`` drain for the whole range, whatever validator
-        sets its heights select.  Every lane carries its own height, and
+        Seal-carrying blocks keep the batched lane route: ONE drain for the
+        whole range (the verifier's stream where it offers one, else one
+        ``verify_seal_lanes`` call), whatever validator sets its heights
+        select.  Every lane carries its own height, and
         the verifier judges it ``signature valid AND signer in the set of
         that height`` (on the device: one table that holds the union of the
         range's sets, the own-set test a host lookup), exactly as the
@@ -218,9 +250,13 @@ class SyncClient:
         committee is the case of one set.  Only a range whose sets
         together need more rows than the verifier's largest table
         (``max_table_rows``) is cut into runs of blocks that fit, a drain
-        each (``sync_regrouped``).  After the mask comes back, each
-        height's valid signers must reach that height's voting-power
-        quorum, in exact integers.
+        each (``sync_regrouped``).  What has to precede the first launch
+        stays in front (the sets, the runs, the quorum a snapshot); a
+        block's hash and lane tuples are made when the drain pulls them
+        (:meth:`_produce`), and as soon as a block's mask is back its valid
+        signers must reach that height's voting-power quorum, in exact
+        integers and in height order: the first block short of it raises,
+        and nothing of the range is returned.
         """
         cert_blocks = [b for b in blocks if b.cert is not None]
         if cert_blocks:
@@ -257,72 +293,139 @@ class SyncClient:
         if len(runs) > 1:
             metrics.inc_counter(SYNC_REGROUPED_KEY)
 
-        masks: List[Optional[np.ndarray]] = [None] * len(blocks)
-        total_lanes = sum(len(b.seals) for b in blocks)
+        # The quorum a snapshot, in front of the first launch: Python
+        # integers throughout (a stake in wei is above 2**60; nothing here
+        # may round or saturate); a snapshot the source hands out for many
+        # heights is summed once.
+        quorums: Dict[int, int] = {}
+        bigint = False
+        for powers in snapshots:
+            if id(powers) not in quorums:
+                quorums[id(powers)] = calculate_quorum(sum(powers.values()))
+                bigint = bigint or max(powers.values(), default=0) >= 1 << 31
+
         with trace.span(
             "chain.sync.verify",
-            lanes=total_lanes,
+            lanes=sum(len(b.seals) for b in blocks),
             heights=len(blocks),
             drains=len(runs),
             sets=len(first_with),
             table_rows=table_rows,
-        ):
+            streamed=False,
+        ) as verify:
             for idxs in runs:
-                lanes: List[Tuple[bytes, CommittedSeal]] = []
-                heights: List[int] = []
-                spans: List[Tuple[int, int, int]] = []  # (block idx, lo, hi)
-                for i in idxs:
-                    block = blocks[i]
-                    proposal_hash = proposal_hash_of(block.proposal)
-                    lo = len(lanes)
-                    lanes.extend(
-                        (proposal_hash, seal) for seal in block.seals
-                    )
-                    heights.extend([stands_for[i]] * len(block.seals))
-                    spans.append((i, lo, len(lanes)))
-                if not lanes:
-                    for i in idxs:
-                        masks[i] = np.zeros(0, dtype=bool)
-                    continue
-                # ONE batched drain for the run, every lane at its height.
-                mask = np.asarray(
-                    self.verifier.verify_seal_lanes(lanes, heights),
-                    dtype=bool,
-                )
-                metrics.inc_counter(SYNC_DRAINS_KEY)
-                for i, lo, hi in spans:
-                    masks[i] = mask[lo:hi]
-
-        # Per block: the power of its valid signers against its own
-        # height's quorum.  Python integers throughout (a stake in wei is
-        # above 2**60; nothing here may round or saturate); a snapshot the
-        # source hands out for many heights is summed once.
-        quorums: Dict[int, Tuple[int, bool]] = {}
-        for powers in snapshots:
-            if id(powers) not in quorums:
-                quorums[id(powers)] = (
-                    calculate_quorum(sum(powers.values())),
-                    max(powers.values(), default=0) >= 1 << 31,
-                )
-        with trace.span(
-            "chain.sync.tally",
-            blocks=len(blocks),
-            bigint=any(big for _quorum, big in quorums.values()),
-        ):
-            for block, mask, powers in zip(blocks, masks, snapshots):
-                valid_signers = {
-                    seal.signer
-                    for seal, ok in zip(block.seals, mask)
-                    if bool(ok)
-                }
-                quorum = quorums[id(powers)][0]
-                got = sum(powers.get(a, 0) for a in valid_signers)
-                if got < quorum:
+                slabs: Deque[Tuple[int, int]] = deque()
+                batches = self._mask_batches(blocks, idxs, stands_for, slabs, verify)
+                tallied = 0
+                with closing(batches):
+                    # A batch is the masks one read-back completed: its
+                    # blocks are tallied while the next chunk runs (all of
+                    # the run's at once where the verifier has no stream).
+                    for batch in batches:
+                        cuts = [slabs.popleft() for _ in batch]
+                        with trace.span(
+                            "chain.sync.tally",
+                            blocks=sum(end - first for first, end in cuts),
+                            bigint=bigint,
+                        ):
+                            for mask, (first, end) in zip(batch, cuts):
+                                lo = 0
+                                for i in idxs[first:end]:
+                                    hi = lo + len(blocks[i].seals)
+                                    self._hold_to_quorum(
+                                        blocks[i],
+                                        mask[lo:hi],
+                                        snapshots[i],
+                                        quorums[id(snapshots[i])],
+                                    )
+                                    lo = hi
+                                tallied += end - first
+                if tallied != len(idxs):
                     raise SyncError(
-                        f"height {block.height}: committed-seal power {got} < "
-                        f"quorum {quorum} ({int(mask.sum())}/{len(block.seals)} "
-                        "seals valid)"
+                        f"the verifier answered {tallied} of {len(idxs)} blocks"
                     )
+
+    def _produce(
+        self,
+        blocks: Sequence[FinalizedBlock],
+        idxs: Sequence[int],
+        stands_for: Sequence[int],
+        slabs: Deque[Tuple[int, int]],
+    ) -> Iterator[Tuple[List[Tuple[bytes, CommittedSeal]], List[int]]]:
+        """One run's lanes, made as they are asked for: ``(lanes, a height a
+        lane)`` of the next blocks that hold ``_PRODUCE_LANES`` lanes
+        together (one block at least), a proposal hash a block and a tuple
+        a lane; which of ``idxs`` they are joins ``slabs``.  What pulls from
+        here decides when the work is done: the verifier's stream pulls a
+        chunk's worth while the chunk before is on the device."""
+        at = 0
+        while at < len(idxs):
+            first = at
+            lanes: List[Tuple[bytes, CommittedSeal]] = []
+            heights: List[int] = []
+            with trace.span("chain.sync.produce") as span:
+                while at < len(idxs) and (at == first or len(lanes) < _PRODUCE_LANES):
+                    block = blocks[idxs[at]]
+                    proposal_hash = proposal_hash_of(block.proposal)
+                    lanes.extend([(proposal_hash, seal) for seal in block.seals])
+                    heights.extend([stands_for[idxs[at]]] * len(block.seals))
+                    at += 1
+                span.note(blocks=at - first, lanes=len(lanes))
+            slabs.append((first, at))
+            yield lanes, heights
+
+    def _mask_batches(
+        self, blocks, idxs, stands_for, slabs, verify
+    ) -> Iterator[List[np.ndarray]]:
+        """The masks of one run, a mask a slab of :meth:`_produce`, in the
+        batches they become known in.  From the verifier's stream where it
+        offers one for a range this size (``verify_seal_lane_stream``:
+        lanes are made, chunks run and blocks are tallied side by side);
+        else every lane is made first, ONE ``verify_seal_lanes`` call
+        judges them, and its slices are one batch.  ONE batched drain
+        either way, every lane at its height."""
+        produced = self._produce(blocks, idxs, stands_for, slabs)
+        lanes = sum(len(blocks[i].seals) for i in idxs)
+        if not lanes:
+            yield [np.zeros(0, dtype=bool) for _ in produced]
+            return
+        offer = getattr(self.verifier, "verify_seal_lane_stream", None)
+        stream = None
+        if offer is not None:
+            stream = offer(produced, set(stands_for[i] for i in idxs), lanes)
+        if stream is not None:
+            verify.note(streamed=True)
+            metrics.inc_counter(SYNC_STREAMED_KEY)
+            yield from stream  # closed with this generator
+        else:
+            made = list(produced)
+            mask = np.asarray(
+                self.verifier.verify_seal_lanes(
+                    [lane for slab, _heights in made for lane in slab],
+                    [height for _slab, heights in made for height in heights],
+                ),
+                dtype=bool,
+            )
+            edges = np.cumsum([0] + [len(slab) for slab, _heights in made])
+            yield [mask[lo:hi] for lo, hi in zip(edges, edges[1:])]
+        metrics.inc_counter(SYNC_DRAINS_KEY)
+
+    @staticmethod
+    def _hold_to_quorum(
+        block: FinalizedBlock, mask, powers: Mapping[bytes, int], quorum: int
+    ) -> None:
+        """The power of a block's valid signers against its own height's
+        quorum, in exact integers."""
+        valid_signers = {
+            seal.signer for seal, ok in zip(block.seals, mask) if bool(ok)
+        }
+        got = sum(powers.get(a, 0) for a in valid_signers)
+        if got < quorum:
+            raise SyncError(
+                f"height {block.height}: committed-seal power {got} < "
+                f"quorum {quorum} ({int(mask.sum())}/{len(block.seals)} "
+                "seals valid)"
+            )
 
     def _verify_cert_blocks(self, blocks: Sequence[FinalizedBlock]) -> None:
         """Batched verification of certificate-carrying blocks.

@@ -22,14 +22,15 @@ from __future__ import annotations
 
 import threading
 import time
-from contextlib import ExitStack
+from contextlib import ExitStack, closing
 from dataclasses import dataclass
-from collections import OrderedDict
+from collections import OrderedDict, deque
 from typing import (
     Callable,
     Dict,
     FrozenSet,
     Iterable,
+    Iterator,
     List,
     Mapping,
     Optional,
@@ -1462,6 +1463,19 @@ class DeviceBatchVerifier:
 
     # -- BatchVerifier protocol ----------------------------------------
 
+    def _chunk_pipeline(self):
+        """The double-buffered executor of a chunked drain and the two seams
+        it drives: ``pack(item)`` returns ``(item, inputs, table_dev)``."""
+        # ledger_key: the pipeline attributes each chunk's readback wait
+        # to the mask program (the dispatch records landed per chunk in
+        # _dispatch_async; the wait is the only timing the async path
+        # cannot observe itself).
+        return (
+            VerifyPipeline(depth=2, ledger_key=(self._program, self._route)),
+            lambda p: (p[0], self._dispatch_async(p[1], p[2])),
+            lambda h: (h[0], self._readback(h[1])),
+        )
+
     def _run_chunk_pipeline(self, items, pack, metric: str):
         """Pipeline (pack -> async dispatch -> readback) over chunk items.
 
@@ -1472,22 +1486,27 @@ class DeviceBatchVerifier:
         per chunk.  Returns ``[(item, mask), ...]`` in item order.
         """
         t0 = time.perf_counter()
-        # ledger_key: the pipeline attributes each chunk's readback wait
-        # to the mask program (the dispatch records landed per chunk in
-        # _dispatch_async; the wait is the only timing the async path
-        # cannot observe itself).
-        report = VerifyPipeline(
-            depth=2, ledger_key=(self._program, self._route)
-        ).run(
-            items,
-            pack,
-            dispatch=lambda p: (p[0], self._dispatch_async(p[1], p[2])),
-            readback=lambda h: (h[0], self._readback(h[1])),
-        )
+        pipeline, dispatch, readback = self._chunk_pipeline()
+        report = pipeline.run(items, pack, dispatch=dispatch, readback=readback)
         metrics.observe(
             ("go-ibft", "device", metric), (time.perf_counter() - t0) * 1e3
         )
         return report.results
+
+    def _stream_chunk_pipeline(self, items, pack, metric: str):
+        """:meth:`_run_chunk_pipeline` as a generator: ``items`` is pulled a
+        chunk at a time (what makes chunk N+1 runs while chunk N is on the
+        device) and each ``(item, mask)`` is yielded as it is read back."""
+        t0 = time.perf_counter()
+        pipeline, dispatch, readback = self._chunk_pipeline()
+        try:
+            yield from pipeline.stream(
+                items, pack, dispatch=dispatch, readback=readback
+            )
+        finally:
+            metrics.observe(
+                ("go-ibft", "device", metric), (time.perf_counter() - t0) * 1e3
+            )
 
     # -- the joint COMMIT dispatch ---------------------------------------
     # A committed seal signs the proposal hash carried IN its COMMIT, so
@@ -1798,76 +1817,200 @@ class DeviceBatchVerifier:
         (:func:`pack_seal_lanes`); the recovery ladder and membership
         check are the same program as the single-hash drain.
 
-        ``height`` is one height for every lane, or a height a lane: a range
-        that crosses validator-set boundaries still rides ONE drain of full
-        chunks, against ONE device table that holds the union of the sets
-        its heights select (the program's membership test is "the claimed
-        signer is a row of the table", and the claimed signer is a host
-        value).  A lane's verdict is then ``signature valid AND signer in
-        the set of the lane's OWN height``: where a height's set is narrower
-        than the table, that last test is a host set lookup, made while the
-        chunk packs (the previous one is on the device meanwhile).  A fixed
-        committee is the case of one set: its table, nothing narrower.
-        Chunks above the largest lane bucket ride the double-buffered
-        pipeline like every other flood.
+        ``height`` is one height for every lane, or a height a lane.  This
+        is :meth:`verify_seal_lane_stream` consumed: the whole list as one
+        block, so a list call and a streamed range run one drain's code.
         """
-        out = np.zeros(len(lanes), dtype=bool)
-        idxs = [
-            i
-            for i, (proposal_hash, seal) in enumerate(lanes)
-            if len(proposal_hash) == 32 and self._well_formed_seal(seal)
-        ]
-        if not idxs:
-            return out
-        sets = {
-            h: self._set_of(h)
-            for h in ({height} if _one_height(height) else set(height))
-        }
+        if not len(lanes):
+            return np.zeros(0, dtype=bool)
+        heights = {height} if _one_height(height) else set(height)
+        return np.concatenate(
+            [
+                mask
+                for masks in self._seal_lane_stream(
+                    [(lanes, height)], heights, streamed=False
+                )
+                for mask in masks
+            ]
+        )
+
+    def verify_seal_lane_stream(
+        self,
+        blocks: Iterable[Tuple[Sequence[Tuple[bytes, CommittedSeal]], LaneHeights]],
+        heights: Iterable[int],
+        lanes: int = 0,
+    ) -> Iterator[List[np.ndarray]]:
+        """The sync drain as a stream: a range's blocks in, their masks out.
+
+        ``blocks`` yields ``(a block's lanes, its height)`` (one height, or a
+        height a lane) and is pulled LAZILY, only as far as the next chunk
+        of ``_dispatch_cap`` well-formed lanes needs: whatever makes a
+        block's lanes (the sync client's proposal hash and lane tuples) runs
+        while the chunk before is on the device.  ``heights`` are the
+        heights the whole range selects, known up front: the ONE device
+        table holds the union of their sets (the program's membership test
+        is "the claimed signer is a row of the table", and the claimed
+        signer is a host value).  A lane's verdict is ``signature valid AND
+        signer in the set of the lane's OWN height``: where a height's set
+        is narrower than the table, that last test is a host set lookup,
+        made while the chunk packs.  A fixed committee is the case of one
+        set: its table, nothing narrower.  ``lanes`` (how many the range
+        has) is what a router above routes by; nothing here depends on it.
+
+        Yields, after each read-back that completes a block, the list of
+        the masks of the blocks it completed: every block gets exactly one
+        mask, in order, as soon as the chunk that holds its last lane is
+        back, with the next chunk on the device meanwhile (depth-2 double
+        buffering, chunks cut ACROSS block boundaries; a malformed lane is
+        skipped with verdict False and takes no room in a chunk).  It is
+        ONE ``verify.drain`` of kind ``seal_lanes``, open from the first
+        pull to the last mask.  Closed early, it reads back what is in
+        flight and yields nothing more.
+        """
+        return self._seal_lane_stream(blocks, heights, streamed=True)
+
+    def _seal_lane_chunks(self, blocks, pending: deque, heights_too: bool):
+        """Chunks of ``_dispatch_cap`` well-formed lanes, cut across
+        ``blocks`` as they come: ``(lanes, heights, parts)``, ``heights`` a
+        lane where ``heights_too``, ``parts`` the ``(entry, where, lo, hi)``
+        that say whose lanes ``[lo, hi)`` of the chunk are.  A block's entry
+        ``[mask, lanes not read back, lanes]`` joins ``pending`` as it is
+        pulled; ``where`` is None for a block that lies whole in one chunk
+        (its mask is the chunk's slice), else its lanes' places in
+        ``entry[0]``, which is made here."""
+        cap = self._dispatch_cap
+        chunk: list = []
+        chunk_heights: list = []
+        parts: list = []
+        for lanes, height in blocks:
+            n = len(lanes)
+            good = [
+                i
+                for i, (proposal_hash, seal) in enumerate(lanes)
+                if len(proposal_hash) == 32
+                and len(seal.signer) == ADDRESS_BYTES
+                and len(seal.signature) == SIG_BYTES
+            ]
+            whole = len(good) == n
+            entry = [None, len(good), n]
+            pending.append(entry)
+            at = 0
+            while at < len(good):
+                take = min(cap - len(chunk), len(good) - at)
+                lo = len(chunk)
+                if whole:
+                    where = None if take == n else slice(at, at + take)
+                    chunk.extend(lanes if take == n else lanes[at : at + take])
+                else:
+                    where = good[at : at + take]
+                    chunk.extend([lanes[i] for i in where])
+                if where is not None and entry[0] is None:
+                    entry[0] = np.zeros(n, dtype=bool)
+                if heights_too:
+                    if _one_height(height):
+                        chunk_heights.extend([height] * take)
+                    elif whole:
+                        chunk_heights.extend(height[at : at + take])
+                    else:
+                        chunk_heights.extend([height[i] for i in where])
+                parts.append((entry, where, lo, lo + take))
+                at += take
+                if len(chunk) == cap:
+                    yield chunk, chunk_heights, parts
+                    chunk, chunk_heights, parts = [], [], []
+        if chunk:
+            yield chunk, chunk_heights, parts
+
+    def _seal_lane_stream(self, blocks, heights, streamed: bool):
+        sets = {h: self._set_of(h) for h in set(heights)}
         table = frozenset().union(*sets.values())
         narrow = any(len(members) < len(table) for members in sets.values())
-        items = [
-            idxs[start : start + self._dispatch_cap]
-            for start in range(0, len(idxs), self._dispatch_cap)
-        ]
+        pending: deque = deque()  # blocks pulled and not yet yielded
 
-        def pack(chunk):
+        def pack(item):
+            chunk, chunk_heights, _parts = item
             with trace.span("verify.pack", kind="seal_lanes", lanes=len(chunk)):
-                inputs = pack_seal_lanes(
-                    [lanes[i] for i in chunk],
-                    pad_lanes=self._pad_lanes(len(chunk)),
+                # The chunker took well-formed lanes only: no second check.
+                inputs = _seal_lane_rows(
+                    [proposal_hash for proposal_hash, _seal in chunk],
+                    [seal.signature for _hash, seal in chunk],
+                    [seal.signer for _hash, seal in chunk],
+                    _lane_count(len(chunk), self._pad_lanes(len(chunk))),
                 )
                 # In the set of its own height, lane by lane, where some
                 # height's set is not the whole table.
                 own = None
                 if narrow:
                     own = np.fromiter(
-                        (lanes[i][1].signer in sets[height[i]] for i in chunk),
+                        (
+                            seal.signer in sets[h]
+                            for (_hash, seal), h in zip(chunk, chunk_heights)
+                        ),
                         dtype=bool,
                         count=len(chunk),
                     )
-            return (chunk, own), inputs, self._table_dev_of(table)
+            return (item, own), inputs, self._table_dev_of(table)
 
+        def ready():
+            """The masks of the blocks at the head of ``pending`` that have
+            every lane back (all False where none was well formed)."""
+            nonlocal judged
+            done = []
+            while pending and not pending[0][1]:
+                mask, _left, n = pending.popleft()
+                done.append(np.zeros(n, dtype=bool) if mask is None else mask)
+                judged += n
+            return done
+
+        chunks = judged = valid = outside = 0
         with trace.span(
             "verify.drain",
             route=self._route,
             kind="seal_lanes",
-            chunks=len(items),
-        ):
-            results = self._run_chunk_pipeline(
-                items, pack, "verify_seal_lanes_ms"
+            streamed=streamed,
+        ) as drain:
+            results = self._stream_chunk_pipeline(
+                self._seal_lane_chunks(blocks, pending, narrow),
+                pack,
+                "verify_seal_lanes_ms",
             )
-            with trace.span("verify.quorum", route="mask"):
-                outside = 0
-                for (chunk, own), mask in results:
-                    mask = mask[: len(chunk)]
-                    if own is not None:
-                        outside += int((mask & ~own).sum())
-                        mask = mask & own
-                    out[np.asarray(chunk)] = mask
+            try:
+                for ((chunk, _heights, parts), own), mask in results:
+                    with trace.span("verify.quorum", route="mask"):
+                        mask = mask[: len(chunk)]
+                        if own is not None:
+                            outside += int((mask & ~own).sum())
+                            mask = mask & own
+                        for entry, where, lo, hi in parts:
+                            if where is None:
+                                entry[0] = mask[lo:hi]
+                            else:
+                                entry[0][where] = mask[lo:hi]
+                            entry[1] -= hi - lo
+                        chunks += 1
+                        valid += int(np.count_nonzero(mask))
+                        done = ready()
+                    if done:
+                        yield done
+                # Blocks with no well-formed lane, behind the last chunk.
+                done = ready()
+                if done:
+                    yield done
+                if trace.enabled():
+                    trace.instant(
+                        "verify.verdicts",
+                        kind="seal_lanes",
+                        route=self._route,
+                        live=judged,
+                        rejected=judged - valid,
+                    )
+            finally:
+                # Closed early (the consumer refused a block): what is in
+                # flight is read back, never abandoned.
+                results.close()
+                drain.note(chunks=chunks)
                 if outside:
                     metrics.inc_counter(SYNC_OUT_OF_SET_LANES_KEY, outside)
-            _note_verdicts("seal_lanes", self._route, out)
-        return out
 
     def verify_seals_early_exit(
         self,
@@ -1973,6 +2116,15 @@ class DeviceBatchVerifier:
 
 QUARANTINED_LANES_KEY = ("go-ibft", "resilient", "quarantined_lanes")
 DRAIN_FAULTS_KEY = ("go-ibft", "resilient", "drain_faults")
+# Sync-drain streams a fast rung did not finish (it raised at some chunk):
+# the blocks it had not answered went through the ladder as a list.
+STREAM_FALLBACKS_KEY = ("go-ibft", "verify", "stream_fallbacks")
+
+
+class _SourceFailed(Exception):
+    """The CALLER's iterable of blocks raised while a rung's stream pulled
+    from it (``__cause__``): not the rung's fault, and not to be mistaken
+    for one."""
 
 # Below this many lanes a sharded dispatch loses to one single-device
 # dispatch: the mesh pads every drain to ``bucket x dp`` lanes and pays a
@@ -2123,13 +2275,91 @@ class ResilientBatchVerifier:
         """Cross-height sync drain through the degradation ladder: poison
         lanes quarantine by bisection, a faulting device demotes to the
         host rungs — the block-sync catch-up path's fallback route."""
-        lanes = list(lanes)
+        return self._drain_seal_lanes(list(lanes), height)
+
+    def _drain_seal_lanes(self, lanes, height, faulted: bool = False):
         return self._drain(
             lanes,
             lambda rung, idxs: self._run_seal_lanes(
                 rung, [lanes[i] for i in idxs], _heights_at(height, idxs)
             ),
+            faulted=faulted,
         )
+
+    def verify_seal_lane_stream(
+        self, blocks, heights: Iterable[int], lanes: int = 0
+    ) -> Optional[Iterator[List[np.ndarray]]]:
+        """The sync drain as a stream
+        (:meth:`DeviceBatchVerifier.verify_seal_lane_stream`: blocks in,
+        the masks of the blocks each read-back completed out) through the
+        ladder, or ``None`` where the rung a drain of ``lanes`` lanes enters
+        at has no stream (the host rungs): the caller then makes the list
+        call, :meth:`verify_seal_lanes`.
+
+        The stream is the fast rung's own.  If it raises before it ends, the
+        masks already yielded stand (their chunks completed), and the blocks
+        not yet answered are materialised and go through the ladder as one
+        list drain: bisection, quarantine, every rung in its turn, and ONE
+        breaker fault for the stream and that drain together.  No verdict is
+        taken from a dispatch that did not complete.
+        """
+        level, probe = self._acquire(lanes)
+        offer = getattr(self._rungs[level][1], "verify_seal_lane_stream", None)
+        source = iter(blocks)
+        waiting: deque = deque()  # pulled by the rung, no mask yet
+
+        def pulled():
+            try:
+                for block in source:
+                    waiting.append(block)
+                    yield block
+            except Exception as err:
+                raise _SourceFailed() from err
+
+        stream = None if offer is None else offer(pulled(), heights, lanes)
+        if stream is None:
+            if probe:
+                self.breaker.abort_probe(level)
+            return None
+        return self._stream_then_ladder(level, probe, stream, source, waiting)
+
+    def _stream_then_ladder(self, level, probe, stream, source, waiting):
+        answered = False  # the breaker has heard of this drain
+        try:
+            with closing(stream):
+                while True:
+                    try:
+                        masks = next(stream)
+                    except StopIteration:
+                        self.breaker.record_success(level)
+                        answered = True
+                        return
+                    except _SourceFailed as err:
+                        raise err.__cause__
+                    except Exception:
+                        break
+                    for _ in masks:
+                        waiting.popleft()
+                    yield masks
+            metrics.inc_counter(STREAM_FALLBACKS_KEY)
+            if probe:
+                # The probed rung ran and failed: the list drain below is
+                # the demoted level's own, with its own record.
+                self.breaker.record_fault(level)
+            answered = True
+            rest = list(waiting) + list(source)
+            lanes = [lane for block, _height in rest for lane in block]
+            heights: List[int] = []
+            for block, height in rest:
+                heights.extend(
+                    [height] * len(block) if _one_height(height) else height
+                )
+            mask = self._drain_seal_lanes(lanes, heights, faulted=not probe)
+            edges = np.cumsum([0] + [len(block) for block, _height in rest])
+            yield [mask[lo:hi] for lo, hi in zip(edges, edges[1:])]
+        finally:
+            if probe and not answered:
+                self.breaker.abort_probe(level)
 
     def verify_seals_early_exit(
         self,
@@ -2149,15 +2379,7 @@ class ResilientBatchVerifier:
         is always available, it just may arrive via the full drain.
         """
         seals = list(seals)
-        level, probe = self.breaker.acquire()
-        if self.mesh is not None and level == 0 and len(seals) < self.mesh_cutover:
-            # Same lane-count cutover as _drain: small drains skip the
-            # padded multi-device launch; a pending mesh probe cannot be
-            # answered by a drain that will not run the mesh.
-            if probe:
-                self.breaker.abort_probe(level)
-                probe = False
-            level = 1
+        level, probe = self._acquire(len(seals))
         rung = self._rungs[level][1]
         fn = getattr(rung, "verify_seals_early_exit", None)
         if fn is not None:
@@ -2226,11 +2448,9 @@ class ResilientBatchVerifier:
 
     # -- drain machinery -------------------------------------------------
 
-    def _drain(self, items, run, quarantinable=None) -> np.ndarray:
-        n = len(items)
-        out = np.zeros(n, dtype=bool)
-        if n == 0:
-            return out
+    def _acquire(self, n: int) -> Tuple[int, bool]:
+        """``(level, is_probe)`` of the rung one drain of ``n`` lanes enters
+        at: the breaker's, but for the mesh's lane-count cutover."""
         level, probe = self.breaker.acquire()
         if self.mesh is not None and level == 0 and n < self.mesh_cutover:
             # Lane-count cutover: small drains skip the mesh rung (padding
@@ -2249,9 +2469,19 @@ class ResilientBatchVerifier:
             # fault counters would be a CircuitBreaker redesign.
             if probe:
                 self.breaker.abort_probe(level)
-            level = 1
+            return 1, False
+        return level, probe
+
+    def _drain(self, items, run, quarantinable=None, faulted=False) -> np.ndarray:
+        """``faulted``: a fast rung's stream already raised over these items
+        (``verify_seal_lane_stream``), and this drain carries its record."""
+        n = len(items)
+        out = np.zeros(n, dtype=bool)
+        if n == 0 and not faulted:
+            return out
+        level, _probe = self._acquire(n)
         quarantined: List[int] = []
-        faulted = [False]
+        faulted = [faulted]
         self._verify(level, list(range(n)), run, out, quarantined, faulted)
         if faulted[0]:
             metrics.inc_counter(DRAIN_FAULTS_KEY)
@@ -2452,6 +2682,17 @@ class AdaptiveBatchVerifier:
         if self._host_sized(len(lanes)):
             return self.host.verify_seal_lanes(lanes, height)
         return self._resilient.verify_seal_lanes(lanes, height)
+
+    def verify_seal_lane_stream(
+        self, blocks, heights: Iterable[int], lanes: int = 0
+    ) -> Optional[Iterator[List[np.ndarray]]]:
+        """The sync drain as a stream, routed like the list call by the
+        range's lane count, which the caller knows before it has made one
+        lane: ``None`` (make the list call: the host path) under the
+        cutover, else the ladder's stream, or its ``None``."""
+        if self._host_sized(lanes):
+            return None
+        return self._resilient.verify_seal_lane_stream(blocks, heights, lanes)
 
     def verify_seals_early_exit(
         self,

@@ -47,7 +47,18 @@ import weakref
 from collections import deque
 from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
+from typing import (
+    Any,
+    Callable,
+    Deque,
+    Dict,
+    Iterable,
+    Iterator,
+    List,
+    Optional,
+    Sequence,
+    Tuple,
+)
 
 import numpy as np
 
@@ -272,24 +283,48 @@ class VerifyPipeline:
         in-flight work is drained (a dispatched batch is never abandoned
         mid-flight — device buffers must be consumed).
         """
-        results: List[Any] = [None] * len(items)
+        report = PipelineReport([], 0.0, 0.0, 0.0, 0.0)
+        report.results.extend(self.stream(items, pack, dispatch, readback, report))
+        return report
+
+    def stream(
+        self,
+        items: Iterable[Any],
+        pack: Callable[[Any], Any],
+        dispatch: Callable[[Any], Any],
+        readback: Callable[[Any], Any],
+        report: Optional[PipelineReport] = None,
+    ) -> Iterator[Any]:
+        """:meth:`run` as a generator: each item's result, in item order,
+        the moment it is read back.
+
+        ``items`` is pulled one item at a time, each only when the pipeline
+        is ready to pack it, so whatever MAKES an item (a generator's body)
+        runs while the item before is on the device; and the consumer has
+        item N's result while item N + 1 executes.  The order of events for
+        a sequence is :meth:`run`'s: pack and dispatch N + 1, then read back
+        N.  Closed early, or on an exception, it reads back what is in
+        flight and drops it.  ``report`` gets the host time attribution
+        (its ``wall_s`` includes what the consumer did between results).
+        """
         inflight: Deque[Tuple[int, Any]] = deque()
-        pack_s = dispatch_s = wait_s = 0.0
+        if report is None:
+            report = PipelineReport([], 0.0, 0.0, 0.0, 0.0)
         t_start = time.perf_counter()
 
-        def _finish_oldest() -> None:
-            nonlocal wait_s
+        def _finish_oldest() -> Any:
             idx, handle = inflight.popleft()
             t0 = time.perf_counter()
             with trace.span("pipeline.readback", item=idx):
-                results[idx] = readback(handle)
+                result = readback(handle)
             dt = time.perf_counter() - t0
-            wait_s += dt
+            report.wait_s += dt
             metrics.observe(READBACK_WAIT_MS_KEY, dt * 1e3)
             if self.ledger_key is not None:
                 cost_ledger.add_wall_ms(
                     self.ledger_key[0], self.ledger_key[1], dt * 1e3
                 )
+            return result
 
         try:
             for i, item in enumerate(items):
@@ -297,26 +332,22 @@ class VerifyPipeline:
                 with trace.span("pipeline.pack", item=i):
                     packed = pack(item)
                 dt = time.perf_counter() - t0
-                pack_s += dt
+                report.pack_s += dt
                 metrics.observe(PACK_MS_KEY, dt * 1e3)
 
                 t0 = time.perf_counter()
                 with trace.span("pipeline.dispatch", item=i):
                     inflight.append((i, dispatch(packed)))
-                dispatch_s += time.perf_counter() - t0
+                report.dispatch_s += time.perf_counter() - t0
 
                 while len(inflight) >= self.depth:
-                    _finish_oldest()
+                    yield _finish_oldest()
+            while inflight:
+                yield _finish_oldest()
         finally:
             while inflight:
                 _finish_oldest()
-        return PipelineReport(
-            results=results,
-            pack_s=pack_s,
-            dispatch_s=dispatch_s,
-            wait_s=wait_s,
-            wall_s=time.perf_counter() - t_start,
-        )
+            report.wall_s = time.perf_counter() - t_start
 
 
 @dataclass

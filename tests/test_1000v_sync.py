@@ -25,6 +25,7 @@ if ROOT not in sys.path:
 from benchmark.lib.committee import Committee, block_bytes, mangle, seal_lanes  # noqa: E402
 from benchmark.lib.harness import ledger_delta, ledger_sum  # noqa: E402
 
+from go_ibft_tpu.chain import sync as sync_module  # noqa: E402
 from go_ibft_tpu.chain.sync import (  # noqa: E402
     MAX_TABLE_ROWS,
     LoopbackSyncNetwork,
@@ -191,8 +192,13 @@ def _small_cap(src) -> DeviceBatchVerifier:
     return verifier
 
 
-def test_catch_up_rides_three_full_chunks_and_a_padded_tail_of_the_2048_row_program(staked):
+def test_catch_up_rides_three_full_chunks_and_a_padded_tail_of_the_2048_row_program(
+    staked, monkeypatch
+):
     src, blocks = staked
+    # A block a slab of the client's producer (as the cell's 800-seal blocks
+    # are): a mask, and a tally, as soon as a block's last lane is back.
+    monkeypatch.setattr(sync_module, "_PRODUCE_LANES", 1)
     client = _client(src, blocks, _small_cap(src))
     assert not trace.enabled() and not cost_ledger.enabled()
     recorder = trace.enable()
@@ -209,9 +215,16 @@ def test_catch_up_rides_three_full_chunks_and_a_padded_tail_of_the_2048_row_prog
     # ONE drain of four chunks; a block is 15 lanes, so the first ends inside
     # the second chunk and the second block runs over two chunk edges.
     (sync,) = [r[5] for r in spans if r[:2] == ("X", "chain.sync.verify")]
-    assert sync == {"lanes": 30, "heights": 2, "drains": 1, "sets": 1, "table_rows": VALIDATORS}
+    assert sync == {
+        "lanes": 30,
+        "heights": 2,
+        "drains": 1,
+        "sets": 1,
+        "table_rows": VALIDATORS,
+        "streamed": True,
+    }
     (outer,) = [r[5] for r in spans if r[:2] == ("X", "verify.drain")]
-    assert (outer["kind"], outer["chunks"]) == ("seal_lanes", 4)
+    assert outer == {"route": "device", "kind": "seal_lanes", "streamed": True, "chunks": 4}
     packs = [r[5]["lanes"] for r in spans if r[:2] == ("X", "verify.pack")]
     assert packs == [CAP, CAP, CAP, 30 - 3 * CAP]
     dispatches = [r[5] for r in spans if r[:2] == ("X", "verify.dispatch")]
@@ -223,9 +236,20 @@ def test_catch_up_rides_three_full_chunks_and_a_padded_tail_of_the_2048_row_prog
     }
     # 93.75% occupancy: the tail's two dead lanes (the cell reads 8000 / 8192).
     assert moved == {"dispatches": 4, "live_lanes": 30, "padded_lanes": 4 * CAP}
-    # The tally saw a 1,000-entry power map a height and exactly quorum.
-    (tally,) = [r[5] for r in spans if r[:2] == ("X", "chain.sync.tally")]
-    assert tally == {"blocks": BLOCKS, "bigint": False}
+    # The tally saw a 1,000-entry power map a height and exactly quorum: a
+    # span a chunk that completed a block (the second chunk and the tail),
+    # the first while the third chunk was on the device, both inside the
+    # drain; and each block's lanes were made as the chunks wanted them.
+    tallies = [r for r in spans if r[:2] == ("X", "chain.sync.tally")]
+    assert [r[5] for r in tallies] == [{"blocks": 1, "bigint": False}] * BLOCKS
+    made = [r[5] for r in spans if r[:2] == ("X", "chain.sync.produce")]
+    assert made == [{"blocks": 1, "lanes": 15}] * BLOCKS
+    starts = sorted(r[3] for r in spans if r[:2] == ("X", "verify.dispatch"))
+    waits = sorted(r[3] + r[4] for r in spans if r[:2] == ("X", "verify.device_wait"))
+    assert starts[2] <= tallies[0][3] and tallies[0][3] + tallies[0][4] <= waits[2] + 1
+    assert waits[3] <= tallies[1][3] + 1
+    (drain,) = [r for r in spans if r[:2] == ("X", "verify.drain")]
+    assert drain[3] <= tallies[0][3] and tallies[1][3] + tallies[1][4] <= drain[3] + drain[4] + 1
 
 
 def test_catch_up_refuses_the_range_with_one_block_at_quorum_less_one(staked):

@@ -28,6 +28,7 @@ from benchmark.lib.rotating_committee import RotatingCommittee  # noqa: E402
 from go_ibft_tpu.chain.sync import (  # noqa: E402
     SYNC_DRAINS_KEY,
     SYNC_REGROUPED_KEY,
+    SYNC_STREAMED_KEY,
     LoopbackSyncNetwork,
     SyncClient,
     SyncError,
@@ -97,6 +98,35 @@ class _Spy:
         return mask
 
 
+class _StreamSpy(_Spy):
+    """The same, and it hands the inner verifier's stream on: a call is then
+    a whole streamed drain (its lanes as they were pulled, its masks as they
+    came back)."""
+
+    def verify_seal_lane_stream(self, blocks, heights, lanes=0):
+        pulled, masks = [], []
+
+        def tee():
+            for block in blocks:
+                pulled.append(block)
+                yield block
+
+        def answers(stream):
+            for batch in stream:
+                masks.extend(batch)
+                yield batch
+            self.calls.append(
+                (
+                    sum(len(slab) for slab, _heights in pulled),
+                    [h for _slab, slab_heights in pulled for h in slab_heights],
+                    np.concatenate(masks),
+                )
+            )
+
+        stream = self.inner.verify_seal_lane_stream(tee(), heights, lanes)
+        return None if stream is None else answers(stream)
+
+
 def _verifier(route: str, src):
     """``host``: the sequential oracle.  ``device``: the default-constructed
     adaptive verifier (a range this size takes its device ladder), its
@@ -119,8 +149,13 @@ def _spans(records, name):
     return [r[5] for r in records if r[0] == "X" and r[1] == name]
 
 
-@pytest.mark.parametrize("route", ["host", "device"])
+@pytest.mark.parametrize("route", ["host", "device", "stream"])
 def test_a_range_across_epochs_is_one_drain_with_the_references_verdicts(chain, route):
+    """``host``: the oracle.  ``device``: the adaptive verifier behind a
+    wrapper that offers no stream (ONE list call, one tally).  ``stream``:
+    the same verifier handing its stream on (PR 53): the same ONE drain of a
+    dozen chunks, the lanes made and the blocks tallied inside it, a tally a
+    read-back that completed a slab of the client's producer."""
     c, blocks, want, outside = chain
     sets = {c.members(e) for e in c.epochs_of(SPAN)}
     assert len(sets) >= 6 and (~want).sum() >= 2 * (BLOCKS // 4) and outside >= BLOCKS // 4
@@ -128,9 +163,11 @@ def test_a_range_across_epochs_is_one_drain_with_the_references_verdicts(chain, 
     assert (reference == want).all()
     assert pos_reference.first_refused(blocks, c.src) is None
 
-    spy = _Spy(_verifier(route, c.src))
+    streams = route == "stream"
+    spy = (_StreamSpy if streams else _Spy)(_verifier("host" if route == "host" else "device", c.src))
     drains = metrics.get_counter(SYNC_DRAINS_KEY)
     regrouped = metrics.get_counter(SYNC_REGROUPED_KEY)
+    streamed = metrics.get_counter(SYNC_STREAMED_KEY)
     out_of_set = metrics.get_counter(batch.SYNC_OUT_OF_SET_LANES_KEY)
     rec = trace.enable()
     try:
@@ -142,6 +179,7 @@ def test_a_range_across_epochs_is_one_drain_with_the_references_verdicts(chain, 
     # ONE drain: every lane of the range in one call, a height a lane.
     assert metrics.get_counter(SYNC_DRAINS_KEY) - drains == 1
     assert metrics.get_counter(SYNC_REGROUPED_KEY) == regrouped
+    assert metrics.get_counter(SYNC_STREAMED_KEY) - streamed == int(streams)
     ((lanes, heights, mask),) = spy.calls
     assert lanes == len(want) == BLOCKS * c.spb
     assert {c.members(c.epoch_of(h)) for h in heights} == sets
@@ -151,9 +189,28 @@ def test_a_range_across_epochs_is_one_drain_with_the_references_verdicts(chain, 
     assert verify["drains"] == 1 and verify["sets"] == len(sets)
     assert verify["lanes"] == lanes and verify["heights"] == BLOCKS
     assert verify["table_rows"] == len(frozenset().union(*sets)) <= 20
-    (tally,) = _spans(records, "chain.sync.tally")
-    assert tally == {"blocks": BLOCKS, "bigint": True}  # stakes in wei
-    if route == "device":
+    assert verify["streamed"] is streams
+    tallies = _spans(records, "chain.sync.tally")
+    assert all(t["bigint"] for t in tallies)  # stakes in wei
+    assert sum(t["blocks"] for t in tallies) == BLOCKS
+    made = _spans(records, "chain.sync.produce")
+    assert sum(m["blocks"] for m in made) == BLOCKS
+    assert sum(m["lanes"] for m in made) == lanes
+    (drain,) = [r for r in records if r[:2] == ("X", "verify.drain")]
+    inside = [
+        drain[3] <= r[3] and r[3] + r[4] <= drain[3] + drain[4] + 1
+        for r in records
+        if r[0] == "X" and r[1] in ("chain.sync.tally", "chain.sync.produce")
+    ]
+    if streams:
+        # 420 lanes in 32-lane chunks; the producer's slabs are 266 and 154
+        # lanes (19 + 11 blocks), so two read-backs completed one each.
+        assert drain[5]["chunks"] == 14 and drain[5]["streamed"] is True
+        assert [t["blocks"] for t in tallies] == [m["blocks"] for m in made] == [19, 11]
+        assert all(inside)
+    else:
+        assert len(tallies) == 1 and not any(inside)
+    if route != "host":
         # The refused lanes that are validly signed by an account of another
         # epoch's set were seen as such, and nothing else was.
         assert metrics.get_counter(batch.SYNC_OUT_OF_SET_LANES_KEY) - out_of_set == outside

@@ -309,20 +309,26 @@ def test_chunked_device_drain_is_one_span_with_a_set_of_phases_a_chunk(
     stub_kernels, recorder
 ):
     """A drain above the largest lane bucket is still ONE ``verify.drain``
-    (what ``engine_self_ms`` subtracts once): a pack, a dispatch and a wait
-    per chunk, one ``verify.quorum``; and the pipeline has packed the
-    second chunk before it waits for the first."""
+    (what ``engine_self_ms`` subtracts once): a pack, a dispatch, a wait and
+    (the sync drain is a stream since PR 53: a chunk's verdicts are
+    assembled as it is read back) a ``verify.quorum`` per chunk; and the
+    pipeline has packed the second chunk before it waits for the first.
+    The list call is the stream consumed: ``streamed`` false, ``chunks``
+    noted when the drain ends."""
     cap = batch._BATCH_BUCKETS[-1]
     w = build_seal_lane_workload(2 * cap + 4, n_validators=N_VALIDATORS)
     verifier = DeviceBatchVerifier(w.validators)
     mask = verifier.verify_seal_lanes(w.lanes, w.height)
     assert mask.all() and len(mask) == 2 * cap + 4
-    _, inside = _outer_and_children(recorder, "verify.drain")
+    outer, inside = _outer_and_children(recorder, "verify.drain")
     assert inside == {
         "verify.pack": 3,
         "verify.dispatch": 3,
         "verify.device_wait": 3,
-        "verify.quorum": 1,
+        "verify.quorum": 3,
+    }
+    assert outer[5] == {
+        "route": "device", "kind": "seal_lanes", "streamed": False, "chunks": 3
     }
     starts = collections.defaultdict(list)
     for _, name, _, ts, _, _ in recorder.snapshot():
@@ -357,9 +363,12 @@ def test_mesh_route_owes_the_same_phases_inside_a_shard_span(
 
 def test_catch_up_on_the_host_route_is_one_drain_a_validator_set(recorder):
     """``SyncClient.catch_up`` with a static validator set hands the whole
-    range to ONE ``verify_seal_lanes`` call: one ``chain.sync.verify``
-    span holding one ``verify.drain`` (``sync_host_ms_per_call`` is the
-    call's wall minus exactly that)."""
+    range to ONE ``verify_seal_lanes`` call (the host oracle offers no
+    stream: ``streamed`` false): one ``chain.sync.verify`` span holding one
+    ``verify.drain`` (``sync_host_ms_per_call`` is the call's wall minus
+    exactly that), with the lanes made in front of it
+    (``chain.sync.produce``) and ONE tally behind it, the masks having come
+    at once."""
     heights = 3
     keys = [PrivateKey.from_seed(b"span-sync-%d" % i) for i in range(4)]
     src = ECDSABackend.static_validators({k.address: 1 for k in keys})
@@ -390,24 +399,37 @@ def test_catch_up_on_the_host_route_is_one_drain_a_validator_set(recorder):
     assert outer[5]["lanes"] == heights * len(keys)
     assert inside == DRAIN_CHILDREN
     (sync,) = [r for r in recorder.snapshot() if r[1] == "chain.sync.verify"]
-    assert sync[5]["drains"] == 1
+    assert sync[5] == {
+        "lanes": heights * len(keys),
+        "heights": heights,
+        "drains": 1,
+        "sets": 1,  # one address set, four rows
+        "table_rows": len(keys),
+        "streamed": False,
+    }
     assert sync[3] <= outer[3] and outer[3] + outer[4] <= sync[3] + sync[4] + 1
-    # One address set, four rows; the tally has a span of its own, after the
-    # drain and inside the call (equal votes: no power needs a big integer).
-    assert sync[5]["sets"] == 1 and sync[5]["table_rows"] == len(keys)
+    # The lanes are made before the drain, the tally has a span of its own
+    # after it, both inside the call (equal votes: no power needs a big
+    # integer).
+    (produce,) = [r for r in recorder.snapshot() if r[1] == "chain.sync.produce"]
+    assert produce[5] == {"blocks": heights, "lanes": heights * len(keys)}
+    assert sync[3] <= produce[3] and produce[3] + produce[4] <= outer[3] + 1
     (tally,) = [r for r in recorder.snapshot() if r[1] == "chain.sync.tally"]
     assert tally[5] == {"blocks": heights, "bigint": False}
     assert outer[3] + outer[4] <= tally[3] + 1
+    assert tally[3] + tally[4] <= sync[3] + sync[4] + 1
 
 
 def test_catch_up_across_two_validator_sets_counts_what_it_did(recorder):
     """A range whose heights select two address sets, on the device route:
     ONE ``chain.sync.verify`` (``drains`` 1, ``sets`` 2, ``table_rows`` the
-    union's), one ``chain.sync.tally`` (``bigint``: a stake in wei), and the
-    three counters: one table upload for the union however often the range
-    comes, the lanes validly signed out of their own height's set, no
-    regrouping (that is for a range wider than the largest table)."""
-    from go_ibft_tpu.chain.sync import SYNC_REGROUPED_KEY
+    union's, ``streamed``: the device verifier's stream), ONE streamed
+    ``verify.drain`` of one chunk, so one ``chain.sync.tally`` (``bigint``:
+    a stake in wei) INSIDE it, and the counters: one table upload for the
+    union however often the range comes, the lanes validly signed out of
+    their own height's set, no regrouping (that is for a range wider than
+    the largest table), a streamed run a call."""
+    from go_ibft_tpu.chain.sync import SYNC_REGROUPED_KEY, SYNC_STREAMED_KEY
     from go_ibft_tpu.verify import DeviceBatchVerifier
 
     keys = [PrivateKey.from_seed(b"span-pos-%d" % i) for i in range(5)]
@@ -445,21 +467,47 @@ def test_catch_up_across_two_validator_sets_counts_what_it_did(recorder):
             batch.TABLE_UPLOADS_KEY,
             batch.SYNC_OUT_OF_SET_LANES_KEY,
             SYNC_REGROUPED_KEY,
+            SYNC_STREAMED_KEY,
+            batch.STREAM_FALLBACKS_KEY,
         )
     }
     for _ in range(2):
         assert len(client.catch_up(1, 4)) == 4
     moved = {key[-1]: metrics.get_counter(key) - was for key, was in before.items()}
     # keys[4] sealed heights 3 and 4, where it is no validator: twice two lanes.
-    assert moved == {"table_uploads": 1, "sync_out_of_set_lanes": 4, "sync_regrouped": 0}
-    syncs = [r[5] for r in recorder.snapshot() if r[1] == "chain.sync.verify"]
-    assert syncs == [
-        {"lanes": 20, "heights": 4, "drains": 1, "sets": 2, "table_rows": 5}
+    assert moved == {
+        "table_uploads": 1,
+        "sync_out_of_set_lanes": 4,
+        "sync_regrouped": 0,
+        "sync_streamed": 2,
+        "stream_fallbacks": 0,
+    }
+    records = [r for r in recorder.snapshot() if r[0] == "X"]
+    syncs = [r for r in records if r[1] == "chain.sync.verify"]
+    assert [r[5] for r in syncs] == [
+        {
+            "lanes": 20,
+            "heights": 4,
+            "drains": 1,
+            "sets": 2,
+            "table_rows": 5,
+            "streamed": True,
+        }
     ] * 2
-    tallies = [r[5] for r in recorder.snapshot() if r[1] == "chain.sync.tally"]
-    assert tallies == [{"blocks": 4, "bigint": True}] * 2
-    drains = [r[5] for r in recorder.snapshot() if r[1] == "verify.drain"]
-    assert [d["kind"] for d in drains] == ["seal_lanes"] * 2
+    tallies = [r for r in records if r[1] == "chain.sync.tally"]
+    assert [r[5] for r in tallies] == [{"blocks": 4, "bigint": True}] * 2
+    drains = [r for r in records if r[1] == "verify.drain"]
+    assert [r[5] for r in drains] == [
+        {"route": "device", "kind": "seal_lanes", "streamed": True, "chunks": 1}
+    ] * 2
+    produced = [r for r in records if r[1] == "chain.sync.produce"]
+    assert [r[5] for r in produced] == [{"blocks": 4, "lanes": 20}] * 2
+    # Made and tallied INSIDE the drain, which lies inside the call.
+    for sync, drain, made, tally in zip(syncs, drains, produced, tallies):
+        assert sync[3] <= drain[3] <= made[3]
+        assert made[3] + made[4] <= tally[3] + 1
+        assert tally[3] + tally[4] <= drain[3] + drain[4] + 1
+        assert drain[3] + drain[4] <= sync[3] + sync[4] + 1
 
 
 # ---------------------------------------------------------------------------

@@ -63,6 +63,9 @@ __all__ = [
     "RECOVER_KERNEL",
     "DISPATCH_LANES_KEY",
     "DISPATCH_MS_KEY",
+    "SERVED_KEY",
+    "TABLE_CUTS_KEY",
+    "UNPINNED_LAUNCHES_KEY",
 ]
 
 # The shared dispatch MUST reuse the single-tenant plane's compiled
@@ -75,6 +78,15 @@ RECOVER_KERNEL = vbatch._recover_kernel
 
 DISPATCH_LANES_KEY = ("go-ibft", "sched", "dispatch_lanes")
 DISPATCH_MS_KEY = ("go-ibft", "sched", "dispatch_ms")
+# What served the flushes, as counters a scrape and the benchmark both read:
+# ``SERVED_KEY + (<route>/<padded lanes>,)`` a kernel launch (``host``: a
+# flush), the launches at a ``(lanes, rows)`` shape boot had pinned and did
+# not load (0: the rule ``_launch`` keeps), and the launches ``_launch`` cut
+# in two because their signers outgrew the pinned table rows.
+SERVED_KEY = ("go-ibft", "sched", "served")
+UNPINNED_LAUNCHES_KEY = ("go-ibft", "sched", "unpinned_launches")
+TABLE_CUTS_KEY = ("go-ibft", "sched", "table_cuts")
+
 
 class _RoutingPackCache:
     """Store-side shim routing ``pack_sender_batch`` cache stores to each
@@ -216,6 +228,8 @@ class CoalescedDispatcher:
             # The recover widths the device route launches (every bucket
             # until ``warmup_committee`` loaded the committee's).
             "widths": list(self._rungs),
+            # ... as ``[lanes, table rows]`` once boot pinned them.
+            "shapes": [list(shape) for shape in sorted(self._rung_tables.items())],
         }
 
     def served(self) -> Dict[str, int]:
@@ -226,6 +240,7 @@ class CoalescedDispatcher:
     def _note_served(self, key: str) -> None:
         with self._served_lock:
             self._served[key] = self._served.get(key, 0) + 1
+        metrics.inc_counter(SERVED_KEY + (key,))
 
     def _pad_lanes(self, n: int) -> int:
         """Mesh dispatches pin the lane dim to ``bucket(ceil(n/dp)) x dp``
@@ -241,7 +256,8 @@ class CoalescedDispatcher:
     def _table(self, addresses: List[bytes], lanes: int) -> np.ndarray:
         """The claimed-address table of one launch at ``lanes`` wide, padded
         (row 0 again, as its own bucket pads: no new member) to the rows boot
-        loaded that width with."""
+        loaded that width with.  :meth:`_launch` hands over no more signers
+        than those rows hold."""
         table = pack_validator_table(addresses)
         rows = self._rung_tables.get(lanes, 0)
         if rows <= table.shape[0]:
@@ -309,7 +325,7 @@ class CoalescedDispatcher:
     def committee_rungs(
         self, n_validators: int, read_tier: bool = False
     ) -> Tuple[int, ...]:
-        """The recover widths a committee of ``n_validators`` can make this
+        """The recover widths ONE committee of ``n_validators`` can make this
         dispatcher launch.  The consensus tier flushes at most a phase's
         worth of lanes of one kind: every bucket up to the committee's own,
         and under ``route="auto"`` none that only a flush below the cutover
@@ -323,24 +339,58 @@ class CoalescedDispatcher:
             low = _bucket(min(self.cutover, top), _BATCH_BUCKETS)
         return tuple(bb for bb in _BATCH_BUCKETS if low <= bb <= top)
 
-    def warmup_committee(
-        self, n_validators: int, read_tier: bool = False
+    def tenant_rungs(
+        self,
+        committees: Sequence[int],
+        max_lanes: int = _BATCH_BUCKETS[-1],
+        read_tier: bool = False,
+    ) -> Tuple[int, ...]:
+        """The recover widths the tenants of ``committees`` (a validator
+        count each) can make this dispatcher launch.  One tenant:
+        :meth:`committee_rungs`.  Several share every flush, and a flush of
+        theirs (catch-up ranges of many chains at once, a phase of each
+        together) coalesces up to ``max_lanes``, the scheduler's dispatch
+        cap: the ONE width that holds the cap, every tail padded to it.
+        """
+        if len(committees) == 1 or read_tier or self.mesh is not None:
+            return self.committee_rungs(max(committees), read_tier)
+        return (_bucket(min(max_lanes, _BATCH_BUCKETS[-1]), _BATCH_BUCKETS),)
+
+    def warmup_tenants(
+        self,
+        committees: Sequence[int],
+        signers: Optional[int] = None,
+        max_lanes: int = _BATCH_BUCKETS[-1],
+        read_tier: bool = False,
     ) -> None:
-        """Pre-compile what a committee of ``n_validators`` can make this
-        dispatcher launch (:meth:`committee_rungs`), each width with the
-        membership table its flush would pack (one claimed address per
-        lane, capped by the committee size), and launch nothing else from
-        here on: a cached ladder executable costs 7-15 s of boot to load, a
-        cold one most of a minute to compile, so a 100-validator committee
-        loads two or three and not all six."""
-        rungs = self.committee_rungs(n_validators, read_tier)
-        tables = {
-            bb: _bucket(min(bb, n_validators), _TABLE_BUCKETS) for bb in rungs
-        }
+        """Pre-compile what the tenants of ``committees`` can make this
+        dispatcher launch (:meth:`tenant_rungs`), each width with the
+        membership table its fullest flush packs: one claimed address per
+        lane, capped by ``signers``, the distinct validators of all the
+        tenants together (their sum where not given).  Nothing else is
+        launched from here on (:meth:`_launch`): a cached ladder executable
+        costs 7-15 s of boot to load, a cold one most of a minute to
+        compile, so a 100-validator committee loads two or three and not
+        all six, and sixteen chains of 316 validators one."""
+        if not committees:
+            raise ValueError("no tenant to warm up for")
+        if self.route == "host":
+            return  # no flush reaches a device program
+        if signers is None:
+            signers = sum(committees)
+        rungs = self.tenant_rungs(committees, max_lanes, read_tier)
+        tables = {bb: _bucket(min(bb, signers), _TABLE_BUCKETS) for bb in rungs}
         for bb in rungs:
             self.warmup(lanes=(bb,), table_rows=tables[bb])
         if self.mesh is None:
             self._rungs, self._rung_tables = rungs, tables
+
+    def warmup_committee(
+        self, n_validators: int, read_tier: bool = False
+    ) -> None:
+        """:meth:`warmup_tenants` for the one committee of a validator's own
+        chain (what ``ValidatorNode`` boots with)."""
+        self.warmup_tenants((n_validators,), read_tier=read_tier)
 
     def dispatch(
         self,
@@ -394,73 +444,86 @@ class CoalescedDispatcher:
     # -- device route ----------------------------------------------------
 
     def _device(self, msgs, lanes, owners) -> Tuple[np.ndarray, np.ndarray]:
+        # A kind's lanes in chunks of the widest program boot loaded: never
+        # at a shape that would compile here.
         cap = self._rungs[-1] * (self.dp if self.mesh is not None else 1)
-        if max(len(msgs), len(lanes)) > cap:
-            # More lanes of a kind than the widest program boot loaded: in
-            # chunks of that width, never at a shape that would compile here.
-            sender_ok = [
-                self._device(msgs[i : i + cap], (), owners)[0]
-                for i in range(0, len(msgs), cap)
+
+        def mask_of(kind: str, items) -> np.ndarray:
+            parts = [
+                self._launch(kind, list(items[i : i + cap]), owners)
+                for i in range(0, len(items), cap)
             ]
-            seal_ok = [
-                self._device((), lanes[i : i + cap], owners)[1]
-                for i in range(0, len(lanes), cap)
-            ]
-            return (
-                np.concatenate(sender_ok) if sender_ok else np.zeros(0, bool),
-                np.concatenate(seal_ok) if seal_ok else np.zeros(0, bool),
+            return np.concatenate(parts) if parts else np.zeros(0, dtype=bool)
+
+        return mask_of("senders", msgs), mask_of("seal_lanes", lanes)
+
+    def _launch(self, kind: str, items: list, owners) -> np.ndarray:
+        """The signature-validity mask of one kind's lanes (at most the
+        widest rung of them): one pack, one table, one kernel launch.
+
+        Claimed-address table: every live lane's claimed signer is a member
+        by construction, so the kernel's (sig & member) mask reduces to
+        signature validity; tenant membership stays on host where each
+        chain's own set applies.  Where the lanes claim more distinct
+        signers than the rows boot loaded their width with (a tenant
+        registered after boot, a peer that serves seals under addresses of
+        its own making), they are cut in two, each half launched at its own
+        width: never a shape that would compile here."""
+        senders = kind == "senders"
+        signers = list(
+            dict.fromkeys(
+                (m.sender for m in items) if senders else (seal.signer for _h, seal in items)
             )
-        sender_ok = np.zeros(len(msgs), dtype=bool)
-        seal_ok = np.zeros(len(lanes), dtype=bool)
-        if msgs:
-            # The pack sequence (cache-hit reuse, oversize payloads
-            # digested on host) is the single-tenant plane's own
-            # implementation — shared, not forked, so a fix there can
-            # never miss this route.  Lookups are pre-routed per tenant;
-            # stores route back through the owners shim.
-            zw, r, s, v, claimed, live = vbatch.pack_sender_digest_rows(
-                msgs,
-                cache=_RoutingPackCache(owners),
-                hits=[
-                    (owners[id(m)].lookup(m) if id(m) in owners else None)
-                    for m in msgs
-                ],
-                pad_lanes=self._pad_lanes(len(msgs)),
+        )
+        padded = self._pad_lanes(len(items))
+        rows = self._rung_tables.get(padded, 0)
+        if rows and len(signers) > rows:
+            metrics.inc_counter(TABLE_CUTS_KEY)
+            half = len(items) // 2
+            return np.concatenate(
+                [
+                    self._launch(kind, items[:half], owners),
+                    self._launch(kind, items[half:], owners),
+                ]
             )
-            # Claimed-address table: every live lane's claimed sender is a
-            # member by construction, so the kernel's (sig & member) mask
-            # reduces to signature validity — tenant membership stays on
-            # host where each chain's own set applies.
-            table = self._table(
-                list(dict.fromkeys(m.sender for m in msgs)), live.shape[0]
-            )
-            sender_ok = self._sig_mask(zw, r, s, v, claimed, table, live)[
-                : len(msgs)
-            ]
-        if lanes:
-            hz, r, s, v, signers, live = pack_seal_lanes(
-                list(lanes), pad_lanes=self._pad_lanes(len(lanes))
-            )
-            table = self._table(
-                list(dict.fromkeys(seal.signer for _h, seal in lanes)),
-                live.shape[0],
-            )
-            seal_ok = self._sig_mask(hz, r, s, v, signers, table, live)[
-                : len(lanes)
-            ]
-        return sender_ok, seal_ok
+        with trace.span("verify.pack", kind=kind, lanes=len(items)) as span:
+            if senders:
+                # The pack sequence (cache-hit reuse, oversize payloads
+                # digested on host) is the single-tenant plane's own
+                # implementation, shared, not forked, so a fix there can
+                # never miss this route.  Lookups are pre-routed per
+                # tenant; stores route back through the owners shim.
+                zw, r, s, v, claimed, live = vbatch.pack_sender_digest_rows(
+                    items,
+                    cache=_RoutingPackCache(owners),
+                    hits=[
+                        (owners[id(m)].lookup(m) if id(m) in owners else None)
+                        for m in items
+                    ],
+                    pad_lanes=padded,
+                )
+            else:
+                zw, r, s, v, claimed, live = pack_seal_lanes(items, pad_lanes=padded)
+            table = self._table(signers, live.shape[0])
+            span.note(table_rows=table.shape[0])
+        return self._sig_mask(zw, r, s, v, claimed, table, live)[: len(items)]
 
     def _sig_mask(self, zw, r, s, v, claimed, table, live) -> np.ndarray:
         """One signature-validity kernel launch: the sharded mask program
         over an attached mesh, the single-device recover ladder otherwise
-        (identical argument layout — mesh_batch kept the sharded program a
+        (identical argument layout: mesh_batch kept the sharded program a
         thin shell around the single-chip one)."""
         sharded = self.mesh is not None
         kernel = self._mask_kernel if sharded else RECOVER_KERNEL
         program = "mesh_verify_mask" if sharded else "ecdsa_recover"
+        route = "mesh" if sharded else "device"
+        if self._rung_tables and self._rung_tables.get(live.shape[0]) != table.shape[0]:
+            # Boot pinned the shapes and this is none of them: it compiles
+            # (or loads) on the flush thread.  Counted where it would happen.
+            metrics.inc_counter(UNPINNED_LAUNCHES_KEY)
         with cost_ledger.dispatch_span(
             program,
-            route="mesh" if sharded else "device",
+            route=route,
             live_mask=live,
             kernels=((program, kernel),),
             site="sched/dispatch.py:_device",
@@ -469,14 +532,15 @@ class CoalescedDispatcher:
             # rows on the sender side): the compiled call stages them.
             with trace.span(
                 "verify.dispatch",
-                route="mesh" if sharded else "device",
+                route=route,
                 # the sharded program keeps the scan (ops/pallas_ladder.py)
                 ladder="scan" if sharded else vbatch.ladder_of(live.shape[0]),
                 table_rows=table.shape[0],
             ):
                 launched = kernel(zw, r, s, v, claimed, table, live)
-            mask = np.asarray(launched)
-        self._note_served(f"{'mesh' if sharded else 'device'}/{mask.shape[0]}")
+            with trace.span("verify.device_wait", route=route):
+                mask = np.asarray(launched)
+        self._note_served(f"{route}/{mask.shape[0]}")
         return mask
 
     # -- host route ------------------------------------------------------

@@ -89,6 +89,7 @@ __all__ = [
     "COALESCED_REQUESTS_KEY",
     "DRAIN_MS_KEY",
     "FLUSH_FAULTS_KEY",
+    "SERVED_LANES_KEY",
 ]
 
 QUEUE_LANES_KEY = ("go-ibft", "sched", "queue_lanes")
@@ -97,6 +98,9 @@ DISPATCHES_KEY = ("go-ibft", "sched", "dispatches")
 COALESCED_REQUESTS_KEY = ("go-ibft", "sched", "coalesced_requests")
 DRAIN_MS_KEY = ("go-ibft", "sched", "drain_ms")
 FLUSH_FAULTS_KEY = ("go-ibft", "sched", "flush_faults")
+# Lanes a flush answered, by the tenant's class: ``SERVED_LANES_KEY +
+# ("<validators>v",)``, the size of the set its last verdicts were held to.
+SERVED_LANES_KEY = ("go-ibft", "sched", "served_lanes")
 # Fixed-bucket per-tenant drain latency for the /metrics endpoint (the
 # tenant chain id renders as the ``tag`` label; off unless
 # metrics.enable_fixed_histograms() ran).
@@ -257,6 +261,9 @@ class _Tenant:
         self.lanes = 0
         self.shed_lanes = 0
         self.sheds = 0
+        # Validators of the set this tenant's last verdicts were held to (0
+        # until boot or a flush has read one): its class in the counters.
+        self.committee = 0
 
 
 def _percentile(samples: Sequence[float], q: float) -> Optional[float]:
@@ -377,8 +384,39 @@ class TenantScheduler:
         """Pre-compile every shape a committee of ``n_validators`` can make
         the dispatcher launch (node startup, while ``/readyz`` is 503);
         ``read_tier``: a proof-serving tenant coalesces up to a full
-        dispatch, so every width."""
+        dispatch, so every width.  The one-tenant case of
+        :meth:`warmup_tenants`, for a node that knows its committee's size
+        before its engine has registered."""
         self._dispatcher.warmup_committee(n_validators, read_tier)
+
+    def warmup_tenants(self, heights) -> dict:
+        """The multi-tenant boot: pin and pre-compile what the tenants
+        registered SO FAR can make the dispatcher launch, and nothing else
+        from then on (process startup, every chain registered, before the
+        first of them verifies).  ``heights``: the height each tenant's
+        validator set is read at (``{tenant id: height}``, or one height
+        for all).  The lane widths follow from the committees' sizes and
+        this scheduler's dispatch cap, the table rows from the distinct
+        validators of all the tenants together
+        (:meth:`CoalescedDispatcher.warmup_tenants`).  A tenant registered
+        later is served from what is loaded: a flush its signers outgrow is
+        cut, never compiled for.  Returns the dispatcher's description."""
+        with self._cv:
+            tenants = list(self._tenants.values())
+        if not tenants:
+            raise ValueError("no tenant is registered")
+        sets = []
+        for tenant in tenants:
+            at = heights if isinstance(heights, int) else heights[tenant.tid]
+            sets.append(frozenset(tenant.validators(at)))
+            tenant.committee = len(sets[-1])
+        self._dispatcher.warmup_tenants(
+            [len(members) for members in sets],
+            signers=len(frozenset().union(*sets)),
+            max_lanes=self.max_dispatch_lanes,
+            read_tier=any(t.rank == PRIORITY_RANK["read"] for t in tenants),
+        )
+        return self._dispatcher.describe()
 
     def reconfigure(
         self,
@@ -801,19 +839,19 @@ class TenantScheduler:
                     req.error = err
                     req.done.set()
                 return
-        self.dispatches += 1
-        self.coalesced_requests += len(batch)
-        self.coalesced_lanes += len(msgs) + len(lanes)
-        metrics.inc_counter(DISPATCHES_KEY)
-        metrics.inc_counter(COALESCED_REQUESTS_KEY, len(batch))
-        off = 0
-        for req in sender_reqs:
-            self._complete(req, sender_ok[off : off + req.lanes])
-            off += req.lanes
-        off = 0
-        for req in seal_reqs:
-            self._complete(req, seal_ok[off : off + req.lanes])
-            off += req.lanes
+            self.dispatches += 1
+            self.coalesced_requests += len(batch)
+            self.coalesced_lanes += len(msgs) + len(lanes)
+            metrics.inc_counter(DISPATCHES_KEY)
+            metrics.inc_counter(COALESCED_REQUESTS_KEY, len(batch))
+            with trace.span(
+                "sched.complete", requests=len(batch), lanes=len(msgs) + len(lanes)
+            ):
+                for reqs, sig_ok in ((sender_reqs, sender_ok), (seal_reqs, seal_ok)):
+                    off = 0
+                    for req in reqs:
+                        self._complete(req, sig_ok[off : off + req.lanes])
+                        off += req.lanes
 
     def _complete(self, req: _Request, sig_ok: np.ndarray) -> None:
         """Apply the tenant's membership check and deliver the verdicts."""
@@ -833,6 +871,7 @@ class TenantScheduler:
                 powers = powers_by_height.get(height)
                 if powers is None:
                     powers = powers_by_height[height] = validators(height)
+                    req.tenant.committee = len(powers)
                 mask[i] = claimed in powers
             if not req.cancelled:
                 req.out[np.asarray(req.out_idxs)] = mask
@@ -848,6 +887,9 @@ class TenantScheduler:
             metrics.observe_fixed(
                 SCHED_DRAIN_MS_FIXED_KEY + (req.tenant.chain_id,), dt_ms
             )
+            metrics.inc_counter(
+                SERVED_LANES_KEY + (f"{req.tenant.committee}v",), req.lanes
+            )
             req.done.set()
 
     # -- evidence --------------------------------------------------------
@@ -861,6 +903,7 @@ class TenantScheduler:
             return {
                 "chain": t.chain_id,
                 "priority": t.priority,
+                "committee": t.committee,
                 "queue_lanes": t.queued_lanes,
                 "requests": requests,
                 "lanes": lanes,

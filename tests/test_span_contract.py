@@ -807,3 +807,62 @@ def test_a_node_never_dispatches_more_often_than_its_handle_queued(node_heights)
     # the COMMITs' envelopes, their seals (more where the sockets spread a
     # phase over several flushes).
     assert len(queued) >= 4 * HEIGHTS
+
+
+# -- the scheduler's own drain (ISSUE 54) ----------------------------------
+
+
+@pytest.mark.parametrize("kind", ["senders", "seal_lanes"])
+def test_a_device_route_flush_of_the_scheduler_names_every_part_of_itself(
+    kind, signed_round, recorder, monkeypatch
+):
+    """``sched.coalesce`` holds ``sched.dispatch`` and, behind it,
+    ``sched.complete``; ``sched.dispatch`` holds the dispatcher's
+    ``verify.pack`` (rows AND claimed-address table: ``kind``, ``lanes``,
+    ``table_rows``), ``verify.dispatch`` and the blocking read-back as
+    ``verify.device_wait``: a flush's wall is the sum of its children."""
+    from go_ibft_tpu.sched import TenantScheduler, dispatch
+
+    monkeypatch.setattr(
+        dispatch, "RECOVER_KERNEL", lambda zw, r, s, v, claimed, table, live: np.asarray(live, dtype=bool)
+    )
+    monkeypatch.setattr(
+        dispatch, "DIGEST_KERNEL", lambda blocks, counts: np.zeros((np.shape(blocks)[0], 8), dtype=np.uint32)
+    )
+    sched = TenantScheduler(window_s=0.002, route="device")
+    handle = sched.register("only", _validators(signed_round))
+    with sched:
+        if kind == "senders":
+            mask = handle.verify_senders(signed_round.prepares)
+        else:
+            mask = handle.verify_seal_lanes(
+                [(signed_round.proposal_hash, s) for s in signed_round.seals], signed_round.height
+            )
+    assert mask.all() and len(mask) == N_VALIDATORS
+    assert recorder.dropped == 0
+    spans = {}
+    for ph, name, track, ts, dur, args in recorder.snapshot():
+        if ph == "X" and name.startswith(("sched.", "verify.")):
+            assert name not in spans, name  # one of each a flush
+            spans[name] = (track, ts, ts + dur, args)
+    assert set(spans) == {
+        "sched.queue", "sched.coalesce", "sched.dispatch", "sched.complete",
+        "verify.pack", "verify.dispatch", "verify.device_wait",
+    }
+
+    def inside(child, parent):
+        (track, t0, t1, _), (ptrack, p0, p1, _) = spans[child], spans[parent]
+        return track == ptrack and p0 <= t0 and t1 <= p1 + 1
+
+    for child in ("sched.dispatch", "sched.complete"):
+        assert inside(child, "sched.coalesce"), child
+    for child in ("verify.pack", "verify.dispatch", "verify.device_wait"):
+        assert inside(child, "sched.dispatch"), child
+    order = ["verify.pack", "verify.dispatch", "verify.device_wait", "sched.complete"]
+    assert [spans[n][1] for n in order] == sorted(spans[n][1] for n in order)
+    assert spans["verify.pack"][3] == {"kind": kind, "lanes": N_VALIDATORS, "table_rows": 128}
+    assert spans["verify.dispatch"][3]["table_rows"] == 128
+    assert spans["verify.dispatch"][3]["route"] == spans["verify.device_wait"][3]["route"] == "device"
+    assert spans["sched.complete"][3] == {"requests": 1, "lanes": N_VALIDATORS}
+    coalesce = spans["sched.coalesce"][3]
+    assert (coalesce["tenants"], coalesce["requests"], coalesce["lanes"]) == (1, 1, N_VALIDATORS)

@@ -64,6 +64,7 @@ __all__ = [
     "NULL_SPAN",
     "instant",
     "set_track",
+    "on_track",
     "next_span_id",
 ]
 
@@ -166,6 +167,31 @@ def set_track(name: str) -> contextvars.Token:
     token.  Rarely needed directly — passing ``track=`` to the outermost
     span of a scope does the same and resets itself."""
     return _track_var.set(name)
+
+
+class _OnTrack:
+    __slots__ = ("_name", "_tok")
+
+    def __init__(self, name: str) -> None:
+        self._name = name
+
+    def __enter__(self):
+        self._tok = _track_var.set(self._name)
+        return self
+
+    def __exit__(self, *exc):
+        _track_var.reset(self._tok)
+        return False
+
+
+def on_track(name: Optional[str]):
+    """Spans opened inside the ``with`` that name no track of their own lie
+    on ``name`` (``None``: wherever they would have).  For a caller that
+    wants another module's spans on a row of its choosing; the shared no-op
+    unless tracing is enabled."""
+    if name is None or _sink is None:
+        return _NULL
+    return _OnTrack(name)
 
 
 def _resolve_track(explicit: Optional[str]) -> str:

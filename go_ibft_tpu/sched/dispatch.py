@@ -34,6 +34,7 @@ picks per drain (measured cutover, small flushes stay on host).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -132,6 +133,30 @@ def well_formed_seal_lane(proposal_hash: bytes, seal: CommittedSeal) -> bool:
         and len(seal.signer) == ADDRESS_BYTES
         and len(seal.signature) == SIG_BYTES
     )
+
+
+_KINDS = ("senders", "seal_lanes")
+
+
+class _Launched:
+    """One flush between its two halves (:meth:`CoalescedDispatcher.launch`,
+    :meth:`CoalescedDispatcher.collect`): its open ``sched.dispatch`` span, and what is still to be read back, by kind in
+    launch order as ``(the kernel's result, live lanes)``; ``host``: the host
+    route's masks, which were ready when the launch returned (``ready``:
+    nothing is left to wait for)."""
+
+    __slots__ = ("total", "span", "t0", "parts", "host")
+
+    def __init__(self, total: int, span) -> None:
+        self.total = total
+        self.span = span
+        self.t0 = time.perf_counter()
+        self.parts: Dict[str, list] = {kind: [] for kind in _KINDS}
+        self.host: Optional[Tuple[np.ndarray, np.ndarray]] = None
+
+    @property
+    def ready(self) -> bool:
+        return self.host is not None
 
 
 class CoalescedDispatcher:
@@ -280,12 +305,7 @@ class CoalescedDispatcher:
                 # when a mesh is attached, the single-device recover
                 # ladder otherwise.
                 gg = self._pad_lanes(bb) if self.mesh is not None else bb
-                kernel = (
-                    self._mask_kernel if self.mesh is not None else RECOVER_KERNEL
-                )
-                program = (
-                    "mesh_verify_mask" if self.mesh is not None else "ecdsa_recover"
-                )
+                kernel, program, _route = self._program()
                 # In both forms :meth:`_sig_mask` calls it: host rows, and
                 # ``zw`` a host array (seal lanes) or the digest program's
                 # output (senders); the ledger counts a form the compiled
@@ -405,23 +425,44 @@ class CoalescedDispatcher:
         Returns ``(sender_sig_ok, seal_sig_ok)``; membership is NOT
         included — the scheduler ANDs each lane with its own tenant's
         validator set.
+
+        A flush is two halves, and this is one after the other:
+        :meth:`launch` (route, packs, tables, every kernel call) and
+        :meth:`collect` (the blocking read-backs).  The scheduler's loop
+        calls the halves itself, with the launch of the next flush between
+        them.
         """
+        return self.collect(self.launch(sender_msgs, seal_lanes, pack_caches))
+
+    def launch(
+        self,
+        sender_msgs: Sequence[IbftMessage],
+        seal_lanes: Sequence[Tuple[bytes, CommittedSeal]],
+        pack_caches: Optional[Dict[int, PackCache]] = None,
+    ) -> "_Launched":
+        """The first half of :meth:`dispatch`: everything up to and
+        including the flush's last kernel call (the route, each kind's chunks
+        packed, their tables cut to the loaded rows, the cost-ledger rows),
+        with nothing read back.  The host route has nothing to wait for: it
+        does its work here.  Returns what :meth:`collect` takes, ONCE, on
+        this dispatcher; ``sched.dispatch`` stays open between the two."""
         total = len(sender_msgs) + len(seal_lanes)
         route = self.route
         if route == "auto":
             route = "device" if total >= self.cutover else "host"
-        import time as _time
-
-        t0 = _time.perf_counter()
-        with trace.span(
-            "sched.dispatch",
-            route=route,
-            lanes=total,
-            senders=len(sender_msgs),
-            seals=len(seal_lanes),
-        ):
+        flush = _Launched(
+            total,
+            trace.begin(
+                "sched.dispatch",
+                route=route,
+                lanes=total,
+                senders=len(sender_msgs),
+                seals=len(seal_lanes),
+            ),
+        )
+        try:
             if route == "device":
-                out = self._device(sender_msgs, seal_lanes, pack_caches or {})
+                self._device(sender_msgs, seal_lanes, pack_caches or {}, flush)
             else:
                 # Host flushes pad nothing (occupancy 1.0); the device
                 # route records per kernel launch inside _device where
@@ -433,33 +474,46 @@ class CoalescedDispatcher:
                     padded=total,
                     site="sched/dispatch.py:dispatch",
                 ):
-                    out = self._host(
+                    flush.host = self._host(
                         sender_msgs, seal_lanes, pack_caches or {}
                     )
+        except BaseException as err:
+            flush.span.end(error=type(err).__name__)
+            raise
+        return flush
+
+    def collect(self, flush: "_Launched") -> Tuple[np.ndarray, np.ndarray]:
+        """The second half of :meth:`dispatch`: each launch's mask read back
+        (``verify.device_wait``: the blocking read-back and nothing else),
+        cut to its lanes, a kind's masks joined."""
+        try:
+            if flush.host is not None:
+                out = flush.host
                 self._note_served("host")
-        metrics.observe(DISPATCH_MS_KEY, (_time.perf_counter() - t0) * 1e3)
-        metrics.observe(DISPATCH_LANES_KEY, float(total))
+            else:
+                out = tuple(self._read_back(flush.parts[kind]) for kind in _KINDS)
+        except BaseException as err:
+            flush.span.end(error=type(err).__name__)
+            raise
+        flush.span.end()
+        metrics.observe(DISPATCH_MS_KEY, (time.perf_counter() - flush.t0) * 1e3)
+        metrics.observe(DISPATCH_LANES_KEY, float(flush.total))
         return out
 
     # -- device route ----------------------------------------------------
 
-    def _device(self, msgs, lanes, owners) -> Tuple[np.ndarray, np.ndarray]:
+    def _device(self, msgs, lanes, owners, flush: "_Launched") -> None:
         # A kind's lanes in chunks of the widest program boot loaded: never
         # at a shape that would compile here.
         cap = self._rungs[-1] * (self.dp if self.mesh is not None else 1)
+        for kind, items in zip(_KINDS, (msgs, lanes)):
+            for i in range(0, len(items), cap):
+                self._launch(kind, list(items[i : i + cap]), owners, flush)
 
-        def mask_of(kind: str, items) -> np.ndarray:
-            parts = [
-                self._launch(kind, list(items[i : i + cap]), owners)
-                for i in range(0, len(items), cap)
-            ]
-            return np.concatenate(parts) if parts else np.zeros(0, dtype=bool)
-
-        return mask_of("senders", msgs), mask_of("seal_lanes", lanes)
-
-    def _launch(self, kind: str, items: list, owners) -> np.ndarray:
-        """The signature-validity mask of one kind's lanes (at most the
-        widest rung of them): one pack, one table, one kernel launch.
+    def _launch(self, kind: str, items: list, owners, flush: "_Launched") -> None:
+        """One kind's lanes (at most the widest rung of them) on their way:
+        one pack, one table, one kernel launch, noted in ``flush`` for
+        :meth:`collect` to read back.
 
         Claimed-address table: every live lane's claimed signer is a member
         by construction, so the kernel's (sig & member) mask reduces to
@@ -480,12 +534,9 @@ class CoalescedDispatcher:
         if rows and len(signers) > rows:
             metrics.inc_counter(TABLE_CUTS_KEY)
             half = len(items) // 2
-            return np.concatenate(
-                [
-                    self._launch(kind, items[:half], owners),
-                    self._launch(kind, items[half:], owners),
-                ]
-            )
+            self._launch(kind, items[:half], owners, flush)
+            self._launch(kind, items[half:], owners, flush)
+            return
         with trace.span("verify.pack", kind=kind, lanes=len(items)) as span:
             if senders:
                 # The pack sequence (cache-hit reuse, oversize payloads
@@ -506,21 +557,30 @@ class CoalescedDispatcher:
                 zw, r, s, v, claimed, live = pack_seal_lanes(items, pad_lanes=padded)
             table = self._table(signers, live.shape[0])
             span.note(table_rows=table.shape[0])
-        return self._sig_mask(zw, r, s, v, claimed, table, live)[: len(items)]
+        flush.parts[kind].append(
+            (self._sig_mask(zw, r, s, v, claimed, table, live), len(items))
+        )
 
-    def _sig_mask(self, zw, r, s, v, claimed, table, live) -> np.ndarray:
-        """One signature-validity kernel launch: the sharded mask program
-        over an attached mesh, the single-device recover ladder otherwise
-        (identical argument layout: mesh_batch kept the sharded program a
-        thin shell around the single-chip one)."""
-        sharded = self.mesh is not None
-        kernel = self._mask_kernel if sharded else RECOVER_KERNEL
-        program = "mesh_verify_mask" if sharded else "ecdsa_recover"
-        route = "mesh" if sharded else "device"
+    def _program(self) -> tuple:
+        """What the device route launches: ``(kernel, its name in the cost
+        ledger, its route)``, the sharded mask program over an attached mesh,
+        the single-device recover ladder otherwise."""
+        if self.mesh is not None:
+            return self._mask_kernel, "mesh_verify_mask", "mesh"
+        return RECOVER_KERNEL, "ecdsa_recover", "device"
+
+    def _sig_mask(self, zw, r, s, v, claimed, table, live):
+        """One signature-validity kernel launch, not read back: the sharded
+        mask program over an attached mesh, the single-device recover ladder
+        otherwise (identical argument layout: mesh_batch kept the sharded
+        program a thin shell around the single-chip one)."""
+        kernel, program, route = self._program()
         if self._rung_tables and self._rung_tables.get(live.shape[0]) != table.shape[0]:
             # Boot pinned the shapes and this is none of them: it compiles
             # (or loads) on the flush thread.  Counted where it would happen.
             metrics.inc_counter(UNPINNED_LAUNCHES_KEY)
+        # The call's own wall here, the read-back's in ``_read_back``: the
+        # two together are what the ledger had of a launch read back at once.
         with cost_ledger.dispatch_span(
             program,
             route=route,
@@ -534,14 +594,26 @@ class CoalescedDispatcher:
                 "verify.dispatch",
                 route=route,
                 # the sharded program keeps the scan (ops/pallas_ladder.py)
-                ladder="scan" if sharded else vbatch.ladder_of(live.shape[0]),
+                ladder=(
+                    "scan" if self.mesh is not None else vbatch.ladder_of(live.shape[0])
+                ),
                 table_rows=table.shape[0],
             ):
-                launched = kernel(zw, r, s, v, claimed, table, live)
+                return kernel(zw, r, s, v, claimed, table, live)
+
+    def _read_back(self, parts: list) -> np.ndarray:
+        """One kind's launches read back: each mask on the host
+        (``verify.device_wait``), cut to its lanes, all of them joined."""
+        _kernel, program, route = self._program()
+        masks = []
+        for launched, lanes in parts:
+            t0 = time.perf_counter()
             with trace.span("verify.device_wait", route=route):
                 mask = np.asarray(launched)
-        self._note_served(f"{route}/{mask.shape[0]}")
-        return mask
+            cost_ledger.add_wall_ms(program, route, (time.perf_counter() - t0) * 1e3)
+            self._note_served(f"{route}/{mask.shape[0]}")
+            masks.append(mask[:lanes])
+        return np.concatenate(masks) if masks else np.zeros(0, dtype=bool)
 
     # -- host route ------------------------------------------------------
 

@@ -18,6 +18,14 @@ module lifts the verify data plane to PROCESS scope:
   reaches a full dispatch (bucket-full) or when the OLDEST queued request
   ages past the coalescing window — an idle tenant contributes nothing
   and therefore never stalls a hot one;
+* **two flushes in flight** (ISSUE 55): a flush is the dispatcher's
+  ``launch`` half and its ``collect`` half, and where the next batch is due
+  when a launch returns, the loop coalesces and launches it BEFORE it reads
+  the first one back, then delivers the first while the second runs on the
+  device (:meth:`TenantScheduler._loop`: one
+  :meth:`~go_ibft_tpu.verify.pipeline.VerifyPipeline.stream` a busy
+  period); never a read-tier batch where a consensus tenant is registered,
+  never during a ``reconfigure`` pause, never a third;
 * **deficit-round-robin fairness with a hard starvation bound**: each
   flush serves the globally oldest queued request FIRST (so no request
   waits behind an unbounded stream of younger ones), then fills the
@@ -71,7 +79,7 @@ from ..messages.wire import IbftMessage
 from ..obs import trace
 from ..utils import metrics
 from ..verify.batch import HostBatchVerifier, _BATCH_BUCKETS
-from ..verify.pipeline import PackCache
+from ..verify.pipeline import PackCache, VerifyPipeline
 from .dispatch import (
     CoalescedDispatcher,
     well_formed_seal_lane,
@@ -89,6 +97,7 @@ __all__ = [
     "COALESCED_REQUESTS_KEY",
     "DRAIN_MS_KEY",
     "FLUSH_FAULTS_KEY",
+    "LAUNCHED_AHEAD_KEY",
     "SERVED_LANES_KEY",
 ]
 
@@ -98,6 +107,8 @@ DISPATCHES_KEY = ("go-ibft", "sched", "dispatches")
 COALESCED_REQUESTS_KEY = ("go-ibft", "sched", "coalesced_requests")
 DRAIN_MS_KEY = ("go-ibft", "sched", "drain_ms")
 FLUSH_FAULTS_KEY = ("go-ibft", "sched", "flush_faults")
+# Flushes launched while another was outstanding (its mask not yet read back).
+LAUNCHED_AHEAD_KEY = ("go-ibft", "sched", "launched_ahead")
 # Lanes a flush answered, by the tenant's class: ``SERVED_LANES_KEY +
 # ("<validators>v",)``, the size of the set its last verdicts were held to.
 SERVED_LANES_KEY = ("go-ibft", "sched", "served_lanes")
@@ -274,6 +285,41 @@ def _percentile(samples: Sequence[float], q: float) -> Optional[float]:
     return ordered[idx]
 
 
+class _Flight:
+    """One flush from its selection to its delivery: the requests, what the
+    dispatcher was handed and what it gave back, between the loop's steps."""
+
+    __slots__ = (
+        "batch", "ahead", "track", "dispatcher", "span",
+        "sender_reqs", "seal_reqs", "msgs", "lanes", "owners",
+        "launched", "masks", "error",
+    )
+
+    def __init__(
+        self, batch: List[_Request], ahead: int, track: Optional[str], dispatcher
+    ) -> None:
+        self.batch = batch
+        self.ahead = ahead  # flushes in flight when this one was selected
+        self.track = track  # its timeline row; None: the flush thread's own
+        self.dispatcher = dispatcher  # both halves run on this one
+        self.span = trace.NULL_SPAN
+        self.sender_reqs: List[_Request] = []
+        self.seal_reqs: List[_Request] = []
+        self.msgs: List[IbftMessage] = []
+        self.lanes: List[Tuple[bytes, CommittedSeal]] = []
+        self.owners: Dict[int, PackCache] = {}
+        self.launched = None
+        self.masks = None
+        self.error: Optional[BaseException] = None
+
+    @property
+    def outstanding(self) -> bool:
+        """Whether a launched flush has a read-back still to wait for: not
+        after a fault, and not where the work was done when the launch
+        returned (the host route)."""
+        return self.error is None and not self.launched.ready
+
+
 class TenantScheduler:
     """Coalesces verify lanes from N tenants into shared dispatches.
 
@@ -327,18 +373,21 @@ class TenantScheduler:
         self._pending_lanes = 0
         self._running = False
         self._thread: Optional[threading.Thread] = None
-        # Live-reconfiguration state (ISSUE 16): ``_inflight`` counts
-        # flushes currently executing outside the lock; ``_paused`` stops
-        # the loop from starting new ones while :meth:`reconfigure` drains
-        # and swaps the dispatcher.  Submissions stay open throughout —
-        # queued work just waits out the (one-flush) pause.
-        self._inflight = 0
+        # Live-reconfiguration state (ISSUE 16): ``_inflight`` holds the
+        # flushes between their selection and their delivery, in launch
+        # order (at most two: the loop launches one ahead of the read-back
+        # of another); ``_paused`` stops the loop from starting new ones
+        # while :meth:`reconfigure` drains and swaps the dispatcher.
+        # Submissions stay open throughout — queued work just waits out the
+        # pause (the flushes in flight, two at most).
+        self._inflight: List[_Flight] = []
         self._paused = False
         # Evidence counters (config #10 reads these via stats()).
         self.dispatches = 0
         self.coalesced_requests = 0
         self.coalesced_lanes = 0
         self.flush_faults = 0
+        self.launched_ahead = 0
 
     # -- lifecycle -------------------------------------------------------
 
@@ -433,8 +482,9 @@ class TenantScheduler:
         The replacement dispatcher is built — and, with ``warm_lanes``,
         pre-compiled — BEFORE the flush loop pauses, so every tenant keeps
         draining through the old data plane while the new mesh programs
-        compile; the swap itself waits only for the single in-flight
-        flush.  ``dp`` / ``devices`` re-enter through
+        compile; the swap itself waits only for the flushes in flight (two
+        at most: the loop launches nothing ahead during the pause, and each
+        flush collects on the dispatcher that launched it).  ``dp`` / ``devices`` re-enter through
         :func:`~go_ibft_tpu.parallel.mesh.mesh_context` (a 1-device
         resolution degrades to the single-device kernels); an explicit
         ``dispatcher`` wins over all shape arguments.  Submissions stay
@@ -688,9 +738,51 @@ class TenantScheduler:
         )
         return window
 
+    def _due_in_locked(self) -> float:
+        """Seconds until what is queued is due a flush (<= 0: now).  At once
+        at bucket-full or where no company can come; else when the oldest
+        queued request has aged past the (arrival-calibrated) window.  Idle
+        tenants contribute no requests and thus no delay."""
+        if self._pending_lanes >= self.max_dispatch_lanes:
+            return 0.0
+        if self._no_company_due_locked():
+            return 0.0
+        oldest = self._oldest_ts_locked()
+        if oldest is None:
+            return 0.0
+        return oldest + self._window_locked() - time.monotonic()
+
+    def _may_launch_ahead_locked(self) -> bool:
+        """Whether the next flush may be launched ahead of the read-back of
+        the one in flight, by what the loop can see: never during a pause;
+        only while that one has a mask outstanding (a host-route flush was
+        done when its launch returned: delivering it comes first); only a
+        batch that is due NOW (the loop does not sleep in the window with a
+        mask outstanding); and only one whose first request is of the highest
+        class that has a tenant registered, so a read-tier batch is never in
+        flight beside another and a consensus request that arrives behind a
+        proof flood waits for one flush of it, as before."""
+        if self._paused or self._pending_reqs == 0:
+            return False
+        if not self._inflight[0].outstanding:
+            return False
+        queued = min(t.rank for t in self._tenants.values() if t.queue)
+        if queued != min(t.rank for t in self._tenants.values()):
+            return False
+        # A stopped scheduler drains: everything queued is due.
+        return not self._running or self._due_in_locked() <= 0
+
     def _loop(self) -> None:
+        """The flush thread.  A flush is two halves (the dispatcher's
+        ``launch`` and ``collect``) and at most TWO are in flight: one busy
+        period is one :meth:`VerifyPipeline.stream` over the batches that are
+        due (:meth:`_due_batches`), so flush N + 1 is coalesced and launched
+        before N is read back, and N is delivered (membership, ``req.out``,
+        ``req.done``) while N + 1 runs on the device.  With nothing due
+        behind a launch the stream reads it back at once: one flush at a
+        time, as it was before there were two."""
+        pipeline = VerifyPipeline(depth=2)
         while True:
-            batch: List[_Request] = []
             with self._cv:
                 while self._running and (
                     self._pending_reqs == 0 or self._paused
@@ -698,37 +790,51 @@ class TenantScheduler:
                     self._cv.wait()
                 if self._pending_reqs == 0 and not self._running:
                     return
-                # Demand-aware window: flush at bucket-full, or when the
-                # oldest queued request ages past the (arrival-calibrated)
-                # window.  Idle tenants contribute no requests and thus no
-                # delay.
-                while self._running and not self._paused:
-                    if self._pending_lanes >= self.max_dispatch_lanes:
-                        break
-                    if self._no_company_due_locked():
-                        break
-                    oldest = self._oldest_ts_locked()
-                    if oldest is None:
-                        break
-                    wait = oldest + self._window_locked() - time.monotonic()
-                    if wait <= 0:
-                        break
-                    self._cv.wait(timeout=wait)
-                    if self._pending_reqs == 0:
-                        break
-                if not (self._paused and self._running):
+            for flight in pipeline.stream(
+                self._due_batches(), self._coalesce, self._launch, self._collect
+            ):
+                self._deliver(flight)
+
+    def _due_batches(self):
+        """One busy period's flushes, each selected only when the stream is
+        ready to launch it.  With nothing in flight this is the demand-aware
+        window: flush at bucket-full, or when the oldest queued request ages
+        past the window.  With a flush in flight it never sleeps: the next
+        batch goes ahead if :meth:`_may_launch_ahead_locked` says so, else
+        the period ends and the stream reads back what is outstanding."""
+        while True:
+            with self._cv:
+                if self._inflight:
+                    go = self._may_launch_ahead_locked()
+                else:
+                    while self._running and not self._paused:
+                        wait = self._due_in_locked()
+                        if wait <= 0:
+                            break
+                        self._cv.wait(timeout=wait)
+                        if self._pending_reqs == 0:
+                            break
                     # A running pause (reconfigure draining the dispatcher)
                     # selects nothing; stop() still drains everything.
-                    batch = self._select_locked()
-                    if batch:
-                        self._inflight += 1
-            if batch:
-                try:
-                    self._flush(batch)
-                finally:
-                    with self._cv:
-                        self._inflight -= 1
-                        self._cv.notify_all()
+                    go = not (self._paused and self._running)
+                batch = self._select_locked() if go else []
+                if not batch:
+                    return
+                # Two flushes in flight overlap in time without nesting: a
+                # timeline row each (the thread's own for the one that found
+                # it free), so no span of one reads as a child of the other's.
+                track = None
+                if any(f.track is None for f in self._inflight):
+                    track = threading.current_thread().name + "+1"
+                # ``_inflight`` holds every flush from here to its delivery
+                # and ``reconfigure`` swaps when it is empty: both halves of
+                # a flush run on the dispatcher read here.
+                flight = _Flight(batch, len(self._inflight), track, self._dispatcher)
+                self._inflight.append(flight)
+                self.launched_ahead += flight.ahead
+            if flight.ahead:
+                metrics.inc_counter(LAUNCHED_AHEAD_KEY)
+            yield flight
 
     def _select_locked(self) -> List[_Request]:
         """Pick one dispatch's worth of requests.
@@ -804,31 +910,64 @@ class TenantScheduler:
         metrics.set_gauge(QUEUE_LANES_KEY, float(self._pending_lanes))
         return batch
 
-    def _flush(self, batch: List[_Request]) -> None:
+    def _coalesce(self, flight: "_Flight") -> "_Flight":
+        """A flush's first step: its requests leave their queues' spans, the
+        lanes of all its tenants are gathered by kind, and ``sched.coalesce``
+        opens (``ahead``: 1 where another flush was outstanding); it closes
+        in :meth:`_deliver`."""
+        batch = flight.batch
         for req in batch:
             req.queue_span.end(requests=len(batch))
-        sender_reqs = [r for r in batch if r.kind == "senders"]
-        seal_reqs = [r for r in batch if r.kind == "seals"]
-        msgs: List[IbftMessage] = []
-        owners: Dict[int, PackCache] = {}
-        for req in sender_reqs:
+        flight.sender_reqs = [r for r in batch if r.kind == "senders"]
+        flight.seal_reqs = [r for r in batch if r.kind == "seals"]
+        for req in flight.sender_reqs:
             for m in req.items:
-                owners[id(m)] = req.tenant.pack_cache
-            msgs.extend(req.items)
-        lanes: List[Tuple[bytes, CommittedSeal]] = []
-        for req in seal_reqs:
-            lanes.extend(req.items)
-        with trace.span(
+                flight.owners[id(m)] = req.tenant.pack_cache
+            flight.msgs.extend(req.items)
+        for req in flight.seal_reqs:
+            flight.lanes.extend(req.items)
+        flight.span = trace.begin(
             "sched.coalesce",
+            track=flight.track,
             tenants=len({r.tenant.tid for r in batch}),
             requests=len(batch),
-            lanes=len(msgs) + len(lanes),
-        ):
-            try:
-                sender_ok, seal_ok = self._dispatcher.dispatch(
-                    msgs, lanes, owners
+            lanes=len(flight.msgs) + len(flight.lanes),
+            ahead=flight.ahead,
+        )
+        return flight
+
+    def _launch(self, flight: "_Flight") -> "_Flight":
+        """The dispatcher's launch half.  A fault is kept for
+        :meth:`_deliver`: it fails THIS flush's requests, and the stream goes
+        on to read back the other one in flight."""
+        try:
+            with trace.on_track(flight.track):
+                flight.launched = flight.dispatcher.launch(
+                    flight.msgs, flight.lanes, flight.owners
                 )
-            except Exception as err:  # noqa: BLE001 - hand back, never block
+        except Exception as err:  # noqa: BLE001 - handed back in _deliver
+            flight.error = err
+        return flight
+
+    def _collect(self, flight: "_Flight") -> "_Flight":
+        """The dispatcher's collect half: the blocking read-backs, on the
+        dispatcher that launched the flush."""
+        if flight.error is None:
+            try:
+                with trace.on_track(flight.track):
+                    flight.masks = flight.dispatcher.collect(flight.launched)
+            except Exception as err:  # noqa: BLE001 - handed back in _deliver
+                flight.error = err
+        return flight
+
+    def _deliver(self, flight: "_Flight") -> None:
+        """A flush's last step, with the next one already on the device:
+        each request's membership and verdicts (``sched.complete``), or its
+        fault handed back; then the flush leaves ``_inflight``."""
+        batch = flight.batch
+        lanes = len(flight.msgs) + len(flight.lanes)
+        try:
+            if flight.error is not None:
                 # The scheduler thread resolves NOTHING itself: each
                 # caller's thread falls back to its tenant's host oracle,
                 # so one poisoned flush cannot stall every tenant behind
@@ -836,22 +975,31 @@ class TenantScheduler:
                 self.flush_faults += 1
                 metrics.inc_counter(FLUSH_FAULTS_KEY)
                 for req in batch:
-                    req.error = err
+                    req.error = flight.error
                     req.done.set()
                 return
             self.dispatches += 1
             self.coalesced_requests += len(batch)
-            self.coalesced_lanes += len(msgs) + len(lanes)
+            self.coalesced_lanes += lanes
             metrics.inc_counter(DISPATCHES_KEY)
             metrics.inc_counter(COALESCED_REQUESTS_KEY, len(batch))
+            sender_ok, seal_ok = flight.masks
             with trace.span(
-                "sched.complete", requests=len(batch), lanes=len(msgs) + len(lanes)
+                "sched.complete", track=flight.track, requests=len(batch), lanes=lanes
             ):
-                for reqs, sig_ok in ((sender_reqs, sender_ok), (seal_reqs, seal_ok)):
+                for reqs, sig_ok in (
+                    (flight.sender_reqs, sender_ok),
+                    (flight.seal_reqs, seal_ok),
+                ):
                     off = 0
                     for req in reqs:
                         self._complete(req, sig_ok[off : off + req.lanes])
                         off += req.lanes
+        finally:
+            flight.span.end()
+            with self._cv:
+                self._inflight.remove(flight)
+                self._cv.notify_all()
 
     def _complete(self, req: _Request, sig_ok: np.ndarray) -> None:
         """Apply the tenant's membership check and deliver the verdicts."""
@@ -938,6 +1086,7 @@ class TenantScheduler:
             requests = self.coalesced_requests
             lanes = self.coalesced_lanes
             faults = self.flush_faults
+            ahead = self.launched_ahead
         return {
             "tenants": tenants,
             "dispatches": dispatches,
@@ -947,6 +1096,8 @@ class TenantScheduler:
                 round(requests / dispatches, 3) if dispatches else None
             ),
             "flush_faults": faults,
+            # Flushes launched while another was outstanding.
+            "launched_ahead": ahead,
             # Tests wrap the dispatcher in doubles without describe() /
             # served(); degrade rather than breaking stats().
             "dispatcher": (

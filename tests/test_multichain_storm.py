@@ -14,6 +14,7 @@ is about SHAPES it runs a stub that records what it is launched with.
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -224,6 +225,86 @@ def test_a_block_cut_to_quorum_less_one_fails_that_tenants_catch_up_alone(storm)
     for k in range(3):
         assert [b.height for b in got[k]] == list(range(firsts[k], firsts[k] + BLOCKS))
     assert sched.stats()["flush_faults"] == 0 and _shed_lanes(sched) == 0
+
+
+def test_a_storm_with_a_backlog_behind_every_flush_is_launched_ahead_and_gives_the_same_verdicts(
+    storm,
+):
+    """The look-ahead's own storm (ISSUE 55): every tenant's requests are
+    queued before the first launch (the pause ``reconfigure`` takes), so each
+    8-lane flush of the real recover program has the next one due behind it
+    and the loop keeps two in flight.  Lane for lane the labels' and the
+    reference's masks, the planted cross-chain seals refused; then the same
+    ranges through ``catch_up`` with ONE block of the 10-validator chain cut
+    to quorum - 1: that tenant's call alone raises."""
+    chains, firsts, ranges, want = storm
+    sched = TenantScheduler(window_s=0.002, route="device", max_dispatch_lanes=8)
+    handles = _registered(sched, chains)
+    requests = sum(len(w) // 8 for w in want)
+
+    def backlog(jobs, queued: int):
+        """``jobs`` at once, none flushed until ``queued`` requests wait."""
+        with sched._cv:
+            sched._paused = True
+        out = []
+        runner = threading.Thread(target=lambda: out.extend(_together(jobs)))
+        runner.start()
+        deadline = time.monotonic() + 60
+        while sched._pending_reqs < queued and time.monotonic() < deadline:
+            time.sleep(0.002)
+        with sched._cv:
+            waiting = sched._pending_reqs
+            sched._paused = False
+            sched._cv.notify_all()
+        runner.join(timeout=120)
+        assert not runner.is_alive() and waiting == queued
+        return out
+
+    clients = []
+    for k, chain in enumerate(chains.chains):
+        blocks = list(ranges[k])
+        if k == 3:
+            at = next(i for i, b in enumerate(blocks) if b.height % SPEC["corrupt_every"])
+            blocks[at] = corrupt_block(blocks[at], chain.quorum - 1)
+            cut = blocks[at].height
+        network = LoopbackSyncNetwork()
+        network.register(PEER, _Source(blocks))
+        clients.append(SyncClient(chain.node, network, handles[k], chain.src))
+    with sched:
+        sched.warmup_tenants(dict(zip((f"chain-{k}" for k in range(4)), firsts)))
+        got = backlog(
+            [
+                lambda k=k: handles[k].verify_seal_lanes(seal_lanes(ranges[k]), firsts[k])
+                for k in range(4)
+            ],
+            requests,
+        )
+        masks = sched.stats()
+        caught = backlog(
+            [
+                lambda k=k: clients[k].catch_up(firsts[k], firsts[k] + BLOCKS - 1)
+                for k in range(4)
+            ],
+            requests,
+        )
+    for k, chain in enumerate(chains.chains):
+        np.testing.assert_array_equal(got[k], want[k])
+        np.testing.assert_array_equal(
+            got[k], np.asarray(reference.lane_mask(ranges[k], chain.src), dtype=bool)
+        )
+        for j, (_hash, seal) in enumerate(seal_lanes(ranges[k])):
+            if seal.signer not in chain.powers:
+                assert not got[k][j]
+    assert isinstance(caught[3], SyncError) and f"height {cut}" in str(caught[3])
+    for k in range(3):
+        assert [b.height for b in caught[k]] == list(range(firsts[k], firsts[k] + BLOCKS))
+    # A backlog behind every flush but a storm's last: all but the first of
+    # a busy period go out ahead of a read-back.
+    assert masks["dispatches"] == requests and masks["launched_ahead"] >= requests // 2
+    stats = sched.stats()
+    assert stats["launched_ahead"] > masks["launched_ahead"]
+    assert stats["flush_faults"] == 0 and _shed_lanes(sched) == 0
+    assert set(stats["served"]) == {"device/8"}
 
 
 class _Source:

@@ -257,9 +257,11 @@ def test_backpressure_sheds_to_oracle_without_blocking():
     inner = CoalescedDispatcher(route="host")
 
     class _GatedDispatcher:
-        def dispatch(self, msgs, lanes, owners):
+        def launch(self, msgs, lanes, owners):
             gate.wait(5.0)
-            return inner.dispatch(msgs, lanes, owners)
+            return inner.launch(msgs, lanes, owners)
+
+        collect = inner.collect
 
         def warmup(self, **kw):
             pass

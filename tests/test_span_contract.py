@@ -866,3 +866,77 @@ def test_a_device_route_flush_of_the_scheduler_names_every_part_of_itself(
     assert spans["sched.complete"][3] == {"requests": 1, "lanes": N_VALIDATORS}
     coalesce = spans["sched.coalesce"][3]
     assert (coalesce["tenants"], coalesce["requests"], coalesce["lanes"]) == (1, 1, N_VALIDATORS)
+
+
+def test_two_flushes_in_flight_name_every_part_of_themselves_once_each_on_a_row_each(
+    signed_round, recorder, monkeypatch
+):
+    """Three one-request flushes queued before the first launch (ISSUE 55):
+    the loop launches each ahead of the read-back of the one before.  Every
+    flush still emits each of its six spans once, with the single flush's
+    args plus ``ahead`` on ``sched.coalesce``, nested as the single flush's
+    are; two flushes that overlap in time lie on a row each, so no span of
+    one is a child (same row, inside it) of the other's."""
+    import threading
+    import time
+
+    from go_ibft_tpu.sched import TenantScheduler, dispatch
+
+    monkeypatch.setattr(
+        dispatch, "RECOVER_KERNEL", lambda zw, r, s, v, claimed, table, live: np.asarray(live, dtype=bool)
+    )
+    sched = TenantScheduler(window_s=0.002, route="device", max_dispatch_lanes=N_VALIDATORS)
+    handle = sched.register("only", _validators(signed_round))
+    lanes = [(signed_round.proposal_hash, s) for s in signed_round.seals]
+    masks = []
+    callers = [
+        threading.Thread(
+            target=lambda: masks.append(handle.verify_seal_lanes(lanes, signed_round.height))
+        )
+        for _ in range(3)
+    ]
+    with sched:
+        with sched._cv:  # the pause reconfigure takes: queue, flush nothing
+            sched._paused = True
+        for t in callers:
+            t.start()
+        deadline = time.monotonic() + 10
+        while sched._pending_reqs < 3 and time.monotonic() < deadline:
+            time.sleep(0.002)
+        with sched._cv:
+            sched._paused = False
+            sched._cv.notify_all()
+        for t in callers:
+            t.join(10)
+    assert len(masks) == 3 and all(m.all() for m in masks)
+    assert sched.stats()["launched_ahead"] == 2
+    assert recorder.dropped == 0
+    parts = ("sched.dispatch", "verify.pack", "verify.dispatch", "verify.device_wait", "sched.complete")
+    spans = [
+        (name, track, ts, ts + dur, args)
+        for ph, name, track, ts, dur, args in recorder.snapshot()
+        if ph == "X" and name in parts + ("sched.coalesce",)
+    ]
+    flushes = sorted((s for s in spans if s[0] == "sched.coalesce"), key=lambda s: s[2])
+    assert [f[4]["ahead"] for f in flushes] == [0, 1, 1]
+    assert len(spans) == 3 * (1 + len(parts))
+    for k, (_name, track, t0, t1, args) in enumerate(flushes):
+        assert args == {"tenants": 1, "requests": 1, "lanes": N_VALIDATORS, "ahead": min(k, 1)}
+        inside = [s for s in spans if s[1] == track and t0 <= s[2] and s[3] <= t1 + 1]
+        mine = {s[0]: s for s in inside}
+        # Its own six on its row inside it, and nothing of another flush.
+        assert len(inside) == 6 and set(mine) == set(parts) | {"sched.coalesce"}, (k, inside)
+        d0, d1 = mine["sched.dispatch"][2:4]
+        for child in ("verify.pack", "verify.dispatch", "verify.device_wait"):
+            assert d0 <= mine[child][2] and mine[child][3] <= d1 + 1, child
+        order = ["verify.pack", "verify.dispatch", "verify.device_wait", "sched.complete"]
+        assert [mine[n][2] for n in order] == sorted(mine[n][2] for n in order)
+        assert mine["verify.pack"][4] == {"kind": "seal_lanes", "lanes": N_VALIDATORS, "table_rows": 128}
+        assert mine["verify.dispatch"][4]["table_rows"] == 128
+        assert mine["verify.device_wait"][4] == {"route": "device"}
+        assert mine["sched.complete"][4] == {"requests": 1, "lanes": N_VALIDATORS}
+    for (_n, track, t0, t1, _a), (_n2, track2, u0, _u1, _a2) in zip(flushes, flushes[1:]):
+        assert t0 < u0 < t1  # the next was launched with this one in flight
+        assert track != track2
+    # No third in flight: flush 3 is launched after flush 1 is delivered.
+    assert flushes[2][2] >= flushes[0][3] - 1
